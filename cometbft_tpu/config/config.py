@@ -13,10 +13,7 @@ surface is flat sections of scalars/lists, which TOML expresses exactly).
 from __future__ import annotations
 
 import os
-try:
-    import tomllib
-except ImportError:  # Python < 3.11: tomli is API-compatible
-    import tomli as tomllib
+import tomllib
 from dataclasses import dataclass, field, fields as dc_fields, is_dataclass, asdict
 from typing import Optional
 

@@ -78,11 +78,26 @@ def default_backend() -> str:
                 import jax
 
                 platform = jax.devices()[0].platform
-                if platform == "cpu":
-                    _DEFAULT_BACKEND = "cpu"
-                else:
-                    _DEFAULT_BACKEND = "tpu" if _tpu_self_check() else "cpu"
             except Exception:
+                # no usable JAX backend at all: say so, a node that was
+                # meant to run on a chip must not land here in silence
+                logging.getLogger("cometbft_tpu.crypto").exception(
+                    "JAX backend initialisation failed — crypto backend "
+                    "is 'cpu'"
+                )
+                platform = "cpu"
+            if platform == "cpu":
+                _DEFAULT_BACKEND = "cpu"
+            elif _tpu_self_check():
+                _DEFAULT_BACKEND = "tpu"
+            else:
+                # _tpu_self_check logged the cause (wrong verdicts or the
+                # exception) at error
+                logging.getLogger("cometbft_tpu.crypto").error(
+                    "a %s device is visible but its verify path failed "
+                    "the self-check — crypto backend is 'cpu'",
+                    platform,
+                )
                 _DEFAULT_BACKEND = "cpu"
         return _DEFAULT_BACKEND
 

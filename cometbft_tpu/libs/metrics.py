@@ -595,7 +595,7 @@ class NodeMetrics:
         # chip availability (ops/device_health): probe results folded from
         # the in-process prober or the chip watcher's status file —
         # VERDICT r5's outage becomes one gauge flip, not a grep of
-        # chipwatch.log.  jax-free by design.
+        # an ad-hoc log.  jax-free by design.
         def _device(key):
             def read():
                 from cometbft_tpu.ops import device_health

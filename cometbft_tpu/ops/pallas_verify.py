@@ -32,9 +32,8 @@ from jax.experimental.pallas import tpu as pltpu
 from cometbft_tpu.ops import fe25519 as fe
 from cometbft_tpu.ops import ed25519_point as ep
 
-# Lanes per grid step.  Measured on a v5e chip: 256 lanes is the sweet
-# spot (172k verifies/s @ 8192; 512 lanes halves throughput — the larger
-# working set spills VMEM).  ~1.3 MB of live field elements per step.
+# Lanes per grid step: ~1.3 MB of live field elements per step.  Which tile
+# is fastest is not measured on the attached chip.
 TILE = 256
 
 

@@ -10,6 +10,9 @@ tier-1 conftest prints a one-line summary that
 Counters (all guarded by one lock):
   * ``compiles`` / ``compile_seconds``   — executables built by tracing +
     XLA compilation (the cost warm-boot exists to amortize)
+  * ``compile_failures``                 — lowerings/compiles the device's
+    compiler refused (``ops.verify.bucket_executable``; each is logged at
+    error and demotes its tier)
   * ``exec_hits`` / ``exec_load_seconds`` — executables deserialized from
     the on-disk cache (no tracing, no compilation)
   * ``exec_misses``                      — cache probes that found nothing
@@ -37,6 +40,7 @@ def _zero() -> dict:
     return {
         "compiles": 0,
         "compile_seconds": 0.0,
+        "compile_failures": 0,
         "exec_hits": 0,
         "exec_load_seconds": 0.0,
         "exec_misses": 0,
@@ -60,6 +64,11 @@ def record_compile(seconds: float) -> None:
     with _LOCK:
         _STATS["compiles"] += 1
         _STATS["compile_seconds"] += float(seconds)
+
+
+def record_compile_failure() -> None:
+    with _LOCK:
+        _STATS["compile_failures"] += 1
 
 
 def record_hit(load_seconds: float) -> None:

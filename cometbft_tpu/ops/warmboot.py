@@ -16,9 +16,11 @@ Supervisor-aware by design:
   into warm executables, not into a compile);
 * a tier whose breaker is OPEN is skipped (warming a dead device is probe
   traffic the breaker exists to prevent);
-* a COMPILE failure records a breaker failure for that tier and moves on —
-  boot is never wedged, and the failure surfaces through the exact same
-  demotion machinery a dispatch failure would use.
+* a COMPILE failure is logged at error with the compiler's message,
+  counted (``warm_failures``, and ``compile_failures`` for the verify
+  tiers) and records a breaker failure for that tier; the pass moves on —
+  boot is never wedged, and the tier demotes through the same machinery a
+  dispatch failure would use.
 
 Enablement: ``COMETBFT_TPU_WARMBOOT=1/0`` overrides; the default is ON
 exactly when the trusted ``tpu`` batch backend is active (the gate the
@@ -266,21 +268,8 @@ def _run_matrices(reg, statuses: dict, dead: set, t0: float) -> dict:
                 else str(info.get("exec_cache", "?"))
             )
             statuses[key] = status
-            if status.startswith("broken:"):
-                # bucket_executable swallows compile/lowering failures
-                # into a fresh "broken:*" status (a dispatch must never
-                # die on cache plumbing) — the warm pass is where they
-                # become breaker failures, so the tier demotes through
-                # the same machinery a dispatch failure would use.  The
-                # breaker self-heals: if the tier's plain-jit dispatch is
-                # actually healthy (only the AOT layer failed), the next
-                # HALF_OPEN probe re-promotes it.
-                raise RuntimeError(f"warm compile failed: {status}")
-            if status in ("disabled", "broken-impl"):
-                # nothing was actually precompiled: AOT off, or the impl
-                # latched broken by an EARLIER pass/dispatch — the breaker
-                # failure was recorded then; re-recording one per pass
-                # would walk a healthy-dispatch tier's breaker open
+            if status == "disabled":
+                # AOT off: nothing was actually precompiled
                 continue
             warmed += 1
         except Exception as e:  # noqa: BLE001 — a compile failure demotes
@@ -290,7 +279,7 @@ def _run_matrices(reg, statuses: dict, dead: set, t0: float) -> dict:
             statuses.setdefault(key, f"error:{type(e).__name__}")
             reg.breaker(backend).record_failure(e)
             reg.record_demotion(backend)
-            logger.warning(
+            logger.error(
                 "warm-boot: compiling %s failed (%r); tier demoted via "
                 "breaker, continuing with the next tier",
                 key,

@@ -26,19 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.6 keeps it in experimental, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_legacy(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_rep=check_vma,
-        )
+from jax import shard_map
 
 from cometbft_tpu.libs import tracing
 from cometbft_tpu.ops import dispatch_stats
@@ -204,9 +192,11 @@ def sharded_verify_call(
     deserialized from disk when a previous process compiled this
     (impl, topology, lanes, donated) shape (the multichip dry-run's
     10240-sig commit no longer re-lowers on every invocation) — and
-    memoized per process.  Falls back to the plain jitted path when AOT
-    lowering or the plugin's serialization can't handle the sharded
-    computation.  ``donated`` defaults to the single-chip donation policy
+    memoized per process.  A lowering or compile failure is loud
+    (``ops.verify.compile_failed``: logged at error, counted, latched) and
+    raised: the elastic supervisor drops the batch to the single-chip
+    chain, and nothing retries the compile quietly through plain jit.
+    ``donated`` defaults to the single-chip donation policy
     (``ops.verify.donation_enabled`` — Pallas/TPU on, CPU CI off)."""
     impl = impl or ov.select_impl(mesh.devices.flat)
     if donated is None:
@@ -221,6 +211,7 @@ def sharded_verify_call(
     jitted, _ = sharded_verify_fn(mesh, impl, donated=donated)
     if not ov.aot_enabled():
         return jitted, {"exec_cache": "disabled"}
+    ov.raise_if_broken(("mesh",) + key)
     from cometbft_tpu.ops import aot_cache
 
     batch_first, vec = mesh_shardings(mesh)
@@ -236,12 +227,12 @@ def sharded_verify_call(
         call, info = aot_cache.load_or_compile(
             jitted, specs, mesh_tag(impl, n_dev, lanes, donated)
         )
-    except Exception as e:  # noqa: BLE001 — sharded AOT unsupported here:
-        # the jitted path compiles lazily exactly as before; memoize the
-        # fallback too, so every later call doesn't repeat the doomed
-        # (and possibly expensive) lowering attempt
-        _CALL_CACHE[key] = jitted
-        return jitted, {"exec_cache": f"broken:{type(e).__name__}"}
+    except Exception as e:  # noqa: BLE001 — whatever the compiler raised
+        raise ov.compile_failed(
+            ("mesh",) + key,
+            f"mesh verify {mesh_tag(impl, n_dev, lanes, donated)}",
+            e,
+        ) from e
     _CALL_CACHE[key] = call
     return call, info
 

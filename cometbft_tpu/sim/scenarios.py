@@ -496,6 +496,13 @@ def _backend_faults_setup(extra_env: Optional[dict] = None):
             )
         else:
             supervisor.set_device_runner(_sim_device_runner)
+            # the hash plane rides the same trusted-backend gate: a block
+            # of >= 32 txs (tx-flood's bursts) would otherwise hash its
+            # data on the real XLA tree kernel, the one thing in a sim run
+            # that is neither virtual-time nor a stand-in
+            from cometbft_tpu.ops import sha256_tree
+
+            sha256_tree.set_tree_runner(sha256_tree.host_tree_runner)
         for k, v in (extra_env or {}).items():
             os.environ[k] = v
         # reset AFTER the env overrides so scenario breakers pick up the
@@ -526,6 +533,9 @@ def _backend_faults_teardown(cluster: SimCluster) -> None:
     verifysched.stats.reset()
     supervisor.clear_fault_injector()
     supervisor.clear_device_runner()
+    from cometbft_tpu.ops import sha256_tree
+
+    sha256_tree.clear_tree_runner()
     saved_env, saved_backend = getattr(cluster, "_backend_saved", ({}, None))
     for k in _BACKEND_ENV_KNOBS:
         v = saved_env.get(k)

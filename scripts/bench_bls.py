@@ -11,7 +11,7 @@ Stages (each stands alone so a hang leaves the completed ones on stdout):
   * G1 batch scalar-mul on the device (ops/bls_g1) vs host, when a
     non-CPU platform is up — the TPU piece of the RLC path
 
-CPU smoke: COMETBFT_TPU_JAX_PLATFORM=cpu python scripts/bench_bls.py
+CPU smoke: JAX_PLATFORMS=cpu python scripts/bench_bls.py
 (device stage reports platform=cpu and skips the kernel).
 """
 
@@ -43,12 +43,9 @@ def _fixture(n):
 
 
 def main() -> None:
-    import jax
+    from cometbft_tpu.libs import cachedir
 
-    plat = os.environ.get("COMETBFT_TPU_JAX_PLATFORM")
-    if plat:
-        jax.config.update("jax_platforms", plat)
-
+    cachedir.enable()
     from cometbft_tpu.crypto import batch as cbatch
     from cometbft_tpu.crypto import bls12381 as bls
 

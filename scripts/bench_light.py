@@ -11,7 +11,7 @@ commit verification routes through ``crypto.batch`` (the TPU seam), so
 the measured number is the consensus-verify path end to end: sign-bytes
 reconstruction, batch packing, device ladder, tally.
 
-Standalone: COMETBFT_TPU_JAX_PLATFORM=cpu python scripts/bench_light.py
+Standalone: JAX_PLATFORMS=cpu python scripts/bench_light.py
 Knobs: BENCH_LIGHT_VALS (default 1000), BENCH_LIGHT_HEIGHTS (default 4).
 Also callable from bench.py's staged TPU worker via ``run(emit)``.
 """
@@ -159,11 +159,9 @@ def run(emit, n_vals: int | None = None, heights: int | None = None) -> dict:
 
 
 def main() -> None:
-    import jax
+    from cometbft_tpu.libs import cachedir
 
-    plat = os.environ.get("COMETBFT_TPU_JAX_PLATFORM")
-    if plat:
-        jax.config.update("jax_platforms", plat)
+    cachedir.enable()
     run(lambda rec: print(json.dumps(rec), flush=True))
 
 

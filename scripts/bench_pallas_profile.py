@@ -11,7 +11,9 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/root/.cache/jax")
+from cometbft_tpu.libs import cachedir  # noqa: E402
+
+cachedir.enable()  # one cache root, named before jax is imported
 
 import numpy as np
 import jax

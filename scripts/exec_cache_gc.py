@@ -44,7 +44,7 @@ def main() -> int:
     ap.add_argument(
         "--dir",
         default=None,
-        help="cache dir (default: COMETBFT_TPU_EXEC_CACHE or ~/.cache)",
+        help="cache dir (default: the executable cache of libs/cachedir)",
     )
     ap.add_argument(
         "--ttl-days",

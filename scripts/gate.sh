@@ -5,7 +5,8 @@
 # this 2-minute gate would have caught every one of them (VERDICT.md r2 #3).
 #
 #   1. full pytest suite (CPU, virtual 8-device mesh via tests/conftest.py)
-#   2. bench.py exits 0 and prints a JSON line (any JAX platform)
+#   2. bench.py: on a TPU exits 0 with the headline line; with no TPU it
+#      must refuse — exit 1, last line "ok": false, no throughput
 #   3. dryrun_multichip(8) on a forced 8-device CPU mesh
 #
 # NIGHTLY=1 additionally runs the slow lane: the -m slow pytest marks
@@ -15,11 +16,10 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-# Shared AOT executable cache (docs/warm-boot.md): repo-local so every
-# gate stage — pytest (and the node subprocesses it spawns), bench, the
-# multichip dry-run — loads executables the previous stage or a previous
-# gate run compiled, instead of re-tracing per process.
-export COMETBFT_TPU_EXEC_CACHE="${COMETBFT_TPU_EXEC_CACHE:-$PWD/.exec_cache}"
+# One cache root (libs/cachedir): every gate stage — pytest (and the node
+# subprocesses it spawns), bench, the multichip dry-run — keeps JAX's
+# compile cache and the AOT executables (docs/warm-boot.md) under
+# JAX_COMPILATION_CACHE_DIR, or .cache/ in the checkout when it is unset.
 
 echo "== gate 1/13: verify/hash/aead call-site + disk-policy lints =="
 python scripts/check_verify_callsites.py
@@ -35,8 +35,13 @@ rm -f /tmp/_gate_t1.log
 python -m pytest tests/ -x -q --durations=40 2>&1 | tee /tmp/_gate_t1.log
 python scripts/check_tier1_budget.py /tmp/_gate_t1.log
 
-echo "== gate 3/13: bench.py =="
-python bench.py
+echo "== gate 3/13: bench.py (TPU: measures; no TPU: must refuse) =="
+out=$(python bench.py) && rc=0 || rc=$?
+echo "$out" | tail -n 2
+if [ "$rc" -ne 0 ]; then
+    echo "$out" | tail -n 1 | grep -q '"ok": false'
+    ! echo "$out" | grep -q '"unit": "verifies/s"'
+fi
 
 echo "== gate 4/13: bench.py --meshfault (elastic mesh fault isolation) =="
 # healthy vs one-dead-chip dispatch on the per-shard host-oracle seam:

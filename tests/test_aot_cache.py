@@ -200,22 +200,28 @@ class TestFingerprint:
         src.write_text("VERSION = 1\n")  # original sources: warm again
         assert aot_cache.load("t-src")[1]["exec_cache"] == "hit"
 
-    def test_trace_env_flip_invalidates(self, tmp_cache, monkeypatch):
-        monkeypatch.delenv("COMETBFT_TPU_MERGED_DECOMPRESS", raising=False)
-        aot_cache.load_or_compile(_JIT, (_arg(),), "t-env")
-        assert aot_cache.load("t-env")[1]["exec_cache"] == "hit"
-        monkeypatch.setenv("COMETBFT_TPU_MERGED_DECOMPRESS", "0")
-        assert aot_cache.load("t-env")[1]["exec_cache"] == "miss"
-        monkeypatch.delenv("COMETBFT_TPU_MERGED_DECOMPRESS")
-        assert aot_cache.load("t-env")[1]["exec_cache"] == "hit"
+    def test_platform_version_change_invalidates(self, tmp_cache, monkeypatch):
+        """An executable serialized by another backend build (another
+        libtpu) must read as a miss, not be handed to a loader that
+        refuses it."""
+        aot_cache.load_or_compile(_JIT, (_arg(),), "t-pv")
+        assert aot_cache.load("t-pv")[1]["exec_cache"] == "hit"
+        real = aot_cache._platform_version()
+        monkeypatch.setattr(
+            aot_cache, "_platform_version", lambda: real + "+other-build"
+        )
+        assert aot_cache.load("t-pv")[1]["exec_cache"] == "miss"
+        assert not aot_cache.has("t-pv")
+        monkeypatch.setattr(aot_cache, "_platform_version", lambda: real)
+        assert aot_cache.load("t-pv")[1]["exec_cache"] == "hit"
 
-    def test_compile_env_flip_invalidates(self, tmp_cache, monkeypatch):
-        """A topology change (XLA_FLAGS) must not share executables."""
+    @pytest.mark.parametrize("var", ["XLA_FLAGS", "LIBTPU_INIT_ARGS"])
+    def test_compile_env_flip_invalidates(self, tmp_cache, monkeypatch, var):
+        """A topology or compiler-flag change must not share executables."""
         aot_cache.load_or_compile(_JIT, (_arg(),), "t-xla")
         assert aot_cache.load("t-xla")[1]["exec_cache"] == "hit"
         monkeypatch.setenv(
-            "XLA_FLAGS",
-            os.environ.get("XLA_FLAGS", "") + " --xla_cpu_fake_flag",
+            var, os.environ.get(var, "") + " --xla_cpu_fake_flag"
         )
         assert aot_cache.load("t-xla")[1]["exec_cache"] == "miss"
 

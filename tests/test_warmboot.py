@@ -118,25 +118,33 @@ class TestRun:
         br = backend_health.registry().breaker("xla")
         assert br.stats()["consecutive_failures"] >= 1
 
-    def test_broken_status_demotes_via_breaker(self, monkeypatch):
-        """bucket_executable swallows compile failures into a "broken:*"
-        status (a dispatch must never die on cache plumbing) — the warm
-        pass must read that status as a COMPILE FAILURE: breaker failure +
-        demotion + remaining tier shapes skipped, not warmed += 1."""
+    def test_compile_failure_is_loud_and_latched(self, monkeypatch, caplog):
+        """bucket_executable does not absorb a compile failure into a
+        quiet retry through plain jit: it logs the compiler's message at
+        error, counts it, latches the shape and raises — and the latched
+        shape raises again without a second compile."""
+        from cometbft_tpu.ops import aot_cache
 
-        def fake_exec(backend, bucket, donated=None):
-            return (lambda **kw: None), {"exec_cache": "broken:RuntimeError"}
+        calls = []
 
-        monkeypatch.setattr(ov, "bucket_executable", fake_exec)
-        monkeypatch.setenv("COMETBFT_TPU_WARMBOOT_BUCKETS", "32,64")
-        d0 = backend_health.snapshot()["demotions"]
-        report = warmboot.run()
-        assert report["failures"] == 1 and report["warmed"] == 0
-        assert report["statuses"]["xla-32"] == "broken:RuntimeError"
-        assert report["statuses"]["xla-64"] == "skipped:tier-demoted"
-        assert backend_health.snapshot()["demotions"] == d0 + 1
-        br = backend_health.registry().breaker("xla")
-        assert br.stats()["consecutive_failures"] >= 1
+        def refuse(jitted, shapes, tag):
+            calls.append(tag)
+            raise RuntimeError("Mosaic failed to compile TPU kernel: forced")
+
+        monkeypatch.setattr(aot_cache, "load_or_compile", refuse)
+        ov.reset_executable_memo()
+        c0 = warm_stats.snapshot()["compile_failures"]
+        with caplog.at_level("ERROR", logger="cometbft_tpu.crypto"):
+            with pytest.raises(ov.TierCompileError, match="Mosaic failed"):
+                ov.bucket_executable("pallas", 128, donated=True)
+        assert any("Mosaic failed" in r.getMessage() for r in caplog.records)
+        assert warm_stats.snapshot()["compile_failures"] == c0 + 1
+        assert ("pallas", 128, True) in ov._AOT_BROKEN
+        with pytest.raises(ov.TierCompileError):
+            ov.bucket_executable("pallas", 128, donated=True)
+        assert calls == ["verify-pallas-128-donated"]
+        ov.reset_executable_memo()
+        assert not ov._AOT_BROKEN
 
     def test_disabled_status_not_counted_warm(self, monkeypatch):
         """COMETBFT_TPU_AOT=0 returns plain jit: nothing was precompiled,
