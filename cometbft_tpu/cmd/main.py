@@ -423,9 +423,11 @@ def cmd_trace(args) -> int:
                 % (
                     stage,
                     row["count"],
-                    row["p50_ms"],
-                    row["p99_ms"],
-                    row["max_ms"],
+                    # None: every span of the stage has left the ring
+                    *(
+                        float("nan") if row[k] is None else row[k]
+                        for k in ("p50_ms", "p99_ms", "max_ms")
+                    ),
                 )
             )
     rounds = doc.get("rounds") or {}

@@ -144,6 +144,9 @@ class _CollectingVerifier(BatchVerifier):
         self.pubs: list[bytes] = []
         self.msgs: list[bytes] = []
         self.sigs: list[bytes] = []
+        # entries of the last verify() that needed no backend: cache hits and
+        # structural rejects (the ``hits`` of the caller's batch.verify span)
+        self.cache_hits = 0
 
     def add(self, pub_key, msg: bytes, sig: bytes) -> None:
         data = pub_key.bytes() if hasattr(pub_key, "bytes") else bytes(pub_key)
@@ -167,6 +170,7 @@ class _CollectingVerifier(BatchVerifier):
         bits, pending = sigcache.partition_misses(
             self.pubs, self.msgs, self.sigs, self.PUB_SIZES, self.SIG_SIZES
         )
+        self.cache_hits = len(self.pubs) - len(pending)
         if pending:
             # Attribution contract: ``_verify_pending`` returns DEFINITIVE
             # verdicts only.  An infrastructure failure must either raise

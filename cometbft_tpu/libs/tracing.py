@@ -12,23 +12,31 @@ submit/queue-wait/flush → ``ops/verify`` bucket dispatch → supervisor tier
 plus the consensus/blocksync/light spans above it.
 
 Span model: ``(trace_id, span_id, parent_id, stage, t_start, t_end,
-attrs)``.  Trace propagation is ambient (a thread-local stack): a span
-opened while another is live becomes its child and inherits its trace id,
-so a commit verification's device dispatches attribute to the commit
-without any API threading.  The clock is injectable (``set_clock``) so
-the deterministic simulator traces on its VirtualClock and two same-seed
-runs produce byte-identical span streams.
+attrs)``.  Trace propagation is ambient on one thread (a thread-local
+stack): a span opened while another is live becomes its child and inherits
+its trace id.  ACROSS threads the parent travels with the work: the
+submitter takes ``current()`` once a segment, the scheduler keeps it on
+the queued items, and the dispatcher and completion threads open their
+spans with ``span(stage, parent=...)`` — so one commit verification is ONE
+tree over the caller, dispatcher and fetch threads.  The clock is
+injectable (``set_clock``) so the deterministic simulator traces on its
+VirtualClock and two same-seed runs produce byte-identical span streams.
 
 Stage taxonomy (dotted, coarse on the hot path — one span per batch or
 per dispatch, never per signature):
 
   * ``txingest.flush`` / ``txingest.shed_sync``  — batched tx admission
-  * ``sched.flush`` / ``sched.shed_fallback``    — verify scheduler
-  * ``verify.commit``                            — commit verification
-    (consensus apply, blocksync frontier, light client)
-  * ``verify.batch`` / ``verify.dispatch``       — bucket dispatch (the
-    dispatch span carries bucket lanes + tier + dispatch seq: the triple
-    an anomaly dump attributes a watchdog fire to)
+  * ``verify.commit`` > ``commit.sign_bytes``, ``batch.verify`` >
+    ``sched.segment`` > ``sched.submit``, ``sched.wait`` — the caller
+    thread of one commit verification, public entry to verdict
+  * ``sched.flush`` > ``sched.slot_wait``, ``sched.dispatch`` — the
+    dispatcher thread (``sched.flush`` lists the ``traces`` it serves);
+    ``sched.fetch``, ``sched.resolve`` — the completion thread, children
+    of the flush; ``sched.shed_fallback``
+  * ``verify.pack`` / ``verify.batch`` / ``verify.dispatch`` >
+    ``verify.launch`` / ``verify.fetch`` — bucket dispatch (the dispatch
+    span carries bucket lanes + tier + dispatch seq: the triple an anomaly
+    dump attributes a watchdog fire to)
   * ``supervisor.host_fallback`` / ``supervisor.bisect``
   * ``consensus.vote`` / ``consensus.proposal`` / ``consensus.vote_ext``
     (per height-round)
@@ -43,6 +51,21 @@ writes the last ``COMETBFT_TPU_TRACE_DUMP_SPANS`` (256) spans as JSONL to
 ``COMETBFT_TPU_TRACE_DIR`` for postmortem.  The dump's first line names
 the anomaly and its attributes; dump bytes are a pure function of the
 span stream, so a sim scenario's dump replays byte-identically per seed.
+
+Stage totals: the ring is the tail of WHOLE spans for an anomaly dump and
+wraps in seconds under load.  Beside it every stage keeps a count and a
+sum of durations by the whole second in which its spans ended (the last
+``TOTALS_KEEP_S`` seconds, never dropping a span): ``stage_totals(t0, t1)``
+reads them over an interval after the fact, and ``stage_summary`` and the
+``/debug/verify_trace`` document read the same store.
+
+Profiler bridge: while a ``with`` span is open the tracer also holds a
+``jax.profiler.TraceAnnotation`` named ``tpubft/<stage>``, so a device
+trace taken over the same interval has every program span on the clock
+of ``XLA Ops``.  The class is taken from ``sys.modules`` when jax is
+already loaded; this module never imports it.  Work that runs on a
+watchdog thread is timed there with ``lap`` (which enters the annotation)
+and recorded by the calling thread.
 
 Kill switch: ``COMETBFT_TPU_TRACE=0`` compiles spans down to no-ops (a
 shared null context manager; one env read per span site) — bench.py
@@ -75,6 +98,7 @@ from __future__ import annotations
 import json
 import logging
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -84,6 +108,9 @@ logger = logging.getLogger("cometbft_tpu.tracing")
 
 DEFAULT_RING = 4096
 DEFAULT_DUMP_SPANS = 256
+# whole seconds of per-stage totals kept beside the ring
+TOTALS_KEEP_S = 300
+ANNOTATION_PREFIX = "tpubft/"
 # anomaly kinds with a dump trigger (docs/observability.md).  Breaker
 # opens are per-taxonomy-kind: the ed25519 device tiers share
 # "breaker_open", while the single-tier secp256k1/BLS breakers get their
@@ -171,6 +198,27 @@ def xnode_enabled() -> bool:
         enabled()
         and os.environ.get("COMETBFT_TPU_TRACE_XNODE", "1") != "0"
     )
+
+
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _annotate(stage: str):
+    """An ENTERED profiler annotation ``tpubft/<stage>`` on this thread, or
+    None while jax is not loaded.  Costs an atomic load when no profiler
+    is open.  Never imports jax: the forensic surfaces that read this
+    module must not be what initializes a backend."""
+    global _ANNOTATION
+    cls = _ANNOTATION
+    if cls is None:
+        jax = sys.modules.get("jax")
+        cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+        if cls is None:
+            return None
+        _ANNOTATION = cls
+    ann = cls(ANNOTATION_PREFIX + stage)
+    ann.__enter__()
+    return ann
 
 
 class TraceContext:
@@ -292,20 +340,24 @@ _NULL_SPAN = _NullSpan()
 
 
 class _SpanCtx:
-    __slots__ = ("tracer", "sp")
+    __slots__ = ("tracer", "sp", "ann")
 
     def __init__(self, tracer: "Tracer", sp: Span):
         self.tracer = tracer
         self.sp = sp
+        self.ann = None
 
     def __enter__(self) -> Span:
         stack = self.tracer._stack()
         stack.append(self.sp)
+        self.ann = _annotate(self.sp.stage)
         return self.sp
 
     def __exit__(self, etype, evalue, tb) -> bool:
         sp = self.sp
         sp.t_end = self.tracer._clock()
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
         if etype is not None:
             sp.attrs.setdefault("error", etype.__name__)
         stack = self.tracer._stack()
@@ -315,6 +367,46 @@ class _SpanCtx:
             stack.remove(sp)
         self.tracer._append(sp)
         return False
+
+
+class Lap:
+    """A stage timed where it runs and recorded later, by the thread that
+    may write the ring: ``with tracing.lap(stage) as lp: ...`` reads the
+    tracer's clock at both ends (and holds the profiler annotation, on
+    THIS thread); ``lp.record(parent=..., **attrs)`` then lands it as a
+    completed span.  Two users: a closure on a watchdog thread (an
+    abandoned worker must never race a span into the ring, so the caller
+    records after ``watchdog_call`` returns, and a lap that never closed
+    records nothing), and the caller's submit and wait stages, which end
+    while the dispatcher is writing (recording both after the wait keeps
+    the ring's order a function of the work, not of thread timing)."""
+
+    __slots__ = ("tracer", "stage", "t0", "t1", "ann")
+
+    def __init__(self, tracer: "Optional[Tracer]", stage: str):
+        self.tracer = tracer  # None: the recorder is off
+        self.stage = stage
+        self.t0 = self.t1 = self.ann = None
+
+    def __enter__(self) -> "Lap":
+        if self.tracer is not None:
+            self.ann = _annotate(self.stage)
+            self.t0 = self.tracer._clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self.tracer is not None:
+            self.t1 = self.tracer._clock()
+            if self.ann is not None:
+                self.ann.__exit__(None, None, None)
+        return False
+
+    def record(self, parent=None, **attrs) -> "Optional[Span]":
+        if self.t1 is None:
+            return None
+        return self.tracer.record_span(
+            self.stage, self.t0, self.t1, parent=parent, **attrs
+        )
 
 
 class _UnderCtx:
@@ -377,6 +469,8 @@ class Tracer:
         self._dump_seq = 0
         self._dumps: "list[str]" = []
         self._overhead_s = 0.0
+        # second in which a span ended -> {stage: [count, seconds]}
+        self._totals: dict = {}
         # process-LIFETIME aggregates: reset() (sim per-run hygiene) does
         # not clear these, so the tier1-trace summary line still reports
         # the whole test run's span volume and recorder overhead
@@ -394,17 +488,21 @@ class Tracer:
             stack = self._tls.stack = []
         return stack
 
-    def span(self, stage: str, **attrs):
+    def span(self, stage: str, parent=None, **attrs):
         """Context manager recording one stage interval.  Nested spans
         (same thread) become children; the root span's id is the trace id.
-        Disabled tracer → the shared no-op span."""
+        ``parent`` (a span or context taken on ANOTHER thread with
+        ``current()``) overrides the ambient one: how a flush joins the
+        trace of the request it serves.  Disabled tracer → the shared
+        no-op span."""
         if not enabled():
             return _NULL_SPAN
         with self._lock:
             sid = self._next_id
             self._next_id += 1
-        stack = self._stack()
-        parent = stack[-1] if stack else None
+        if parent is None or parent is _NULL_SPAN:
+            stack = self._stack()
+            parent = stack[-1] if stack else None
         sp = Span(
             trace_id=parent.trace_id if parent is not None else sid,
             span_id=sid,
@@ -418,6 +516,15 @@ class Tracer:
     def current_trace(self) -> Optional[int]:
         stack = getattr(self._tls, "stack", None)
         return stack[-1].trace_id if stack else None
+
+    def current(self) -> Optional[Span]:
+        """This thread's innermost open span: what work handed to another
+        thread carries along as its ``parent``."""
+        stack = getattr(self._tls, "stack", None)
+        return stack[-1] if stack else None
+
+    def lap(self, stage: str) -> Lap:
+        return Lap(self if enabled() else None, stage)
 
     def time(self) -> float:
         """The tracer's clock (virtual in sim).  Event-driven callers use
@@ -513,7 +620,7 @@ class Tracer:
         with self._lock:
             sid = self._next_id
             self._next_id += 1
-        if parent is not None:
+        if parent is not None and parent is not _NULL_SPAN:
             trace_id, parent_id = parent.trace_id, parent.span_id
         else:
             trace_id, parent_id = sid, None
@@ -547,6 +654,21 @@ class Tracer:
             self._ring.append(sp)
             self._recorded += 1
             self._life_recorded += 1
+            sec = int(sp.t_end)
+            bucket = self._totals.get(sec)
+            if bucket is None:
+                bucket = self._totals[sec] = {}
+                if len(self._totals) > TOTALS_KEEP_S:
+                    for old in [
+                        k for k in self._totals if k <= sec - TOTALS_KEEP_S
+                    ]:
+                        del self._totals[old]
+            tot = bucket.get(sp.stage)
+            if tot is None:
+                bucket[sp.stage] = [1, sp.t_end - sp.t_start]
+            else:
+                tot[0] += 1
+                tot[1] += sp.t_end - sp.t_start
             # exit-path cost only (the enter path is of the same order):
             # an approximate but honestly *measured* recorder overhead the
             # tier1-trace summary line reports as a share of wall time
@@ -672,10 +794,45 @@ class Tracer:
             ring = list(self._ring)
         return [sp.to_dict() for sp in (ring[-n:] if n > 0 else ring)]
 
+    def stage_totals(
+        self, t0: Optional[float] = None, t1: Optional[float] = None
+    ) -> dict:
+        """``{stage: (count, seconds)}`` over the spans that ENDED in the
+        whole seconds inside ``[t0, t1]`` on the tracer's clock (a second
+        cut by either end is left out, so a mean over the same seconds
+        loses nothing to the cut); every kept second where a bound is
+        None.  Read after the fact: no snapshot is needed beforehand, and
+        no span is dropped however often the ring has wrapped."""
+        out: dict = {}
+        with self._lock:
+            for sec, bucket in self._totals.items():
+                if (t0 is not None and sec < t0) or (
+                    t1 is not None and sec + 1 > t1
+                ):
+                    continue
+                for stage, (n, s) in bucket.items():
+                    got = out.get(stage)
+                    out[stage] = (
+                        (n, s) if got is None else (got[0] + n, got[1] + s)
+                    )
+        return out
+
+    def stage_seconds(self) -> dict:
+        """The store itself, ``{second: {stage: (count, seconds)}}``: which
+        stage grows as a run goes on."""
+        with self._lock:
+            return {
+                sec: {k: (v[0], v[1]) for k, v in bucket.items()}
+                for sec, bucket in sorted(self._totals.items())
+            }
+
     def stage_summary(self) -> dict:
-        """Per-stage count / total / p50 / p99 over the spans currently in
-        the ring (bounded by the ring, so the percentiles describe the
-        recent window — exactly what a regression hunt wants)."""
+        """Per-stage count and total over the last ``TOTALS_KEEP_S``
+        seconds (the store ``stage_totals`` reads: right however often the
+        ring has wrapped), with p50 / p99 / max over the ``ring_count``
+        spans of the stage still in the ring (the recent tail — what a
+        regression hunt wants; None for a stage whose spans have all left
+        the ring)."""
         with self._lock:
             ring = list(self._ring)
         by_stage: dict = {}
@@ -684,15 +841,18 @@ class Tracer:
                 continue
             by_stage.setdefault(sp.stage, []).append(sp.t_end - sp.t_start)
         out = {}
-        for stage, durs in sorted(by_stage.items()):
-            durs.sort()
+        for stage, (count, seconds) in sorted(self.stage_totals().items()):
+            durs = sorted(by_stage.get(stage, ()))
             n = len(durs)
             out[stage] = {
-                "count": n,
-                "total_ms": round(sum(durs) * 1e3, 3),
-                "p50_ms": round(durs[n // 2] * 1e3, 3),
-                "p99_ms": round(durs[min(n - 1, (n * 99) // 100)] * 1e3, 3),
-                "max_ms": round(durs[-1] * 1e3, 3),
+                "count": count,
+                "total_ms": round(seconds * 1e3, 3),
+                "ring_count": n,
+                "p50_ms": round(durs[n // 2] * 1e3, 3) if n else None,
+                "p99_ms": round(durs[min(n - 1, (n * 99) // 100)] * 1e3, 3)
+                if n
+                else None,
+                "max_ms": round(durs[-1] * 1e3, 3) if n else None,
             }
         return out
 
@@ -882,6 +1042,7 @@ class Tracer:
             self._dump_seq = 0
             self._dumps = []
             self._overhead_s = 0.0
+            self._totals = {}
 
 
 _TRACER: Optional[Tracer] = None
@@ -912,8 +1073,31 @@ def span(stage: str, **attrs):
     return get_tracer().span(stage, **attrs)
 
 
+def lap(stage: str) -> Lap:
+    return get_tracer().lap(stage)
+
+
+def current() -> Optional[Span]:
+    return get_tracer().current() if enabled() else None
+
+
 def record_anomaly(kind: str, **attrs) -> Optional[str]:
     return get_tracer().record_anomaly(kind, **attrs)
+
+
+def wall_seconds(sp, t0: float) -> float:
+    """Wall seconds of the block a ``with`` span just closed over, for the
+    histogram beside it: the span's own two readings where it is a real
+    span on the default clock, so that span and histogram are fed from one
+    pair; ``perf_counter() - t0`` for the no-op span or an injected
+    (virtual) clock."""
+    if (
+        type(sp) is Span
+        and sp.t_end is not None
+        and get_tracer()._clock is time.perf_counter
+    ):
+        return sp.t_end - sp.t_start
+    return time.perf_counter() - t0
 
 
 def summary_line() -> str:

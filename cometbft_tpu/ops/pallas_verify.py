@@ -105,6 +105,8 @@ def _build(batch: int, tile: int):
         ],
         out_specs=lane_spec(1),
         out_shape=jax.ShapeDtypeStruct((1, batch), jnp.int32),
+        # the name a device trace prints for the kernel
+        name="ed25519_verify",
     )
 
 
@@ -129,18 +131,21 @@ def verify_core_pallas(a_bytes, r_bytes, s_bytes, m_bytes, s_ok,
         s_bytes = jnp.concatenate([s_bytes, zeros2])
         m_bytes = jnp.concatenate([m_bytes, zeros2])
         s_ok = jnp.concatenate([s_ok, jnp.zeros((pad,), s_ok.dtype)])
-    ya, sa = fe.unpack255(a_bytes)
-    yr, sr = fe.unpack255(r_bytes)
-    dig_s = fe.signed_digits_msb_first(s_bytes)
-    dig_m = fe.signed_digits_msb_first(m_bytes)
-    out = _build(batch + pad, tile)(
-        ya.v,
-        sa[None, :].astype(jnp.int32),
-        yr.v,
-        sr[None, :].astype(jnp.int32),
-        dig_s,
-        dig_m,
-        s_ok[None, :].astype(jnp.int32),
-        jnp.asarray(ep._niels_base_table()),
-    )
-    return out[0, :batch] != 0
+    with jax.named_scope("verify.unpack"):
+        ya, sa = fe.unpack255(a_bytes)
+        yr, sr = fe.unpack255(r_bytes)
+        dig_s = fe.signed_digits_msb_first(s_bytes)
+        dig_m = fe.signed_digits_msb_first(m_bytes)
+        args = (
+            ya.v,
+            sa[None, :].astype(jnp.int32),
+            yr.v,
+            sr[None, :].astype(jnp.int32),
+            dig_s,
+            dig_m,
+            s_ok[None, :].astype(jnp.int32),
+            jnp.asarray(ep._niels_base_table()),
+        )
+    out = _build(batch + pad, tile)(*args)
+    with jax.named_scope("verify.pack_bits"):
+        return out[0, :batch] != 0

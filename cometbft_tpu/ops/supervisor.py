@@ -280,24 +280,6 @@ def watchdog_call(
         raise
 
 
-def _profile_ctx():
-    """Optional on-device profiler capture (``COMETBFT_TPU_PROFILE_DIR``):
-    wraps one supervised dispatch in ``jax.profiler.trace`` so the
-    perfetto trace of the actual kernel schedule lands next to the flight
-    recorder's host-side spans.  Returns a context manager or None; any
-    profiler failure (nested capture, missing backend) degrades to an
-    unprofiled dispatch — profiling must never fail a verify."""
-    d = os.environ.get("COMETBFT_TPU_PROFILE_DIR")
-    if not d:
-        return None
-    try:
-        import jax
-
-        return jax.profiler.trace(d)
-    except Exception:  # noqa: BLE001 — profiling is never load-bearing
-        return None
-
-
 def supervised_device_call(
     backend: str,
     fn: Callable,
@@ -338,6 +320,43 @@ def supervised_device_call(
 # -- supervised ed25519 verification ----------------------------------------
 
 
+def _pack(pubs, msgs, sigs, min_b: int):
+    """``prepare_batch`` (host pack, SHA-512) under its span."""
+    from cometbft_tpu.ops import verify as ov
+
+    with tracing.span("verify.pack", n=len(pubs)) as sp:
+        arrays, n, structural = ov.prepare_batch(pubs, msgs, sigs, min_b)
+        sp.set(lanes=arrays["s_ok"].shape[0], bytes=_nbytes(arrays))
+    return arrays, n, structural
+
+
+def _nbytes(arrays: dict) -> int:
+    return sum(v.nbytes for v in arrays.values())
+
+
+def _record_launch(launched, dsp, backend: str, arrays: dict) -> None:
+    """``verify.launch``, timed inside the watchdog closure, recorded by the
+    calling thread once ``watchdog_call`` has returned: an abandoned worker
+    writes no span."""
+    launched.record(
+        parent=dsp,
+        tier=backend,
+        lanes=arrays["s_ok"].shape[0],
+        bytes=_nbytes(arrays),
+    )
+
+
+def _launch(backend: str, lanes: int, arrays: dict):
+    """Resolve the bucket's executable, transfer, call: returns the
+    UNFETCHED device array.  Runs on the watchdog worker."""
+    import jax.numpy as jnp
+
+    from cometbft_tpu.ops import verify as ov
+
+    call, _ = ov.bucket_executable(backend, lanes)
+    return call(**{k: jnp.asarray(v) for k, v in arrays.items()})
+
+
 def _validate_accept(accept, lanes: int) -> np.ndarray:
     """Wrong-shape/dtype output is an infrastructure failure (a kernel
     regression or memory corruption), never a verdict."""
@@ -360,52 +379,31 @@ def _attempt(backend: str, pubs, msgs, sigs) -> np.ndarray:
     worker can't race a late span into a deterministic sim's flight
     record.  It carries the (tier, lanes, dispatch-seq) triple an anomaly
     dump attributes a watchdog fire to."""
-    import jax.numpy as jnp
-
     from cometbft_tpu.ops import verify as ov
 
     min_b = ov._PALLAS_MIN_BUCKET if backend == "pallas" else ov._BUCKETS[0]
-    arrays, n, structural = ov.prepare_batch(pubs, msgs, sigs, min_b)
+    arrays, n, structural = _pack(pubs, msgs, sigs, min_b)
     lanes = arrays["s_ok"].shape[0]
     inj = _FAULT_INJECTOR
     runner = _DEVICE_RUNNER
     # the ordinal this dispatch will record (single dispatch in flight per
     # attempt; concurrent attempts only skew the label, never the verdict)
     seq = dispatch_stats.dispatch_count() + 1
+    launched = tracing.lap("verify.launch")
 
     def run():
         transform = inj(backend, pubs, msgs, sigs) if inj is not None else None
         dispatch_stats.record_dispatch(lanes, n)
-        if runner is not None:
-            out = np.asarray(runner(backend, pubs, msgs, sigs, lanes))
-        else:
-            # executable resolution (exec-cache load or AOT compile) runs
-            # INSIDE the watchdog worker: a wedged compile is abandoned
-            # like a wedged dispatch, and the device-runner seam above
-            # never pays a compile at all
-            call, _ = ov.bucket_executable(backend, lanes)
-            # jax.profiler.trace raises at __enter__ on a collision
-            # ("profile already in progress" — concurrent dispatches), so
-            # the enter itself must be guarded or a profiling collision
-            # would read as a backend failure and demote a healthy tier
-            prof = _profile_ctx()
-            entered = False
-            if prof is not None:
-                try:
-                    prof.__enter__()
-                    entered = True
-                except Exception:  # noqa: BLE001 — never fail a verify
-                    prof = None
-            try:
-                out = np.asarray(
-                    call(**{k: jnp.asarray(v) for k, v in arrays.items()})
-                )
-            finally:
-                if entered:
-                    try:
-                        prof.__exit__(None, None, None)
-                    except Exception:  # noqa: BLE001 — profiling only
-                        pass
+        with launched:
+            if runner is not None:
+                dev = runner(backend, pubs, msgs, sigs, lanes)
+            else:
+                # executable resolution (exec-cache load or AOT compile)
+                # runs INSIDE the watchdog worker: a wedged compile is
+                # abandoned like a wedged dispatch, and the device-runner
+                # seam above never pays a compile at all
+                dev = _launch(backend, lanes, arrays)
+        out = np.asarray(dev)
         if transform is not None:
             out = transform(out)
         return out
@@ -414,8 +412,9 @@ def _attempt(backend: str, pubs, msgs, sigs) -> np.ndarray:
     try:
         with tracing.span(
             "verify.dispatch", tier=backend, lanes=lanes, n=n, dispatch=seq
-        ):
+        ) as dsp:
             accept = watchdog_call(run, backend=backend, note_anomaly=False)
+            _record_launch(launched, dsp, backend, arrays)
     except DispatchTimeoutError:
         # the failed span is already in the ring (the with-block closed),
         # so the dump this triggers shows it as its most recent entry
@@ -583,8 +582,6 @@ def verify_batches_overlapped_supervised(work) -> list:
     forcing, fetch in order), but every dispatch AND fetch is watchdogged,
     and a failure re-runs the affected batch on the next tier down — later
     batches in the window skip the failed device immediately."""
-    import jax.numpy as jnp
-
     from cometbft_tpu.ops import verify as ov
 
     work = [(list(p), list(m), list(s)) for p, m, s in work]
@@ -611,32 +608,31 @@ def verify_batches_overlapped_supervised(work) -> list:
         if dead:
             inflight.append((None, None, 0, None, 0, w))
             continue
-        arrays, n, structural = ov.prepare_batch(*w, min_b)
+        arrays, n, structural = _pack(*w, min_b)
         lanes = arrays["s_ok"].shape[0]
         inj = _FAULT_INJECTOR
         runner = _DEVICE_RUNNER
+        launched = tracing.lap("verify.launch")
 
-        def dispatch(arrays=arrays, w=w, lanes=lanes, n=n):
+        def dispatch(arrays=arrays, w=w, lanes=lanes, n=n, launched=launched):
             transform = (
                 inj(backend, *w) if inj is not None else None
             )
             dispatch_stats.record_dispatch(lanes, n)
-            if runner is not None:
-                # device-runner seam (sim/tests): synchronous stand-in —
-                # np.asarray at fetch time is then a no-op
-                return np.asarray(runner(backend, *w, lanes)), transform
-            call, _ = ov.bucket_executable(backend, lanes)
-            return (
-                call(**{k: jnp.asarray(v) for k, v in arrays.items()}),
-                transform,
-            )
+            with launched:
+                if runner is not None:
+                    # device-runner seam (sim/tests): synchronous stand-in
+                    # — np.asarray at fetch time is then a no-op
+                    return np.asarray(runner(backend, *w, lanes)), transform
+                return _launch(backend, lanes, arrays), transform
 
         try:
             with tracing.span(
                 "verify.dispatch", tier=backend, lanes=lanes, n=n,
                 window=len(work),
-            ):
+            ) as dsp:
                 dev, transform = watchdog_call(dispatch, backend=backend)
+                _record_launch(launched, dsp, backend, arrays)
         except Exception as e:  # noqa: BLE001
             br.record_failure(e)
             reg.record_demotion(backend)
@@ -670,10 +666,10 @@ def verify_batches_overlapped_supervised(work) -> list:
             t0 = time.perf_counter()
             with tracing.span(
                 "verify.fetch", tier=backend, lanes=lanes, n=n
-            ):
+            ) as fsp:
                 got = watchdog_call(fetch, backend=backend)
             dispatch_stats.record_dispatch_time(
-                backend, lanes, time.perf_counter() - t0
+                backend, lanes, tracing.wall_seconds(fsp, t0)
             )
             accept = _validate_accept(got, lanes)
         except Exception as e:  # noqa: BLE001
@@ -784,29 +780,26 @@ def dispatch_verify(pubs, msgs, sigs, lane=None) -> _InflightVerify:
             h.lanes = ov.bucket_size(max(n, 1), min_b)
             dispatch_stats.record_lane_dispatch(backend, h.lanes, n)
             return h
-        arrays, _, structural = ov.prepare_batch(pubs, msgs, sigs, min_b)
+        arrays, _, structural = _pack(pubs, msgs, sigs, min_b)
         lanes = arrays["s_ok"].shape[0]
         inj = _FAULT_INJECTOR
+        launched = tracing.lap("verify.launch")
 
         def dispatch():
-            import jax.numpy as jnp
-
             transform = (
                 inj(backend, pubs, msgs, sigs) if inj is not None else None
             )
             dispatch_stats.record_dispatch(lanes, n)
-            call, _ = ov.bucket_executable(backend, lanes)
-            return (
-                call(**{k: jnp.asarray(v) for k, v in arrays.items()}),
-                transform,
-            )
+            with launched:
+                return _launch(backend, lanes, arrays), transform
 
         try:
             with tracing.span(
                 "verify.dispatch", tier=backend, lanes=lanes, n=n,
                 pipelined=True,
-            ):
+            ) as dsp:
                 h.dev, h.transform = watchdog_call(dispatch, backend=backend)
+                _record_launch(launched, dsp, backend, arrays)
         except Exception as e:  # noqa: BLE001 — dispatch failure demotes;
             # the batch re-verifies on the next tier at fetch time
             reg.breaker(backend).record_failure(e)
@@ -880,10 +873,10 @@ def fetch_verify(h: _InflightVerify) -> np.ndarray:
                 t0 = time.perf_counter()
                 with tracing.span(
                     "verify.fetch", tier=h.backend, lanes=h.lanes, n=h.n
-                ):
+                ) as fsp:
                     got = watchdog_call(fetch, backend=h.backend)
                 dispatch_stats.record_dispatch_time(
-                    h.backend, h.lanes, time.perf_counter() - t0
+                    h.backend, h.lanes, tracing.wall_seconds(fsp, t0)
                 )
                 accept = _validate_accept(got, h.lanes)
             except Exception as e:  # noqa: BLE001 — fetch failure demotes

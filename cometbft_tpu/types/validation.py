@@ -10,6 +10,7 @@ one kernel launch; per-signature accept bits make failure attribution free
 
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 from fractions import Fraction
@@ -137,6 +138,31 @@ def _tally(entries, tallied: int, count_all: bool, voting_power_needed: int):
         raise NotEnoughPowerError(tallied, voting_power_needed)
 
 
+@contextlib.contextmanager
+def _commit_span(commit: Commit, count_all: bool):
+    """``verify.commit`` around a WHOLE public call, basic checks included;
+    the verify-latency histogram is fed from the span's own readings (a
+    call that raises records the span, with its error, and no sample)."""
+    t0 = time.perf_counter()
+    with tracing.span(
+        "verify.commit",
+        height=getattr(commit, "height", None),
+        sigs=len(getattr(commit, "signatures", None) or ()),
+        count_all=count_all,
+    ) as sp:
+        yield sp
+    dispatch_stats.record_verify_latency(tracing.wall_seconds(sp, t0))
+
+
+def _sign_bytes(chain_id: str, commit: Commit, entries) -> list:
+    """One native call builds every sign-bytes (10k-commit hot path);
+    python per-index fallback inside."""
+    with tracing.span("commit.sign_bytes", sigs=len(entries)):
+        return commit.all_vote_sign_bytes(
+            chain_id, [idx for idx, _, _ in entries]
+        )
+
+
 def _verify_commit(
     chain_id: str,
     vals: ValidatorSet,
@@ -145,8 +171,10 @@ def _verify_commit(
     count_all: bool,
     lookup_by_address: bool,
     backend: Optional[str] = None,
+    sp=tracing._NULL_SPAN,
 ) -> None:
-    """Shared engine for all three public variants.
+    """Shared engine for all three public variants; ``sp`` is the caller's
+    ``verify.commit`` span.
 
     count_all=True  -> verify every non-absent signature (consensus safety).
     count_all=False -> stop as soon as tallied power exceeds the threshold
@@ -154,51 +182,38 @@ def _verify_commit(
     lookup_by_address -> trusting mode: commit indexes may not match the
                        validator set; match signatures by address.
     """
-    t0 = time.perf_counter()
-    with tracing.span(
-        "verify.commit",
-        height=commit.height,
-        sigs=len(commit.signatures),
-        count_all=count_all,
-    ) as sp:
-        entries, tallied = _collect_entries(
-            vals, commit, voting_power_needed, count_all, lookup_by_address
-        )
-        sp.set(entries=len(entries))
+    entries, tallied = _collect_entries(
+        vals, commit, voting_power_needed, count_all, lookup_by_address
+    )
+    sp.set(entries=len(entries))
 
-        # Verify the collected signatures (batch seam).  The batch
-        # verifiers pre-filter through the consensus-wide signature cache,
-        # so a commit whose votes were verified at gossip time ships zero
-        # device work.
-        if entries:
-            use_batch = _should_batch(vals, commit) and len(entries) >= 2
-            if use_batch:
-                bv = cbatch.create_batch_verifier(
-                    entries[0][1].pub_key, backend
-                )
-                # one native call builds every sign-bytes (10k-commit hot
-                # path); python per-index fallback inside
-                sign_bytes = commit.all_vote_sign_bytes(
-                    chain_id, [idx for idx, _, _ in entries]
-                )
+    # Verify the collected signatures (batch seam).  The batch verifiers
+    # pre-filter through the consensus-wide signature cache, so a commit
+    # whose votes were verified at gossip time ships zero device work.
+    if entries:
+        use_batch = _should_batch(vals, commit) and len(entries) >= 2
+        if use_batch:
+            bv = cbatch.create_batch_verifier(entries[0][1].pub_key, backend)
+            sign_bytes = _sign_bytes(chain_id, commit, entries)
+            with tracing.span("batch.verify", sigs=len(entries)) as bsp:
                 for (idx, val, cs), sb in zip(entries, sign_bytes):
                     bv.add(val.pub_key, sb, cs.signature)
                 ok, bits = bv.verify()
-                if not ok:
-                    _judge_entries(entries, bits)
-                    raise CommitVerificationError("batch verification failed")
-            else:
-                for idx, val, cs in entries:
-                    if not sigcache.verify_with_cache(
-                        val.pub_key,
-                        commit.vote_sign_bytes(chain_id, idx),
-                        cs.signature,
-                    ):
-                        raise InvalidSignatureError(idx)
+                bsp.set(hits=getattr(bv, "cache_hits", 0))
+            if not ok:
+                _judge_entries(entries, bits)
+                raise CommitVerificationError("batch verification failed")
+        else:
+            for idx, val, cs in entries:
+                if not sigcache.verify_with_cache(
+                    val.pub_key,
+                    commit.vote_sign_bytes(chain_id, idx),
+                    cs.signature,
+                ):
+                    raise InvalidSignatureError(idx)
 
-        # Tally voting power for the committed block.
-        _tally(entries, tallied, count_all, voting_power_needed)
-    dispatch_stats.record_verify_latency(time.perf_counter() - t0)
+    # Tally voting power for the committed block.
+    _tally(entries, tallied, count_all, voting_power_needed)
 
 
 @dataclass
@@ -239,7 +254,7 @@ def prepare_commit_light(
     _verify_basic(vals, commit, height, block_id)
     needed = vals.total_voting_power() * 2 // 3
     entries, tallied = _collect_entries(vals, commit, needed, count_all, False)
-    msgs = commit.all_vote_sign_bytes(chain_id, [idx for idx, _, _ in entries])
+    msgs = _sign_bytes(chain_id, commit, entries)
     return PreparedCommit(
         chain_id=chain_id,
         vals=vals,
@@ -303,9 +318,10 @@ def verify_commit(
 ) -> None:
     """Full verification: every signature checked, +2/3 power required
     (reference: types/validation.go:28)."""
-    _verify_basic(vals, commit, height, block_id)
-    needed = vals.total_voting_power() * 2 // 3
-    _verify_commit(chain_id, vals, commit, needed, True, False, backend)
+    with _commit_span(commit, True) as sp:
+        _verify_basic(vals, commit, height, block_id)
+        needed = vals.total_voting_power() * 2 // 3
+        _verify_commit(chain_id, vals, commit, needed, True, False, backend, sp)
 
 
 def verify_commit_light(
@@ -317,9 +333,10 @@ def verify_commit_light(
     backend: Optional[str] = None,
 ) -> None:
     """Light verification: stop at +2/3 (reference: types/validation.go:63)."""
-    _verify_basic(vals, commit, height, block_id)
-    needed = vals.total_voting_power() * 2 // 3
-    _verify_commit(chain_id, vals, commit, needed, False, False, backend)
+    with _commit_span(commit, False) as sp:
+        _verify_basic(vals, commit, height, block_id)
+        needed = vals.total_voting_power() * 2 // 3
+        _verify_commit(chain_id, vals, commit, needed, False, False, backend, sp)
 
 
 def verify_commit_light_trusting(
@@ -332,10 +349,11 @@ def verify_commit_light_trusting(
     """Trusting-period verification against a possibly different validator
     set; needs > trust_level of this set's power
     (reference: types/validation.go:129)."""
-    if commit is None or not commit.signatures:
-        raise CommitVerificationError("nil or empty commit")
-    if trust_level.numerator * 3 < trust_level.denominator:  # < 1/3
-        raise CommitVerificationError("trust level must be >= 1/3")
-    total = vals.total_voting_power()
-    needed = total * trust_level.numerator // trust_level.denominator
-    _verify_commit(chain_id, vals, commit, needed, False, True, backend)
+    with _commit_span(commit, False) as sp:
+        if commit is None or not commit.signatures:
+            raise CommitVerificationError("nil or empty commit")
+        if trust_level.numerator * 3 < trust_level.denominator:  # < 1/3
+            raise CommitVerificationError("trust level must be >= 1/3")
+        total = vals.total_voting_power()
+        needed = total * trust_level.numerator // trust_level.denominator
+        _verify_commit(chain_id, vals, commit, needed, False, True, backend, sp)

@@ -194,9 +194,13 @@ class _Item:
     # the queue: submit->drain is QUEUE WAIT, drain->verdict is DEVICE
     # time — recorded as separate histograms so queue pressure and device
     # slowness are distinguishable regressions (docs/observability.md)
-    __slots__ = ("pub", "msg", "sig", "prio", "future", "t0", "t_drain")
+    # ctx = the submitter's innermost open span (None outside any): the
+    # flush that serves the item joins its trace
+    __slots__ = (
+        "pub", "msg", "sig", "prio", "future", "t0", "t_drain", "ctx",
+    )
 
-    def __init__(self, pub, msg, sig, prio, future, t0):
+    def __init__(self, pub, msg, sig, prio, future, t0, ctx=None):
         self.pub = pub
         self.msg = msg
         self.sig = sig
@@ -204,6 +208,10 @@ class _Item:
         self.future = future
         self.t0 = t0
         self.t_drain = t0
+        self.ctx = ctx
+
+
+_AMBIENT = object()  # submit(ctx=...): "take this thread's current span"
 
 
 class VerifyScheduler:
@@ -271,15 +279,20 @@ class VerifyScheduler:
         sig: bytes,
         priority: int = PRIO_CONSENSUS,
         precleared: bool = False,
+        ctx=_AMBIENT,
     ) -> "Future[bool]":
         """Queue one (pub, msg, sig) check; returns a Future resolving to
         the definitive verdict.  A sigcache hit resolves immediately
         without occupying a queue slot (``precleared=True`` skips that
         lookup — for bridges that just partitioned the cache themselves).
         Raises ``QueueFullError`` for non-consensus classes when the queue
-        is at capacity; consensus submissions are always admitted."""
+        is at capacity; consensus submissions are always admitted.
+        ``ctx`` is the span the serving flush is traced under: this
+        thread's current one unless ``submit_many`` already took it."""
         prio = min(max(int(priority), 0), N_CLASSES - 1)
         fut: "Future[bool]" = Future()
+        if ctx is _AMBIENT:
+            ctx = tracing.current()
         if not precleared:
             hit = sigcache.get_cache().get(pub, msg, sig)
             if hit is not None:
@@ -297,7 +310,7 @@ class VerifyScheduler:
                         f"shedding class {stats.CLASS_NAMES[prio]}"
                     )
                 self._queues[prio].append(
-                    _Item(pub, msg, sig, prio, fut, time.perf_counter())
+                    _Item(pub, msg, sig, prio, fut, time.perf_counter(), ctx)
                 )
                 self._count += 1
                 stats.record_submit(prio)
@@ -345,11 +358,13 @@ class VerifyScheduler:
         sheds come back as ``None`` — the caller verifies those itself.
         A scheduler stopped mid-segment (teardown race) marks the rest
         ``None`` the same way: already-queued futures still resolve (close
-        drains the queue), the remainder degrade to the caller's fallback."""
+        drains the queue), the remainder degrade to the caller's fallback.
+        The submitter's trace context is taken once, not once a signature."""
         out: "list[Optional[Future]]" = []
+        ctx = tracing.current()
         for p, m, s in zip(pubs, msgs, sigs):
             try:
-                out.append(self.submit(p, m, s, priority, precleared))
+                out.append(self.submit(p, m, s, priority, precleared, ctx))
             except QueueFullError:
                 out.append(None)
             except RuntimeError:
@@ -571,6 +586,56 @@ class VerifyScheduler:
                 raise  # SystemExit etc.: die, but only AFTER resolving
                 # (the next submit detects the dead thread and restarts)
 
+    @staticmethod
+    def _flush_span(reason: str, items: "list[_Item]"):
+        """``sched.flush``, saying where its items came from: the trace ids
+        it serves (a flush may serve several requests), the first
+        submitter's span as its parent, and how long the oldest item
+        waited in the queue."""
+        if not tracing.enabled():
+            return tracing.span("sched.flush")
+        ctxs = {it.ctx for it in items}
+        ctxs.discard(None)
+        return tracing.span(
+            "sched.flush",
+            parent=items[0].ctx,
+            reason=reason,
+            items=len(items),
+            traces=sorted({c.trace_id for c in ctxs}),
+            queue_wait_s=round(
+                items[0].t_drain - min(it.t0 for it in items), 9
+            ),
+        )
+
+    @staticmethod
+    def _set_results(items, bits, now: float, lo: int, hi: int) -> None:
+        for i in range(lo, hi):
+            it = items[i]
+            if it.future.done():
+                continue
+            it.future.set_result(bool(bits[i]))
+            stats.record_verdict(
+                it.prio,
+                now - it.t0,
+                queue_wait_s=it.t_drain - it.t0,
+                device_s=now - it.t_drain,
+            )
+
+    def _resolve(self, items, bits, fsp, settle=None) -> None:
+        """``sched.resolve``: ``settle()`` (the verdict map and the cache
+        puts of a fetched flush), then ``set_result`` for every item.  The
+        span lands BEFORE the last future resolves, so a deterministic
+        sim's ring order cannot race its waiter; that one ``set_result``
+        is the part of the stage the span does not hold."""
+        n = len(items)
+        last = max(n - 1, 0)
+        with tracing.span("sched.resolve", parent=fsp, items=n):
+            if settle is not None:
+                settle()
+            now = time.perf_counter()
+            self._set_results(items, bits, now, 0, last)
+        self._set_results(items, bits, now, last, n)
+
     def _execute_inner(
         self, items: "list[_Item]", reason: str, recorded: "list[bool]"
     ) -> None:
@@ -581,7 +646,7 @@ class VerifyScheduler:
 
         # flush span (closed BEFORE futures resolve, like the stats below,
         # so a deterministic sim's ring order cannot race its waiters)
-        with tracing.span("sched.flush", reason=reason, items=n) as fsp:
+        with self._flush_span(reason, items) as fsp:
             # structural filter (garbage never occupies a device lane) +
             # in-flight dedup: concurrent gossip of the same vote collapses
             # into one lane, both futures share the verdict
@@ -618,26 +683,7 @@ class VerifyScheduler:
                 ]
                 lanes = ov.bucket_size(len(ordered), ov._min_bucket())
                 results = ov.verify_segments(work)
-                # verdicts keyed by FIRST index of each dedup group (the
-                # hash was already paid once in the dedup loop above)
-                verdict_by_first = dict(
-                    zip(ordered, (bool(b) for seg in results for b in seg))
-                )
-                # resolve every member of each dedup group + cache
-                # writeback.  Inlined rather than sigcache.writeback: that
-                # would re-hash every entry, and the dedup loop already
-                # holds the keys — on the single dispatcher thread a third
-                # SHA-256 per item gates every waiter's latency.
-                # Supervised verdicts are always definitive, so caching
-                # unconditionally is safe.
-                cache = sigcache.get_cache()
-                cache_on = cache.enabled()
-                for k, ixs in uniq.items():
-                    v = verdict_by_first[ixs[0]]
-                    for i in ixs:
-                        bits[i] = v
-                    if cache_on:
-                        cache._put(k, v)
+                self._settle(bits, uniq, ordered, results)
             fsp.set(misses=len(firsts), lanes=lanes)
 
         # record BEFORE resolving: set_result unblocks waiters, and a
@@ -651,15 +697,27 @@ class VerifyScheduler:
             interval_s=interval,
         )
         recorded[0] = True
-        now = time.perf_counter()
-        for i, it in enumerate(items):
-            it.future.set_result(bool(bits[i]))
-            stats.record_verdict(
-                it.prio,
-                now - it.t0,
-                queue_wait_s=it.t_drain - it.t0,
-                device_s=now - it.t_drain,
-            )
+        self._resolve(items, bits, fsp)
+
+    @staticmethod
+    def _settle(bits, uniq, ordered, results) -> None:
+        """Verdicts keyed by FIRST index of each dedup group (the hash was
+        already paid once in the dedup loop) resolve every member of the
+        group, and go to the cache.  Inlined rather than
+        ``sigcache.writeback``: that would re-hash every entry, and the
+        dedup loop already holds the keys.  Supervised verdicts are always
+        definitive, so caching unconditionally is safe."""
+        verdict_by_first = dict(
+            zip(ordered, (bool(b) for seg in results for b in seg))
+        )
+        cache = sigcache.get_cache()
+        cache_on = cache.enabled()
+        for k, ixs in uniq.items():
+            v = verdict_by_first[ixs[0]]
+            for i in ixs:
+                bits[i] = v
+            if cache_on:
+                cache._put(k, v)
 
     # -- in-flight pipeline (docs/verify-scheduler.md) --------------------
 
@@ -691,7 +749,7 @@ class VerifyScheduler:
         sigs = [it.sig for it in items]
         interval = self._flush_interval()
 
-        with tracing.span("sched.flush", reason=reason, items=n) as fsp:
+        with self._flush_span(reason, items) as fsp:
             bits: "list[Optional[bool]]" = [None] * n
             uniq: "OrderedDict[bytes, list[int]]" = OrderedDict()
             for i in range(n):
@@ -730,13 +788,17 @@ class VerifyScheduler:
                 # completion thread so a dead one is restarted rather
                 # than waited on forever
                 with self._fcond:
-                    while self._inflight >= cap:
-                        if (
-                            self._fetch_thread is None
-                            or not self._fetch_thread.is_alive()
+                    if self._inflight >= cap:
+                        with tracing.span(
+                            "sched.slot_wait", inflight=self._inflight, cap=cap
                         ):
-                            break
-                        self._fcond.wait(0.1)
+                            while self._inflight >= cap:
+                                if (
+                                    self._fetch_thread is None
+                                    or not self._fetch_thread.is_alive()
+                                ):
+                                    break
+                                self._fcond.wait(0.1)
                     self._inflight += 1
                     stats.record_inflight(self._inflight)
                 self._ensure_fetch_thread()
@@ -779,18 +841,12 @@ class VerifyScheduler:
         recorded[0] = True
         if handle is None:
             # nothing device-bound (all garbage/empty): resolve inline
-            now = time.perf_counter()
-            for i, it in enumerate(items):
-                it.future.set_result(bool(bits[i]))
-                stats.record_verdict(
-                    it.prio,
-                    now - it.t0,
-                    queue_wait_s=it.t_drain - it.t0,
-                    device_s=now - it.t_drain,
-                )
+            self._resolve(items, bits, fsp)
             return
         with self._fcond:
-            self._fetch_queue.append((handle, items, bits, uniq, ordered))
+            # the flush span rides along: the completion thread's spans
+            # are its children
+            self._fetch_queue.append((handle, items, bits, uniq, ordered, fsp))
             self._fcond.notify_all()
 
     def _ensure_fetch_thread(self) -> None:
@@ -834,30 +890,28 @@ class VerifyScheduler:
         completion thread in drain order; cannot leave a future
         unresolved — a fetch that somehow escapes the supervisor's
         degradation chain resolves the flush on the host reference."""
-        handle, items, bits, uniq, ordered = pf
+        handle, items, bits, uniq, ordered, fsp = pf
+        results = None
         try:
             from cometbft_tpu.ops import verify as ov
 
-            with tracing.span("sched.fetch", items=len(items)):
+            with tracing.span("sched.fetch", parent=fsp, items=len(items)):
                 results = ov.fetch_segments(handle)
-            verdict_by_first = dict(
-                zip(ordered, (bool(b) for seg in results for b in seg))
-            )
-            cache = sigcache.get_cache()
-            cache_on = cache.enabled()
-            for k, ixs in uniq.items():
-                v = verdict_by_first[ixs[0]]
-                for i in ixs:
-                    bits[i] = v
-                if cache_on:
-                    cache._put(k, v)
         except BaseException:  # noqa: BLE001 — swallow even SystemExit:
             # the completion thread must outlive one bad flush or every
             # queued flush behind it strands its futures
-            logger.exception(
-                "pipelined flush fetch failed unexpectedly; resolving %d "
-                "items on the host reference",
-                len(items),
+            logger.exception("pipelined flush fetch failed unexpectedly")
+
+        def settle() -> None:
+            try:
+                if results is not None:
+                    self._settle(bits, uniq, ordered, results)
+            except BaseException:  # noqa: BLE001 — as above
+                logger.exception("pipelined flush resolve failed unexpectedly")
+            if None not in bits:
+                return
+            logger.error(
+                "resolving the flush's unanswered items on the host reference"
             )
             from cometbft_tpu.crypto import ed25519_ref as ref
 
@@ -872,17 +926,8 @@ class VerifyScheduler:
                     )
                 except Exception:  # noqa: BLE001 — malformed input
                     bits[i] = False
-        now = time.perf_counter()
-        for i, it in enumerate(items):
-            if it.future.done():
-                continue
-            it.future.set_result(bool(bits[i]))
-            stats.record_verdict(
-                it.prio,
-                now - it.t0,
-                queue_wait_s=it.t_drain - it.t0,
-                device_s=now - it.t_drain,
-            )
+
+        self._resolve(items, bits, fsp, settle)
 
 
 # -- process-wide instance ----------------------------------------------------
@@ -1009,32 +1054,40 @@ def verify_segment_sync(
     control are verified in one direct supervised dispatch instead, so the
     call never blocks on queue capacity."""
     prio = current_priority() if priority is None else priority
-    futs = get_scheduler().submit_many(
-        pubs, msgs, sigs, prio, precleared=True
-    )
-    shed = [i for i, f in enumerate(futs) if f is None]
-    direct: dict = {}
-    if shed:
-        from cometbft_tpu.ops import verify as ov
-
-        t0 = time.perf_counter()
-        with tracing.span(
-            "sched.shed_fallback",
-            cls=stats.CLASS_NAMES[_clamp_prio(prio)],
-            items=len(shed),
-        ):
-            got = ov.verify_batch(
-                [pubs[i] for i in shed],
-                [msgs[i] for i in shed],
-                [sigs[i] for i in shed],
+    with tracing.span("sched.segment", items=len(pubs)) as seg:
+        # submit and wait end while the dispatcher writes its own spans:
+        # timed here, recorded together after the wait (``tracing.Lap``)
+        with tracing.lap("sched.submit") as submitted:
+            futs = get_scheduler().submit_many(
+                pubs, msgs, sigs, prio, precleared=True
             )
-        dt = time.perf_counter() - t0
-        for _ in shed:
-            # every shed item experienced the whole direct dispatch —
-            # that IS its submit->verdict latency, kept in the record
-            stats.record_shed_fallback(prio, dt)
-        direct = {i: bool(b) for i, b in zip(shed, got)}
-    return [
-        direct[i] if f is None else bool(f.result())
-        for i, f in enumerate(futs)
-    ]
+        shed = [i for i, f in enumerate(futs) if f is None]
+        direct: dict = {}
+        if shed:
+            from cometbft_tpu.ops import verify as ov
+
+            t0 = time.perf_counter()
+            with tracing.span(
+                "sched.shed_fallback",
+                cls=stats.CLASS_NAMES[_clamp_prio(prio)],
+                items=len(shed),
+            ):
+                got = ov.verify_batch(
+                    [pubs[i] for i in shed],
+                    [msgs[i] for i in shed],
+                    [sigs[i] for i in shed],
+                )
+            dt = time.perf_counter() - t0
+            for _ in shed:
+                # every shed item experienced the whole direct dispatch —
+                # that IS its submit->verdict latency, kept in the record
+                stats.record_shed_fallback(prio, dt)
+            direct = {i: bool(b) for i, b in zip(shed, got)}
+        with tracing.lap("sched.wait") as waited:
+            out = [
+                direct[i] if f is None else bool(f.result())
+                for i, f in enumerate(futs)
+            ]
+        submitted.record(parent=seg, items=len(futs), shed=len(shed))
+        waited.record(parent=seg, futures=len(futs) - len(shed))
+    return out
