@@ -41,14 +41,17 @@ class Histo:
         self.sum = 0.0
         self.n = 0
 
-    def observe(self, v: float) -> None:
-        self.sum += v
-        self.n += 1
+    def observe(self, v: float, n: int = 1) -> None:
+        """``n`` samples of the value ``v`` (a segment's signatures share
+        its times): the same counts and quantiles as n single calls, the
+        sum as one product."""
+        self.sum += v * n
+        self.n += n
         for i, b in enumerate(self.bounds):
             if v <= b:
-                self.counts[i] += 1
+                self.counts[i] += n
                 return
-        self.counts[-1] += 1
+        self.counts[-1] += n
 
     def to_dict(self) -> dict:
         """The wire shape ``CallbackHistogram`` renders: non-cumulative

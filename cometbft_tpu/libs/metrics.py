@@ -744,7 +744,12 @@ class NodeMetrics:
         )
         self.sched_submitted = r.labeled_callback_gauge(
             "sched", "submitted", "class", _sched("submitted"),
-            "Verification requests admitted, per priority class",
+            "Signatures admitted to the verify queue, per priority class",
+        )
+        self.sched_segments = r.labeled_callback_gauge(
+            "sched", "segments", "class", _sched("segments"),
+            "Queue entries those signatures arrived as, per priority class "
+            "(one a segment; submitted / segments = signatures an entry)",
         )
         self.sched_shed = r.labeled_callback_gauge(
             "sched", "shed", "class", _sched("shed"),

@@ -9,13 +9,15 @@ module is the missing shared engine — the continuous-batching scheduler
 shape from inference serving applied to signature verification
 (docs/verify-scheduler.md):
 
-  * callers ``submit(pub, msg, sig, priority)`` and get back a Future (or
-    bridge whole segments via ``verify_segment_sync``);
-  * one dispatcher thread coalesces pending items ACROSS all submitters
+  * the queue's unit is the SEGMENT: n >= 1 signatures from one
+    submitter, one queue entry, one lock acquisition and one Future that
+    resolves to its verdicts by index.  ``verify_segment_sync`` bridges a
+    batch verifier's whole segment that way; ``submit(pub, msg, sig,
+    priority)`` is the n = 1 case of the same entry;
+  * one dispatcher thread coalesces pending entries ACROSS all submitters
     into a single ``ops/verify.verify_segments`` dispatch, flushing when
-    the oldest item has waited ``COMETBFT_TPU_SCHED_FLUSH_US`` (~2000) or
-    when a padding bucket fills (at which point the dispatch carries zero
-    padding waste);
+    the oldest entry has waited ``COMETBFT_TPU_SCHED_FLUSH_US`` (~2000) or
+    when the queued signatures fill a padding bucket;
   * the sigcache is consulted before any queue slot or device lane is
     occupied, and duplicate in-flight triples (the same vote gossiped by
     two peers at once) collapse into one lane;
@@ -28,11 +30,12 @@ shape from inference serving applied to signature verification
 Priority classes and admission control: ``consensus`` (vote/proposal/
 extension checks) > ``evidence_light`` (evidence, light client) > ``bulk``
 (blocksync, mempool).  The queue is bounded (``COMETBFT_TPU_SCHED_QUEUE``,
-default 8192); overload sheds ONLY non-consensus classes — a shed caller
-falls back to its own synchronous verify (it loses the batching win, never
-the verdict) — while consensus submissions are always admitted: consensus
-traffic is bounded by validator count x rounds, and blocking or dropping a
-vote is a liveness hazard no queue bound justifies.
+default 8192 signatures); overload sheds ONLY non-consensus classes — a
+segment that would pass the bound is admitted up to it, and the shed caller
+falls back to its own synchronous verify for the rest (it loses the batching
+win, never the verdict) — while consensus submissions are always admitted
+whole: consensus traffic is bounded by validator count x rounds, and
+blocking or dropping a vote is a liveness hazard no queue bound justifies.
 
 Activation: the scheduler takes the verify path only when
 ``COMETBFT_TPU_VERIFY_SCHED`` != 0 (default on) AND the accelerator batch
@@ -189,36 +192,55 @@ def priority_class(priority: int):
 # -- the scheduler -----------------------------------------------------------
 
 
-class _Item:
+class _Entry:
+    # THE unit of the queue: a segment of n >= 1 signatures from one
+    # submitter, answered by ONE future — its verdicts by index, or the
+    # bit itself for ``submit``'s n = 1 entry (``scalar``)
     # t0 = submit time, t_drain = when the dispatcher drained it out of
     # the queue: submit->drain is QUEUE WAIT, drain->verdict is DEVICE
     # time — recorded as separate histograms so queue pressure and device
     # slowness are distinguishable regressions (docs/observability.md)
     # ctx = the submitter's innermost open span (None outside any): the
-    # flush that serves the item joins its trace
+    # flush that serves the entry joins its trace
     __slots__ = (
-        "pub", "msg", "sig", "prio", "future", "t0", "t_drain", "ctx",
+        "pubs", "msgs", "sigs", "n", "prio", "future", "t0", "t_drain",
+        "ctx", "scalar",
     )
 
-    def __init__(self, pub, msg, sig, prio, future, t0, ctx=None):
-        self.pub = pub
-        self.msg = msg
-        self.sig = sig
+    def __init__(self, pubs, msgs, sigs, prio, t0, ctx, scalar):
+        self.pubs = pubs
+        self.msgs = msgs
+        self.sigs = sigs
+        self.n = len(pubs)
         self.prio = prio
-        self.future = future
+        self.future: Future = Future()
         self.t0 = t0
         self.t_drain = t0
         self.ctx = ctx
+        self.scalar = scalar
 
 
-_AMBIENT = object()  # submit(ctx=...): "take this thread's current span"
+def _ref_bit(pub: bytes, msg: bytes, sig: bytes) -> bool:
+    """One verdict on the host reference: what every failure path
+    answers with, so a future resolves with a definitive bit whatever
+    broke above it."""
+    from cometbft_tpu.crypto import ed25519_ref as ref
+
+    try:
+        return len(pub) == 32 and len(sig) == 64 and bool(
+            ref.verify_zip215(pub, msg, sig)
+        )
+    except Exception:  # noqa: BLE001 — malformed input
+        return False
 
 
 class VerifyScheduler:
-    """One dispatcher thread over three priority queues.  Thread-safe;
-    lazily starts its thread on the first queued submission and drains
-    everything (reason ``shutdown``) on ``close()`` — a future handed out
-    is always eventually resolved."""
+    """One dispatcher thread over three priority queues of segments.
+    Thread-safe; lazily starts its thread on the first queued submission
+    and drains everything (reason ``shutdown``) on ``close()`` — a future
+    handed out is always eventually resolved.  ``_count``, ``queue_cap``,
+    the ``full`` target and ``MAX_DRAIN`` count SIGNATURES, whatever the
+    entries that carry them."""
 
     def __init__(
         self,
@@ -245,7 +267,7 @@ class VerifyScheduler:
         self.queue_cap = max(int(queue_cap), 1)
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
-        self._queues: "list[deque[_Item]]" = [
+        self._queues: "list[deque[_Entry]]" = [
             deque() for _ in range(N_CLASSES)
         ]
         self._count = 0
@@ -254,7 +276,7 @@ class VerifyScheduler:
         self._paused = False
         self._full_target: Optional[int] = None
         # flush-interval histo — stamped at DISPATCH SUBMISSION on the
-        # dispatcher thread (monotonic there), never from item drain
+        # dispatcher thread (monotonic there), never from entry drain
         # times: with K flushes in flight, drain-time deltas could go
         # negative or interleave
         self._last_flush_t: Optional[float] = None
@@ -272,48 +294,37 @@ class VerifyScheduler:
 
     # -- submission -------------------------------------------------------
 
-    def submit(
-        self,
-        pub: bytes,
-        msg: bytes,
-        sig: bytes,
-        priority: int = PRIO_CONSENSUS,
-        precleared: bool = False,
-        ctx=_AMBIENT,
-    ) -> "Future[bool]":
-        """Queue one (pub, msg, sig) check; returns a Future resolving to
-        the definitive verdict.  A sigcache hit resolves immediately
-        without occupying a queue slot (``precleared=True`` skips that
-        lookup — for bridges that just partitioned the cache themselves).
-        Raises ``QueueFullError`` for non-consensus classes when the queue
-        is at capacity; consensus submissions are always admitted.
-        ``ctx`` is the span the serving flush is traced under: this
-        thread's current one unless ``submit_many`` already took it."""
-        prio = min(max(int(priority), 0), N_CLASSES - 1)
-        fut: "Future[bool]" = Future()
-        if ctx is _AMBIENT:
-            ctx = tracing.current()
-        if not precleared:
-            hit = sigcache.get_cache().get(pub, msg, sig)
-            if hit is not None:
-                stats.record_submit_hit(prio)
-                fut.set_result(bool(hit))
-                return fut
-        try:
-            with self._cond:
-                if self._stopped:
-                    raise RuntimeError("verify scheduler is stopped")
-                if prio != PRIO_CONSENSUS and self._count >= self.queue_cap:
-                    stats.record_shed(prio)
-                    raise QueueFullError(
-                        f"verify queue at capacity ({self.queue_cap}); "
-                        f"shedding class {stats.CLASS_NAMES[prio]}"
-                    )
-                self._queues[prio].append(
-                    _Item(pub, msg, sig, prio, fut, time.perf_counter(), ctx)
+    def _enqueue(self, pubs, msgs, sigs, prio: int, scalar: bool = False):
+        """Queue a segment under ONE acquisition of the lock, with one
+        notify; returns ``(futures, admitted)``: the futures of the entries
+        that hold ``pubs[:admitted]``, in order (one, unless the segment is
+        longer than ``MAX_DRAIN`` and was cut).  Admission is decided once:
+        consensus is always admitted whole; any other class up to
+        ``queue_cap`` signatures queued, and the rest is shed to the
+        caller.  Raises ``RuntimeError`` once the scheduler is stopped."""
+        n = len(pubs)
+        ctx = tracing.current()
+        futs: "list[Future]" = []
+        with self._cond:
+            if self._stopped:
+                raise RuntimeError("verify scheduler is stopped")
+            admitted = n
+            if prio != PRIO_CONSENSUS:
+                admitted = min(n, max(self.queue_cap - self._count, 0))
+            t0 = time.perf_counter()
+            for lo in range(0, admitted, MAX_DRAIN):
+                hi = min(lo + MAX_DRAIN, admitted)
+                entry = _Entry(
+                    pubs[lo:hi], msgs[lo:hi], sigs[lo:hi], prio, t0, ctx,
+                    scalar,
                 )
-                self._count += 1
-                stats.record_submit(prio)
+                self._queues[prio].append(entry)
+                stats.record_submit(prio, hi - lo)
+                futs.append(entry.future)
+            self._count += admitted
+            if admitted < n:
+                stats.record_shed(prio, n - admitted)
+            if admitted:
                 if self._thread is None or not self._thread.is_alive():
                     # lazily started — and RESTARTED if it ever died (an
                     # exception escaping even the _execute fallback, e.g.
@@ -323,7 +334,7 @@ class VerifyScheduler:
                     if self._thread is not None:
                         logger.error(
                             "verify dispatcher thread died; restarting "
-                            "(%d items pending)",
+                            "(%d signatures pending)",
                             self._count,
                         )
                     self._thread = threading.Thread(
@@ -331,7 +342,7 @@ class VerifyScheduler:
                     )
                     self._thread.start()
                 self._cond.notify_all()
-        except QueueFullError:
+        if admitted < n:
             # flight-recorder anomaly (the FIRST shed dumps the ring;
             # later sheds are counted), recorded AFTER the cond is
             # released: the dump's file IO must never block other
@@ -341,36 +352,56 @@ class VerifyScheduler:
                 "queue_shed",
                 cls=stats.CLASS_NAMES[prio],
                 queue_cap=self.queue_cap,
+                items=n - admitted,
             )
-            raise
-        return fut
+        return futs, admitted
 
-    def submit_many(
+    def submit(
+        self,
+        pub: bytes,
+        msg: bytes,
+        sig: bytes,
+        priority: int = PRIO_CONSENSUS,
+    ) -> "Future[bool]":
+        """Queue one (pub, msg, sig) check — the n = 1 segment — and
+        return a Future resolving to its definitive verdict.  A sigcache
+        hit resolves immediately without occupying a queue slot.  Raises
+        ``QueueFullError`` for non-consensus classes when the queue is at
+        capacity; consensus submissions are always admitted."""
+        prio = _clamp_prio(priority)
+        hit = sigcache.get_cache().get(pub, msg, sig)
+        if hit is not None:
+            stats.record_submit_hit(prio)
+            fut: "Future[bool]" = Future()
+            fut.set_result(bool(hit))
+            return fut
+        futs, admitted = self._enqueue([pub], [msg], [sig], prio, scalar=True)
+        if not admitted:
+            raise QueueFullError(
+                f"verify queue at capacity ({self.queue_cap}); "
+                f"shedding class {stats.CLASS_NAMES[prio]}"
+            )
+        return futs[0]
+
+    def submit_segment(
         self,
         pubs: Sequence[bytes],
         msgs: Sequence[bytes],
         sigs: Sequence[bytes],
         priority: int = PRIO_CONSENSUS,
-        precleared: bool = False,
-    ) -> "list[Optional[Future]]":
-        """Submit a whole segment before waiting on any item, so the
-        pieces can coalesce into one flush.  Entries the admission control
-        sheds come back as ``None`` — the caller verifies those itself.
-        A scheduler stopped mid-segment (teardown race) marks the rest
-        ``None`` the same way: already-queued futures still resolve (close
-        drains the queue), the remainder degrade to the caller's fallback.
-        The submitter's trace context is taken once, not once a signature."""
-        out: "list[Optional[Future]]" = []
-        ctx = tracing.current()
-        for p, m, s in zip(pubs, msgs, sigs):
-            try:
-                out.append(self.submit(p, m, s, priority, precleared, ctx))
-            except QueueFullError:
-                out.append(None)
-            except RuntimeError:
-                out.extend([None] * (len(msgs) - len(out)))
-                break
-        return out
+    ) -> "tuple[list[Future], int]":
+        """Queue a whole segment of cache MISSES (the batch seam has
+        already taken its hits) as one entry with one future, which
+        resolves to the segment's verdicts by index.  Returns ``(futures,
+        admitted)`` as ``_enqueue`` does: the signatures from ``admitted``
+        on were shed by admission control and the caller verifies those
+        itself.  A scheduler stopped under the caller (teardown race)
+        admits nothing, so the whole segment degrades to the caller's
+        fallback the same way."""
+        try:
+            return self._enqueue(pubs, msgs, sigs, _clamp_prio(priority))
+        except RuntimeError:
+            return [], 0
 
     # -- test/bench hooks -------------------------------------------------
 
@@ -429,7 +460,7 @@ class VerifyScheduler:
     # -- dispatcher -------------------------------------------------------
 
     def _bucket_target(self) -> int:
-        """Items that fill the smallest padding bucket for the active
+        """Signatures that fill the smallest padding bucket for the active
         kernel: flushing there costs zero padding waste, so waiting any
         longer only adds latency.  The base bucket is computed once, off
         the submit path (the ops import pulls in jax); the LIVE elastic
@@ -479,15 +510,23 @@ class VerifyScheduler:
         heads = [q[0].t0 for q in self._queues if q]
         return min(heads) if heads else None
 
-    def _drain(self) -> "list[_Item]":
-        out: "list[_Item]" = []
+    def _drain(self) -> "list[_Entry]":
+        """Whole entries, consensus first, up to ``MAX_DRAIN`` signatures
+        and always at least one entry (none is longer: ``_enqueue`` cuts);
+        an entry that does not fit stays at the head of its queue, and no
+        lower class overtakes it."""
+        out: "list[_Entry]" = []
+        n = 0
         now = time.perf_counter()
         for q in self._queues:  # consensus first
-            while q and len(out) < MAX_DRAIN:
-                it = q.popleft()
-                it.t_drain = now
-                out.append(it)
-        self._count -= len(out)
+            while q and (not out or n + q[0].n <= MAX_DRAIN):
+                e = q.popleft()
+                e.t_drain = now
+                out.append(e)
+                n += e.n
+            if q:
+                break
+        self._count -= n
         return out
 
     def _run(self) -> None:
@@ -534,157 +573,192 @@ class VerifyScheduler:
                         continue
                     if self._count == 0:
                         continue
-                items = self._drain()
-            if items:
-                self._execute(items, reason)
+                entries = self._drain()
+            if entries:
+                self._execute(entries, reason)
 
     # -- flush ------------------------------------------------------------
 
-    def _execute(self, items: "list[_Item]", reason: str) -> None:
+    def _execute(self, entries: "list[_Entry]", reason: str) -> None:
         recorded = [False]
         try:
             if pipeline_enabled():
                 # in-flight pipeline: dispatch without blocking on the
                 # verdicts — enqueueing onto the completion FIFO is the
                 # LAST step, so any exception reaching the fallback below
-                # means these items were never handed off and the host
+                # means these entries were never handed off and the host
                 # reference resolve covers all of them
-                self._dispatch_flush(items, reason, recorded)
+                self._dispatch_flush(entries, reason, recorded)
             else:
-                self._execute_inner(items, reason, recorded)
+                self._execute_inner(entries, reason, recorded)
         except BaseException as e:  # noqa: BLE001 — futures must ALWAYS
-            # resolve: these items left the queue, so the submit-path
+            # resolve: these entries left the queue, so the submit-path
             # dispatcher restart can never recover them — an unresolved
             # future here is a permanent consensus hang in result()
+            n = sum(en.n for en in entries)
             logger.exception(
-                "verify flush failed unexpectedly; resolving %d items on "
-                "the host reference",
-                len(items),
+                "verify flush failed unexpectedly; resolving %d signatures "
+                "on the host reference",
+                n,
             )
-            from cometbft_tpu.crypto import ed25519_ref as ref
-
             # exactly-once flush accounting: if the inner pass failed
-            # before recording, account the drained items here or
+            # before recording, account the drained signatures here or
             # queue_depth stays inflated forever
             if not recorded[0]:
-                stats.record_flush(
-                    reason, items=len(items), misses=0, lanes=0
-                )
+                stats.record_flush(reason, items=n, misses=0, lanes=0)
+            bits: "list[Optional[bool]]" = [None] * n
+            self._answer_on_reference(entries, bits)
             now = time.perf_counter()
-            for it in items:
-                if it.future.done():
-                    continue
-                try:
-                    ok = len(it.pub) == 32 and len(it.sig) == 64 and bool(
-                        ref.verify_zip215(it.pub, it.msg, it.sig)
-                    )
-                except Exception:  # noqa: BLE001 — malformed input
-                    ok = False
-                it.future.set_result(ok)
-                stats.record_verdict(it.prio, now - it.t0)
+            for en, part in self._slices(entries, bits):
+                self._finish(en, part, now)
             if not isinstance(e, Exception):
                 raise  # SystemExit etc.: die, but only AFTER resolving
                 # (the next submit detects the dead thread and restarts)
 
     @staticmethod
-    def _flush_span(reason: str, items: "list[_Item]"):
-        """``sched.flush``, saying where its items came from: the trace ids
-        it serves (a flush may serve several requests), the first
-        submitter's span as its parent, and how long the oldest item
-        waited in the queue."""
+    def _answer_on_reference(entries, bits) -> None:
+        """Fill the bits still ``None`` (flat over the entries) from the
+        host reference; an entry whose future is already resolved is
+        skipped."""
+        i = 0
+        for en in entries:
+            if en.future.done():
+                i += en.n
+                continue
+            for p, m, s in zip(en.pubs, en.msgs, en.sigs):
+                if bits[i] is None:
+                    bits[i] = _ref_bit(p, m, s)
+                i += 1
+
+    @staticmethod
+    def _slices(entries, bits):
+        """Each entry with its own part of the flat ``bits``."""
+        lo = 0
+        for en in entries:
+            yield en, bits[lo:lo + en.n]
+            lo += en.n
+
+    @staticmethod
+    def _flush_span(reason: str, entries: "list[_Entry]", n: int):
+        """``sched.flush``, saying where its signatures came from: the
+        trace ids it serves (a flush may serve several requests), the
+        first submitter's span as its parent, and how long the oldest
+        entry waited in the queue."""
         if not tracing.enabled():
             return tracing.span("sched.flush")
-        ctxs = {it.ctx for it in items}
+        ctxs = {en.ctx for en in entries}
         ctxs.discard(None)
         return tracing.span(
             "sched.flush",
-            parent=items[0].ctx,
+            parent=entries[0].ctx,
             reason=reason,
-            items=len(items),
+            items=n,
+            segments=len(entries),
             traces=sorted({c.trace_id for c in ctxs}),
             queue_wait_s=round(
-                items[0].t_drain - min(it.t0 for it in items), 9
+                entries[0].t_drain - min(en.t0 for en in entries), 9
             ),
         )
 
     @staticmethod
-    def _set_results(items, bits, now: float, lo: int, hi: int) -> None:
-        for i in range(lo, hi):
-            it = items[i]
-            if it.future.done():
+    def _plan(entries: "list[_Entry]"):
+        """The front half both flush paths share, over the entries' lists
+        laid end to end.  Structural filter: garbage never occupies a
+        device lane.  In-flight dedup ACROSS the entries: concurrent
+        gossip of the same vote collapses into one lane, every index that
+        holds it shares the verdict.  One work segment per priority class
+        present: ``verify_segments`` fuses them into ONE dispatch
+        (recording cross-class fusion in ops/dispatch_stats) and splits
+        the bits back per class.  Returns ``(bits, uniq, ordered, work,
+        lanes)``: ``bits`` flat with the filtered indices already False,
+        ``ordered`` the first index of each dedup group in ``work``'s
+        order."""
+        pubs: "list[bytes]" = []
+        msgs: "list[bytes]" = []
+        sigs: "list[bytes]" = []
+        prios: "list[int]" = []
+        for en in entries:
+            pubs.extend(en.pubs)
+            msgs.extend(en.msgs)
+            sigs.extend(en.sigs)
+            prios.extend([en.prio] * en.n)
+        n = len(pubs)
+        bits: "list[Optional[bool]]" = [None] * n
+        uniq: "OrderedDict[bytes, list[int]]" = OrderedDict()
+        for i in range(n):
+            if len(pubs[i]) != 32 or len(sigs[i]) != 64:
+                bits[i] = False
                 continue
-            it.future.set_result(bool(bits[i]))
-            stats.record_verdict(
-                it.prio,
-                now - it.t0,
-                queue_wait_s=it.t_drain - it.t0,
-                device_s=now - it.t_drain,
-            )
+            k = sigcache._key(pubs[i], msgs[i], sigs[i])
+            uniq.setdefault(k, []).append(i)
+        # every index that passed the filter, beyond its group's first
+        stats.record_dedup(n - bits.count(False) - len(uniq))
+        ordered: "list[int]" = []
+        work: "list[tuple]" = []
+        lanes = 0
+        if uniq:
+            from cometbft_tpu.ops import verify as ov
 
-    def _resolve(self, items, bits, fsp, settle=None) -> None:
+            by_class: "list[list[int]]" = [[] for _ in range(N_CLASSES)]
+            for ixs in uniq.values():
+                by_class[prios[ixs[0]]].append(ixs[0])
+            ordered = [i for cls in by_class for i in cls]
+            work = [
+                (
+                    [pubs[i] for i in cls],
+                    [msgs[i] for i in cls],
+                    [sigs[i] for i in cls],
+                )
+                for cls in by_class
+                if cls
+            ]
+            lanes = ov.bucket_size(len(ordered), ov._min_bucket())
+        return bits, uniq, ordered, work, lanes
+
+    @staticmethod
+    def _finish(en: "_Entry", bits, now: float) -> None:
+        """Resolve one entry: n signatures' worth of statistics in one
+        weighted observation, then its one ``set_result`` — the waiter
+        wakes to bookkeeping already done."""
+        if en.future.done():
+            return
+        stats.record_verdict(
+            en.prio,
+            now - en.t0,
+            len(bits),
+            queue_wait_s=en.t_drain - en.t0,
+            device_s=now - en.t_drain,
+        )
+        en.future.set_result(bits[0] if en.scalar else bits)
+
+    def _resolve(self, entries, bits, fsp, settle=None) -> None:
         """``sched.resolve``: ``settle()`` (the verdict map and the cache
-        puts of a fetched flush), then ``set_result`` for every item.  The
-        span lands BEFORE the last future resolves, so a deterministic
-        sim's ring order cannot race its waiter; that one ``set_result``
-        is the part of the stage the span does not hold."""
-        n = len(items)
-        last = max(n - 1, 0)
-        with tracing.span("sched.resolve", parent=fsp, items=n):
+        puts of a fetched flush), then each entry's slice of ``bits`` to
+        its future.  The span lands BEFORE the last future resolves, so a
+        deterministic sim's ring order cannot race its waiter; that one
+        ``_finish`` is the part of the stage the span does not hold."""
+        with tracing.span("sched.resolve", parent=fsp, items=len(bits)):
             if settle is not None:
                 settle()
             now = time.perf_counter()
-            self._set_results(items, bits, now, 0, last)
-        self._set_results(items, bits, now, last, n)
+            *head, last = self._slices(entries, bits)
+            for en, part in head:
+                self._finish(en, part, now)
+        self._finish(*last, now)
 
     def _execute_inner(
-        self, items: "list[_Item]", reason: str, recorded: "list[bool]"
+        self, entries: "list[_Entry]", reason: str, recorded: "list[bool]"
     ) -> None:
-        n = len(items)
-        pubs = [it.pub for it in items]
-        msgs = [it.msg for it in items]
-        sigs = [it.sig for it in items]
-
+        n = sum(en.n for en in entries)
         # flush span (closed BEFORE futures resolve, like the stats below,
         # so a deterministic sim's ring order cannot race its waiters)
-        with self._flush_span(reason, items) as fsp:
-            # structural filter (garbage never occupies a device lane) +
-            # in-flight dedup: concurrent gossip of the same vote collapses
-            # into one lane, both futures share the verdict
-            bits: "list[Optional[bool]]" = [None] * n
-            uniq: "OrderedDict[bytes, list[int]]" = OrderedDict()
-            for i in range(n):
-                if len(pubs[i]) != 32 or len(sigs[i]) != 64:
-                    bits[i] = False
-                    continue
-                k = sigcache._key(pubs[i], msgs[i], sigs[i])
-                uniq.setdefault(k, []).append(i)
-            firsts = [ixs[0] for ixs in uniq.values()]
-            stats.record_dedup(sum(len(ixs) - 1 for ixs in uniq.values()))
-
-            lanes = 0
-            if firsts:
+        with self._flush_span(reason, entries, n) as fsp:
+            bits, uniq, ordered, work, lanes = self._plan(entries)
+            if work:
                 from cometbft_tpu.ops import verify as ov
 
-                # one segment per priority class present: verify_segments
-                # fuses them into ONE dispatch (recording cross-class
-                # fusion in ops/dispatch_stats), splits bits back per class
-                by_class: "list[list[int]]" = [[] for _ in range(N_CLASSES)]
-                for i in firsts:
-                    by_class[items[i].prio].append(i)
-                ordered = [i for cls in by_class for i in cls]
-                work = [
-                    (
-                        [pubs[i] for i in cls],
-                        [msgs[i] for i in cls],
-                        [sigs[i] for i in cls],
-                    )
-                    for cls in by_class
-                    if cls
-                ]
-                lanes = ov.bucket_size(len(ordered), ov._min_bucket())
-                results = ov.verify_segments(work)
-                self._settle(bits, uniq, ordered, results)
-            fsp.set(misses=len(firsts), lanes=lanes)
+                self._settle(bits, uniq, ordered, ov.verify_segments(work))
+            fsp.set(misses=len(ordered), lanes=lanes)
 
         # record BEFORE resolving: set_result unblocks waiters, and a
         # caller reading stats right after its verdict (the sim's
@@ -693,11 +767,11 @@ class VerifyScheduler:
         # fallback from double-counting if a resolve below raises
         interval = self._flush_interval()
         stats.record_flush(
-            reason, items=n, misses=len(firsts), lanes=lanes,
+            reason, items=n, misses=len(ordered), lanes=lanes,
             interval_s=interval,
         )
         recorded[0] = True
-        self._resolve(items, bits, fsp)
+        self._resolve(entries, bits, fsp)
 
     @staticmethod
     def _settle(bits, uniq, ordered, results) -> None:
@@ -734,53 +808,24 @@ class VerifyScheduler:
         return interval
 
     def _dispatch_flush(
-        self, items: "list[_Item]", reason: str, recorded: "list[bool]"
+        self, entries: "list[_Entry]", reason: str, recorded: "list[bool]"
     ) -> None:
-        """The pipelined front half of a flush: structural filter +
-        dedup + ONE fused dispatch (``ops.verify.dispatch_segments``),
+        """The pipelined front half of a flush: ``_plan`` (filter, dedup,
+        per-class work) + ONE fused dispatch (``ops.verify.dispatch_segments``),
         then hand the in-flight handle to the completion thread and
         return to draining — up to ``inflight_target()`` flushes ride
         the device concurrently, round-robined across healthy mesh
         lanes.  Identical front-half semantics to ``_execute_inner``;
         only WHERE the fetch happens moves."""
-        n = len(items)
-        pubs = [it.pub for it in items]
-        msgs = [it.msg for it in items]
-        sigs = [it.sig for it in items]
+        n = sum(en.n for en in entries)
         interval = self._flush_interval()
 
-        with self._flush_span(reason, items) as fsp:
-            bits: "list[Optional[bool]]" = [None] * n
-            uniq: "OrderedDict[bytes, list[int]]" = OrderedDict()
-            for i in range(n):
-                if len(pubs[i]) != 32 or len(sigs[i]) != 64:
-                    bits[i] = False
-                    continue
-                k = sigcache._key(pubs[i], msgs[i], sigs[i])
-                uniq.setdefault(k, []).append(i)
-            firsts = [ixs[0] for ixs in uniq.values()]
-            stats.record_dedup(sum(len(ixs) - 1 for ixs in uniq.values()))
-
-            lanes = 0
+        with self._flush_span(reason, entries, n) as fsp:
+            bits, uniq, ordered, work, lanes = self._plan(entries)
             handle = None
-            ordered: "list[int]" = []
-            if firsts:
+            if work:
                 from cometbft_tpu.ops import verify as ov
 
-                by_class: "list[list[int]]" = [[] for _ in range(N_CLASSES)]
-                for i in firsts:
-                    by_class[items[i].prio].append(i)
-                ordered = [i for cls in by_class for i in cls]
-                work = [
-                    (
-                        [pubs[i] for i in cls],
-                        [msgs[i] for i in cls],
-                        [sigs[i] for i in cls],
-                    )
-                    for cls in by_class
-                    if cls
-                ]
-                lanes = ov.bucket_size(len(ordered), ov._min_bucket())
                 self._ensure_fetch_thread()
                 cap = max(inflight_target(), 1)
                 # reserve an in-flight slot BEFORE dispatching — the cap
@@ -832,21 +877,23 @@ class VerifyScheduler:
                         stats.record_inflight(self._inflight)
                         self._fcond.notify_all()
                     raise
-            fsp.set(misses=len(firsts), lanes=lanes)
+            fsp.set(misses=len(ordered), lanes=lanes)
 
         stats.record_flush(
-            reason, items=n, misses=len(firsts), lanes=lanes,
+            reason, items=n, misses=len(ordered), lanes=lanes,
             interval_s=interval,
         )
         recorded[0] = True
         if handle is None:
             # nothing device-bound (all garbage/empty): resolve inline
-            self._resolve(items, bits, fsp)
+            self._resolve(entries, bits, fsp)
             return
         with self._fcond:
             # the flush span rides along: the completion thread's spans
             # are its children
-            self._fetch_queue.append((handle, items, bits, uniq, ordered, fsp))
+            self._fetch_queue.append(
+                (handle, entries, bits, uniq, ordered, fsp)
+            )
             self._fcond.notify_all()
 
     def _ensure_fetch_thread(self) -> None:
@@ -890,12 +937,12 @@ class VerifyScheduler:
         completion thread in drain order; cannot leave a future
         unresolved — a fetch that somehow escapes the supervisor's
         degradation chain resolves the flush on the host reference."""
-        handle, items, bits, uniq, ordered, fsp = pf
+        handle, entries, bits, uniq, ordered, fsp = pf
         results = None
         try:
             from cometbft_tpu.ops import verify as ov
 
-            with tracing.span("sched.fetch", parent=fsp, items=len(items)):
+            with tracing.span("sched.fetch", parent=fsp, items=len(bits)):
                 results = ov.fetch_segments(handle)
         except BaseException:  # noqa: BLE001 — swallow even SystemExit:
             # the completion thread must outlive one bad flush or every
@@ -911,23 +958,12 @@ class VerifyScheduler:
             if None not in bits:
                 return
             logger.error(
-                "resolving the flush's unanswered items on the host reference"
+                "resolving the flush's unanswered signatures on the host "
+                "reference"
             )
-            from cometbft_tpu.crypto import ed25519_ref as ref
+            self._answer_on_reference(entries, bits)
 
-            for i, it in enumerate(items):
-                if it.future.done() or bits[i] is not None:
-                    continue
-                try:
-                    bits[i] = len(it.pub) == 32 and len(
-                        it.sig
-                    ) == 64 and bool(
-                        ref.verify_zip215(it.pub, it.msg, it.sig)
-                    )
-                except Exception:  # noqa: BLE001 — malformed input
-                    bits[i] = False
-
-        self._resolve(items, bits, fsp, settle)
+        self._resolve(entries, bits, fsp, settle)
 
 
 # -- process-wide instance ----------------------------------------------------
@@ -1050,44 +1086,42 @@ def verify_segment_sync(
 ) -> "list[bool]":
     """The batch-verifier bridge: submit a pre-partitioned segment of raw
     ed25519 triples (the caller — ``_CollectingVerifier`` — already took
-    its cache hits) and wait for all verdicts.  Entries shed by admission
-    control are verified in one direct supervised dispatch instead, so the
-    call never blocks on queue capacity."""
+    its cache hits) as ONE queue entry and wait on its one future.  The
+    tail that admission control shed is verified in one direct supervised
+    dispatch instead, so the call never blocks on queue capacity."""
     prio = current_priority() if priority is None else priority
-    with tracing.span("sched.segment", items=len(pubs)) as seg:
+    n = len(pubs)
+    with tracing.span("sched.segment", items=n) as seg:
         # submit and wait end while the dispatcher writes its own spans:
         # timed here, recorded together after the wait (``tracing.Lap``)
         with tracing.lap("sched.submit") as submitted:
-            futs = get_scheduler().submit_many(
-                pubs, msgs, sigs, prio, precleared=True
+            futs, admitted = get_scheduler().submit_segment(
+                pubs, msgs, sigs, prio
             )
-        shed = [i for i, f in enumerate(futs) if f is None]
-        direct: dict = {}
-        if shed:
+        direct: "list[bool]" = []
+        if admitted < n:
             from cometbft_tpu.ops import verify as ov
 
             t0 = time.perf_counter()
             with tracing.span(
                 "sched.shed_fallback",
                 cls=stats.CLASS_NAMES[_clamp_prio(prio)],
-                items=len(shed),
+                items=n - admitted,
             ):
                 got = ov.verify_batch(
-                    [pubs[i] for i in shed],
-                    [msgs[i] for i in shed],
-                    [sigs[i] for i in shed],
+                    pubs[admitted:], msgs[admitted:], sigs[admitted:]
                 )
-            dt = time.perf_counter() - t0
-            for _ in shed:
-                # every shed item experienced the whole direct dispatch —
-                # that IS its submit->verdict latency, kept in the record
-                stats.record_shed_fallback(prio, dt)
-            direct = {i: bool(b) for i, b in zip(shed, got)}
+            # every shed signature experienced the whole direct dispatch —
+            # that IS its submit->verdict latency, kept in the record
+            stats.record_shed_fallback(
+                prio, time.perf_counter() - t0, n - admitted
+            )
+            direct = [bool(b) for b in got]
+        out: "list[bool]" = []
         with tracing.lap("sched.wait") as waited:
-            out = [
-                direct[i] if f is None else bool(f.result())
-                for i, f in enumerate(futs)
-            ]
-        submitted.record(parent=seg, items=len(futs), shed=len(shed))
-        waited.record(parent=seg, futures=len(futs) - len(shed))
+            for f in futs:
+                out.extend(f.result())
+        out.extend(direct)
+        submitted.record(parent=seg, items=n, shed=n - admitted)
+        waited.record(parent=seg, futures=len(futs))
     return out

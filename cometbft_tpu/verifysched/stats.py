@@ -8,10 +8,14 @@ lane count at flush time, where ``ops.verify`` is already imported, so this
 module never has to).
 
 Counters (all guarded by one lock):
-  * ``submitted[class]``     — items admitted to the queue, per priority class
+  * ``submitted[class]``     — signatures admitted to the queue, per priority
+    class
+  * ``segments[class]``      — queue entries those signatures arrived as (one
+    entry a segment, n >= 1 signatures; ``submitted / segments`` is the
+    signatures an entry)
   * ``submit_hits[class]``   — submissions resolved from the sigcache without
     ever occupying a queue slot
-  * ``shed[class]``          — submissions rejected by admission control
+  * ``shed[class]``          — signatures rejected by admission control
     (never ``consensus``: that class is exempt from shedding by design)
   * ``queue_depth``          — items currently pending (gauge-style)
   * ``flushes[reason]``      — dispatcher flushes by trigger:
@@ -24,8 +28,11 @@ Counters (all guarded by one lock):
     occupied (occupancy = flush_misses / flush_lanes)
   * ``dedup_hits``           — duplicate in-flight triples collapsed into a
     single lane at flush time (concurrent gossip of the same vote)
-  * ``verdicts[class]`` / ``latency_seconds[class]`` — resolved futures and
-    cumulative submit->verdict latency, per priority class
+  * ``verdicts[class]`` / ``latency_seconds[class]`` — resolved signatures
+    and their cumulative submit->verdict latency, per priority class
+
+Every count and every histogram sample is one SIGNATURE: an entry of n
+signatures is recorded once, with weight n.
 
 Hot-path latency HISTOGRAMS (docs/observability.md) — real distributions,
 not just cumulative sums, rendered on /metrics as histogram series:
@@ -56,6 +63,7 @@ _LOCK = threading.Lock()
 def _zero() -> dict:
     return {
         "submitted": {c: 0 for c in CLASS_NAMES},
+        "segments": {c: 0 for c in CLASS_NAMES},
         "submit_hits": {c: 0 for c in CLASS_NAMES},
         "shed": {c: 0 for c in CLASS_NAMES},
         "queue_depth": 0,
@@ -86,10 +94,13 @@ def _cls(priority: int) -> str:
     return CLASS_NAMES[min(max(int(priority), 0), len(CLASS_NAMES) - 1)]
 
 
-def record_submit(priority: int) -> None:
+def record_submit(priority: int, n: int = 1) -> None:
+    """One entry of ``n`` signatures admitted to the queue."""
     with _LOCK:
-        _STATS["submitted"][_cls(priority)] += 1
-        _STATS["queue_depth"] += 1
+        c = _cls(priority)
+        _STATS["submitted"][c] += n
+        _STATS["segments"][c] += 1
+        _STATS["queue_depth"] += n
 
 
 def record_submit_hit(priority: int) -> None:
@@ -97,9 +108,9 @@ def record_submit_hit(priority: int) -> None:
         _STATS["submit_hits"][_cls(priority)] += 1
 
 
-def record_shed(priority: int) -> None:
+def record_shed(priority: int, n: int = 1) -> None:
     with _LOCK:
-        _STATS["shed"][_cls(priority)] += 1
+        _STATS["shed"][_cls(priority)] += n
 
 
 def record_flush(
@@ -138,35 +149,37 @@ def record_dedup(n: int) -> None:
 def record_verdict(
     priority: int,
     latency_s: float,
+    n: int = 1,
     queue_wait_s: "float | None" = None,
     device_s: "float | None" = None,
 ) -> None:
-    """One resolved future.  ``queue_wait_s`` (submit->drain) and
-    ``device_s`` (drain->verdict) are recorded as SEPARATE distributions
-    when the dispatcher knows them — a latency regression then names the
-    guilty half instead of hiding in the conflated total."""
+    """One resolved entry: ``n`` signatures that share its times.
+    ``queue_wait_s`` (submit->drain) and ``device_s`` (drain->verdict)
+    are recorded as SEPARATE distributions when the dispatcher knows
+    them — a latency regression then names the guilty half instead of
+    hiding in the conflated total."""
     with _LOCK:
         c = _cls(priority)
-        _STATS["verdicts"][c] += 1
-        _STATS["latency_seconds"][c] += float(latency_s)
-        _STATS["latency_hist"][c].observe(float(latency_s))
+        _STATS["verdicts"][c] += n
+        _STATS["latency_seconds"][c] += float(latency_s) * n
+        _STATS["latency_hist"][c].observe(float(latency_s), n)
         if queue_wait_s is not None:
-            _STATS["queue_wait_seconds"][c] += float(queue_wait_s)
-            _STATS["queue_wait_hist"][c].observe(float(queue_wait_s))
+            _STATS["queue_wait_seconds"][c] += float(queue_wait_s) * n
+            _STATS["queue_wait_hist"][c].observe(float(queue_wait_s), n)
         if device_s is not None:
-            _STATS["device_hist"][c].observe(float(device_s))
+            _STATS["device_hist"][c].observe(float(device_s), n)
 
 
-def record_shed_fallback(priority: int, latency_s: float) -> None:
+def record_shed_fallback(priority: int, latency_s: float, n: int = 1) -> None:
     """A shed (or scheduler-inactive-mid-teardown) caller finished its
-    synchronous fallback verify: the sample lands in the SAME
-    submit->verdict latency record as scheduled work, so shedding can
-    never silently improve the histogram it degraded."""
+    synchronous fallback verify of ``n`` signatures: the samples land in
+    the SAME submit->verdict latency record as scheduled work, so
+    shedding can never silently improve the histogram it degraded."""
     with _LOCK:
         c = _cls(priority)
-        _STATS["shed_fallback"][c] += 1
-        _STATS["latency_seconds"][c] += float(latency_s)
-        _STATS["latency_hist"][c].observe(float(latency_s))
+        _STATS["shed_fallback"][c] += n
+        _STATS["latency_seconds"][c] += float(latency_s) * n
+        _STATS["latency_hist"][c].observe(float(latency_s), n)
 
 
 def queue_depth() -> int:

@@ -371,17 +371,14 @@ class TestSchedulerIntegration:
         sched.pause()
         try:
             pubs, msgs, sigs = zip(*[_triple(i, b"shed") for i in range(4)])
-            futs = sched.submit_many(
-                pubs, msgs, sigs, verifysched.PRIO_BLOCKSYNC,
-                precleared=True,
+            futs, admitted = sched.submit_segment(
+                pubs, msgs, sigs, verifysched.PRIO_BLOCKSYNC
             )
-            shed = [i for i, f in enumerate(futs) if f is None]
-            assert shed  # cap 1: the rest shed
+            assert admitted == 1  # cap 1: the rest shed
         finally:
             sched.resume()
         for f in futs:
-            if f is not None:
-                f.result(timeout=30)
+            assert f.result(timeout=30) == [True]
         # the scheduler-level wrappers run the fallback; drive one directly
         from cometbft_tpu.crypto.keys import Ed25519PubKey
 

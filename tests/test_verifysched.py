@@ -9,6 +9,7 @@ integration, cache writeback — sits ABOVE that seam and runs unchanged.
 One smoke test exercises a single real dispatch through the full stack.
 """
 
+import functools
 import hashlib
 import threading
 import time
@@ -71,6 +72,19 @@ def _make_sigs(n, tag=b"vs", invalid_every=None):
     return pubs, msgs, sigs
 
 
+def _segment(sched, pubs, msgs, sigs, priority=verifysched.PRIO_CONSENSUS):
+    """Queue one segment, admitted whole; its futures (one, unless the
+    segment is longer than ``MAX_DRAIN``)."""
+    futs, admitted = sched.submit_segment(pubs, msgs, sigs, priority)
+    assert admitted == len(pubs)
+    return futs
+
+
+def _verdicts(futs, timeout=30):
+    """The segments' verdicts by index, end to end."""
+    return [b for f in futs for b in f.result(timeout=timeout)]
+
+
 def _oracle(pubs, msgs, sigs):
     return [
         len(p) == 32
@@ -95,10 +109,9 @@ class TestSchedulerCore:
         pubs[5], sigs[11] = b"\x01" * 31, b"\x02" * 63
         sched = verifysched.get_scheduler()
         sched.pause()
-        futs = sched.submit_many(pubs, msgs, sigs)
+        futs = _segment(sched, pubs, msgs, sigs)
         sched.resume()
-        got = [f.result(timeout=30) for f in futs]
-        assert got == _oracle(pubs, msgs, sigs)
+        assert _verdicts(futs) == _oracle(pubs, msgs, sigs)
 
     def test_concurrent_submitters_coalesce_fewer_dispatches(self, sched_env):
         """THE acceptance property: under 8 concurrent submitters the
@@ -126,8 +139,8 @@ class TestSchedulerCore:
 
         def submitter(t):
             barrier.wait()
-            futs = sched.submit_many(*batches[t], priority=prios[t])
-            results[t] = [f.result(timeout=30) for f in futs]
+            futs = _segment(sched, *batches[t], priority=prios[t])
+            results[t] = _verdicts(futs)
 
         threads = [
             threading.Thread(target=submitter, args=(t,))
@@ -183,8 +196,8 @@ class TestSchedulerCore:
         sched = VerifyScheduler(flush_us=5_000_000)
         try:
             pubs, msgs, sigs = _make_sigs(32, b"full")
-            futs = sched.submit_many(pubs, msgs, sigs)
-            assert [f.result(timeout=30) for f in futs] == [True] * 32
+            futs = _segment(sched, pubs, msgs, sigs)
+            assert _verdicts(futs) == [True] * 32
             assert sstats.snapshot()["flushes"]["full"] >= 1
         finally:
             sched.close()
@@ -200,35 +213,40 @@ class TestSchedulerCore:
         finally:
             sched.close()
 
-    def test_dispatcher_restarts_after_death(self, sched_env):
+    @pytest.mark.parametrize("n", [1, 6], ids=["vote", "segment"])
+    def test_dispatcher_restarts_after_death(self, sched_env, n):
         """A dispatcher killed by an escaping BaseException must not turn
-        the scheduler into a future-black-hole: the drained items resolve
-        on the host fallback BEFORE the thread dies, and the next submit
-        detects the dead thread and restarts it."""
+        the scheduler into a future-black-hole: the drained entry resolves
+        on the host fallback BEFORE the thread dies (a vote's bit, a
+        segment's verdicts by index), and the next submit detects the dead
+        thread and restarts it."""
         sched = VerifyScheduler(flush_us=500)
+
+        def ask(pubs, msgs, sigs):
+            if n == 1:
+                return [sched.submit(pubs[0], msgs[0], sigs[0]).result(30)]
+            return _verdicts(_segment(sched, pubs, msgs, sigs))
+
         try:
-            pubs, msgs, sigs = _make_sigs(1, b"dead")
+            pubs, msgs, sigs = _make_sigs(n, b"dead", invalid_every=5)
             orig_inner = sched._execute_inner
             orig_disp = sched._dispatch_flush
 
-            def dying(items, reason, recorded):
+            def dying(entries, reason, recorded):
                 raise SystemExit  # BaseException: kills the thread
 
             # both flush paths (pipelined and single-flight) must feed the
             # same host-fallback-then-die contract
             sched._execute_inner = dying
             sched._dispatch_flush = dying
-            f1 = sched.submit(pubs[0], msgs[0], sigs[0])
             # already-drained future still resolves (host fallback)...
-            assert f1.result(timeout=30) is True
+            assert ask(pubs, msgs, sigs) == _oracle(pubs, msgs, sigs)
             t = sched._thread
             t.join(10)
             assert not t.is_alive()  # ...and THEN the thread died
             sched._execute_inner = orig_inner
             sched._dispatch_flush = orig_disp
-            p2, m2, s2 = _make_sigs(1, b"alive")
-            f2 = sched.submit(p2[0], m2[0], s2[0])
-            assert f2.result(timeout=30) is True
+            assert ask(*_make_sigs(n, b"alive")) == [True] * n
             assert sched._thread is not t  # a fresh dispatcher took over
             assert sstats.snapshot()["queue_depth"] == 0
         finally:
@@ -238,12 +256,251 @@ class TestSchedulerCore:
         sched = VerifyScheduler(flush_us=10_000_000)
         pubs, msgs, sigs = _make_sigs(3, b"shut")
         sched.pause()
-        futs = sched.submit_many(pubs, msgs, sigs)
+        futs = _segment(sched, pubs, msgs, sigs)
         sched.close()  # overrides pause; every future must resolve
-        assert [f.result(timeout=30) for f in futs] == [True] * 3
+        assert _verdicts(futs) == [True] * 3
         assert sstats.snapshot()["flushes"]["shutdown"] >= 1
         with pytest.raises(RuntimeError):
             sched.submit(pubs[0], msgs[0], b"\x00" * 64)
+
+
+# ----------------------------------------------------------------------
+# the queue's unit: one entry, one lock, one future a segment
+# ----------------------------------------------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def _lib_sigs(n, tag=b"seg"):
+    """n valid triples signed by the host library over 8 keys (the
+    reference signer takes 2.4 ms a signature; 1,500 are wanted).  Cached:
+    copy before tampering."""
+    keys = [
+        Ed25519PrivKey.from_seed(hashlib.sha256(b"%s-key-%d" % (tag, i)).digest())
+        for i in range(8)
+    ]
+    pubs, msgs, sigs = [], [], []
+    for i in range(n):
+        k = keys[i % 8]
+        msgs.append(b"%s-msg-%d" % (tag, i))
+        pubs.append(k.pub_key().bytes())
+        sigs.append(k.sign(msgs[-1]))
+    return pubs, msgs, sigs
+
+
+def _tampered(sigs, *at):
+    out = list(sigs)
+    for i in at:
+        out[i] = out[i][:32] + bytes([out[i][32] ^ 1]) + out[i][33:]
+    return out
+
+
+def _lib_runner(backend, pubs, msgs, sigs, lanes):
+    """Device stand-in on the host library (0.2 ms a signature against the
+    reference's 4 ms): same verdicts on valid and bit-flipped signatures."""
+    out = np.zeros(lanes, dtype=bool)
+    out[: len(pubs)] = [
+        Ed25519PubKey(p).verify_signature(m, s)
+        for p, m, s in zip(pubs, msgs, sigs)
+    ]
+    return out
+
+
+class TestSegmentUnit:
+    @pytest.mark.parametrize("n", [1, 117, 1500])
+    def test_segment_is_one_entry_one_future(self, sched_env, n):
+        """A segment of n is ONE queue entry and ONE future, and every
+        statistic still counts n signatures."""
+        supervisor.set_device_runner(_lib_runner)
+        pubs, msgs, sigs = _lib_sigs(n)
+        sigs = _tampered(sigs, n // 2)
+        sched = verifysched.get_scheduler()
+        sched.pause()
+        futs = _segment(sched, pubs, msgs, sigs)
+        assert len(futs) == 1
+        assert sum(len(q) for q in sched._queues) == 1
+        assert sched.pending() == n
+        snap = sstats.snapshot()
+        assert snap["submitted"]["consensus"] == n
+        assert snap["segments"]["consensus"] == 1
+        assert snap["queue_depth"] == n
+        sched.resume()
+        assert futs[0].result(timeout=60) == [i != n // 2 for i in range(n)]
+        snap = sstats.snapshot()
+        assert snap["queue_depth"] == 0
+        assert snap["verdicts"]["consensus"] == n
+        for hist in ("queue_wait_hist", "device_hist", "latency_hist"):
+            assert snap[hist]["consensus"]["count"] == n
+        assert snap["flush_items"] == n
+        assert sum(snap["flushes"].values()) == 1
+
+    def test_paused_segment_flushes_once_full(self, sched_env):
+        """One 1,500-signature segment is one flush, reason ``full`` (the
+        deadline is out of reach), and ``sched.flush`` says it served one
+        entry."""
+        from cometbft_tpu.libs import tracing
+
+        supervisor.set_device_runner(_lib_runner)
+        tracing.get_tracer().reset()
+        pubs, msgs, sigs = _lib_sigs(1500)
+        sched = VerifyScheduler(flush_us=60_000_000)
+        try:
+            sched.pause()
+            futs = _segment(sched, pubs, msgs, sigs)
+            sched.resume()
+            assert _verdicts(futs, 60) == [True] * 1500
+        finally:
+            sched.close()
+        assert sstats.snapshot()["flushes"] == {
+            "deadline": 0, "full": 1, "shutdown": 0,
+        }
+        (flush,) = [
+            sp
+            for sp in tracing.get_tracer().tail(100)
+            if sp["stage"] == "sched.flush"
+        ]
+        assert flush["attrs"]["segments"] == 1
+        assert flush["attrs"]["items"] == 1500
+        assert flush["attrs"]["reason"] == "full"
+
+    @pytest.mark.parametrize("at", [0, 58, 116], ids=["first", "middle", "last"])
+    def test_per_index_bits(self, sched_env, at):
+        """Every index gets its own bit: the one tampered signature is
+        named wherever it sits."""
+        supervisor.set_device_runner(_lib_runner)
+        pubs, msgs, sigs = _lib_sigs(117)
+        got = verifysched.verify_segment_sync(
+            pubs, msgs, _tampered(sigs, at), verifysched.PRIO_CONSENSUS
+        )
+        assert got == [i != at for i in range(117)]
+
+    def test_bulk_segment_admitted_up_to_cap(self, sched_env, monkeypatch):
+        """A bulk segment that would pass ``queue_cap`` is admitted up to
+        the cap, decided once, and the direct dispatch answers the rest
+        (the tampered index lies in the shed tail)."""
+        monkeypatch.setenv("COMETBFT_TPU_SCHED_QUEUE", "8")
+        verifysched.reset_scheduler()
+        pubs, msgs, sigs = _make_sigs(12, b"cap")
+        sigs = _tampered(sigs, 10)
+        got = verifysched.verify_segment_sync(
+            pubs, msgs, sigs, verifysched.PRIO_BLOCKSYNC
+        )
+        assert got == [i != 10 for i in range(12)]
+        snap = sstats.snapshot()
+        assert snap["submitted"]["bulk"] == 8
+        assert snap["segments"]["bulk"] == 1
+        assert snap["shed"]["bulk"] == 4
+        assert snap["shed_fallback"]["bulk"] == 4
+        assert snap["latency_hist"]["bulk"]["count"] == 12
+        assert snap["queue_depth"] == 0
+
+    def test_consensus_segment_admitted_whole_past_cap(self, sched_env):
+        sched = VerifyScheduler(flush_us=1000, queue_cap=8)
+        try:
+            sched.pause()
+            pubs, msgs, sigs = _make_sigs(12, b"cap-cons")
+            bulk, admitted = sched.submit_segment(
+                pubs, msgs, sigs, verifysched.PRIO_BLOCKSYNC
+            )
+            assert admitted == 8 and len(bulk) == 1
+            # the queue is at its cap: nothing more of a sheddable class...
+            assert sched.submit_segment(
+                pubs, msgs, sigs, verifysched.PRIO_LIGHT
+            ) == ([], 0)
+            # ...and consensus whole
+            cons = _segment(sched, pubs, msgs, sigs)
+            assert len(cons) == 1 and sched.pending() == 20
+            sched.resume()
+            assert _verdicts(bulk) == [True] * 8
+            assert _verdicts(cons) == [True] * 12
+            snap = sstats.snapshot()
+            assert snap["shed"] == {
+                "consensus": 0, "evidence_light": 12, "bulk": 4,
+            }
+            assert snap["queue_depth"] == 0
+        finally:
+            sched.close()
+
+    def test_segment_longer_than_max_drain_resolves_whole(
+        self, sched_env, monkeypatch
+    ):
+        """A segment longer than ``MAX_DRAIN`` is cut into entries of at
+        most ``MAX_DRAIN`` when it is submitted, no flush carries more,
+        and the caller still gets every index's bit in order."""
+        from cometbft_tpu.verifysched import service
+
+        monkeypatch.setattr(service, "MAX_DRAIN", 16)
+        pubs, msgs, sigs = _make_sigs(50, b"cut", invalid_every=7)
+        sched = verifysched.get_scheduler()
+        sched.pause()
+        futs = _segment(sched, pubs, msgs, sigs)
+        assert [len(q) for q in sched._queues] == [4, 0, 0]
+        sched.resume()
+        assert [len(f.result(timeout=30)) for f in futs] == [16, 16, 16, 2]
+        assert _verdicts(futs) == _oracle(pubs, msgs, sigs)
+        snap = sstats.snapshot()
+        assert snap["segments"]["consensus"] == 4
+        assert snap["flush_items"] == 50
+        assert sum(snap["flushes"].values()) == 4
+        assert verifysched.verify_segment_sync(
+            pubs, msgs, sigs, verifysched.PRIO_CONSENSUS
+        ) == _oracle(pubs, msgs, sigs)
+
+    def test_same_triple_in_two_segments_one_lane(self, sched_env):
+        """In-flight dedup works ACROSS the entries of a flush: the same
+        vote in two peers' segments is one lane, both get its verdict."""
+        pubs, msgs, sigs = _make_sigs(7, b"dup-seg")
+        sigs = _tampered(sigs, 3)
+        sched = verifysched.get_scheduler()
+        sched.pause()
+        a = _segment(sched, pubs[:5], msgs[:5], sigs[:5])
+        b = _segment(sched, pubs[3:], msgs[3:], sigs[3:])  # 3 and 4 again
+        sched.resume()
+        want = _oracle(pubs, msgs, sigs)
+        assert _verdicts(a) == want[:5]
+        assert _verdicts(b) == want[3:]
+        snap = sstats.snapshot()
+        assert snap["dedup_hits"] == 2
+        assert snap["flush_misses"] == 7
+        assert snap["flush_items"] == 9
+        assert sum(snap["flushes"].values()) == 1
+
+    @pytest.mark.parametrize("pipeline", ["0", "1"])
+    def test_flush_that_raises_resolves_on_reference(
+        self, sched_env, monkeypatch, pipeline
+    ):
+        monkeypatch.setenv("COMETBFT_TPU_SCHED_PIPELINE", pipeline)
+        pubs, msgs, sigs = _make_sigs(9, b"boom-%s" % pipeline.encode(), 4)
+        sched = VerifyScheduler(flush_us=500)
+        try:
+
+            def boom(entries):
+                raise ValueError("planted")
+
+            sched._plan = boom
+            futs = _segment(sched, pubs, msgs, sigs)
+            assert _verdicts(futs) == _oracle(pubs, msgs, sigs)
+            snap = sstats.snapshot()
+            assert snap["queue_depth"] == 0
+            assert snap["verdicts"]["consensus"] == 9
+        finally:
+            sched.close()
+
+    def test_fetch_that_raises_resolves_on_reference(
+        self, sched_env, monkeypatch
+    ):
+        """The completion thread answers what a lost fetch left open."""
+        from cometbft_tpu.ops import verify as ov
+
+        def lost(handle):
+            raise OSError("planted")
+
+        monkeypatch.setattr(ov, "fetch_segments", lost)
+        pubs, msgs, sigs = _make_sigs(9, b"lost", invalid_every=4)
+        pubs[2] = b"\x01" * 31  # filtered before the device: stays False
+        sched = verifysched.get_scheduler()
+        futs = _segment(sched, pubs, msgs, sigs)
+        assert _verdicts(futs) == _oracle(pubs, msgs, sigs)
+        assert sstats.snapshot()["inflight_depth"] == 0
 
 
 # ----------------------------------------------------------------------
@@ -507,10 +764,9 @@ class TestSupervisorIntegration:
         pubs, msgs, sigs = _make_sigs(24, b"flt-%s" % mode.encode(), invalid_every=4)
         sched = verifysched.get_scheduler()
         sched.pause()
-        futs = sched.submit_many(pubs, msgs, sigs)
+        futs = _segment(sched, pubs, msgs, sigs)
         sched.resume()
-        got = [f.result(timeout=60) for f in futs]
-        assert got == _oracle(pubs, msgs, sigs)
+        assert _verdicts(futs, 60) == _oracle(pubs, msgs, sigs)
         snap = backend_health.snapshot()
         assert snap["demotions"] >= 1
         assert snap["fallback_signatures"] > 0  # resolved on the host tier
@@ -521,8 +777,8 @@ class TestSupervisorIntegration:
         supervisor.set_fault_injector(supervisor.FaultyBackend("raise"))
         pubs, msgs, sigs = _make_sigs(8, b"nnc")
         sched = verifysched.get_scheduler()
-        futs = sched.submit_many(pubs, msgs, sigs)
-        assert all(f.result(timeout=60) is True for f in futs)
+        futs = _segment(sched, pubs, msgs, sigs)
+        assert _verdicts(futs, 60) == [True] * 8
         supervisor.clear_fault_injector()
         assert all(
             verifysched.verify_cached(Ed25519PubKey(p), m, s)
@@ -573,8 +829,7 @@ class TestInflightPipeline:
         monkeypatch.setenv("COMETBFT_TPU_SCHED_PIPELINE", "0")
         sched = VerifyScheduler(flush_us=500)
         try:
-            futs = sched.submit_many(pubs, msgs, sigs)
-            single = [f.result(timeout=60) for f in futs]
+            single = _verdicts(_segment(sched, pubs, msgs, sigs), 60)
         finally:
             sched.close()
         assert single == _oracle(pubs, msgs, sigs)
@@ -584,8 +839,7 @@ class TestInflightPipeline:
         monkeypatch.setenv("COMETBFT_TPU_SCHED_INFLIGHT", "3")
         sched = VerifyScheduler(flush_us=500)
         try:
-            futs = sched.submit_many(pubs, msgs, sigs)
-            piped = [f.result(timeout=60) for f in futs]
+            piped = _verdicts(_segment(sched, pubs, msgs, sigs), 60)
         finally:
             sched.close()
         assert piped == single
@@ -608,19 +862,19 @@ class TestInflightPipeline:
         try:
             a = _make_sigs(4, b"ovl-a")
             b = _make_sigs(4, b"ovl-b")
-            futs = sched.submit_many(*a)
+            futs = _segment(sched, *a)
             deadline = time.perf_counter() + 10
             # flush A dispatched, its fetch parked on the gate...
             while dispatch_stats.snapshot()["inflight_depth"] < 1:
                 assert time.perf_counter() < deadline
                 threading.Event().wait(0.005)
             # ...and flush B ships right behind it
-            futs += sched.submit_many(*b)
+            futs += _segment(sched, *b)
             while dispatch_stats.snapshot()["inflight_depth"] < 2:
                 assert time.perf_counter() < deadline
                 threading.Event().wait(0.005)
             gate.set()
-            assert all(f.result(timeout=30) is True for f in futs)
+            assert _verdicts(futs) == [True] * 8
         finally:
             gate.set()
             sched.close()
@@ -649,14 +903,12 @@ class TestInflightPipeline:
             for r in range(self.WIDTH):
                 sched.pause()
                 lo, hi = r * 6, (r + 1) * 6
-                futs += sched.submit_many(
-                    pubs[lo:hi], msgs[lo:hi], sigs[lo:hi]
+                futs += _segment(
+                    sched, pubs[lo:hi], msgs[lo:hi], sigs[lo:hi]
                 )
                 sched.resume()
-                assert all(
-                    f.result(timeout=60) is not None for f in futs[lo:hi]
-                )
-            got = [f.result(timeout=60) for f in futs]
+                assert len(futs[-1].result(timeout=60)) == hi - lo
+            got = _verdicts(futs, 60)
         finally:
             sched.close()
         assert got == _oracle(pubs, msgs, sigs)
@@ -688,14 +940,12 @@ class TestInflightPipeline:
             for r in range(self.WIDTH):
                 sched.pause()
                 lo, hi = r * 4, (r + 1) * 4
-                futs += sched.submit_many(
-                    pubs[lo:hi], msgs[lo:hi], sigs[lo:hi]
+                futs += _segment(
+                    sched, pubs[lo:hi], msgs[lo:hi], sigs[lo:hi]
                 )
                 sched.resume()
-                assert all(
-                    f.result(timeout=60) is not None for f in futs[lo:hi]
-                )
-            got = [f.result(timeout=60) for f in futs]
+                assert len(futs[-1].result(timeout=60)) == hi - lo
+            got = _verdicts(futs, 60)
         finally:
             sched.close()
         assert got == _oracle(pubs, msgs, sigs)
@@ -714,8 +964,7 @@ class TestInflightPipeline:
         pubs, msgs, sigs = _make_sigs(12, b"pipe-off", invalid_every=4)
         sched = VerifyScheduler(flush_us=500)
         try:
-            futs = sched.submit_many(pubs, msgs, sigs)
-            got = [f.result(timeout=30) for f in futs]
+            got = _verdicts(_segment(sched, pubs, msgs, sigs))
         finally:
             sched.close()
         assert got == _oracle(pubs, msgs, sigs)
@@ -751,8 +1000,8 @@ class TestMetricsAndTooling:
 
         pubs, msgs, sigs = _make_sigs(3, b"met")
         sched = verifysched.get_scheduler()
-        futs = sched.submit_many(pubs, msgs, sigs)
-        assert all(f.result(timeout=30) for f in futs)
+        futs = _segment(sched, pubs, msgs, sigs)
+        assert _verdicts(futs) == [True] * 3
         out = NodeMetrics().registry.expose()
         assert 'cometbft_sched_submitted{class="consensus"} 3' in out
         assert 'cometbft_sched_shed{class="consensus"} 0' in out
@@ -809,10 +1058,9 @@ def test_real_dispatch_smoke(monkeypatch):
         pubs, msgs, sigs = _make_sigs(6, b"real", invalid_every=3)
         sched = verifysched.get_scheduler()
         sched.pause()
-        futs = sched.submit_many(pubs, msgs, sigs)
+        futs = _segment(sched, pubs, msgs, sigs)
         sched.resume()
-        got = [f.result(timeout=300) for f in futs]
-        assert got == _oracle(pubs, msgs, sigs)
+        assert _verdicts(futs, 300) == _oracle(pubs, msgs, sigs)
         assert sstats.snapshot()["flush_lanes"] == 32
     finally:
         verifysched.reset_scheduler()
