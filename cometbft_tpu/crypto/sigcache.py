@@ -216,6 +216,9 @@ def verify_with_cache(pub_key, msg: bytes, sig: bytes) -> bool:
     cache = get_cache()
     hit = cache.get(pub, msg, sig)
     if hit is not None:
+        from cometbft_tpu.libs import tracing
+
+        tracing.mark(hit=True)
         return hit
     ok = bool(pub_key.verify_signature(msg, sig))
     cache.put(pub, msg, sig, ok)

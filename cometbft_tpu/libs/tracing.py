@@ -898,6 +898,13 @@ class Tracer:
                 e = g["nodes"][node] = {"node": node, "steps": {}}
             return e
 
+        # a light client's step is a trace of its own (``light.verify`` is
+        # the root of its commit passes), as standalone as a bare call
+        light_traces = {
+            sp.trace_id
+            for sp in ring
+            if sp.stage == "light.verify" and sp.parent_id is None
+        }
         for sp in ring:
             if sp.t_end is None:
                 continue
@@ -931,7 +938,7 @@ class Tracer:
                     sp.duration
                 )
             elif sp.stage == "verify.commit":
-                if sp.parent_id is None:
+                if sp.parent_id is None or sp.trace_id in light_traces:
                     # a standalone verification (light client, statesync
                     # trust check, the sim's invariant checker): its own
                     # trace root by construction — not a linkage failure
@@ -1079,6 +1086,15 @@ def lap(stage: str) -> Lap:
 
 def current() -> Optional[Span]:
     return get_tracer().current() if enabled() else None
+
+
+def mark(**attrs) -> None:
+    """Set attributes on this thread's innermost open span, if there is one:
+    how a layer below says what it found (a single-signature lookup that the
+    signature cache answered marks ``hit`` on ``consensus.vote``)."""
+    sp = current()
+    if sp is not None:
+        sp.set(**attrs)
 
 
 def record_anomaly(kind: str, **attrs) -> Optional[str]:

@@ -13,6 +13,7 @@ import time
 from dataclasses import dataclass
 from fractions import Fraction
 
+from cometbft_tpu.libs import tracing
 from cometbft_tpu.types import validation
 from cometbft_tpu.types.basic import Timestamp
 from cometbft_tpu.types.light import LightBlock
@@ -94,17 +95,31 @@ def verify_adjacent(
     delaying consensus votes (docs/verify-scheduler.md)."""
     from cometbft_tpu import verifysched
 
-    _check_adjacent_headers(
-        chain_id, trusted, new, trusting_period_s, now, max_clock_drift_s
+    with _verify_span(trusted, new, True):
+        with tracing.span("light.checks"):
+            _check_adjacent_headers(
+                chain_id, trusted, new, trusting_period_s, now, max_clock_drift_s
+            )
+        with verifysched.priority_class(verifysched.PRIO_LIGHT):
+            validation.verify_commit_light(
+                chain_id,
+                new.validator_set,
+                new.signed_header.commit.block_id,
+                new.height,
+                new.signed_header.commit,
+            )
+
+
+def _verify_span(trusted: LightBlock, new: LightBlock, adjacent: bool):
+    """``light.verify`` around one step of the verifier, with ``light.checks``
+    (the header, time and hash-link checks: a header hash and the new set's
+    Merkle root) and the commit passes as its children."""
+    return tracing.span(
+        "light.verify",
+        adjacent=adjacent,
+        height=new.height,
+        trusted_height=trusted.height,
     )
-    with verifysched.priority_class(verifysched.PRIO_LIGHT):
-        validation.verify_commit_light(
-            chain_id,
-            new.validator_set,
-            new.signed_header.commit.block_id,
-            new.height,
-            new.signed_header.commit,
-        )
 
 
 def _check_adjacent_headers(
@@ -242,31 +257,35 @@ def verify_non_adjacent(
         return verify_adjacent(
             chain_id, trusted, new, trusting_period_s, now, max_clock_drift_s
         )
-    if header_expired(trusted.signed_header.header.time, trusting_period_s, now):
-        raise ErrOldHeaderExpired("trusted header expired")
-    _validate_new_block(chain_id, trusted, new, now, max_clock_drift_s)
     from cometbft_tpu import verifysched
 
-    # >trust_level of the TRUSTED set signed the new header
-    try:
+    with _verify_span(trusted, new, False):
+        with tracing.span("light.checks"):
+            if header_expired(
+                trusted.signed_header.header.time, trusting_period_s, now
+            ):
+                raise ErrOldHeaderExpired("trusted header expired")
+            _validate_new_block(chain_id, trusted, new, now, max_clock_drift_s)
+        # >trust_level of the TRUSTED set signed the new header
+        try:
+            with verifysched.priority_class(verifysched.PRIO_LIGHT):
+                validation.verify_commit_light_trusting(
+                    chain_id,
+                    trusted.validator_set,
+                    new.signed_header.commit,
+                    trust_level=trust_level,
+                )
+        except validation.NotEnoughPowerError as e:
+            raise ErrNewValSetCantBeTrusted(str(e)) from e
+        # and +2/3 of the NEW set signed it
         with verifysched.priority_class(verifysched.PRIO_LIGHT):
-            validation.verify_commit_light_trusting(
+            validation.verify_commit_light(
                 chain_id,
-                trusted.validator_set,
+                new.validator_set,
+                new.signed_header.commit.block_id,
+                new.height,
                 new.signed_header.commit,
-                trust_level=trust_level,
             )
-    except validation.NotEnoughPowerError as e:
-        raise ErrNewValSetCantBeTrusted(str(e)) from e
-    # and +2/3 of the NEW set signed it
-    with verifysched.priority_class(verifysched.PRIO_LIGHT):
-        validation.verify_commit_light(
-            chain_id,
-            new.validator_set,
-            new.signed_header.commit.block_id,
-            new.height,
-            new.signed_header.commit,
-        )
 
 
 class ErrNewValSetCantBeTrusted(VerificationError):

@@ -10,11 +10,13 @@ decides the path:
 
   * kill switch off (``COMETBFT_TPU_PROOFSERVE=0``) → the exact serial
     reference, restoring pre-plane behavior bit for bit;
-  * tiny trees (fewer than ``COMETBFT_TPU_MERKLE_MIN_BATCH`` leaves,
-    default 32 — a 14-field header hash, a 4-validator valset) → the
-    reference as well: bucket padding + dispatch latency would dwarf the
-    14 hashes, and the reference IS the correctness oracle so there is
-    nothing to gate;
+  * trees of fewer than ``COMETBFT_TPU_MERKLE_MIN_BATCH`` leaves → the
+    reference as well.  The default, ``DEFAULT_MIN_BATCH``, lies above the
+    tree kernel's whole ladder, because on the chip the device tree was
+    slower than the host tree at every size it takes (the readings are
+    beside the constant): a node hashes on the host unless its operator
+    lowers the variable.  The reference IS the correctness oracle, so
+    there is nothing to gate;
   * everything else → ``ops/sha256_tree.tree_root``/``tree_proofs``,
     which itself supervises device→host degradation behind the
     ``merkle_device`` breaker.
@@ -29,7 +31,15 @@ import os
 
 from cometbft_tpu.crypto import merkle
 
-DEFAULT_MIN_BATCH = 32
+# No crossover inside the tree kernel's ladder (8 to 16,384 lanes) on a TPU
+# v5e (by hand, PR 28, medians of five, device tree against
+# ``merkle.hash_from_byte_slices``, ms): 32 leaves 7.27 / 0.06; 128 10.1 / 0.20;
+# 512 13.3 / 0.80; 1,000 16.2 / 1.55; 4,096 31.7 / 6.29; 16,384 85.0 / 26.3.
+# One leaf pass and one pass a level, each fetched to the host, cost about
+# 7 ms before the first leaf and 4.8 us a leaf after it; the host tree costs
+# 1.6 us a leaf.  So the default sends every tree to the host; ROADMAP.md D4
+# decides what becomes of the device plane.
+DEFAULT_MIN_BATCH = 32768
 
 
 def enabled() -> bool:
@@ -49,11 +59,27 @@ def min_batch() -> int:
         return DEFAULT_MIN_BATCH
 
 
+def _on_reference(leaves: int) -> bool:
+    """The one gate of the plane: switched off, or a tree too small."""
+    return not enabled() or leaves < min_batch()
+
+
+def tier_for(leaves: int) -> str:
+    """Where a tree of ``leaves`` goes: ``device`` or ``host`` (what a span
+    around a hashing call site says; a device pass that degrades says so in
+    its own ``merkle.tree`` span)."""
+    if _on_reference(leaves):
+        return "host"
+    from cometbft_tpu.ops import sha256_tree
+
+    return "device" if sha256_tree.device_active() else "host"
+
+
 def tree_hash(items) -> bytes:
     """Merkle root of ``items`` — bit-identical to
     ``merkle.hash_from_byte_slices`` on every path."""
     items = list(items)
-    if not enabled() or len(items) < min_batch():
+    if _on_reference(len(items)):
         return merkle.hash_from_byte_slices(items)
     from cometbft_tpu.ops import sha256_tree
 
@@ -64,7 +90,7 @@ def tree_proofs(items):
     """(root, [Proof]) for ``items`` — bit-identical to
     ``merkle.proofs_from_byte_slices`` on every path."""
     items = list(items)
-    if not enabled() or len(items) < min_batch():
+    if _on_reference(len(items)):
         return merkle.proofs_from_byte_slices(items)
     from cometbft_tpu.ops import sha256_tree
 

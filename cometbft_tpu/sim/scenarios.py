@@ -2645,6 +2645,12 @@ def run_scenario(
 
     _sstats.reset()
     _istats.reset()
+    # the signature cache is per-run too: ``consensus.vote`` spans say
+    # whether the cache answered (``hit``), and a second same-seed run in
+    # one process would find every signature of the first one there
+    from cometbft_tpu.crypto import sigcache as _sigcache
+
+    _sigcache.reset_cache()
     # proof-plane counters are per-run too: every scenario's commits hash
     # through the plane, and a soak row must reflect ITS run alone
     from cometbft_tpu.proofserve import stats as _pstats

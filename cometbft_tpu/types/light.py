@@ -74,9 +74,18 @@ class LightBlock:
         err = self.signed_header.validate_basic(chain_id)
         if err:
             return err
-        if (
-            self.signed_header.header.validators_hash
-            != self.validator_set.hash()
-        ):
+        if self.signed_header.header.validators_hash != self._validators_hash():
             return "validator set does not match header validators_hash"
         return None
+
+    def _validators_hash(self) -> bytes:
+        """The set's Merkle root under a ``valset.hash`` span: the one hash
+        of a light client's request that grows with the set.  The span is
+        here and not in ``ValidatorSet.hash``, which a node calls several
+        times a block."""
+        from cometbft_tpu.libs import tracing
+        from cometbft_tpu.proofserve import plane
+
+        n = len(self.validator_set)
+        with tracing.span("valset.hash", leaves=n, tier=plane.tier_for(n)):
+            return self.validator_set.hash()

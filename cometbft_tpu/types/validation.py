@@ -139,16 +139,21 @@ def _tally(entries, tallied: int, count_all: bool, voting_power_needed: int):
 
 
 @contextlib.contextmanager
-def _commit_span(commit: Commit, count_all: bool):
+def _commit_span(commit: Commit, mode: str):
     """``verify.commit`` around a WHOLE public call, basic checks included;
     the verify-latency histogram is fed from the span's own readings (a
-    call that raises records the span, with its error, and no sample)."""
+    call that raises records the span, with its error, and no sample).
+    ``mode`` is ``full`` / ``light`` / ``trusting``; the trusting pass is a
+    stage of its own, ``verify.commit.trusting``, so that the two passes a
+    skipping light client makes over one commit are told apart in the
+    recorder's totals."""
     t0 = time.perf_counter()
     with tracing.span(
-        "verify.commit",
+        "verify.commit.trusting" if mode == "trusting" else "verify.commit",
         height=getattr(commit, "height", None),
         sigs=len(getattr(commit, "signatures", None) or ()),
-        count_all=count_all,
+        count_all=mode == "full",
+        mode=mode,
     ) as sp:
         yield sp
     dispatch_stats.record_verify_latency(tracing.wall_seconds(sp, t0))
@@ -185,7 +190,10 @@ def _verify_commit(
     entries, tallied = _collect_entries(
         vals, commit, voting_power_needed, count_all, lookup_by_address
     )
-    sp.set(entries=len(entries))
+    # collection stops at the entry that carries the tally past the threshold
+    stopped = not count_all and tallied > voting_power_needed
+    scanned = entries[-1][0] + 1 if stopped else len(commit.signatures)
+    sp.set(entries=len(entries), scanned=scanned, skipped=scanned - len(entries))
 
     # Verify the collected signatures (batch seam).  The batch verifiers
     # pre-filter through the consensus-wide signature cache, so a commit
@@ -318,7 +326,7 @@ def verify_commit(
 ) -> None:
     """Full verification: every signature checked, +2/3 power required
     (reference: types/validation.go:28)."""
-    with _commit_span(commit, True) as sp:
+    with _commit_span(commit, "full") as sp:
         _verify_basic(vals, commit, height, block_id)
         needed = vals.total_voting_power() * 2 // 3
         _verify_commit(chain_id, vals, commit, needed, True, False, backend, sp)
@@ -333,7 +341,7 @@ def verify_commit_light(
     backend: Optional[str] = None,
 ) -> None:
     """Light verification: stop at +2/3 (reference: types/validation.go:63)."""
-    with _commit_span(commit, False) as sp:
+    with _commit_span(commit, "light") as sp:
         _verify_basic(vals, commit, height, block_id)
         needed = vals.total_voting_power() * 2 // 3
         _verify_commit(chain_id, vals, commit, needed, False, False, backend, sp)
@@ -349,7 +357,7 @@ def verify_commit_light_trusting(
     """Trusting-period verification against a possibly different validator
     set; needs > trust_level of this set's power
     (reference: types/validation.go:129)."""
-    with _commit_span(commit, False) as sp:
+    with _commit_span(commit, "trusting") as sp:
         if commit is None or not commit.signatures:
             raise CommitVerificationError("nil or empty commit")
         if trust_level.numerator * 3 < trust_level.denominator:  # < 1/3

@@ -88,8 +88,11 @@ class Vote:
         from cometbft_tpu import verifysched
         from cometbft_tpu.libs import tracing
 
+        # ``hit``: the verdict came from the signature cache (set where the
+        # lookup is made, ``tracing.mark``)
         with tracing.span(
-            "consensus.vote", h=self.height, r=self.round_, t=self.type_
+            "consensus.vote", h=self.height, r=self.round_, t=self.type_,
+            hit=False,
         ) as sp:
             ok = verifysched.verify_cached(
                 pub_key,

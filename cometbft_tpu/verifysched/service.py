@@ -372,6 +372,7 @@ class VerifyScheduler:
         hit = sigcache.get_cache().get(pub, msg, sig)
         if hit is not None:
             stats.record_submit_hit(prio)
+            tracing.mark(hit=True)
             fut: "Future[bool]" = Future()
             fut.set_result(bool(hit))
             return fut
@@ -1037,9 +1038,17 @@ def verify_cached(pub_key, msg: bytes, sig: bytes, priority=None) -> bool:
         pub = _ed25519_pub(pub_key)
         if pub is not None:
             try:
-                return bool(
-                    get_scheduler().submit(pub, msg, sig, prio).result()
-                )
+                # timed here, recorded together after the wait, as in
+                # ``verify_segment_sync``; the parent is the caller's open
+                # span (``consensus.vote``)
+                with tracing.lap("sched.submit") as submitted:
+                    fut = get_scheduler().submit(pub, msg, sig, prio)
+                with tracing.lap("sched.wait") as waited:
+                    ok = bool(fut.result())
+                parent = tracing.current()
+                submitted.record(parent=parent, items=1, shed=0)
+                waited.record(parent=parent, futures=1)
+                return ok
             except (QueueFullError, RuntimeError):
                 # shed, or scheduler torn down under us (reset race):
                 # synchronous fallback — spanned + histogram-sampled
