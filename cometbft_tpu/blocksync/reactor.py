@@ -341,7 +341,7 @@ class BlocksyncReactor(Reactor):
         window = peek(k)
         if len(window) < 3:
             return  # the two-block pipeline covers short runs
-        to_fuse = []  # (fingerprint, height, prepared, bits, miss_indices)
+        to_fuse = []  # (fingerprint, height, prepared, sigcache.Partition)
         for i in range(len(window) - 1):
             h = window[i][0]
             commit = window[i + 1][1].last_commit
@@ -365,13 +365,13 @@ class BlocksyncReactor(Reactor):
                 )
             except validation.CommitVerificationError:
                 continue  # malformed: let the sequential path raise/redo/ban
-            bits, miss = sigcache.partition_misses(
+            part = sigcache.partition_misses(
                 prepared.pubs, prepared.msgs, prepared.sigs
             )
-            if not miss:
+            if not part.miss:
                 self._fused[fp] = h  # fully cached already
                 continue
-            to_fuse.append((fp, h, prepared, bits, miss))
+            to_fuse.append((fp, h, prepared, part))
         if not to_fuse:
             return
         from cometbft_tpu.libs import tracing
@@ -382,23 +382,23 @@ class BlocksyncReactor(Reactor):
                 "blocksync.prefetch",
                 commits=len(to_fuse),
                 h0=to_fuse[0][1],
-                sigs=sum(len(miss) for *_, miss in to_fuse),
+                sigs=sum(len(part.miss) for *_, part in to_fuse),
             ):
                 results = ov.verify_segments(
                     [
                         (
-                            [p.pubs[j] for j in miss],
-                            [p.msgs[j] for j in miss],
-                            [p.sigs[j] for j in miss],
+                            [p.pubs[j] for j in part.miss],
+                            [p.msgs[j] for j in part.miss],
+                            [p.sigs[j] for j in part.miss],
                         )
-                        for _, _, p, _, miss in to_fuse
+                        for _, _, p, part in to_fuse
                     ]
                 )
         except Exception as e:  # noqa: BLE001 — prefetch must never stall sync
             self.logger.error("fused verify prefetch failed", err=repr(e))
             return
-        for (fp, h, p, bits, miss), got in zip(to_fuse, results):
-            sigcache.writeback(p.pubs, p.msgs, p.sigs, bits, miss, got)
+        for (fp, h, _, part), got in zip(to_fuse, results):
+            sigcache.writeback(part, got)
             self._fused[fp] = h
         # trim memo entries behind the frontier
         frontier = self.pool.height

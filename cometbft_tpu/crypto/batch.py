@@ -147,6 +147,13 @@ class _CollectingVerifier(BatchVerifier):
         # entries of the last verify() that needed no backend: cache hits and
         # structural rejects (the ``hits`` of the caller's batch.verify span)
         self.cache_hits = 0
+        # cache keys the last verify() hashed (the span's ``keys``): one for
+        # each structurally possible entry, none with the cache switched off
+        self.keys_hashed = 0
+        # the keys of the entries handed to ``_verify_pending``, aligned with
+        # them (None with the cache off): a backend that queues them for the
+        # scheduler sends the keys along, so its dedup hashes nothing
+        self.pending_keys: Optional[list] = None
 
     def add(self, pub_key, msg: bytes, sig: bytes) -> None:
         data = pub_key.bytes() if hasattr(pub_key, "bytes") else bytes(pub_key)
@@ -167,10 +174,12 @@ class _CollectingVerifier(BatchVerifier):
             return False, []
         from cometbft_tpu.crypto import sigcache
 
-        bits, pending = sigcache.partition_misses(
+        part = sigcache.partition_misses(
             self.pubs, self.msgs, self.sigs, self.PUB_SIZES, self.SIG_SIZES
         )
+        bits, pending = part.bits, part.miss
         self.cache_hits = len(self.pubs) - len(pending)
+        self.keys_hashed = part.hashed
         if pending:
             # Attribution contract: ``_verify_pending`` returns DEFINITIVE
             # verdicts only.  An infrastructure failure must either raise
@@ -178,22 +187,27 @@ class _CollectingVerifier(BatchVerifier):
             # not a False bit) or yield ``None`` for the affected entries
             # (skipped by writeback so a possibly-valid signature is never
             # negative-cached, then surfaced as a BackendError below).
-            got = self._verify_pending(
-                [self.pubs[i] for i in pending],
-                [self.msgs[i] for i in pending],
-                [self.sigs[i] for i in pending],
-            )
-            sigcache.writeback(
-                self.pubs, self.msgs, self.sigs, bits, pending, got
-            )
-        if any(b is None for b in bits):
+            pubs, msgs, sigs = self.pubs, self.msgs, self.sigs
+            if len(pending) < len(pubs):
+                # every entry a miss (every fresh commit) goes on as it is
+                pubs = [pubs[i] for i in pending]
+                msgs = [msgs[i] for i in pending]
+                sigs = [sigs[i] for i in pending]
+            self.pending_keys = part.keys
+            got = self._verify_pending(pubs, msgs, sigs)
+            # the one put of each fresh verdict, whatever path answered it
+            # (scheduler, shed tail, direct dispatch, host): under the key
+            # the look-up hashed, before this call returns
+            sigcache.writeback(part, got)
+        if None in bits:
             from cometbft_tpu.crypto import backend_health
 
             raise backend_health.BackendError(
                 "batch backend produced no definitive verdict for some "
                 "entries (infrastructure failure, not a signature verdict)"
             )
-        bits = [bool(b) for b in bits]
+        # every bit is a bool by now: a structural False, a cached verdict
+        # or ``writeback``'s
         return all(bits) and len(bits) > 0, bits
 
 
@@ -222,7 +236,9 @@ class TpuBatchVerifier(_CollectingVerifier):
             # light/catchup work into one fused dispatch — the scheduler
             # resolves only definitive supervised verdicts, matching this
             # method's attribution contract
-            return verifysched.verify_segment_sync(pubs, msgs, sigs)
+            return verifysched.verify_segment_sync(
+                pubs, msgs, sigs, keys=self.pending_keys
+            )
         from cometbft_tpu.ops import verify as _ops_verify
 
         return [bool(b) for b in _ops_verify.verify_batch(pubs, msgs, sigs)]

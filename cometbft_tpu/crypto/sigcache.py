@@ -27,6 +27,19 @@ Key safety (docs/verify-stream.md):
     that invariant predates this cache (batch vs single verification
     already selected per call site) and is what the self-checks enforce.
 
+The key's life (docs/verify-stream.md "One key a signature"): a triple's key
+is hashed ONCE a request, by whoever looks it up first, and travels with the
+triple from there — ``partition_misses`` returns the keys of its misses,
+``writeback`` takes them back and hashes nothing, and in between they ride the
+scheduler's queue entry, whose in-flight dedup reads them.  Whoever hashed a
+key stores its verdict, once: the batch seam's ``writeback`` for a segment,
+the scheduler's ``_settle`` for the single votes it keyed itself.  The cache
+is visited by the segment: one look-up pass and one put pass, each under one
+acquisition of the lock (``_get_many`` / ``_put_many``), with the hits,
+misses, LRU order and evictions that a loop of ``_get`` / ``_put`` gives.
+``stats()`` counts both: ``keys`` hashed and ``puts`` stored — a fresh commit
+of n signatures reads n and n.
+
 Kill-switch: ``COMETBFT_TPU_SIGCACHE=0`` disables lookups AND inserts,
 restoring the uncached behavior exactly.  ``COMETBFT_TPU_SIGCACHE_SIZE``
 bounds the entry count (default 65536; ~48 B of digest+flag per entry plus
@@ -37,23 +50,24 @@ from __future__ import annotations
 
 import hashlib
 import os
+import struct
 import threading
 from collections import OrderedDict
-from typing import Optional
+from typing import NamedTuple, Optional
 
 DEFAULT_CAPACITY = 65536
 
 
+_U32 = struct.Struct("<I").pack
+
+
 def _key(pub: bytes, msg: bytes, sig: bytes) -> bytes:
-    h = hashlib.sha256()
     # length framing: (pub, msg, sig) concatenations can otherwise alias
-    # across entries with variable-length msgs
-    h.update(len(pub).to_bytes(4, "little"))
-    h.update(pub)
-    h.update(len(msg).to_bytes(4, "little"))
-    h.update(msg)
-    h.update(sig)
-    return h.digest()
+    # across entries with variable-length msgs.  One buffer, one call: the
+    # digest is that of the five pieces fed in turn (tests pin the framing)
+    return hashlib.sha256(
+        b"".join((_U32(len(pub)), pub, _U32(len(msg)), msg, sig))
+    ).digest()
 
 
 class SigCache:
@@ -65,6 +79,8 @@ class SigCache:
         self._entries: "OrderedDict[bytes, bool]" = OrderedDict()
         self._hits = 0
         self._misses = 0
+        self._keys = 0  # keys hashed
+        self._puts = 0  # verdicts stored
 
     @staticmethod
     def enabled() -> bool:
@@ -75,7 +91,16 @@ class SigCache:
         counting: the stats then honestly read as all-miss-no-traffic)."""
         if not self.enabled():
             return None
-        return self._get(_key(pub, msg, sig))
+        return self._get(self.hash_keys((pub,), (msg,), (sig,))[0])
+
+    def hash_keys(self, pubs, msgs, sigs) -> "list[bytes]":
+        """The triples' cache keys, counted in ``stats()["keys"]``: every
+        key hashed for this cache comes through here, so the count says how
+        often a request pays the SHA-256."""
+        keys = [_key(p, m, s) for p, m, s in zip(pubs, msgs, sigs)]
+        with self._lock:
+            self._keys += len(keys)
+        return keys
 
     def _get(self, k: bytes) -> Optional[bool]:
         """Lookup past the kill-switch check — batch callers
@@ -90,17 +115,47 @@ class SigCache:
             self._hits += 1
             return v
 
+    def _get_many(self, keys) -> "list[Optional[bool]]":
+        """``[self._get(k) for k in keys]`` under ONE acquisition of the
+        lock: the same verdicts, counts and LRU order (a look-up evicts
+        nothing, so reading all the keys before moving the hits to the end,
+        in order, is what the loop does)."""
+        entries = self._entries
+        with self._lock:
+            out = [entries.get(k) for k in keys]
+            misses = out.count(None)
+            if misses < len(out):
+                for k, v in zip(keys, out):
+                    if v is not None:
+                        entries.move_to_end(k)
+            self._hits += len(out) - misses
+            self._misses += misses
+        return out
+
     def put(self, pub: bytes, msg: bytes, sig: bytes, ok: bool) -> None:
         if not self.enabled():
             return
-        self._put(_key(pub, msg, sig), ok)
+        self._put(self.hash_keys((pub,), (msg,), (sig,))[0], ok)
 
     def _put(self, k: bytes, ok: bool) -> None:
+        self._put_many((k,), (ok,))
+
+    def _put_many(self, keys, verdicts) -> None:
+        """A loop of ``_put`` under ONE acquisition of the lock: inserted
+        and moved to the end in order, then the oldest evicted down to the
+        capacity — the survivors and their order are those of evicting
+        after every insert (an LRU keeps the last ``capacity`` distinct
+        keys by their latest touch, whenever it trims)."""
+        entries = self._entries
         with self._lock:
-            self._entries[k] = bool(ok)
-            self._entries.move_to_end(k)
-            while len(self._entries) > self.capacity:
-                self._entries.popitem(last=False)
+            n = 0
+            for k, ok in zip(keys, verdicts):
+                entries[k] = bool(ok)
+                entries.move_to_end(k)
+                n += 1
+            self._puts += n
+            while len(entries) > self.capacity:
+                entries.popitem(last=False)
 
     def __len__(self) -> int:
         with self._lock:
@@ -111,15 +166,20 @@ class SigCache:
             self._entries.clear()
             self._hits = 0
             self._misses = 0
+            self._keys = 0
+            self._puts = 0
 
     def stats(self) -> dict:
         with self._lock:
             hits, misses = self._hits, self._misses
+            keys, puts = self._keys, self._puts
             size = len(self._entries)
         total = hits + misses
         return {
             "hits": hits,
             "misses": misses,
+            "keys": keys,
+            "puts": puts,
             "size": size,
             "capacity": self.capacity,
             "hit_rate": (hits / total) if total else 0.0,
@@ -153,73 +213,115 @@ def reset_cache() -> None:
         _CACHE = None
 
 
+class Partition(NamedTuple):
+    """What ``partition_misses`` found; ``writeback`` takes it back whole."""
+
+    bits: list  # verdict by index, ``None`` where ``miss`` says so
+    miss: list  # the indices the caller has to verify
+    keys: Optional[list]  # their cache keys, by ``miss``; None: cache off
+    hashed: int  # keys hashed for the look-up (hits and misses)
+
+
+def _all_sized(items, sizes: tuple) -> bool:
+    """Every item of an allowed length (no ``sizes``: no rule), asked of the
+    whole list at once: the answer is yes for every commit a node builds."""
+    return not sizes or set(map(len, items)).issubset(sizes)
+
+
 def partition_misses(
     pubs,
     msgs,
     sigs,
     pub_sizes: tuple = (32,),
     sig_sizes: tuple = (64,),
-):
+) -> Partition:
     """THE cache/structural prefilter, shared by every consumer (batch
     verifiers, blocksync window prefetch, light-client chain sync) so the
     size rules and get/put protocol cannot diverge.
 
-    Returns (bits, miss_indices): ``bits[i]`` is the resolved verdict —
-    False for structurally impossible pub/sig lengths (they must never
-    occupy backend lanes), the cached verdict on a hit — or None for the
-    entries listed in ``miss_indices``, which the caller verifies and
-    feeds to ``writeback``.  Empty ``pub_sizes``/``sig_sizes`` disable
-    that structural filter."""
+    ``bits[i]`` is the resolved verdict — False for structurally impossible
+    pub/sig lengths (they must never occupy backend lanes, and get no key),
+    the cached verdict on a hit — or None for the entries listed in
+    ``miss``, which the caller verifies and feeds to ``writeback``.  Each
+    structurally possible triple is hashed once and all are looked up in
+    one visit to the cache; the misses' ``keys`` go on with them (to the
+    scheduler's dedup, then to ``writeback``), so nobody hashes them again.
+    Empty ``pub_sizes``/``sig_sizes`` disable that structural filter."""
     cache = get_cache()
-    enabled = cache.enabled()  # hoisted: one env read per batch, not per sig
-    bits: list = [None] * len(pubs)
+    n = len(pubs)
+    bits: list = [None] * n
+    cand = list(range(n))
+    if not (_all_sized(pubs, pub_sizes) and _all_sized(sigs, sig_sizes)):
+        cand = [
+            i
+            for i in cand
+            if (not pub_sizes or len(pubs[i]) in pub_sizes)
+            and (not sig_sizes or len(sigs[i]) in sig_sizes)
+        ]
+        bits = [False] * n
+        for i in cand:
+            bits[i] = None
+        pubs = [pubs[i] for i in cand]
+        msgs = [msgs[i] for i in cand]
+        sigs = [sigs[i] for i in cand]
+    if not cache.enabled():  # one env read per batch, not per sig
+        return Partition(bits, cand, None, 0)
+    keys = cache.hash_keys(pubs, msgs, sigs)
+    got = cache._get_many(keys)
+    if got.count(None) == len(got):  # every fresh commit: nothing to sort
+        return Partition(bits, cand, keys, len(keys))
     miss: list = []
-    for i, (p, m, s) in enumerate(zip(pubs, msgs, sigs)):
-        if (pub_sizes and len(p) not in pub_sizes) or (
-            sig_sizes and len(s) not in sig_sizes
-        ):
-            bits[i] = False
-            continue
-        hit = cache._get(_key(p, m, s)) if enabled else None
-        if hit is not None:
+    miss_keys: list = []
+    for i, k, hit in zip(cand, keys, got):
+        if hit is None:
+            miss.append(i)
+            miss_keys.append(k)
+        else:
             bits[i] = hit
-            continue
-        miss.append(i)
-    return bits, miss
+    return Partition(bits, miss, miss_keys, len(keys))
 
 
-def writeback(pubs, msgs, sigs, bits, miss_indices, results) -> None:
+def writeback(part: Partition, results) -> None:
     """Resolve ``partition_misses``'s holes: record each fresh verdict in
-    ``bits`` and in the cache (``results`` aligns with ``miss_indices``).
+    ``part.bits`` and, under the key the look-up hashed, in the cache
+    (``results`` aligns with ``part.miss``) — the ONE put a fresh verdict
+    gets, made on the caller's path before its verify returns, so the
+    caller's next look-up finds it.
 
     Only DEFINITIVE verdicts are cached: a ``None`` result marks an entry
     the backend could not judge (an infrastructure failure — see
     docs/backend-supervisor.md).  Caching ``False`` for it would negative-
     cache a possibly-valid signature forever, so the hole is left in
     ``bits`` for the caller to surface as an error, never as a verdict."""
-    cache = get_cache()
-    enabled = cache.enabled()  # hoisted: one env read per batch, not per sig
-    for i, r in zip(miss_indices, results):
-        if r is None:
-            continue
-        r = bool(r)
-        bits[i] = r
-        if enabled:
-            cache._put(_key(pubs[i], msgs[i], sigs[i]), r)
+    bits, keys = part.bits, part.keys
+    got = [None if r is None else bool(r) for r in results]
+    for i, r in zip(part.miss, got):
+        if r is not None:
+            bits[i] = r
+    if keys is None:
+        return
+    if None in got:
+        judged = [(k, r) for k, r in zip(keys, got) if r is not None]
+        keys, got = [k for k, _ in judged], [r for _, r in judged]
+    if got:
+        get_cache()._put_many(keys, got)
 
 
 def verify_with_cache(pub_key, msg: bytes, sig: bytes) -> bool:
     """Single-signature verification through the cache: the drop-in for
     ``pub_key.verify_signature(msg, sig)`` on consensus paths (vote,
     proposal, vote-extension checks)."""
-    pub = pub_key.bytes() if hasattr(pub_key, "bytes") else bytes(pub_key)
     cache = get_cache()
-    hit = cache.get(pub, msg, sig)
+    if not cache.enabled():
+        return bool(pub_key.verify_signature(msg, sig))
+    pub = pub_key.bytes() if hasattr(pub_key, "bytes") else bytes(pub_key)
+    (k,) = cache.hash_keys((pub,), (msg,), (sig,))  # once, for get and put
+    hit = cache._get(k)
     if hit is not None:
         from cometbft_tpu.libs import tracing
 
         tracing.mark(hit=True)
         return hit
     ok = bool(pub_key.verify_signature(msg, sig))
-    cache.put(pub, msg, sig, ok)
+    cache._put(k, ok)
     return ok

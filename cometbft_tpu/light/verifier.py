@@ -211,20 +211,20 @@ def verify_adjacent_chain(
         current = lb
 
     # device pass: ship only cache misses, one overlapped batch per header
-    per_header = []  # (prepared, bits-with-None-holes, miss_indices)
-    for p in prepared:
-        bits, miss = sigcache.partition_misses(p.pubs, p.msgs, p.sigs)
-        per_header.append((p, bits, miss))
+    per_header = [  # (prepared, its sigcache.Partition: bits with None holes)
+        (p, sigcache.partition_misses(p.pubs, p.msgs, p.sigs))
+        for p in prepared
+    ]
     from cometbft_tpu.ops import verify as ov
 
     work = [
         (
-            [p.pubs[j] for j in miss],
-            [p.msgs[j] for j in miss],
-            [p.sigs[j] for j in miss],
+            [p.pubs[j] for j in part.miss],
+            [p.msgs[j] for j in part.miss],
+            [p.sigs[j] for j in part.miss],
         )
-        for p, _, miss in per_header
-        if miss
+        for p, part in per_header
+        if part.miss
     ]
     from cometbft_tpu.libs import tracing
 
@@ -232,15 +232,15 @@ def verify_adjacent_chain(
         "light.chain",
         headers=len(news),
         h0=news[0].height,
-        sigs=sum(len(m) for _, _, m in per_header),
+        sigs=sum(len(part.miss) for _, part in per_header),
     ):
         fresh = iter(ov.verify_batches_overlapped(work) if work else [])
 
     # judge strictly in order
-    for p, bits, miss in per_header:
-        if miss:
-            sigcache.writeback(p.pubs, p.msgs, p.sigs, bits, miss, next(fresh))
-        validation.finish_commit_light(p, bits)
+    for p, part in per_header:
+        if part.miss:
+            sigcache.writeback(part, next(fresh))
+        validation.finish_commit_light(p, part.bits)
 
 
 def verify_non_adjacent(

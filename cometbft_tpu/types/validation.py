@@ -207,7 +207,10 @@ def _verify_commit(
                 for (idx, val, cs), sb in zip(entries, sign_bytes):
                     bv.add(val.pub_key, sb, cs.signature)
                 ok, bits = bv.verify()
-                bsp.set(hits=getattr(bv, "cache_hits", 0))
+                bsp.set(
+                    hits=getattr(bv, "cache_hits", 0),
+                    keys=getattr(bv, "keys_hashed", 0),
+                )
             if not ok:
                 _judge_entries(entries, bits)
                 raise CommitVerificationError("batch verification failed")

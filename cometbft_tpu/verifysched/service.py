@@ -54,7 +54,7 @@ import logging
 import os
 import threading
 import time
-from collections import OrderedDict, deque
+from collections import deque
 from concurrent.futures import Future
 from typing import Optional, Sequence
 
@@ -202,15 +202,25 @@ class _Entry:
     # slowness are distinguishable regressions (docs/observability.md)
     # ctx = the submitter's innermost open span (None outside any): the
     # flush that serves the entry joins its trace
+    # keys = the triples' sigcache keys as the submitter's look-up hashed
+    # them, riding along so that the dedup hashes nothing; None until
+    # ``_plan`` keys an entry that brought none (then with None at the
+    # structurally impossible indices).  puts = the scheduler stores this
+    # entry's verdicts: whoever hashed a key puts its verdict, once — the
+    # scheduler for what it keyed itself (``submit``'s votes, an entry that
+    # brought no keys), the batch seam's ``writeback`` for a segment whose
+    # keys it sent along
     __slots__ = (
-        "pubs", "msgs", "sigs", "n", "prio", "future", "t0", "t_drain",
-        "ctx", "scalar",
+        "pubs", "msgs", "sigs", "keys", "puts", "n", "prio", "future", "t0",
+        "t_drain", "ctx", "scalar",
     )
 
-    def __init__(self, pubs, msgs, sigs, prio, t0, ctx, scalar):
+    def __init__(self, pubs, msgs, sigs, keys, puts, prio, t0, ctx, scalar):
         self.pubs = pubs
         self.msgs = msgs
         self.sigs = sigs
+        self.keys = keys
+        self.puts = puts
         self.n = len(pubs)
         self.prio = prio
         self.future: Future = Future()
@@ -294,11 +304,15 @@ class VerifyScheduler:
 
     # -- submission -------------------------------------------------------
 
-    def _enqueue(self, pubs, msgs, sigs, prio: int, scalar: bool = False):
+    def _enqueue(
+        self, pubs, msgs, sigs, prio: int, scalar: bool = False,
+        keys=None, puts: bool = True,
+    ):
         """Queue a segment under ONE acquisition of the lock, with one
         notify; returns ``(futures, admitted)``: the futures of the entries
         that hold ``pubs[:admitted]``, in order (one, unless the segment is
-        longer than ``MAX_DRAIN`` and was cut).  Admission is decided once:
+        longer than ``MAX_DRAIN`` and was cut; ``keys`` are cut with the
+        lists).  Admission is decided once:
         consensus is always admitted whole; any other class up to
         ``queue_cap`` signatures queued, and the rest is shed to the
         caller.  Raises ``RuntimeError`` once the scheduler is stopped."""
@@ -315,8 +329,9 @@ class VerifyScheduler:
             for lo in range(0, admitted, MAX_DRAIN):
                 hi = min(lo + MAX_DRAIN, admitted)
                 entry = _Entry(
-                    pubs[lo:hi], msgs[lo:hi], sigs[lo:hi], prio, t0, ctx,
-                    scalar,
+                    pubs[lo:hi], msgs[lo:hi], sigs[lo:hi],
+                    None if keys is None else keys[lo:hi], puts,
+                    prio, t0, ctx, scalar,
                 )
                 self._queues[prio].append(entry)
                 stats.record_submit(prio, hi - lo)
@@ -367,16 +382,24 @@ class VerifyScheduler:
         return a Future resolving to its definitive verdict.  A sigcache
         hit resolves immediately without occupying a queue slot.  Raises
         ``QueueFullError`` for non-consensus classes when the queue is at
-        capacity; consensus submissions are always admitted."""
+        capacity; consensus submissions are always admitted.  The key is
+        hashed once, here, for the look-up; it rides the entry to the dedup
+        and to the put, which is the scheduler's (``_settle``)."""
         prio = _clamp_prio(priority)
-        hit = sigcache.get_cache().get(pub, msg, sig)
-        if hit is not None:
-            stats.record_submit_hit(prio)
-            tracing.mark(hit=True)
-            fut: "Future[bool]" = Future()
-            fut.set_result(bool(hit))
-            return fut
-        futs, admitted = self._enqueue([pub], [msg], [sig], prio, scalar=True)
+        cache = sigcache.get_cache()
+        keys = None
+        if cache.enabled():
+            keys = cache.hash_keys((pub,), (msg,), (sig,))
+            hit = cache._get(keys[0])
+            if hit is not None:
+                stats.record_submit_hit(prio)
+                tracing.mark(hit=True)
+                fut: "Future[bool]" = Future()
+                fut.set_result(bool(hit))
+                return fut
+        futs, admitted = self._enqueue(
+            [pub], [msg], [sig], prio, scalar=True, keys=keys
+        )
         if not admitted:
             raise QueueFullError(
                 f"verify queue at capacity ({self.queue_cap}); "
@@ -390,6 +413,7 @@ class VerifyScheduler:
         msgs: Sequence[bytes],
         sigs: Sequence[bytes],
         priority: int = PRIO_CONSENSUS,
+        keys: Optional[Sequence[bytes]] = None,
     ) -> "tuple[list[Future], int]":
         """Queue a whole segment of cache MISSES (the batch seam has
         already taken its hits) as one entry with one future, which
@@ -398,9 +422,19 @@ class VerifyScheduler:
         on were shed by admission control and the caller verifies those
         itself.  A scheduler stopped under the caller (teardown race)
         admits nothing, so the whole segment degrades to the caller's
-        fallback the same way."""
+        fallback the same way.
+
+        ``keys``: the triples' sigcache keys (``sigcache.partition_misses``
+        hashed them for its look-up), aligned with the lists.  A segment
+        that brings them is deduplicated on them and its verdicts are NOT
+        stored here: the caller's ``sigcache.writeback`` puts them, under
+        the same keys.  One that brings none is keyed, and stored, by the
+        scheduler."""
         try:
-            return self._enqueue(pubs, msgs, sigs, _clamp_prio(priority))
+            return self._enqueue(
+                pubs, msgs, sigs, _clamp_prio(priority),
+                keys=keys, puts=keys is None,
+            )
         except RuntimeError:
             return [], 0
 
@@ -662,59 +696,90 @@ class VerifyScheduler:
         )
 
     @staticmethod
-    def _plan(entries: "list[_Entry]"):
+    def _key_entry(en: "_Entry") -> None:
+        """Key an entry that brought no keys (the cache switched off at the
+        seam, or a caller that made no look-up): the dedup needs them
+        whatever the cache does.  The structurally impossible get None."""
+        possible = [
+            i
+            for i in range(en.n)
+            if len(en.pubs[i]) == 32 and len(en.sigs[i]) == 64
+        ]
+        hashed = sigcache.get_cache().hash_keys(
+            [en.pubs[i] for i in possible],
+            [en.msgs[i] for i in possible],
+            [en.sigs[i] for i in possible],
+        )
+        en.keys = [None] * en.n
+        for i, k in zip(possible, hashed):
+            en.keys[i] = k
+
+    @classmethod
+    def _plan(cls, entries: "list[_Entry]"):
         """The front half both flush paths share, over the entries' lists
         laid end to end.  Structural filter: garbage never occupies a
-        device lane.  In-flight dedup ACROSS the entries: concurrent
+        device lane.  In-flight dedup ACROSS the entries, on the keys they
+        carry (an entry that brought none is keyed here): concurrent
         gossip of the same vote collapses into one lane, every index that
         holds it shares the verdict.  One work segment per priority class
         present: ``verify_segments`` fuses them into ONE dispatch
         (recording cross-class fusion in ops/dispatch_stats) and splits
-        the bits back per class.  Returns ``(bits, uniq, ordered, work,
+        the bits back per class.  Returns ``(bits, dups, ordered, work,
         lanes)``: ``bits`` flat with the filtered indices already False,
-        ``ordered`` the first index of each dedup group in ``work``'s
-        order."""
+        ``dups`` the ``(index, first index)`` of every index beyond its
+        dedup group's first, ``ordered`` the first index of each group in
+        ``work``'s order."""
         pubs: "list[bytes]" = []
         msgs: "list[bytes]" = []
         sigs: "list[bytes]" = []
+        keys: "list[Optional[bytes]]" = []
         prios: "list[int]" = []
         for en in entries:
+            if en.keys is None:
+                cls._key_entry(en)
             pubs.extend(en.pubs)
             msgs.extend(en.msgs)
             sigs.extend(en.sigs)
+            keys.extend(en.keys)
             prios.extend([en.prio] * en.n)
         n = len(pubs)
         bits: "list[Optional[bool]]" = [None] * n
-        uniq: "OrderedDict[bytes, list[int]]" = OrderedDict()
-        for i in range(n):
-            if len(pubs[i]) != 32 or len(sigs[i]) != 64:
+        first: "dict[bytes, int]" = {}  # insertion-ordered, as the lanes are
+        dups: "list[tuple[int, int]]" = []
+        for i, k in enumerate(keys):
+            if k is None or len(pubs[i]) != 32 or len(sigs[i]) != 64:
                 bits[i] = False
                 continue
-            k = sigcache._key(pubs[i], msgs[i], sigs[i])
-            uniq.setdefault(k, []).append(i)
-        # every index that passed the filter, beyond its group's first
-        stats.record_dedup(n - bits.count(False) - len(uniq))
+            j = first.setdefault(k, i)
+            if j != i:
+                dups.append((i, j))
+        stats.record_dedup(len(dups))
         ordered: "list[int]" = []
         work: "list[tuple]" = []
         lanes = 0
-        if uniq:
+        if first:
             from cometbft_tpu.ops import verify as ov
 
             by_class: "list[list[int]]" = [[] for _ in range(N_CLASSES)]
-            for ixs in uniq.values():
-                by_class[prios[ixs[0]]].append(ixs[0])
-            ordered = [i for cls in by_class for i in cls]
-            work = [
-                (
-                    [pubs[i] for i in cls],
-                    [msgs[i] for i in cls],
-                    [sigs[i] for i in cls],
-                )
-                for cls in by_class
-                if cls
-            ]
+            for i in first.values():
+                by_class[prios[i]].append(i)
+            ordered = [i for ixs in by_class for i in ixs]
+            if any(len(ixs) == n for ixs in by_class):
+                # every index its group's first, all of one class (a fresh
+                # commit's segment alone in its flush): the lists as they are
+                work = [(pubs, msgs, sigs)]
+            else:
+                work = [
+                    (
+                        [pubs[i] for i in ixs],
+                        [msgs[i] for i in ixs],
+                        [sigs[i] for i in ixs],
+                    )
+                    for ixs in by_class
+                    if ixs
+                ]
             lanes = ov.bucket_size(len(ordered), ov._min_bucket())
-        return bits, uniq, ordered, work, lanes
+        return bits, dups, ordered, work, lanes
 
     @staticmethod
     def _finish(en: "_Entry", bits, now: float) -> None:
@@ -754,11 +819,13 @@ class VerifyScheduler:
         # flush span (closed BEFORE futures resolve, like the stats below,
         # so a deterministic sim's ring order cannot race its waiters)
         with self._flush_span(reason, entries, n) as fsp:
-            bits, uniq, ordered, work, lanes = self._plan(entries)
+            bits, dups, ordered, work, lanes = self._plan(entries)
             if work:
                 from cometbft_tpu.ops import verify as ov
 
-                self._settle(bits, uniq, ordered, ov.verify_segments(work))
+                self._settle(
+                    entries, bits, dups, ordered, ov.verify_segments(work)
+                )
             fsp.set(misses=len(ordered), lanes=lanes)
 
         # record BEFORE resolving: set_result unblocks waiters, and a
@@ -775,24 +842,34 @@ class VerifyScheduler:
         self._resolve(entries, bits, fsp)
 
     @staticmethod
-    def _settle(bits, uniq, ordered, results) -> None:
-        """Verdicts keyed by FIRST index of each dedup group (the hash was
-        already paid once in the dedup loop) resolve every member of the
-        group, and go to the cache.  Inlined rather than
-        ``sigcache.writeback``: that would re-hash every entry, and the
-        dedup loop already holds the keys.  Supervised verdicts are always
+    def _settle(entries, bits, dups, ordered, results) -> None:
+        """The verdicts, in ``ordered``'s order, to the first index of each
+        dedup group and from there to the group's other members; then the
+        cache puts that are the scheduler's to make — one for each index of
+        an entry it keyed itself (``_Entry.puts``: ``submit``'s single
+        votes), under the key that entry carries, in one visit.  A segment
+        that came with its keys is put by the batch seam's ``writeback``,
+        on the caller's path, not here.  Supervised verdicts are always
         definitive, so caching unconditionally is safe."""
-        verdict_by_first = dict(
-            zip(ordered, (bool(b) for seg in results for b in seg))
-        )
+        for i, b in zip(ordered, (b for seg in results for b in seg)):
+            bits[i] = bool(b)
+        for i, j in dups:
+            bits[i] = bits[j]
         cache = sigcache.get_cache()
-        cache_on = cache.enabled()
-        for k, ixs in uniq.items():
-            v = verdict_by_first[ixs[0]]
-            for i in ixs:
-                bits[i] = v
-            if cache_on:
-                cache._put(k, v)
+        if not cache.enabled():
+            return
+        put_keys: "list[bytes]" = []
+        put_bits: "list[bool]" = []
+        lo = 0
+        for en in entries:
+            if en.puts:
+                for k, v in zip(en.keys, bits[lo:lo + en.n]):
+                    if k is not None:
+                        put_keys.append(k)
+                        put_bits.append(v)
+            lo += en.n
+        if put_keys:
+            cache._put_many(put_keys, put_bits)
 
     # -- in-flight pipeline (docs/verify-scheduler.md) --------------------
 
@@ -822,7 +899,7 @@ class VerifyScheduler:
         interval = self._flush_interval()
 
         with self._flush_span(reason, entries, n) as fsp:
-            bits, uniq, ordered, work, lanes = self._plan(entries)
+            bits, dups, ordered, work, lanes = self._plan(entries)
             handle = None
             if work:
                 from cometbft_tpu.ops import verify as ov
@@ -893,7 +970,7 @@ class VerifyScheduler:
             # the flush span rides along: the completion thread's spans
             # are its children
             self._fetch_queue.append(
-                (handle, entries, bits, uniq, ordered, fsp)
+                (handle, entries, bits, dups, ordered, fsp)
             )
             self._fcond.notify_all()
 
@@ -934,11 +1011,11 @@ class VerifyScheduler:
 
     def _resolve_flush(self, pf: tuple) -> None:
         """The completion half of one pipelined flush: fetch verdicts,
-        write the sigcache back, resolve every future.  Runs on the
+        settle them (``_settle``), resolve every future.  Runs on the
         completion thread in drain order; cannot leave a future
         unresolved — a fetch that somehow escapes the supervisor's
         degradation chain resolves the flush on the host reference."""
-        handle, entries, bits, uniq, ordered, fsp = pf
+        handle, entries, bits, dups, ordered, fsp = pf
         results = None
         try:
             from cometbft_tpu.ops import verify as ov
@@ -953,7 +1030,7 @@ class VerifyScheduler:
         def settle() -> None:
             try:
                 if results is not None:
-                    self._settle(bits, uniq, ordered, results)
+                    self._settle(entries, bits, dups, ordered, results)
             except BaseException:  # noqa: BLE001 — as above
                 logger.exception("pipelined flush resolve failed unexpectedly")
             if None not in bits:
@@ -1092,12 +1169,16 @@ def verify_segment_sync(
     msgs: Sequence[bytes],
     sigs: Sequence[bytes],
     priority=None,
+    keys: Optional[Sequence[bytes]] = None,
 ) -> "list[bool]":
     """The batch-verifier bridge: submit a pre-partitioned segment of raw
     ed25519 triples (the caller — ``_CollectingVerifier`` — already took
     its cache hits) as ONE queue entry and wait on its one future.  The
     tail that admission control shed is verified in one direct supervised
-    dispatch instead, so the call never blocks on queue capacity."""
+    dispatch instead, so the call never blocks on queue capacity.  ``keys``
+    are the triples' sigcache keys where the caller's look-up hashed them
+    (``submit_segment``): the caller then stores every verdict this
+    returns, the shed tail's too, and the scheduler none."""
     prio = current_priority() if priority is None else priority
     n = len(pubs)
     with tracing.span("sched.segment", items=n) as seg:
@@ -1105,7 +1186,7 @@ def verify_segment_sync(
         # timed here, recorded together after the wait (``tracing.Lap``)
         with tracing.lap("sched.submit") as submitted:
             futs, admitted = get_scheduler().submit_segment(
-                pubs, msgs, sigs, prio
+                pubs, msgs, sigs, prio, keys
             )
         direct: "list[bool]" = []
         if admitted < n:

@@ -271,9 +271,10 @@ def test_second_pass_hits_what_the_first_pass_verified(device_stub, n):
     assert program_verdict(trusted, new, now_s) == ("accepted",)
     seam = [s["attrs"] for s in tracing.get_tracer().tail(64)
             if s["stage"] == "batch.verify"]
-    assert [(a["sigs"], a["hits"]) for a in seam] == [
-        (len(picked), 0),  # the trusting pass: every triple new
-        (prefix, len(picked)),  # the light pass: what the first wrote back
+    assert [(a["sigs"], a["hits"], a["keys"]) for a in seam] == [
+        (len(picked), 0, len(picked)),  # the trusting pass: every triple new
+        # the light pass: what the first wrote back; one key a look-up
+        (prefix, len(picked), prefix),
     ]
     assert len([i for i in picked if i < prefix]) == len(picked)
     assert dispatch_stats.snapshot()["dispatches"] == 2  # both passes shipped misses

@@ -55,6 +55,26 @@ class TestSigCache:
         c.put(b"ab", b"c", b"s", True)
         assert c.get(b"a", b"bc", b"s") is None
 
+    @pytest.mark.parametrize(
+        "pub,msg,sig",
+        [
+            (b"\x01" * 32, b"vote sign bytes " * 8, b"\x02" * 64),
+            (b"\x03" * 33, b"", b"\x04" * 64),  # secp256k1 sizes, empty msg
+            (b"\x05" * 96, b"m" * 300, b"\x06" * 96),  # bls12_381 sizes
+        ],
+        ids=["ed25519", "secp256k1", "bls"],
+    )
+    def test_key_is_the_framed_digest(self, pub, msg, sig):
+        """The key is SHA-256 over len(pub) | pub | len(msg) | msg | sig,
+        lengths as 4 bytes little-endian, however it is computed."""
+        h = hashlib.sha256()
+        h.update(len(pub).to_bytes(4, "little"))
+        h.update(pub)
+        h.update(len(msg).to_bytes(4, "little"))
+        h.update(msg)
+        h.update(sig)
+        assert sigcache._key(pub, msg, sig) == h.digest()
+
     def test_kill_switch_disables_lookup_and_insert(self, monkeypatch):
         c = sigcache.SigCache()
         c.put(b"p", b"m", b"s", True)
@@ -99,6 +119,174 @@ class TestSigCache:
         assert sigcache.verify_with_cache(pub, msg, bad) is False
         st = sigcache.get_cache().stats()
         assert st["hits"] == 2
+
+
+def _k(i: int) -> bytes:
+    return hashlib.sha256(b"key-%d" % i).digest()
+
+
+def _filled(capacity: int, entries) -> "sigcache.SigCache":
+    c = sigcache.SigCache(capacity=capacity)
+    for i, ok in entries:
+        c._put(_k(i), ok)
+    return c
+
+
+def _state(c: "sigcache.SigCache"):
+    """Everything the contract names: the entries in LRU order with their
+    verdicts (so the evictions too), hits and misses."""
+    st = c.stats()
+    return list(c._entries.items()), st["hits"], st["misses"]
+
+
+# (capacity, what the cache holds beforehand, the batch's key numbers)
+_BATCH_CASES = {
+    "empty": (8, [], [0, 1, 2, 3]),
+    "part-filled": (8, [(0, True), (1, False), (5, True)], [1, 2, 0, 3, 5]),
+    "at-capacity": (4, [(0, True), (1, False), (2, True), (3, True)],
+                    [2, 7, 0, 8, 9]),
+    "same-key-twice": (4, [(0, True), (1, True), (2, False)],
+                       [1, 6, 1, 7, 6, 0]),
+    "longer-than-capacity": (3, [(0, True)], [1, 2, 3, 0, 4, 1, 5]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BATCH_CASES))
+class TestBatchOperations:
+    """The segment's two visits against a loop of ``_get`` / ``_put`` on
+    the same keys: same verdicts, same counts, same LRU order, same
+    evictions."""
+
+    def test_get_many_is_a_loop_of_get(self, case):
+        capacity, held, batch = _BATCH_CASES[case]
+        keys = [_k(i) for i in batch]
+        loop, many = _filled(capacity, held), _filled(capacity, held)
+        want = [loop._get(k) for k in keys]
+        assert many._get_many(keys) == want
+        assert _state(many) == _state(loop)
+        assert many.stats()["hits"] + many.stats()["misses"] == len(keys)
+
+    def test_put_many_is_a_loop_of_put(self, case):
+        capacity, held, batch = _BATCH_CASES[case]
+        keys = [_k(i) for i in batch]
+        # a key held twice gets two verdicts: the later one stays
+        verdicts = [j % 3 != 0 for j in range(len(keys))]
+        loop, many = _filled(capacity, held), _filled(capacity, held)
+        before = loop.stats()["puts"]
+        for k, ok in zip(keys, verdicts):
+            loop._put(k, ok)
+        many._put_many(keys, verdicts)
+        assert _state(many) == _state(loop)
+        assert len(many) <= capacity
+        assert many.stats()["puts"] == loop.stats()["puts"] == before + len(keys)
+
+
+class TestKeyHashedOnce:
+    """``partition_misses`` hashes each possible triple once and hands the
+    misses' keys on; ``writeback`` takes them back and hashes nothing."""
+
+    @pytest.fixture
+    def key_calls(self, monkeypatch):
+        calls = []
+        real = sigcache._key
+
+        def counting(pub, msg, sig):
+            calls.append((pub, msg, sig))
+            return real(pub, msg, sig)
+
+        monkeypatch.setattr(sigcache, "_key", counting)
+        return calls
+
+    @staticmethod
+    def _triples(n, tag=b"once"):
+        return (
+            [hashlib.sha256(tag + b"p%d" % i).digest() for i in range(n)],
+            [b"m%d" % i for i in range(n)],
+            [hashlib.sha512(tag + b"s%d" % i).digest() for i in range(n)],
+        )
+
+    def test_partition_returns_the_keys_of_its_misses(self, key_calls):
+        pubs, msgs, sigs = self._triples(5)
+        cache = sigcache.get_cache()
+        cache.put(pubs[1], msgs[1], sigs[1], True)
+        cache.put(pubs[3], msgs[3], sigs[3], False)
+        del key_calls[:]
+        before = cache.stats()
+        part = sigcache.partition_misses(pubs, msgs, sigs)
+        assert part.bits == [None, True, None, False, None]
+        assert part.miss == [0, 2, 4]
+        assert part.keys == [
+            sigcache._key(pubs[i], msgs[i], sigs[i]) for i in part.miss
+        ]
+        assert part.hashed == 5
+        after = cache.stats()
+        assert after["keys"] - before["keys"] == 5
+        assert after["hits"] - before["hits"] == 2
+        assert after["misses"] - before["misses"] == 3
+        assert len(key_calls) == 5 + 3  # the look-up's, and this test's own
+
+    def test_writeback_hashes_nothing_and_puts_once(self, key_calls):
+        pubs, msgs, sigs = self._triples(6)
+        part = sigcache.partition_misses(pubs, msgs, sigs)
+        assert part.miss == list(range(6)) and len(part.keys) == 6
+        assert len(key_calls) == 6
+        sigcache.writeback(part, [True, False, True, True, False, True])
+        assert len(key_calls) == 6  # not one more
+        assert part.bits == [True, False, True, True, False, True]
+        st = sigcache.get_cache().stats()
+        assert st["keys"] == 6 and st["puts"] == 6 and st["size"] == 6
+        # what was put is what a look-up by the triple finds
+        again = sigcache.partition_misses(pubs, msgs, sigs)
+        assert again.miss == [] and again.keys == []
+        assert again.bits == part.bits
+
+    def test_none_result_is_not_cached(self, key_calls):
+        pubs, msgs, sigs = self._triples(3)
+        part = sigcache.partition_misses(pubs, msgs, sigs)
+        sigcache.writeback(part, [True, None, False])
+        assert part.bits == [True, None, False]  # the hole stays a hole
+        st = sigcache.get_cache().stats()
+        assert st["puts"] == 2 and st["size"] == 2
+        again = sigcache.partition_misses(pubs, msgs, sigs)
+        assert again.miss == [1]  # still to be verified, under its key
+        assert again.keys == [sigcache._key(pubs[1], msgs[1], sigs[1])]
+
+    def test_wrong_lengths_are_false_and_get_no_key(self, key_calls):
+        pubs, msgs, sigs = self._triples(4)
+        pubs[1] = pubs[1][:31]
+        sigs[2] = sigs[2] + b"\x00"
+        part = sigcache.partition_misses(pubs, msgs, sigs)
+        assert part.bits == [None, False, False, None]
+        assert part.miss == [0, 3] and len(part.keys) == 2
+        assert part.hashed == 2 and len(key_calls) == 2
+        assert all(len(c[0]) == 32 and len(c[2]) == 64 for c in key_calls)
+        # other key sizes filter by their own rule (secp256k1: 33 / 64)
+        part = sigcache.partition_misses(
+            [b"\x02" * 33, pubs[0]], msgs[:2], sigs[:1] + sigs[3:], (33,), (64,)
+        )
+        assert part.bits == [None, False] and part.miss == [0]
+
+    def test_cache_off_makes_no_keys(self, key_calls, monkeypatch):
+        monkeypatch.setenv("COMETBFT_TPU_SIGCACHE", "0")
+        pubs, msgs, sigs = self._triples(3)
+        pubs[2] = b"short"
+        part = sigcache.partition_misses(pubs, msgs, sigs)
+        assert part.bits == [None, None, False] and part.miss == [0, 1]
+        assert part.keys is None and part.hashed == 0
+        sigcache.writeback(part, [True, False])
+        assert part.bits == [True, False, False]
+        assert not key_calls
+        st = sigcache.get_cache().stats()
+        assert st["keys"] == 0 and st["puts"] == 0 and st["size"] == 0
+
+    def test_verify_with_cache_hashes_once(self, key_calls):
+        priv, pub = _keypair(b"once-single")
+        sig = priv.sign(b"m")
+        assert sigcache.verify_with_cache(pub, b"m", sig) is True
+        assert len(key_calls) == 1  # one key serves the look-up and the put
+        assert sigcache.verify_with_cache(pub, b"m", sig) is True
+        st = sigcache.get_cache().stats()
+        assert (st["keys"], st["puts"], st["hits"]) == (2, 1, 1)
 
 
 class TestMetricsExposition:

@@ -464,10 +464,10 @@ class TestSigcacheAudit:
         seed = b"\x09" * 32
         pub, msg = ref.pubkey_from_seed(seed), b"audit"
         sig = ref.sign(seed, msg)
-        bits, miss = sigcache.partition_misses([pub], [msg], [sig])
-        assert miss == [0]
-        sigcache.writeback([pub], [msg], [sig], bits, miss, [None])
-        assert bits[0] is None  # hole stays a hole, not False
+        part = sigcache.partition_misses([pub], [msg], [sig])
+        assert part.miss == [0]
+        sigcache.writeback(part, [None])
+        assert part.bits[0] is None  # hole stays a hole, not False
         assert sigcache.get_cache().get(pub, msg, sig) is None  # NOT cached
         sigcache.reset_cache()
 

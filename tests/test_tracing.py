@@ -532,7 +532,7 @@ class TestRequestTree:
         flush = one["sched.flush"]["attrs"]
         assert flush["traces"] == [root["span"]]
         assert flush["queue_wait_s"] >= 0
-        assert one["batch.verify"]["attrs"] == {"sigs": 4, "hits": 0}
+        assert one["batch.verify"]["attrs"] == {"sigs": 4, "hits": 0, "keys": 4}
         assert one["verify.launch"]["attrs"]["lanes"] >= 4
         assert one["verify.pack"]["attrs"]["bytes"] > 0
         # the entry's basic checks are inside the request's span now

@@ -504,6 +504,196 @@ class TestSegmentUnit:
 
 
 # ----------------------------------------------------------------------
+# one key a signature, one put a verdict (docs/verify-stream.md)
+# ----------------------------------------------------------------------
+
+
+def _bv_verify(pubs, msgs, sigs, priority=verifysched.PRIO_CONSENSUS):
+    """The triples through ``TpuBatchVerifier``, as a commit's are."""
+    from cometbft_tpu.crypto.batch import TpuBatchVerifier
+
+    bv = TpuBatchVerifier()
+    for p, m, s in zip(pubs, msgs, sigs):
+        bv.add(Ed25519PubKey(p), m, s)
+    with verifysched.priority_class(priority):
+        return bv.verify()
+
+
+def _cached(pubs, msgs, sigs):
+    """What the cache holds for the triples, read past its counters."""
+    entries = sigcache.get_cache()._entries
+    return [
+        entries.get(sigcache._key(p, m, s))
+        for p, m, s in zip(pubs, msgs, sigs)
+    ]
+
+
+class TestKeysRideTheSegment:
+    def test_fresh_commit_hashes_n_keys_and_makes_n_puts(self, sched_env):
+        """A fresh commit of n signatures through the scheduler: n keys
+        hashed (the seam's look-up; the dedup and the write-back hash
+        none) and n verdicts stored (the seam's write-back; the scheduler
+        stores none).  A second ``verify()`` of the same triples, made
+        right after the first returns, is all hits and no flush: the put
+        is on the caller's path (what the light client's second pass
+        rests on)."""
+        supervisor.set_device_runner(_lib_runner)
+        n = 117
+        pubs, msgs, sigs = _lib_sigs(n, b"fresh")
+        sigs = _tampered(sigs, 40)
+        want = [i != 40 for i in range(n)]
+        assert _bv_verify(pubs, msgs, sigs) == (False, want)
+        st = sigcache.get_cache().stats()
+        assert (st["keys"], st["puts"]) == (n, n)
+        assert (st["hits"], st["misses"], st["size"]) == (0, n, n)
+        snap = sstats.snapshot()
+        assert sum(snap["flushes"].values()) == 1
+        assert snap["flush_misses"] == n
+        assert _bv_verify(pubs, msgs, sigs) == (False, want)
+        st = sigcache.get_cache().stats()
+        assert (st["keys"], st["puts"]) == (2 * n, n)  # the look-up's keys
+        assert (st["hits"], st["misses"]) == (n, n)
+        snap = sstats.snapshot()
+        assert sum(snap["flushes"].values()) == 1  # nothing was queued
+        assert snap["submitted"]["consensus"] == n
+
+    @pytest.mark.parametrize("keyed", ["carried", "none-cache-off"])
+    def test_dedup_same_lanes_with_carried_keys_as_with_none(
+        self, sched_env, monkeypatch, keyed
+    ):
+        """In-flight dedup across two entries ships the same lanes whether
+        the entries carry the seam's keys or the scheduler keys them (the
+        cache switched off, where the seam makes none)."""
+        shipped = []
+
+        def runner(backend, pubs, msgs, sigs, lanes):
+            shipped.append((list(pubs), list(msgs), list(sigs)))
+            return _oracle_runner(backend, pubs, msgs, sigs, lanes)
+
+        supervisor.set_device_runner(runner)
+        pubs, msgs, sigs = _make_sigs(7, b"dup-keys")
+        sigs = _tampered(sigs, 3)
+        if keyed == "none-cache-off":
+            monkeypatch.setenv("COMETBFT_TPU_SIGCACHE", "0")
+        a = sigcache.partition_misses(pubs[:5], msgs[:5], sigs[:5])
+        b = sigcache.partition_misses(pubs[3:], msgs[3:], sigs[3:])
+        assert (a.keys is None) == (b.keys is None) == (keyed != "carried")
+        sched = verifysched.get_scheduler()
+        sched.pause()
+        fa, _ = sched.submit_segment(pubs[:5], msgs[:5], sigs[:5], keys=a.keys)
+        fb, _ = sched.submit_segment(pubs[3:], msgs[3:], sigs[3:], keys=b.keys)
+        sched.resume()
+        want = _oracle(pubs, msgs, sigs)
+        assert _verdicts(fa) == want[:5] and _verdicts(fb) == want[3:]
+        # 3 and 4 came twice and went once: seven lanes, in order
+        assert shipped == [(pubs, msgs, sigs)]
+        snap = sstats.snapshot()
+        assert snap["dedup_hits"] == 2 and snap["flush_misses"] == 7
+        st = sigcache.get_cache().stats()
+        # carried: the two look-ups' keys and no put (the seam's to make);
+        # cache off: the scheduler's own keys for its dedup, and no put
+        assert (st["keys"], st["puts"], st["size"]) == (9, 0, 0)
+
+    @pytest.mark.parametrize(
+        "path", ["longer-than-max-drain", "shed-tail", "scheduler-inactive"]
+    )
+    def test_every_verdict_cached_exactly_once(
+        self, sched_env, monkeypatch, path
+    ):
+        """Whatever answered the seam's misses — several entries of one
+        segment, the direct dispatch of a shed tail, the path without the
+        scheduler — each verdict is stored once, under the look-up's key."""
+        from cometbft_tpu.verifysched import service
+
+        supervisor.set_device_runner(_lib_runner)
+        n, prio = 50, verifysched.PRIO_CONSENSUS
+        if path == "longer-than-max-drain":
+            monkeypatch.setattr(service, "MAX_DRAIN", 16)
+        elif path == "shed-tail":
+            monkeypatch.setenv("COMETBFT_TPU_SCHED_QUEUE", "8")
+            verifysched.reset_scheduler()
+            prio = verifysched.PRIO_BLOCKSYNC
+        else:
+            monkeypatch.setenv("COMETBFT_TPU_VERIFY_SCHED", "0")
+        pubs, msgs, sigs = _lib_sigs(n, b"once-%s" % path.encode())
+        sigs = _tampered(sigs, 7, 44)
+        want = [i not in (7, 44) for i in range(n)]
+        assert _bv_verify(pubs, msgs, sigs, prio) == (False, want)
+        st = sigcache.get_cache().stats()
+        assert (st["keys"], st["puts"], st["size"]) == (n, n, n)
+        assert _cached(pubs, msgs, sigs) == want
+        snap = sstats.snapshot()
+        if path == "longer-than-max-drain":
+            assert snap["segments"]["consensus"] == 4
+        elif path == "shed-tail":
+            assert snap["shed"]["bulk"] == n - 8
+        else:
+            assert snap["submitted"]["consensus"] == 0
+
+    def test_keys_are_cut_with_the_lists(self, sched_env, monkeypatch):
+        from cometbft_tpu.verifysched import service
+
+        monkeypatch.setattr(service, "MAX_DRAIN", 16)
+        pubs, msgs, sigs = _make_sigs(40, b"cut-keys")
+        part = sigcache.partition_misses(pubs, msgs, sigs)
+        sched = verifysched.get_scheduler()
+        sched.pause()
+        futs, admitted = sched.submit_segment(pubs, msgs, sigs, keys=part.keys)
+        assert admitted == 40
+        entries = list(sched._queues[verifysched.PRIO_CONSENSUS])
+        assert [en.n for en in entries] == [16, 16, 8]
+        assert [k for en in entries for k in en.keys] == part.keys
+        for en in entries:
+            assert en.keys == [
+                sigcache._key(*t) for t in zip(en.pubs, en.msgs, en.sigs)
+            ]
+            assert en.puts is False  # the seam's write-back stores these
+        sched.resume()
+        assert _verdicts(futs) == [True] * 40
+        assert sigcache.get_cache().stats()["puts"] == 0
+
+    def test_submit_carries_its_key_and_the_scheduler_puts(
+        self, sched_env, monkeypatch
+    ):
+        """``submit``'s n = 1 entry: one key, hashed for the look-up, rides
+        the entry to the dedup and to the put, which is the scheduler's."""
+        calls = []
+        real = sigcache._key
+        monkeypatch.setattr(
+            sigcache, "_key", lambda *t: calls.append(t) or real(*t)
+        )
+        (pub,), (msg,), (sig,) = _make_sigs(1, b"n1")
+        sched = verifysched.get_scheduler()
+        sched.pause()
+        fut = sched.submit(pub, msg, sig)
+        (entry,) = sched._queues[verifysched.PRIO_CONSENSUS]
+        assert entry.keys == [real(pub, msg, sig)] and entry.puts is True
+        assert entry.scalar is True
+        sched.resume()
+        assert fut.result(timeout=30) is True
+        assert calls == [(pub, msg, sig)]  # once, whatever came after
+        st = sigcache.get_cache().stats()
+        assert (st["keys"], st["puts"], st["size"]) == (1, 1, 1)
+        assert _cached([pub], [msg], [sig]) == [True]
+
+    def test_keyless_segment_is_keyed_and_stored_by_the_scheduler(
+        self, sched_env
+    ):
+        """A caller that made no look-up brings no keys: the scheduler
+        hashes the structurally possible triples for its dedup and stores
+        their verdicts itself, once each."""
+        pubs, msgs, sigs = _make_sigs(6, b"keyless", invalid_every=4)
+        pubs[2] = b"\x01" * 31  # no key, no lane, no put
+        want = _oracle(pubs, msgs, sigs)
+        assert verifysched.verify_segment_sync(
+            pubs, msgs, sigs, verifysched.PRIO_CONSENSUS
+        ) == want
+        st = sigcache.get_cache().stats()
+        assert (st["keys"], st["puts"], st["size"]) == (5, 5, 5)
+        assert _cached(pubs, msgs, sigs) == want[:2] + [None] + want[3:]
+
+
+# ----------------------------------------------------------------------
 # admission control / backpressure
 # ----------------------------------------------------------------------
 
