@@ -48,8 +48,6 @@ sys.path.insert(0, ROOT)
 _MUST_BE_UNSET = (
     "COMETBFT_TPU_CRYPTO_BACKEND",
     "COMETBFT_TPU_VERIFY_IMPL",
-    "COMETBFT_TPU_SUPERVISOR",
-    "COMETBFT_TPU_AOT",
     "COMETBFT_TPU_MESH",
 )
 

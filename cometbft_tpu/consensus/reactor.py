@@ -224,8 +224,16 @@ class ConsensusReactor(Reactor):
         with self._ps_lock:
             self._peer_states[peer.id] = ps
         peer.set("cons_peer_state", ps)
-        # tell the new peer where we are
-        peer.try_send(STATE_CHANNEL, cmsg.encode_gossip_msg(self._our_nrs()))
+        # tell the new peer where we are, unless blocksync/statesync still
+        # run (reference: reactor.go AddPeer).  ``receive`` drops votes and
+        # proposals until the hand-off, and the sender marks what it sent
+        # as delivered: a peer told our height too early never sends them
+        # again, and at two validators nothing times out after that.  The
+        # first step after ``switch_to_consensus`` broadcasts it.
+        if not self.wait_sync:
+            peer.try_send(
+                STATE_CHANNEL, cmsg.encode_gossip_msg(self._our_nrs())
+            )
         for target, name in (
             (self._gossip_data_routine, "cons-gossip-data"),
             (self._gossip_votes_routine, "cons-gossip-votes"),

@@ -299,42 +299,31 @@ class Secp256k1BatchVerifier(_CollectingVerifier):
 
     def _verify_pending(self, pubs, msgs, sigs) -> list[bool]:
         if self._backend != "cpu" and _secp_device_ok():
+            # supervised: the breaker decides whether the device is
+            # probed at all, the watchdog bounds a wedge, and a failure
+            # demotes (metrics + backoff) instead of silently retrying
+            # the dead device on every batch
+            from cometbft_tpu.crypto import backend_health
             from cometbft_tpu.ops import supervisor
 
-            if not supervisor.enabled():
-                try:
-                    from cometbft_tpu.ops import secp_verify as sv
+            def _device():
+                from cometbft_tpu.ops import secp_verify as sv
 
-                    return [bool(b) for b in sv.verify_batch(pubs, msgs, sigs)]
-                except Exception:
-                    logging.getLogger("cometbft_tpu.crypto").exception(
-                        "device secp verify failed; host fallback"
+                return [bool(b) for b in sv.verify_batch(pubs, msgs, sigs)]
+
+            def _validate(bits):
+                if len(bits) != len(pubs):
+                    raise backend_health.BackendOutputError(
+                        f"secp device returned {len(bits)} bits "
+                        f"for {len(pubs)} inputs"
                     )
-            else:
-                # supervised: the breaker decides whether the device is
-                # probed at all, the watchdog bounds a wedge, and a failure
-                # demotes (metrics + backoff) instead of silently retrying
-                # the dead device on every batch
-                from cometbft_tpu.crypto import backend_health
 
-                def _device():
-                    from cometbft_tpu.ops import secp_verify as sv
-
-                    return [bool(b) for b in sv.verify_batch(pubs, msgs, sigs)]
-
-                def _validate(bits):
-                    if len(bits) != len(pubs):
-                        raise backend_health.BackendOutputError(
-                            f"secp device returned {len(bits)} bits "
-                            f"for {len(pubs)} inputs"
-                        )
-
-                bits = supervisor.supervised_device_call(
-                    "secp_device", _device, _validate,
-                    fallback_units=len(pubs),
-                )
-                if bits is not None:
-                    return bits
+            bits = supervisor.supervised_device_call(
+                "secp_device", _device, _validate,
+                fallback_units=len(pubs),
+            )
+            if bits is not None:
+                return bits
         from cometbft_tpu.crypto.secp256k1 import Secp256k1PubKey
 
         bits = []
@@ -517,15 +506,12 @@ class BlsBatchVerifier(_CollectingVerifier):
         # host fallback, and the native path is the better degraded tier.
         use_device = self._backend != "cpu" and _bls_device_ok()
         if use_device:
-            from cometbft_tpu.ops import supervisor
+            from cometbft_tpu.crypto import backend_health
 
-            if supervisor.enabled():
-                from cometbft_tpu.crypto import backend_health
-
-                use_device = (
-                    backend_health.registry().breaker("bls_g1").state
-                    != backend_health.OPEN
-                )
+            use_device = (
+                backend_health.registry().breaker("bls_g1").state
+                != backend_health.OPEN
+            )
         g1_parts = []
         if use_device:
             pks = [bls.g1_deserialize(pubs[i]) for i in entries]
@@ -581,6 +567,7 @@ class BlsBatchVerifier(_CollectingVerifier):
         from cometbft_tpu.crypto import bls12381 as bls
 
         if backend != "cpu" and _bls_device_ok():
+            from cometbft_tpu.crypto import backend_health
             from cometbft_tpu.ops import supervisor
 
             def _device():
@@ -593,28 +580,18 @@ class BlsBatchVerifier(_CollectingVerifier):
                     for a in out
                 ]
 
-            if not supervisor.enabled():
-                try:
-                    return _device()
-                except Exception:
-                    logging.getLogger("cometbft_tpu.crypto").exception(
-                        "TPU BLS G1 path raised - host fallback"
+            def _validate(out):
+                if len(out) != len(pks):
+                    raise backend_health.BackendOutputError(
+                        f"bls_g1 returned {len(out)} points for "
+                        f"{len(pks)} inputs"
                     )
-            else:
-                from cometbft_tpu.crypto import backend_health
 
-                def _validate(out):
-                    if len(out) != len(pks):
-                        raise backend_health.BackendOutputError(
-                            f"bls_g1 returned {len(out)} points for "
-                            f"{len(pks)} inputs"
-                        )
-
-                out = supervisor.supervised_device_call(
-                    "bls_g1", _device, _validate, fallback_units=len(pks)
-                )
-                if out is not None:
-                    return out
+            out = supervisor.supervised_device_call(
+                "bls_g1", _device, _validate, fallback_units=len(pks)
+            )
+            if out is not None:
+                return out
         return [bls.E1.mul_scalar(pk, r) for pk, r in zip(pks, rs)]
 
 

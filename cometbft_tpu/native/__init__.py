@@ -10,6 +10,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import tempfile
 import threading
 from typing import Optional
 
@@ -38,26 +39,40 @@ def _fresh(so: str, src: str) -> bool:
 
 
 def _build(src: str, so: str) -> bool:
+    """Compile to a temporary name of this call's own beside the target,
+    then rename over it: several processes building at once (the test
+    suite's workers on a fresh checkout) each finish a whole library, and
+    the last rename wins."""
+    try:
+        fd, tmp = tempfile.mkstemp(
+            prefix=os.path.basename(so) + ".", suffix=".tmp",
+            dir=os.path.dirname(so),
+        )
+        os.close(fd)
+    except OSError:
+        return False
     try:
         subprocess.run(
-            [
-                "g++",
-                "-O3",
-                "-shared",
-                "-fPIC",
-                "-std=c++17",
-                "-o",
-                so + ".tmp",
-                src,
-            ],
+            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, src],
             check=True,
             capture_output=True,
             timeout=120,
         )
-        os.replace(so + ".tmp", so)
+        os.chmod(tmp, 0o755)  # mkstemp's 0600 may survive the linker
+        os.replace(tmp, so)
         return True
-    except (subprocess.SubprocessError, OSError, FileNotFoundError):
+    except (subprocess.SubprocessError, OSError):
+        try:
+            os.remove(tmp)
+        except OSError:
+            pass
         return False
+
+
+def _ready(src: str, so: str) -> bool:
+    """Fresh already, built now, or, where this build failed, built
+    meanwhile by another process."""
+    return _fresh(so, src) or _build(src, so) or _fresh(so, src)
 
 
 def lib() -> Optional[ctypes.CDLL]:
@@ -69,7 +84,7 @@ def lib() -> Optional[ctypes.CDLL]:
         _tried = True
         if os.environ.get("COMETBFT_TPU_NO_NATIVE"):
             return None
-        if not _fresh(_SO, _SRC) and not _build(_SRC, _SO):
+        if not _ready(_SRC, _SO):
             return None
         try:
             cdll = ctypes.CDLL(_SO)
@@ -139,7 +154,7 @@ def bls() -> Optional[ctypes.CDLL]:
         _bls_tried = True
         if os.environ.get("COMETBFT_TPU_NO_NATIVE"):
             return None
-        if not _fresh(_BLS_SO, _BLS_SRC) and not _build(_BLS_SRC, _BLS_SO):
+        if not _ready(_BLS_SRC, _BLS_SO):
             return None
         try:
             cdll = ctypes.CDLL(_BLS_SO)

@@ -381,12 +381,6 @@ def load_or_compile(jitted, kwargs, tag: str):
     return compiled, info
 
 
-def enabled() -> bool:
-    """COMETBFT_TPU_AOT=0 bypasses the executable cache everywhere
-    (bisection escape hatch: plain jit dispatch, no disk traffic)."""
-    return os.environ.get("COMETBFT_TPU_AOT", "1") != "0"
-
-
 _MEMO_LOCK = threading.Lock()
 _MEMO: dict = {}
 
@@ -398,8 +392,6 @@ def cached_call(jitted, args: tuple, tag: str):
     AOT-compiles+persists; any failure degrades to the plain jitted call.
     The memo mirrors jit's internal cache, including its limitation that
     trace-affecting env flips only apply before a tag's first use."""
-    if not enabled():
-        return jitted(*args)
     with _MEMO_LOCK:
         call = _MEMO.get(tag)
     if call is None:

@@ -19,8 +19,10 @@ just cumulative sums:
   * ``buckets[lanes]``           — dispatch count per padding bucket (the
     per-bucket histogram the bucket-ladder pruning decisions read)
   * ``dispatch_hist[tier-lanes]`` — device dispatch WALL time per
-    (supervisor tier, padding bucket): a sick lane is attributable to a
-    shape and a tier from one scrape
+    (supervisor tier, padding bucket), as the one fetch sees it
+    (``ops/supervisor._fetch_launched``: from the launch's return to the
+    accept bits on the host): a sick lane is attributable to a shape and
+    a tier from one scrape
   * ``shard_hist[device]``       — per-device shard fetch wall time on the
     mesh-sharded verify path (``parallel/mesh.fetch_sharded``): one sick
     chip is ONE outlier series, visible per lane before multi-lane

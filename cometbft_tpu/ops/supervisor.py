@@ -39,8 +39,13 @@ standard shim (modes: raise / hang / wrong_shape / flap) driven by
 counters, and the sim scenarios ``backend_brownout`` / ``backend_wedge`` /
 ``backend_flap`` install it at virtual times (cometbft_tpu/sim/scenarios).
 
-Kill-switch: ``COMETBFT_TPU_SUPERVISOR=0`` restores the raw unsupervised
-dispatch path exactly.
+One launch, one fetch: the single-chip dispatch is written once, as
+``_launch_verify`` (pack, injector, executable, call: one watchdog deadline)
+and ``_fetch_launched`` (the watchdogged copy back, validation, the accept
+bits).  ``dispatch_verify`` / ``fetch_verify`` are the async primitive over
+them, with the breaker and the degradation around each half;
+``verify_supervised`` walks the chain with launch-then-fetch (``_attempt``)
+and bisects.  There is no unsupervised path.
 """
 
 from __future__ import annotations
@@ -65,10 +70,6 @@ logger = logging.getLogger("cometbft_tpu.crypto")
 
 DEFAULT_TIMEOUT_MS = 120000.0
 HOST_BACKEND = "host"
-
-
-def enabled() -> bool:
-    return os.environ.get("COMETBFT_TPU_SUPERVISOR", "1") != "0"
 
 
 def dispatch_timeout_s() -> float:
@@ -267,7 +268,7 @@ def watchdog_call(
     secp256k1/BLS device paths share: any device call a consensus thread
     must survive goes through here.  A fire lands in the flight recorder
     (``note_anomaly=False`` for callers that record their own with richer
-    attribution, like ``_attempt``'s bucket/dispatch attrs)."""
+    attribution, like ``_launch_verify``'s bucket/dispatch attrs)."""
     t = dispatch_timeout_s() if timeout_s is None else timeout_s
     if not t or t <= 0:
         return fn()
@@ -330,20 +331,14 @@ def _pack(pubs, msgs, sigs, min_b: int):
     return arrays, n, structural
 
 
+def _min_bucket(backend: str) -> int:
+    from cometbft_tpu.ops import verify as ov
+
+    return ov._PALLAS_MIN_BUCKET if backend == "pallas" else ov._BUCKETS[0]
+
+
 def _nbytes(arrays: dict) -> int:
     return sum(v.nbytes for v in arrays.values())
-
-
-def _record_launch(launched, dsp, backend: str, arrays: dict) -> None:
-    """``verify.launch``, timed inside the watchdog closure, recorded by the
-    calling thread once ``watchdog_call`` has returned: an abandoned worker
-    writes no span."""
-    launched.record(
-        parent=dsp,
-        tier=backend,
-        lanes=arrays["s_ok"].shape[0],
-        bytes=_nbytes(arrays),
-    )
 
 
 def _launch(backend: str, lanes: int, arrays: dict):
@@ -369,26 +364,72 @@ def _validate_accept(accept, lanes: int) -> np.ndarray:
     return accept
 
 
-def _attempt(backend: str, pubs, msgs, sigs) -> np.ndarray:
-    """One supervised dispatch on one device backend.  Raises
-    ``DispatchTimeoutError`` / ``BackendOutputError`` / whatever the kernel
-    raised; never returns partial results.
+class _InflightVerify:
+    """One supervised verify between its launch and its fetch.
+
+    Kinds:
+      * ``lane``       — routed at one healthy mesh ordinal
+        (``elastic.dispatch_lane``; the shard runs at fetch time on the
+        completion pool, under the shard watchdog);
+      * ``chip``       — a real async device dispatch already in the
+        device queue (unfetched device array + injector transform);
+      * ``deferred``   — the device-runner seam is installed (sim/tests):
+        the whole ``_attempt`` runs at fetch time, so overlap — and the
+        injector's raise/hang — happen on the completion pool;
+      * ``supervised`` — fully degraded at dispatch time (or the dispatch
+        itself failed): fetch walks ``verify_supervised`` with ``skip``.
+
+    ``error`` says how the device part ended: the exception that
+    ``dispatch_verify`` or ``fetch_verify`` answered by degrading, None
+    while all is well.
+    """
+
+    __slots__ = (
+        "kind", "pubs", "msgs", "sigs", "n", "lanes", "backend",
+        "lane", "lane_handle", "dev", "transform", "structural", "skip",
+        "error",
+    )
+
+    def __init__(self, pubs, msgs, sigs, backend=None):
+        self.kind = "supervised"
+        self.pubs = pubs
+        self.msgs = msgs
+        self.sigs = sigs
+        self.n = len(pubs)
+        self.lanes = 0
+        self.backend = backend
+        self.lane = None
+        self.lane_handle = None
+        self.dev = None
+        self.transform = None
+        self.structural = None
+        self.skip = ()
+        self.error = None
+
+    def give_up(self) -> None:
+        """Leave what is in flight on the device where it is: the fetch
+        re-verifies below ``backend`` and waits for nothing."""
+        if self.kind in ("chip", "deferred"):
+            self.kind = "supervised"
+            self.skip = (self.backend,)
+            self.dev = None
+
+
+def _launch_verify(h: _InflightVerify, **attrs) -> None:
+    """THE launch: pack, then injector, executable and call under ONE
+    watchdog deadline; ``h`` leaves with the UNFETCHED device array.
+    Raises ``DispatchTimeoutError`` or whatever the kernel raised.
 
     The dispatch SPAN is recorded on the CALLING thread around
     ``watchdog_call`` — never by the worker — so an abandoned (wedged)
     worker can't race a late span into a deterministic sim's flight
-    record.  It carries the (tier, lanes, dispatch-seq) triple an anomaly
-    dump attributes a watchdog fire to."""
-    from cometbft_tpu.ops import verify as ov
-
-    min_b = ov._PALLAS_MIN_BUCKET if backend == "pallas" else ov._BUCKETS[0]
-    arrays, n, structural = _pack(pubs, msgs, sigs, min_b)
-    lanes = arrays["s_ok"].shape[0]
+    record.  It carries the (tier, lanes) pair, and with ``attrs`` the
+    dispatch ordinal, that an anomaly dump attributes a watchdog fire to."""
+    backend, pubs, msgs, sigs = h.backend, h.pubs, h.msgs, h.sigs
+    arrays, n, h.structural = _pack(pubs, msgs, sigs, _min_bucket(backend))
+    h.lanes = lanes = arrays["s_ok"].shape[0]
     inj = _FAULT_INJECTOR
     runner = _DEVICE_RUNNER
-    # the ordinal this dispatch will record (single dispatch in flight per
-    # attempt; concurrent attempts only skew the label, never the verdict)
-    seq = dispatch_stats.dispatch_count() + 1
     launched = tracing.lap("verify.launch")
 
     def run():
@@ -396,37 +437,66 @@ def _attempt(backend: str, pubs, msgs, sigs) -> np.ndarray:
         dispatch_stats.record_dispatch(lanes, n)
         with launched:
             if runner is not None:
-                dev = runner(backend, pubs, msgs, sigs, lanes)
-            else:
-                # executable resolution (exec-cache load or AOT compile)
-                # runs INSIDE the watchdog worker: a wedged compile is
-                # abandoned like a wedged dispatch, and the device-runner
-                # seam above never pays a compile at all
-                dev = _launch(backend, lanes, arrays)
-        out = np.asarray(dev)
-        if transform is not None:
-            out = transform(out)
-        return out
+                # device-runner seam (sim/tests): a synchronous stand-in,
+                # whose fetch is then a no-op
+                return runner(backend, pubs, msgs, sigs, lanes), transform
+            # executable resolution (exec-cache load or AOT compile)
+            # runs INSIDE the watchdog worker: a wedged compile is
+            # abandoned like a wedged dispatch, and the device-runner
+            # seam above never pays a compile at all
+            return _launch(backend, lanes, arrays), transform
 
-    t0 = time.perf_counter()
     try:
         with tracing.span(
-            "verify.dispatch", tier=backend, lanes=lanes, n=n, dispatch=seq
+            "verify.dispatch", tier=backend, lanes=lanes, n=n, **attrs
         ) as dsp:
-            accept = watchdog_call(run, backend=backend, note_anomaly=False)
-            _record_launch(launched, dsp, backend, arrays)
+            h.dev, h.transform = watchdog_call(
+                run, backend=backend, note_anomaly=False
+            )
+            # timed inside the watchdog closure, recorded by the calling
+            # thread once ``watchdog_call`` has returned: an abandoned
+            # worker writes no span
+            launched.record(
+                parent=dsp, tier=backend, lanes=lanes, bytes=_nbytes(arrays)
+            )
     except DispatchTimeoutError:
         # the failed span is already in the ring (the with-block closed),
         # so the dump this triggers shows it as its most recent entry
         tracing.record_anomaly(
-            "watchdog_fire", tier=backend, lanes=lanes, n=n, dispatch=seq
+            "watchdog_fire", tier=backend, lanes=lanes, n=n, **attrs
         )
         raise
-    finally:
-        dispatch_stats.record_dispatch_time(
-            backend, lanes, time.perf_counter() - t0
-        )
-    return (_validate_accept(accept, lanes) & structural)[:n]
+
+
+def _fetch_launched(h: _InflightVerify) -> np.ndarray:
+    """THE fetch of what ``_launch_verify`` left in ``h``: the watchdogged
+    device-to-host copy, then (n,) accept bits.  Raises; never returns
+    partial results.  ``dispatch_hist`` is fed the fetch wait."""
+
+    def fetch():
+        a = np.asarray(h.dev)
+        return h.transform(a) if h.transform is not None else a
+
+    t0 = time.perf_counter()
+    with tracing.span(
+        "verify.fetch", tier=h.backend, lanes=h.lanes, n=h.n
+    ) as fsp:
+        got = watchdog_call(fetch, backend=h.backend)
+    dispatch_stats.record_dispatch_time(
+        h.backend, h.lanes, tracing.wall_seconds(fsp, t0)
+    )
+    return (_validate_accept(got, h.lanes) & h.structural)[: h.n]
+
+
+def _attempt(backend: str, pubs, msgs, sigs) -> np.ndarray:
+    """One supervised dispatch on one device backend, launch then fetch.
+    Raises ``DispatchTimeoutError`` / ``BackendOutputError`` / whatever the
+    kernel raised; never returns partial results."""
+    h = _InflightVerify(pubs, msgs, sigs, backend)
+    # the ordinal this dispatch will record (single dispatch in flight per
+    # attempt; concurrent attempts only skew the label, never the verdict)
+    _launch_verify(h, dispatch=dispatch_stats.dispatch_count() + 1)
+    return _fetch_launched(h)
 
 
 def host_verify(pubs, msgs, sigs) -> np.ndarray:
@@ -576,160 +646,33 @@ def verify_supervised(
         return host_verify(pubs, msgs, sigs)
 
 
-def verify_batches_overlapped_supervised(work) -> list:
-    """Supervised version of ``ops.verify.verify_batches_overlapped``:
-    same host/device overlap when healthy (dispatch all batches without
-    forcing, fetch in order), but every dispatch AND fetch is watchdogged,
-    and a failure re-runs the affected batch on the next tier down — later
-    batches in the window skip the failed device immediately."""
-    from cometbft_tpu.ops import verify as ov
-
-    work = [(list(p), list(m), list(s)) for p, m, s in work]
-    if not work:
-        return []
-    reg = backend_health.registry()
-    backend = None
-    for b in device_chain():
-        if reg.breaker(b).allow():
-            backend = b
-            break
-    if backend is None:
-        # fully degraded: per-batch host verification, no device to overlap
-        with tracing.span(
-            "verify.window", batches=len(work), tier=HOST_BACKEND
-        ):
-            return [host_verify(*w) for w in work]
-    br = reg.breaker(backend)
-    min_b = ov._PALLAS_MIN_BUCKET if backend == "pallas" else ov._BUCKETS[0]
-
-    inflight: list = []  # (dev_or_None, transform, n, structural, lanes, w)
-    dead = False
-    for w in work:
-        if dead:
-            inflight.append((None, None, 0, None, 0, w))
-            continue
-        arrays, n, structural = _pack(*w, min_b)
-        lanes = arrays["s_ok"].shape[0]
-        inj = _FAULT_INJECTOR
-        runner = _DEVICE_RUNNER
-        launched = tracing.lap("verify.launch")
-
-        def dispatch(arrays=arrays, w=w, lanes=lanes, n=n, launched=launched):
-            transform = (
-                inj(backend, *w) if inj is not None else None
-            )
-            dispatch_stats.record_dispatch(lanes, n)
-            with launched:
-                if runner is not None:
-                    # device-runner seam (sim/tests): synchronous stand-in
-                    # — np.asarray at fetch time is then a no-op
-                    return np.asarray(runner(backend, *w, lanes)), transform
-                return _launch(backend, lanes, arrays), transform
-
-        try:
-            with tracing.span(
-                "verify.dispatch", tier=backend, lanes=lanes, n=n,
-                window=len(work),
-            ) as dsp:
-                dev, transform = watchdog_call(dispatch, backend=backend)
-                _record_launch(launched, dsp, backend, arrays)
-        except Exception as e:  # noqa: BLE001
-            br.record_failure(e)
-            reg.record_demotion(backend)
-            logger.warning(
-                "crypto backend %s overlapped dispatch failed (%r); "
-                "degrading window",
-                backend,
-                e,
-            )
-            dead = True
-            inflight.append((None, None, 0, None, 0, w))
-            continue
-        inflight.append((dev, transform, n, structural, lanes, w))
-
-    out = []
-    wedged = False
-    for dev, transform, n, structural, lanes, w in inflight:
-        if dev is None or wedged:
-            # wedged: once one fetch times out, the device is stuck and
-            # every remaining fetch of the window would serially pay the
-            # full watchdog deadline for the same answer — skip straight
-            # to the fallback tier instead
-            out.append(verify_supervised(*w, skip=(backend,)))
-            continue
-
-        def fetch(dev=dev, transform=transform):
-            a = np.asarray(dev)
-            return transform(a) if transform is not None else a
-
-        try:
-            t0 = time.perf_counter()
-            with tracing.span(
-                "verify.fetch", tier=backend, lanes=lanes, n=n
-            ) as fsp:
-                got = watchdog_call(fetch, backend=backend)
-            dispatch_stats.record_dispatch_time(
-                backend, lanes, tracing.wall_seconds(fsp, t0)
-            )
-            accept = _validate_accept(got, lanes)
-        except Exception as e:  # noqa: BLE001
-            br.record_failure(e)
-            reg.record_demotion(backend)
-            if isinstance(e, DispatchTimeoutError):
-                wedged = True
-            out.append(verify_supervised(*w, skip=(backend,)))
-            continue
-        br.record_success()
-        out.append((accept & structural)[:n])
-    return out
-
-
 # -- in-flight dispatch/fetch seam (docs/verify-scheduler.md) -----------------
 #
 # The async half of ``verify_supervised``: ``dispatch_verify`` routes one
 # batch toward a mesh lane or the single-chip chain WITHOUT blocking on its
 # verdict, and ``fetch_verify`` resolves it later (the verifysched
-# completion pool / ``ops.verify.verify_pipelined``).  Every failure mode
-# at fetch time degrades exactly like the synchronous path — a wedged or
-# failed lane/backend is demoted alone and the batch re-verifies on the
+# completion pool, ``ops.verify.verify_pipelined`` and
+# ``verify_batches_overlapped``).  Every failure mode at fetch time
+# degrades exactly like the synchronous path — a wedged or failed
+# lane/backend is demoted alone and the batch re-verifies on the
 # single-chip chain (host floor), so accept bits stay definitive verdicts.
 
 
-class _InflightVerify:
-    """One supervised verify in flight between dispatch and fetch.
-
-    Kinds:
-      * ``lane``       — routed at one healthy mesh ordinal
-        (``elastic.dispatch_lane``; the shard runs at fetch time on the
-        completion pool, under the shard watchdog);
-      * ``chip``       — a real async device dispatch already in the
-        device queue (unfetched device array + injector transform);
-      * ``deferred``   — the device-runner seam is installed (sim/tests):
-        the whole ``_attempt`` runs at fetch time, so overlap — and the
-        injector's raise/hang — happen on the completion pool;
-      * ``supervised`` — fully degraded at dispatch time (or the dispatch
-        itself failed): fetch walks ``verify_supervised`` with ``skip``.
-    """
-
-    __slots__ = (
-        "kind", "pubs", "msgs", "sigs", "n", "lanes", "backend",
-        "lane", "lane_handle", "dev", "transform", "structural", "skip",
+def _demote(h: _InflightVerify, e: BaseException, when: str) -> None:
+    """A failed launch or fetch demotes its backend; the batch re-verifies
+    below it at fetch, and ``h.error`` keeps how it ended."""
+    reg = backend_health.registry()
+    reg.breaker(h.backend).record_failure(e)
+    reg.record_demotion(h.backend)
+    logger.warning(
+        "crypto backend %s pipelined %s failed (%r); batch re-verifies on "
+        "the next tier",
+        h.backend,
+        when,
+        e,
     )
-
-    def __init__(self, pubs, msgs, sigs):
-        self.kind = "supervised"
-        self.pubs = pubs
-        self.msgs = msgs
-        self.sigs = sigs
-        self.n = len(pubs)
-        self.lanes = 0
-        self.backend = None
-        self.lane = None
-        self.lane_handle = None
-        self.dev = None
-        self.transform = None
-        self.structural = None
-        self.skip = ()
+    h.error = e
+    h.skip = (h.backend,)
 
 
 def dispatch_verify(pubs, msgs, sigs, lane=None) -> _InflightVerify:
@@ -758,65 +701,29 @@ def dispatch_verify(pubs, msgs, sigs, lane=None) -> _InflightVerify:
                 dispatch_stats.record_lane_dispatch(str(h.lane), h.lanes, n)
                 return h
         reg = backend_health.registry()
-        backend = None
         for b in device_chain():
             if reg.breaker(b).allow():
-                backend = b
+                h.backend = b
                 break
-        if backend is None:
+        if h.backend is None:
             # fully degraded: fetch walks the chain (host floor answers)
             dispatch_stats.record_lane_dispatch(HOST_BACKEND, max(n, 1), n)
             return h
-        h.backend = backend
-        min_b = (
-            ov._PALLAS_MIN_BUCKET if backend == "pallas" else ov._BUCKETS[0]
-        )
         if _DEVICE_RUNNER is not None:
             # device-runner seam: the stand-in runs synchronously, so the
             # only way it can overlap is to defer it to the completion
             # pool entirely — which also puts the injector's raise/hang
             # where a real device fault would surface: at fetch
             h.kind = "deferred"
-            h.lanes = ov.bucket_size(max(n, 1), min_b)
-            dispatch_stats.record_lane_dispatch(backend, h.lanes, n)
-            return h
-        arrays, _, structural = _pack(pubs, msgs, sigs, min_b)
-        lanes = arrays["s_ok"].shape[0]
-        inj = _FAULT_INJECTOR
-        launched = tracing.lap("verify.launch")
-
-        def dispatch():
-            transform = (
-                inj(backend, pubs, msgs, sigs) if inj is not None else None
-            )
-            dispatch_stats.record_dispatch(lanes, n)
-            with launched:
-                return _launch(backend, lanes, arrays), transform
-
-        try:
-            with tracing.span(
-                "verify.dispatch", tier=backend, lanes=lanes, n=n,
-                pipelined=True,
-            ) as dsp:
-                h.dev, h.transform = watchdog_call(dispatch, backend=backend)
-                _record_launch(launched, dsp, backend, arrays)
-        except Exception as e:  # noqa: BLE001 — dispatch failure demotes;
-            # the batch re-verifies on the next tier at fetch time
-            reg.breaker(backend).record_failure(e)
-            reg.record_demotion(backend)
-            logger.warning(
-                "crypto backend %s pipelined dispatch failed (%r); batch "
-                "will re-verify on the next tier at fetch",
-                backend,
-                e,
-            )
-            h.backend = None
-            h.skip = (backend,)
-            return h
-        h.kind = "chip"
-        h.lanes = lanes
-        h.structural = structural
-        dispatch_stats.record_lane_dispatch(backend, lanes, n)
+            h.lanes = ov.bucket_size(max(n, 1), _min_bucket(h.backend))
+        else:
+            try:
+                _launch_verify(h, pipelined=True)
+            except Exception as e:  # noqa: BLE001 — any dispatch error
+                _demote(h, e, "dispatch")
+                return h
+            h.kind = "chip"
+        dispatch_stats.record_lane_dispatch(h.backend, h.lanes, n)
         return h
     except BaseException:
         # a dispatch that never produced a handle must not leak depth
@@ -829,7 +736,6 @@ def fetch_verify(h: _InflightVerify) -> np.ndarray:
     for infrastructure reasons — every failure mode degrades the guilty
     lane/backend alone and re-verifies on the single-chip chain, whose
     floor is the host ZIP-215 oracle."""
-    reg = backend_health.registry()
     try:
         if h.kind == "lane":
             from cometbft_tpu.parallel import elastic
@@ -844,49 +750,17 @@ def fetch_verify(h: _InflightVerify) -> np.ndarray:
                 width = max(0, len(elastic.healthy_ordinals()) - 1)
                 elastic.note_lane_failure(ordinal, err, width)
                 return verify_supervised(h.pubs, h.msgs, h.sigs, mesh=False)
-        if h.kind == "deferred":
-            br = reg.breaker(h.backend)
+        if h.kind in ("chip", "deferred"):
             try:
-                bits = _attempt(h.backend, h.pubs, h.msgs, h.sigs)
-            except Exception as e:  # noqa: BLE001 — any dispatch error
-                br.record_failure(e)
-                reg.record_demotion(h.backend)
-                logger.warning(
-                    "crypto backend %s pipelined verify failed (%r); "
-                    "retrying on the next verify tier",
-                    h.backend,
-                    e,
-                )
-                return verify_supervised(
-                    h.pubs, h.msgs, h.sigs, skip=(h.backend,), mesh=False
-                )
-            br.record_success()
-            return bits
-        if h.kind == "chip":
-            br = reg.breaker(h.backend)
-
-            def fetch():
-                a = np.asarray(h.dev)
-                return h.transform(a) if h.transform is not None else a
-
-            try:
-                t0 = time.perf_counter()
-                with tracing.span(
-                    "verify.fetch", tier=h.backend, lanes=h.lanes, n=h.n
-                ) as fsp:
-                    got = watchdog_call(fetch, backend=h.backend)
-                dispatch_stats.record_dispatch_time(
-                    h.backend, h.lanes, tracing.wall_seconds(fsp, t0)
-                )
-                accept = _validate_accept(got, h.lanes)
-            except Exception as e:  # noqa: BLE001 — fetch failure demotes
-                br.record_failure(e)
-                reg.record_demotion(h.backend)
-                return verify_supervised(
-                    h.pubs, h.msgs, h.sigs, skip=(h.backend,), mesh=False
-                )
-            br.record_success()
-            return (accept & h.structural)[: h.n]
+                if h.kind == "chip":
+                    bits = _fetch_launched(h)
+                else:
+                    bits = _attempt(h.backend, h.pubs, h.msgs, h.sigs)
+            except Exception as e:  # noqa: BLE001 — any error demotes
+                _demote(h, e, "fetch")
+            else:
+                backend_health.registry().breaker(h.backend).record_success()
+                return bits
         return verify_supervised(
             h.pubs, h.msgs, h.sigs, skip=h.skip, mesh=False
         )

@@ -25,7 +25,6 @@ import hashlib
 import logging
 import os
 import threading
-import time
 import warnings
 from collections import deque
 from typing import Optional, Sequence
@@ -41,7 +40,6 @@ warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable"
 )
 
-from cometbft_tpu.libs import tracing
 from cometbft_tpu.ops import dispatch_stats
 from cometbft_tpu.ops import fe25519 as fe
 from cometbft_tpu.ops import ed25519_point as ep
@@ -223,26 +221,15 @@ def compile_failed(key, what: str, e: BaseException) -> TierCompileError:
     return TierCompileError(msg)
 
 
-def aot_enabled() -> bool:
-    """COMETBFT_TPU_AOT=0 bypasses the executable cache entirely and
-    restores the plain jit dispatch path (bisection escape hatch)."""
-    return os.environ.get("COMETBFT_TPU_AOT", "1") != "0"
-
-
 def donation_enabled() -> bool:
-    """Whether the hot loop uses input-donating executables by default.
-
-    ``COMETBFT_TPU_DONATE=1/0`` overrides; the default is ON exactly for
-    the Pallas/TPU production path.  The XLA-CPU CI path defaults OFF on
-    purpose: donation changes the compiled artifact, so defaulting it on
-    would force a fresh ~100s compile of every bucket shape the first time
-    a host runs this code (measured on the CI host) for an aliasing win
-    that only matters at device-HBM bandwidth.  Callers that reuse
+    """Whether the hot loop uses input-donating executables by default:
+    ON exactly for the Pallas/TPU production path.  The XLA-CPU CI path is
+    OFF on purpose: donation changes the compiled artifact, so turning it
+    on would force a fresh ~100s compile of every bucket shape the first
+    time a host runs this code (measured on the CI host) for an aliasing
+    win that only matters at device-HBM bandwidth.  Callers that reuse
     device-resident inputs across calls (bench timed reps, chip_validate)
     always pass ``donated=False`` explicitly."""
-    env = os.environ.get("COMETBFT_TPU_DONATE")
-    if env is not None:
-        return env != "0"
     return _use_pallas()
 
 
@@ -283,7 +270,7 @@ def bucket_executable(
     the jitted kernels).  info["exec_cache"] records where it came from:
     ``memo`` (process cache), ``hit`` (deserialized from disk — no tracing,
     no compilation), ``miss``/``stale`` + ``compile_s`` (freshly built and
-    persisted), ``disabled`` (plain jit).
+    persisted).
 
     A lowering or compile failure is LOUD: logged at error with the
     compiler's message, counted (``warm_stats`` ``compile_failures``),
@@ -291,9 +278,6 @@ def bucket_executable(
     supervised callers demote the tier through its breaker."""
     if donated is None:
         donated = donation_enabled()
-    jitted = _bucket_jitted(impl, donated)
-    if not aot_enabled():
-        return jitted, {"exec_cache": "disabled"}
     key = (impl, lanes, bool(donated))
     raise_if_broken(key)
     with _EXEC_LOCK:
@@ -304,7 +288,9 @@ def bucket_executable(
 
     try:
         call, info = aot_cache.load_or_compile(
-            jitted, _bucket_shapes(lanes), bucket_tag(impl, lanes, donated)
+            _bucket_jitted(impl, donated),
+            _bucket_shapes(lanes),
+            bucket_tag(impl, lanes, donated),
         )
     except Exception as e:  # noqa: BLE001 — whatever the compiler raised
         raise compile_failed(
@@ -325,13 +311,6 @@ def reset_executable_memo() -> None:
     from cometbft_tpu.ops import aot_cache
 
     aot_cache.reset_memo()
-
-
-def _dispatch_bucket(arrays: dict, impl: str):
-    """Ship one packed bucket to the device; returns the UNFETCHED device
-    array so overlapped callers keep their async-dispatch pipelining."""
-    call, _ = bucket_executable(impl, arrays["s_ok"].shape[0])
-    return call(**{k: jnp.asarray(v) for k, v in arrays.items()})
 
 
 def prepare_batch(
@@ -473,67 +452,95 @@ def verify_batch(
 ) -> np.ndarray:
     """Verify a batch; returns (n,) bool numpy array of per-signature results.
 
-    Supervised by default (ops/supervisor): the dispatch runs under a
-    watchdog deadline and a device failure degrades down the verified
-    chain pallas -> xla -> host instead of raising — accept bits are
-    always definitive verdicts, never infrastructure errors in disguise.
-    On a multi-chip host the supervised path shards across the elastic
-    device mesh first (``parallel/elastic`` — one sick chip loses a lane,
-    not the fleet); ``_maybe_enable_mesh`` below decides activation once
-    per process.  ``COMETBFT_TPU_SUPERVISOR=0`` restores the raw dispatch
-    below."""
+    Supervised (ops/supervisor): the dispatch runs under a watchdog
+    deadline and a device failure degrades down the verified chain
+    pallas -> xla -> host instead of raising — accept bits are always
+    definitive verdicts, never infrastructure errors in disguise.  On a
+    multi-chip host the supervised path shards across the elastic device
+    mesh first (``parallel/elastic`` — one sick chip loses a lane, not the
+    fleet); ``_maybe_enable_mesh`` decides activation once per process."""
     from cometbft_tpu.ops import supervisor
 
-    if supervisor.enabled():
-        _maybe_enable_mesh()
-        return supervisor.verify_supervised(pubs, msgs, sigs)
-    arrays, n, structural = prepare_batch(pubs, msgs, sigs, _min_bucket())
-    impl = select_impl()
-    lanes = arrays["s_ok"].shape[0]
-    dispatch_stats.record_dispatch(lanes, n)
-    seq = dispatch_stats.dispatch_count()
-    t0 = time.perf_counter()
-    with tracing.span(
-        "verify.dispatch", tier=impl, lanes=lanes, n=n, dispatch=seq
-    ):
-        accept = np.asarray(_dispatch_bucket(arrays, impl))
-    dispatch_stats.record_dispatch_time(impl, lanes, time.perf_counter() - t0)
-    return (accept & structural)[:n]
+    _maybe_enable_mesh()
+    return supervisor.verify_supervised(pubs, msgs, sigs)
 
 
 def verify_batches_overlapped(
     work: "Sequence[tuple[Sequence[bytes], Sequence[bytes], Sequence[bytes]]]",
 ) -> list:
     """Verify several (pubs, msgs, sigs) batches with host/device overlap:
-    each batch is DISPATCHED before the previous result is fetched, so the
-    host prep (SHA-512 + packing) of batch i+1 runs while the device
-    ladders batch i, and on backends that queue dispatches the kernels
-    pipeline (VERDICT r4 #3 — amortizing the per-dispatch floor across
-    consecutive commits).  Whether dispatches pipeline on the attached
-    chip is not measured yet.
+    every batch is DISPATCHED (``supervisor.dispatch_verify``) before the
+    first result is fetched, so the host prep (SHA-512 + packing) of batch
+    i+1 runs while the device ladders batch i, and on backends that queue
+    dispatches the kernels pipeline (VERDICT r4 #3 — amortizing the
+    per-dispatch floor across consecutive commits).  Whether dispatches
+    pipeline on the attached chip is not measured yet.
 
     Returns a list of (n,) bool arrays, one per input batch.
 
-    Supervised by default: each dispatch and each fetch runs under the
-    watchdog, a mid-window device failure re-runs the affected batch on
-    the next tier down (the rest of the window skips the dead device),
-    and with every device breaker open the whole window resolves on the
-    host — degraded, never aborted."""
+    Each dispatch and each fetch runs under the watchdog, a mid-window
+    device failure re-runs the affected batch on the next tier down, and
+    with every device breaker open the whole window resolves on the host —
+    degraded, never aborted."""
     from cometbft_tpu.ops import supervisor
 
-    if supervisor.enabled():
-        return supervisor.verify_batches_overlapped_supervised(work)
-    impl = select_impl()
-    min_b = _min_bucket()
-    inflight = []  # (device result, n, structural)
+    # The breaker opens at its third failure, so by itself it would let a
+    # window pay the watchdog deadline three times over for one stuck
+    # device.  The window's own rule: once a dispatch has failed, nothing
+    # more is dispatched; once a deadline has passed, nothing more is
+    # waited for.  What is left re-verifies below the backend at fault.
+    handles: list = []
+    failed = None  # the handle whose dispatch failed
     for pubs, msgs, sigs in work:
-        arrays, n, structural = prepare_batch(pubs, msgs, sigs, min_b)
-        dispatch_stats.record_dispatch(arrays["s_ok"].shape[0], n)
-        dev = _dispatch_bucket(arrays, impl)
-        inflight.append((dev, n, structural))  # no block: async dispatch
-    return [
-        (np.asarray(dev) & structural)[:n] for dev, n, structural in inflight
-    ]
+        h = None
+        if failed is None:
+            h = supervisor.dispatch_verify(pubs, msgs, sigs)
+            if h.error is not None:
+                failed = h
+        handles.append(h)
+    wedged = failed is not None and isinstance(
+        failed.error, supervisor.DispatchTimeoutError
+    )
+    out = []
+    for (pubs, msgs, sigs), h in zip(work, handles):
+        if h is None:
+            out.append(
+                supervisor.verify_supervised(
+                    pubs, msgs, sigs, skip=failed.skip, mesh=False
+                )
+            )
+            continue
+        if wedged:
+            h.give_up()
+        out.append(supervisor.fetch_verify(h))
+        wedged = wedged or isinstance(
+            h.error, supervisor.DispatchTimeoutError
+        )
+    return out
+
+
+def _fuse(work) -> tuple:
+    """Several segments laid end to end, for ONE bucket-padded dispatch."""
+    pubs: list = []
+    msgs: list = []
+    sigs: list = []
+    for p, m, s in work:
+        pubs.extend(p)
+        msgs.extend(m)
+        sigs.extend(s)
+    if len(work) > 1:
+        dispatch_stats.record_fused(len(work))
+    return pubs, msgs, sigs
+
+
+def _split(bits, sizes) -> "list[np.ndarray]":
+    """The fused dispatch's accept bits, back out per segment."""
+    out = []
+    off = 0
+    for n in sizes:
+        out.append(bits[off : off + n])
+        off += n
+    return out
 
 
 def verify_segments(
@@ -559,22 +566,7 @@ def verify_segments(
         return [np.zeros(0, dtype=bool) for _ in work]
     if total > _BUCKETS[-1]:
         return verify_batches_overlapped(work)
-    pubs: list = []
-    msgs: list = []
-    sigs: list = []
-    for p, m, s in work:
-        pubs.extend(p)
-        msgs.extend(m)
-        sigs.extend(s)
-    if len(work) > 1:
-        dispatch_stats.record_fused(len(work))
-    bits = verify_batch(pubs, msgs, sigs)
-    out = []
-    off = 0
-    for n in sizes:
-        out.append(bits[off : off + n])
-        off += n
-    return out
+    return _split(verify_batch(*_fuse(work)), sizes)
 
 
 # -- in-flight pipeline seam (docs/verify-scheduler.md) -----------------------
@@ -589,12 +581,12 @@ def verify_segments(
 
 
 class _SegmentsHandle:
-    """One fused multi-segment verify between dispatch and fetch."""
+    """One fused multi-segment verify between dispatch and fetch; ``sup``
+    is the supervisor's handle, None where nothing is in flight."""
 
-    __slots__ = ("kind", "sizes", "work", "sup")
+    __slots__ = ("sizes", "work", "sup")
 
     def __init__(self, work, sizes):
-        self.kind = "sync"
         self.work = work
         self.sizes = sizes
         self.sup = None
@@ -606,52 +598,29 @@ def dispatch_segments(work, lane=None) -> _SegmentsHandle:
     at one elastic-mesh ordinal (round-robined by the scheduler) so K
     concurrent flushes spread across lanes instead of piling onto one.
     Shapes with no single fused dispatch (empty, or overflowing the
-    largest bucket) — and the unsupervised raw path — resolve
-    synchronously at fetch time."""
+    largest bucket) resolve synchronously at fetch time."""
     from cometbft_tpu.ops import supervisor
 
     work = [(list(p), list(m), list(s)) for p, m, s in work]
     sizes = [len(p) for p, _, _ in work]
     h = _SegmentsHandle(work, sizes)
-    total = sum(sizes)
-    if total == 0:
-        h.kind = "empty"
-        return h
-    if total > _BUCKETS[-1] or not supervisor.enabled():
-        return h  # "sync": fetch runs the verify_segments path verbatim
-    pubs: list = []
-    msgs: list = []
-    sigs: list = []
-    for p, m, s in work:
-        pubs.extend(p)
-        msgs.extend(m)
-        sigs.extend(s)
-    if len(work) > 1:
-        dispatch_stats.record_fused(len(work))
-    _maybe_enable_mesh()
-    h.kind = "sup"
-    h.sup = supervisor.dispatch_verify(pubs, msgs, sigs, lane=lane)
+    if 0 < sum(sizes) <= _BUCKETS[-1]:
+        pubs, msgs, sigs = _fuse(work)
+        _maybe_enable_mesh()
+        h.sup = supervisor.dispatch_verify(pubs, msgs, sigs, lane=lane)
     return h
 
 
 def fetch_segments(h: _SegmentsHandle) -> "list[np.ndarray]":
     """Resolve one in-flight fused dispatch: list of (n_i,) bool arrays,
     one per input segment.  Like ``verify_segments``, cannot raise for
-    infrastructure reasons on the supervised path — the supervisor
-    degrades a failed/wedged lane alone and re-verifies down the chain."""
-    if h.kind == "empty":
-        return [np.zeros(0, dtype=bool) for _ in h.work]
-    if h.kind == "sync":
+    infrastructure reasons — the supervisor degrades a failed/wedged lane
+    alone and re-verifies down the chain."""
+    if h.sup is None:
         return verify_segments(h.work)
     from cometbft_tpu.ops import supervisor
 
-    bits = supervisor.fetch_verify(h.sup)
-    out = []
-    off = 0
-    for n in h.sizes:
-        out.append(bits[off : off + n])
-        off += n
-    return out
+    return _split(supervisor.fetch_verify(h.sup), h.sizes)
 
 
 def verify_pipelined(
@@ -681,8 +650,6 @@ def verify_pipelined(
     n = len(pubs)
     if n == 0:
         return np.zeros(0, dtype=bool)
-    if not supervisor.enabled():
-        return verify_batch(pubs, msgs, sigs)
     _maybe_enable_mesh()
     ordinals = elastic.healthy_ordinals()
     width = max(len(ordinals), 1)

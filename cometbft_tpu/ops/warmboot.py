@@ -268,9 +268,6 @@ def _run_matrices(reg, statuses: dict, dead: set, t0: float) -> dict:
                 else str(info.get("exec_cache", "?"))
             )
             statuses[key] = status
-            if status == "disabled":
-                # AOT off: nothing was actually precompiled
-                continue
             warmed += 1
         except Exception as e:  # noqa: BLE001 — a compile failure demotes
             # the tier via the breaker; boot itself never wedges
@@ -350,7 +347,7 @@ def _run_matrices(reg, statuses: dict, dead: set, t0: float) -> dict:
                     else str(info.get("exec_cache", "?"))
                 )
                 statuses[tag] = status
-                if not status.startswith(("broken", "disabled")):
+                if not status.startswith("broken"):
                     warmed += 1
         except Exception as e:  # noqa: BLE001 — boot never wedges
             failures += 1

@@ -209,8 +209,6 @@ def sharded_verify_call(
     if hit is not None:
         return hit, {"exec_cache": "memo"}
     jitted, _ = sharded_verify_fn(mesh, impl, donated=donated)
-    if not ov.aot_enabled():
-        return jitted, {"exec_cache": "disabled"}
     ov.raise_if_broken(("mesh",) + key)
     from cometbft_tpu.ops import aot_cache
 

@@ -370,7 +370,6 @@ _BACKEND_ENV_KNOBS = (
     "COMETBFT_TPU_VERIFY_SCHED",
     "COMETBFT_TPU_SCHED_FLUSH_US",
     "COMETBFT_TPU_SCHED_QUEUE",
-    "COMETBFT_TPU_SCHED_PIPELINE",
     "COMETBFT_TPU_SCHED_INFLIGHT",
     "COMETBFT_TPU_TXINGEST",
     "COMETBFT_TPU_TXINGEST_QUEUE",
@@ -2205,7 +2204,6 @@ SCENARIOS: dict[str, Scenario] = {
             setup=_backend_faults_setup(
                 {
                     "COMETBFT_TPU_VERIFY_SCHED": "1",
-                    "COMETBFT_TPU_SCHED_PIPELINE": "1",
                     "COMETBFT_TPU_SCHED_INFLIGHT": "2",
                     "COMETBFT_TPU_SCHED_FLUSH_US": "500",
                 }

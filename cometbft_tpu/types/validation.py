@@ -295,7 +295,7 @@ def fused_verify_eligible(validator_sets=()) -> bool:
 
     if cbatch.default_backend() != "tpu":
         return False
-    if supervisor.enabled() and supervisor.active_backend() is None:
+    if supervisor.active_backend() is None:
         return False
     for vals in validator_sets:
         if not all(

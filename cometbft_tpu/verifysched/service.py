@@ -15,9 +15,11 @@ shape from inference serving applied to signature verification
     batch verifier's whole segment that way; ``submit(pub, msg, sig,
     priority)`` is the n = 1 case of the same entry;
   * one dispatcher thread coalesces pending entries ACROSS all submitters
-    into a single ``ops/verify.verify_segments`` dispatch, flushing when
+    into a single ``ops/verify.dispatch_segments`` dispatch, flushing when
     the oldest entry has waited ``COMETBFT_TPU_SCHED_FLUSH_US`` (~2000) or
-    when the queued signatures fill a padding bucket;
+    when the queued signatures fill a padding bucket; it never waits for
+    verdicts: one completion thread fetches them (``fetch_segments``) in
+    drain order, so flush i+1 is packed while flush i is on the device;
   * the sigcache is consulted before any queue slot or device lane is
     occupied, and duplicate in-flight triples (the same vote gossiped by
     two peers at once) collapse into one lane;
@@ -122,15 +124,6 @@ def scheduler_active() -> bool:
     """True when submissions should take the scheduler path: kill switch
     on AND the batch backend trusted (``backend_trusted``)."""
     return enabled() and backend_trusted()
-
-
-def pipeline_enabled() -> bool:
-    """In-flight pipelining (docs/verify-scheduler.md "In-flight
-    pipeline"): the dispatcher ships flush i+1 while flush i is still on
-    the device, and one completion thread resolves verdicts in drain
-    order.  ``COMETBFT_TPU_SCHED_PIPELINE=0`` restores the single-flight
-    dispatcher bit-for-bit."""
-    return os.environ.get("COMETBFT_TPU_SCHED_PIPELINE", "1") != "0"
 
 
 def inflight_target() -> int:
@@ -290,10 +283,10 @@ class VerifyScheduler:
         # times: with K flushes in flight, drain-time deltas could go
         # negative or interleave
         self._last_flush_t: Optional[float] = None
-        # in-flight pipeline state (pipeline_enabled()): FIFO of
-        # dispatched-but-unfetched flushes, resolved in drain order by
-        # one completion thread; _fcond has its OWN lock so a waiting
-        # dispatcher never blocks submitters
+        # in-flight pipeline state (docs/verify-scheduler.md "In-flight
+        # pipeline"): FIFO of dispatched-but-unfetched flushes, resolved
+        # in drain order by one completion thread; _fcond has its OWN
+        # lock so a waiting dispatcher never blocks submitters
         self._flock = threading.Lock()
         self._fcond = threading.Condition(self._flock)
         self._fetch_queue: "deque[tuple]" = deque()
@@ -617,15 +610,11 @@ class VerifyScheduler:
     def _execute(self, entries: "list[_Entry]", reason: str) -> None:
         recorded = [False]
         try:
-            if pipeline_enabled():
-                # in-flight pipeline: dispatch without blocking on the
-                # verdicts — enqueueing onto the completion FIFO is the
-                # LAST step, so any exception reaching the fallback below
-                # means these entries were never handed off and the host
-                # reference resolve covers all of them
-                self._dispatch_flush(entries, reason, recorded)
-            else:
-                self._execute_inner(entries, reason, recorded)
+            # dispatch without blocking on the verdicts — enqueueing onto
+            # the completion FIFO is the LAST step, so any exception
+            # reaching the fallback below means these entries were never
+            # handed off and the host reference resolve covers all of them
+            self._dispatch_flush(entries, reason, recorded)
         except BaseException as e:  # noqa: BLE001 — futures must ALWAYS
             # resolve: these entries left the queue, so the submit-path
             # dispatcher restart can never recover them — an unresolved
@@ -636,7 +625,7 @@ class VerifyScheduler:
                 "on the host reference",
                 n,
             )
-            # exactly-once flush accounting: if the inner pass failed
+            # exactly-once flush accounting: if the flush failed
             # before recording, account the drained signatures here or
             # queue_depth stays inflated forever
             if not recorded[0]:
@@ -716,13 +705,13 @@ class VerifyScheduler:
 
     @classmethod
     def _plan(cls, entries: "list[_Entry]"):
-        """The front half both flush paths share, over the entries' lists
-        laid end to end.  Structural filter: garbage never occupies a
+        """The front half of a flush, over the entries' lists laid end to
+        end.  Structural filter: garbage never occupies a
         device lane.  In-flight dedup ACROSS the entries, on the keys they
         carry (an entry that brought none is keyed here): concurrent
         gossip of the same vote collapses into one lane, every index that
         holds it shares the verdict.  One work segment per priority class
-        present: ``verify_segments`` fuses them into ONE dispatch
+        present: ``dispatch_segments`` fuses them into ONE dispatch
         (recording cross-class fusion in ops/dispatch_stats) and splits
         the bits back per class.  Returns ``(bits, dups, ordered, work,
         lanes)``: ``bits`` flat with the filtered indices already False,
@@ -812,35 +801,6 @@ class VerifyScheduler:
                 self._finish(en, part, now)
         self._finish(*last, now)
 
-    def _execute_inner(
-        self, entries: "list[_Entry]", reason: str, recorded: "list[bool]"
-    ) -> None:
-        n = sum(en.n for en in entries)
-        # flush span (closed BEFORE futures resolve, like the stats below,
-        # so a deterministic sim's ring order cannot race its waiters)
-        with self._flush_span(reason, entries, n) as fsp:
-            bits, dups, ordered, work, lanes = self._plan(entries)
-            if work:
-                from cometbft_tpu.ops import verify as ov
-
-                self._settle(
-                    entries, bits, dups, ordered, ov.verify_segments(work)
-                )
-            fsp.set(misses=len(ordered), lanes=lanes)
-
-        # record BEFORE resolving: set_result unblocks waiters, and a
-        # caller reading stats right after its verdict (the sim's
-        # end-of-run capture asserts queue_depth == 0) must not race the
-        # dispatcher's bookkeeping; ``recorded`` keeps the _execute
-        # fallback from double-counting if a resolve below raises
-        interval = self._flush_interval()
-        stats.record_flush(
-            reason, items=n, misses=len(ordered), lanes=lanes,
-            interval_s=interval,
-        )
-        recorded[0] = True
-        self._resolve(entries, bits, fsp)
-
     @staticmethod
     def _settle(entries, bits, dups, ordered, results) -> None:
         """The verdicts, in ``ordered``'s order, to the first index of each
@@ -888,16 +848,17 @@ class VerifyScheduler:
     def _dispatch_flush(
         self, entries: "list[_Entry]", reason: str, recorded: "list[bool]"
     ) -> None:
-        """The pipelined front half of a flush: ``_plan`` (filter, dedup,
-        per-class work) + ONE fused dispatch (``ops.verify.dispatch_segments``),
+        """The front half of a flush: ``_plan`` (filter, dedup, per-class
+        work) + ONE fused dispatch (``ops.verify.dispatch_segments``),
         then hand the in-flight handle to the completion thread and
         return to draining — up to ``inflight_target()`` flushes ride
         the device concurrently, round-robined across healthy mesh
-        lanes.  Identical front-half semantics to ``_execute_inner``;
-        only WHERE the fetch happens moves."""
+        lanes."""
         n = sum(en.n for en in entries)
         interval = self._flush_interval()
 
+        # flush span (closed BEFORE futures resolve, like the stats below,
+        # so a deterministic sim's ring order cannot race its waiters)
         with self._flush_span(reason, entries, n) as fsp:
             bits, dups, ordered, work, lanes = self._plan(entries)
             handle = None
@@ -957,6 +918,11 @@ class VerifyScheduler:
                     raise
             fsp.set(misses=len(ordered), lanes=lanes)
 
+        # record BEFORE resolving: set_result unblocks waiters, and a
+        # caller reading stats right after its verdict (the sim's
+        # end-of-run capture asserts queue_depth == 0) must not race the
+        # dispatcher's bookkeeping; ``recorded`` keeps the _execute
+        # fallback from double-counting if a resolve below raises
         stats.record_flush(
             reason, items=n, misses=len(ordered), lanes=lanes,
             interval_s=interval,
@@ -1010,7 +976,7 @@ class VerifyScheduler:
                     self._fcond.notify_all()
 
     def _resolve_flush(self, pf: tuple) -> None:
-        """The completion half of one pipelined flush: fetch verdicts,
+        """The completion half of one flush: fetch verdicts,
         settle them (``_settle``), resolve every future.  Runs on the
         completion thread in drain order; cannot leave a future
         unresolved — a fetch that somehow escapes the supervisor's

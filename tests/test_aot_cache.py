@@ -298,15 +298,7 @@ class TestConcurrency:
         assert (np.asarray(loaded(_arg())) == np.arange(8) * 2 + 1).all()
 
 
-class TestKillSwitchAndFallback:
-    def test_aot_kill_switch(self, tmp_cache, monkeypatch):
-        monkeypatch.setenv("COMETBFT_TPU_AOT", "0")
-        out = aot_cache.cached_call(_JIT, (_arg(),), "t-off")
-        assert np.asarray(out).tolist() == (np.arange(8) * 2 + 1).tolist()
-        assert not os.path.exists(tmp_cache)  # no disk traffic at all
-        call, info = ov.bucket_executable("xla", 32)
-        assert info["exec_cache"] == "disabled"
-
+class TestFallback:
     def test_cached_call_falls_back_on_cache_error(
         self, tmp_cache, monkeypatch
     ):

@@ -205,3 +205,73 @@ class TestCommitSignBytes:
         # python fallback path must agree too
         monkeypatch.setattr(native, "lib", lambda: None)
         assert commit.all_vote_sign_bytes("csb-chain", [5, 1, 2]) == want
+
+
+class TestBuildRace:
+    """ROADMAP D12: six xdist workers on a fresh checkout all build the
+    same library; each compiles to a name of its own."""
+
+    def test_two_builds_never_share_an_output_path(self, tmp_path, monkeypatch):
+        import subprocess
+
+        so = str(tmp_path / "_lib.so")
+        outs = []
+
+        def fake_run(cmd, **kw):
+            outs.append(cmd[cmd.index("-o") + 1])
+
+        monkeypatch.setattr(subprocess, "run", fake_run)
+        assert native._build("x.cpp", so) and native._build("x.cpp", so)
+        assert len(set(outs)) == 2 and so + ".tmp" not in outs
+        assert all(os.path.dirname(o) == str(tmp_path) for o in outs)
+        assert os.listdir(tmp_path) == ["_lib.so"]  # renamed, nothing left
+
+    def test_failed_build_leaves_nothing_and_a_fresh_library_is_taken(
+        self, tmp_path, monkeypatch
+    ):
+        import subprocess
+
+        src, so = tmp_path / "x.cpp", tmp_path / "_lib.so"
+        src.write_text("")
+
+        def failing_run(cmd, **kw):
+            # another process finishes its build while this one fails
+            so.write_bytes(b"built elsewhere")
+            raise subprocess.CalledProcessError(1, cmd)
+
+        monkeypatch.setattr(subprocess, "run", failing_run)
+        assert not native._build(str(src), str(so))
+        assert sorted(os.listdir(tmp_path)) == ["_lib.so", "x.cpp"]
+        so.unlink()
+        assert native._ready(str(src), str(so))  # the loser finds it fresh
+        monkeypatch.setattr(
+            subprocess, "run",
+            lambda cmd, **kw: (_ for _ in ()).throw(FileNotFoundError("g++")),
+        )
+        so.unlink()
+        assert not native._ready(str(src), str(so))  # no toolchain, no library
+
+    def test_concurrent_real_builds_all_end_with_a_loadable_library(
+        self, tmp_path
+    ):
+        import shutil
+        import threading
+
+        if shutil.which("g++") is None:
+            pytest.skip("no g++")
+        src, so = tmp_path / "one.cpp", tmp_path / "_one.so"
+        src.write_text('extern "C" int one() { return 1; }\n')
+        results = []
+        threads = [
+            threading.Thread(
+                target=lambda: results.append(native._build(str(src), str(so)))
+            )
+            for _ in range(4)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert results == [True] * 4
+        assert sorted(os.listdir(tmp_path)) == ["_one.so", "one.cpp"]
+        assert ctypes.CDLL(str(so)).one() == 1

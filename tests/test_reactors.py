@@ -348,3 +348,49 @@ class TestBlocksyncBodyValidation:
         r.pool = FakePool()
         assert r._process_blocks() is True  # handled (rejected + redo)
         assert r.pool.redone == [1, 2]
+
+
+class TestConsensusHandOff:
+    """A node still in blocksync drops what a peer sends for the height it
+    announced, and the peer marks it delivered: so it announces nothing
+    until the hand-off (reference: reactor.go AddPeer)."""
+
+    @staticmethod
+    def _reactor(wait_sync):
+        import threading
+        from types import SimpleNamespace
+
+        from cometbft_tpu.consensus.reactor import ConsensusReactor
+
+        cs = SimpleNamespace(
+            rs=SimpleNamespace(
+                height=1, round_=0, step=1, start_time=time.time(),
+                last_commit=None, votes=None,
+            ),
+            _mtx=threading.Lock(),
+            _started=True,
+            add_step_listener=lambda fn: None,
+            add_vote_listener=lambda fn: None,
+            update_to_state=lambda state: None,
+        )
+        return ConsensusReactor(cs, block_store=None, wait_sync=wait_sync)
+
+    @staticmethod
+    def _peer(sent):
+        from types import SimpleNamespace
+
+        return SimpleNamespace(
+            id="peer0", is_running=False, set=lambda k, v: None,
+            try_send=lambda chan, msg: sent.append(chan) or True,
+        )
+
+    def test_add_peer_is_silent_while_syncing_and_speaks_after(self):
+        from cometbft_tpu.consensus.reactor import STATE_CHANNEL
+
+        sent = []
+        r = self._reactor(wait_sync=True)
+        r.add_peer(self._peer(sent))
+        assert sent == []
+        r.switch_to_consensus(state=None)
+        r.add_peer(self._peer(sent))
+        assert sent == [STATE_CHANNEL]

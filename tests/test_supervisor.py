@@ -25,7 +25,7 @@ import pytest
 
 from cometbft_tpu.crypto import backend_health as bh
 from cometbft_tpu.crypto import ed25519_ref as ref
-from cometbft_tpu.ops import supervisor
+from cometbft_tpu.ops import dispatch_stats, supervisor
 
 
 @pytest.fixture(autouse=True)
@@ -81,6 +81,42 @@ def _mixed_batch(rng: np.random.Generator, n: int):
 
 def _oracle(pubs, msgs, sigs):
     return [ref.verify_zip215(p, m, s) for p, m, s in zip(pubs, msgs, sigs)]
+
+
+class _SlowArray:
+    """What a wedged device hands back: the copy to the host sleeps."""
+
+    def __init__(self, bits, sleep_s=0.0):
+        self.bits, self.sleep_s = bits, sleep_s
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(self.sleep_s)
+        return self.bits
+
+
+def _install_fake_chip(monkeypatch, batches, sleep_s=0.0):
+    """The ``chip`` kind without a compile: ``_launch`` (executable,
+    transfer, call) is replaced by a host stand-in that answers each lane
+    it was handed with the oracle's bit for the triple packed there, as an
+    unfetched "device array".  Pack, both watchdog calls, the spans and the
+    breaker handling above it run as on a chip.  Returns the launches."""
+    known = {}
+    for pubs, msgs, sigs in batches:
+        for p, m, g in zip(pubs, msgs, sigs):
+            if len(p) == 32 and len(g) == 64:
+                known[(p, g[:32])] = ref.verify_zip215(p, m, g)
+    launches = []
+
+    def fake_launch(backend, lanes, arrays):
+        out = np.zeros(lanes, dtype=bool)
+        for i in range(lanes):
+            key = (arrays["a_bytes"][i].tobytes(), arrays["r_bytes"][i].tobytes())
+            out[i] = known.get(key, False)
+        launches.append((backend, lanes))
+        return _SlowArray(out, sleep_s)
+
+    monkeypatch.setattr(supervisor, "_launch", fake_launch)
+    return launches
 
 
 class _FakeClock:
@@ -216,9 +252,12 @@ class TestFaultDifferential:
     equal to the pure-host oracle and no exception reaches the caller —
     the acceptance criterion of ISSUE 4."""
 
+    @pytest.mark.parametrize("entry", ["verify_batch", "dispatch_fetch"])
     @pytest.mark.parametrize("mode", ["raise", "hang", "wrong_shape", "flap"])
     @pytest.mark.parametrize("seed", [0, 1])
-    def test_verify_batch_bitwise_oracle(self, mode, seed, monkeypatch):
+    def test_verify_batch_bitwise_oracle(self, mode, seed, entry, monkeypatch):
+        """Both entries of the one launch and the one fetch: the
+        synchronous chain walk and the async primitive every cell takes."""
         from cometbft_tpu.ops import verify as ov
 
         if mode == "hang":
@@ -232,8 +271,72 @@ class TestFaultDifferential:
         )
         supervisor.set_fault_injector(shim)
         for _ in range(4):  # several batches: breaker transitions included
-            got = ov.verify_batch(pubs, msgs, sigs)
+            if entry == "verify_batch":
+                got = ov.verify_batch(pubs, msgs, sigs)
+            else:
+                got = supervisor.fetch_verify(
+                    supervisor.dispatch_verify(pubs, msgs, sigs)
+                )
             assert list(got) == _oracle(pubs, msgs, sigs)
+            assert dispatch_stats.snapshot()["inflight_depth"] == 0
+
+    @pytest.mark.parametrize("mode", ["raise", "hang", "wrong_shape", "flap"])
+    def test_chip_kind_bitwise_oracle(self, mode, monkeypatch):
+        """The served road's own kind, ``chip`` (a launch at dispatch, a
+        fetch later), under every fault mode, a wedge at launch included."""
+        if mode == "hang":
+            monkeypatch.setenv("COMETBFT_TPU_DISPATCH_TIMEOUT_MS", "60")
+        pubs, msgs, sigs = _mixed_batch(np.random.default_rng(5), 12)
+        _install_fake_chip(monkeypatch, [(pubs, msgs, sigs)])
+        supervisor.set_fault_injector(
+            supervisor.FaultyBackend(mode, hang_s=0.25, fail_n=2, pass_n=1)
+        )
+        kinds = set()
+        for _ in range(4):
+            h = supervisor.dispatch_verify(pubs, msgs, sigs)
+            kinds.add(h.kind)
+            assert list(supervisor.fetch_verify(h)) == _oracle(
+                pubs, msgs, sigs
+            )
+            assert dispatch_stats.snapshot()["inflight_depth"] == 0
+        assert kinds <= {"chip", "supervised"}
+        assert ("chip" in kinds) == (mode in ("wrong_shape", "flap"))
+
+    def test_chip_dispatch_is_one_pack_and_two_watchdog_calls(
+        self, monkeypatch
+    ):
+        """What the benchmark reads of a dispatch on the served path: one
+        ``verify.pack``, ``verify.dispatch`` > ``verify.launch`` under one
+        deadline, ``verify.fetch`` under a second, one dispatch counted,
+        ``dispatch_hist`` fed the fetch wait."""
+        from cometbft_tpu.libs import tracing
+
+        pubs, msgs, sigs = _mixed_batch(np.random.default_rng(6), 9)
+        launches = _install_fake_chip(monkeypatch, [(pubs, msgs, sigs)], 0.02)
+        deadlines = []
+        real = supervisor._WATCHDOG.call
+        monkeypatch.setattr(
+            supervisor._WATCHDOG, "call",
+            lambda fn, t: deadlines.append(t) or real(fn, t),
+        )
+        tracing.get_tracer().reset()
+        dispatch_stats.reset()
+        h = supervisor.dispatch_verify(pubs, msgs, sigs)
+        assert (h.kind, h.error, len(deadlines)) == ("chip", None, 1)
+        assert list(supervisor.fetch_verify(h)) == _oracle(pubs, msgs, sigs)
+        assert len(deadlines) == 2 and launches == [("xla", 32)]
+        spans = tracing.get_tracer().tail(20)
+        assert [sp["stage"] for sp in spans] == [
+            "verify.pack", "verify.launch", "verify.dispatch", "verify.fetch",
+        ]
+        by = {sp["stage"]: sp for sp in spans}
+        assert by["verify.launch"]["parent"] == by["verify.dispatch"]["span"]
+        assert by["verify.dispatch"]["attrs"]["pipelined"] is True
+        snap = dispatch_stats.snapshot()
+        assert (snap["dispatches"], snap["lanes_total"]) == (1, 32)
+        assert snap["lanes_used"] == 9 and snap["inflight_depth"] == 0
+        hist = snap["dispatch_hist"]["xla-32"]
+        assert hist["count"] == 1 and hist["sum"] >= 0.02  # the fetch wait
 
     def test_verify_segments_under_fault(self):
         from cometbft_tpu.ops import verify as ov
@@ -265,6 +368,51 @@ class TestFaultDifferential:
         outs = ov.verify_batches_overlapped(work)
         assert [list(o) for o in outs] == [_oracle(*w) for w in work]
         assert runner.calls == calls  # no device dispatch while open
+
+    @pytest.mark.parametrize("wedged_at", ["launch", "fetch"])
+    def test_overlapped_window_pays_one_deadline(self, wedged_at, monkeypatch):
+        """The breaker opens at its third failure; a window on a stuck
+        device stops waiting at its first."""
+        from cometbft_tpu.ops import verify as ov
+
+        monkeypatch.setenv("COMETBFT_TPU_DISPATCH_TIMEOUT_MS", "60")
+        rng = np.random.default_rng(7)
+        work = [_mixed_batch(rng, k) for k in (4, 3, 5, 2)]
+        if wedged_at == "launch":
+            supervisor.set_device_runner(_CountingRunner())
+            supervisor.set_fault_injector(
+                supervisor.FaultyBackend("hang", hang_s=0.25)
+            )
+        else:
+            launches = _install_fake_chip(monkeypatch, work, sleep_s=0.25)
+        outs = ov.verify_batches_overlapped(work)
+        assert [list(o) for o in outs] == [_oracle(*w) for w in work]
+        assert bh.snapshot()["watchdog_fires"] == 1
+        assert dispatch_stats.snapshot()["inflight_depth"] == 0
+        if wedged_at == "fetch":
+            assert len(launches) == 4  # all in flight before the first fetch
+
+    @pytest.mark.parametrize(
+        "sizes", [(), (0, 0), (7,), (3, 5, 2), (30, 30, 10)],
+        ids=["no-work", "empty", "one", "classes", "past-largest"],
+    )
+    def test_segments_sync_and_async_agree(self, sizes, monkeypatch):
+        """``verify_segments`` and ``fetch_segments(dispatch_segments)``:
+        one concatenate, one split, the oracle's bits by segment."""
+        from cometbft_tpu.ops import verify as ov
+
+        # the largest bucket cut to 64 lanes: 70 signatures overflow it
+        monkeypatch.setattr(ov, "_BUCKETS", [32, 64])
+        rng = np.random.default_rng(8)
+        work = [_mixed_batch(rng, k) for k in sizes]
+        supervisor.set_device_runner(_CountingRunner())
+        want = [_oracle(*w) for w in work]
+        sync = ov.verify_segments(work)
+        h = ov.dispatch_segments(work)
+        assert (h.sup is not None) == (0 < sum(sizes) <= 64)
+        assert [list(o) for o in sync] == want
+        assert [list(o) for o in ov.fetch_segments(h)] == want
+        assert dispatch_stats.snapshot()["inflight_depth"] == 0
 
     def test_no_invalid_signature_error_from_infra(self, monkeypatch):
         """A commit whose signatures are all VALID must verify even while

@@ -229,22 +229,17 @@ class TestSchedulerCore:
 
         try:
             pubs, msgs, sigs = _make_sigs(n, b"dead", invalid_every=5)
-            orig_inner = sched._execute_inner
             orig_disp = sched._dispatch_flush
 
             def dying(entries, reason, recorded):
                 raise SystemExit  # BaseException: kills the thread
 
-            # both flush paths (pipelined and single-flight) must feed the
-            # same host-fallback-then-die contract
-            sched._execute_inner = dying
             sched._dispatch_flush = dying
             # already-drained future still resolves (host fallback)...
             assert ask(pubs, msgs, sigs) == _oracle(pubs, msgs, sigs)
             t = sched._thread
             t.join(10)
             assert not t.is_alive()  # ...and THEN the thread died
-            sched._execute_inner = orig_inner
             sched._dispatch_flush = orig_disp
             assert ask(*_make_sigs(n, b"alive")) == [True] * n
             assert sched._thread is not t  # a fresh dispatcher took over
@@ -464,12 +459,8 @@ class TestSegmentUnit:
         assert snap["flush_items"] == 9
         assert sum(snap["flushes"].values()) == 1
 
-    @pytest.mark.parametrize("pipeline", ["0", "1"])
-    def test_flush_that_raises_resolves_on_reference(
-        self, sched_env, monkeypatch, pipeline
-    ):
-        monkeypatch.setenv("COMETBFT_TPU_SCHED_PIPELINE", pipeline)
-        pubs, msgs, sigs = _make_sigs(9, b"boom-%s" % pipeline.encode(), 4)
+    def test_flush_that_raises_resolves_on_reference(self, sched_env):
+        pubs, msgs, sigs = _make_sigs(9, b"boom", 4)
         sched = VerifyScheduler(flush_us=500)
         try:
 
@@ -1007,32 +998,20 @@ class TestInflightPipeline:
         device_health.reset()
         backend_health.reset()
 
-    def test_differential_pipelined_vs_single_flight(
-        self, sched_env, monkeypatch
-    ):
-        """K-in-flight verdicts bitwise-equal to single-flight on a
-        randomized valid/invalid mix including structural garbage — the
-        acceptance property for ``COMETBFT_TPU_SCHED_PIPELINE``."""
+    def test_differential_k_in_flight_vs_oracle(self, sched_env, monkeypatch):
+        """K-in-flight verdicts over the mesh lanes bitwise-equal to the
+        host oracle on a randomized valid/invalid mix including structural
+        garbage, and nothing left in flight."""
         pubs, msgs, sigs = _make_sigs(96, b"pipe-mix", invalid_every=3)
         pubs[7], sigs[13] = b"\x01" * 30, b"\x02" * 60
-
-        monkeypatch.setenv("COMETBFT_TPU_SCHED_PIPELINE", "0")
-        sched = VerifyScheduler(flush_us=500)
-        try:
-            single = _verdicts(_segment(sched, pubs, msgs, sigs), 60)
-        finally:
-            sched.close()
-        assert single == _oracle(pubs, msgs, sigs)
-
-        sigcache.reset_cache()  # the first run must not seed the second
-        monkeypatch.setenv("COMETBFT_TPU_SCHED_PIPELINE", "1")
         monkeypatch.setenv("COMETBFT_TPU_SCHED_INFLIGHT", "3")
         sched = VerifyScheduler(flush_us=500)
         try:
             piped = _verdicts(_segment(sched, pubs, msgs, sigs), 60)
         finally:
             sched.close()
-        assert piped == single
+        assert piped == _oracle(pubs, msgs, sigs)
+        assert dispatch_stats.snapshot()["inflight_depth"] == 0
 
     def test_dispatch_overlap_inflight_high_water(
         self, sched_env, monkeypatch
@@ -1145,22 +1124,6 @@ class TestInflightPipeline:
         assert reg.breaker("mesh_dev2").stats()["failures_total"] == 0
         snap = tracing.get_tracer().snapshot()
         assert snap["anomalies"].get("shard_watchdog_fire", 0) >= 1
-
-    def test_pipeline_kill_switch_single_flight(self, sched_env, monkeypatch):
-        """``COMETBFT_TPU_SCHED_PIPELINE=0`` restores single-flight
-        bit-for-bit: no completion pool, no in-flight accounting, same
-        verdicts."""
-        monkeypatch.setenv("COMETBFT_TPU_SCHED_PIPELINE", "0")
-        pubs, msgs, sigs = _make_sigs(12, b"pipe-off", invalid_every=4)
-        sched = VerifyScheduler(flush_us=500)
-        try:
-            got = _verdicts(_segment(sched, pubs, msgs, sigs))
-        finally:
-            sched.close()
-        assert got == _oracle(pubs, msgs, sigs)
-        assert sched._fetch_thread is None  # never instantiated
-        assert dispatch_stats.snapshot()["inflight_hwm"] == 0
-        assert sstats.snapshot()["inflight_hwm"] == 0
 
     def test_bucket_target_fallback_clamps_to_bucket(
         self, sched_env, monkeypatch

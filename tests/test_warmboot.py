@@ -146,20 +146,6 @@ class TestRun:
         ov.reset_executable_memo()
         assert not ov._AOT_BROKEN
 
-    def test_disabled_status_not_counted_warm(self, monkeypatch):
-        """COMETBFT_TPU_AOT=0 returns plain jit: nothing was precompiled,
-        so the pass must not report those shapes as warmed (and must not
-        demote anything either)."""
-
-        def fake_exec(backend, bucket, donated=None):
-            return (lambda **kw: None), {"exec_cache": "disabled"}
-
-        monkeypatch.setattr(ov, "bucket_executable", fake_exec)
-        monkeypatch.setenv("COMETBFT_TPU_WARMBOOT_BUCKETS", "32,64")
-        report = warmboot.run()
-        assert report["warmed"] == 0 and report["failures"] == 0
-        assert set(report["statuses"].values()) == {"disabled"}
-
     def test_open_breaker_skipped(self, monkeypatch):
         """Warming a dead device is probe traffic the breaker exists to
         prevent: an OPEN tier is skipped wholesale."""
