@@ -8,7 +8,10 @@ canonical vote each validator signed (reference: types/block.go:901).
 
 from __future__ import annotations
 
+from array import array
 from dataclasses import dataclass, field
+from itertools import pairwise
+from operator import attrgetter
 from typing import Optional
 
 from cometbft_tpu.crypto import tmhash
@@ -26,6 +29,11 @@ from cometbft_tpu.types.basic import (
 from cometbft_tpu.types.canonical import canonical_vote_sign_bytes
 from cometbft_tpu.types.part_set import PartSet
 from cometbft_tpu.types.vote import CommitSig
+
+_FLAG = attrgetter("block_id_flag")
+_TIMESTAMP = attrgetter("timestamp")
+_SECONDS = attrgetter("seconds")
+_NANOS = attrgetter("nanos")
 
 
 @dataclass(frozen=True)
@@ -150,7 +158,11 @@ class Commit:
         of the per-vote loop in types/vote.go:151 + canonical.go:57);
         falls back to the per-index python encoder.  Byte equality is
         differential-tested in tests/test_native.py."""
-        idxs = list(range(len(self.signatures))) if indices is None else indices
+        sigs = (
+            self.signatures
+            if indices is None
+            else list(map(self.signatures.__getitem__, indices))
+        )
         lib = None
         try:
             from cometbft_tpu import native
@@ -160,24 +172,28 @@ class Commit:
             lib = None
         if lib is not None and not hasattr(lib, "commit_sign_bytes"):
             lib = None  # prebuilt .so predating the symbol
-        if lib is None or not idxs:
-            return [self.vote_sign_bytes(chain_id, i) for i in idxs]
+        if lib is None or not sigs:
+            return self._vote_sign_bytes_each(chain_id, indices)
         import ctypes
 
-        n = len(idxs)
-        flags = bytes(self.signatures[i].block_id_flag for i in idxs)
-        ts_s = (ctypes.c_int64 * n)(
-            *(self.signatures[i].timestamp.seconds for i in idxs)
-        )
-        ts_ns = (ctypes.c_int64 * n)(
-            *(self.signatures[i].timestamp.nanos for i in idxs)
-        )
+        # the call's inputs and the cut of its output in C-level passes: no
+        # Python step a signature
+        n = len(sigs)
+        i64s = ctypes.c_int64 * n
+        try:
+            flags = bytes(map(_FLAG, sigs))
+            times = list(map(_TIMESTAMP, sigs))
+            ts_s = array("q", list(map(_SECONDS, times)))
+            ts_ns = array("q", list(map(_NANOS, times)))
+        except (TypeError, ValueError, OverflowError):
+            # a flag past a byte or a time past int64: the encoder's to judge
+            return self._vote_sign_bytes_each(chain_id, indices)
         cid = chain_id.encode()
         # per-vote ceiling: type 2 + height/round 18 + block id ~80 +
         # timestamp ~16 + chain id + delimited framing 5
         cap = n * (128 + len(cid)) + 256
         out = ctypes.create_string_buffer(cap)
-        offs = (ctypes.c_int64 * (n + 1))()
+        offs = array("q", bytes(8 * (n + 1)))
         total = lib.commit_sign_bytes(
             cid, len(cid),
             self.height, self.round_,
@@ -185,12 +201,17 @@ class Commit:
             self.block_id.part_set_header.total,
             self.block_id.part_set_header.hash,
             len(self.block_id.part_set_header.hash),
-            flags, ts_s, ts_ns, n, out, cap, offs,
+            flags, i64s.from_buffer(ts_s), i64s.from_buffer(ts_ns), n,
+            out, cap, (ctypes.c_int64 * (n + 1)).from_buffer(offs),
         )
         if total < 0:
-            return [self.vote_sign_bytes(chain_id, i) for i in idxs]
+            return self._vote_sign_bytes_each(chain_id, indices)
         raw = out.raw
-        return [raw[offs[i] : offs[i + 1]] for i in range(n)]
+        return [raw[a:b] for a, b in pairwise(offs.tolist())]
+
+    def _vote_sign_bytes_each(self, chain_id: str, indices) -> list[bytes]:
+        idxs = range(len(self.signatures)) if indices is None else indices
+        return [self.vote_sign_bytes(chain_id, i) for i in idxs]
 
     def hash(self) -> bytes:
         items = []
@@ -205,6 +226,11 @@ class Commit:
         return plane.tree_hash(items)
 
     def validate_basic(self) -> str | None:
+        return self.validate_basic_head() or self._validate_basic_signatures()
+
+    def validate_basic_head(self) -> str | None:
+        """``validate_basic`` less its scan of the signatures, for a caller
+        that has read them itself (``types/validation``'s one pass)."""
         if self.height < 0:
             return "negative height"
         if self.round_ < 0:
@@ -214,6 +240,9 @@ class Commit:
                 return "commit cannot be for nil block"
             if not self.signatures:
                 return "no signatures in commit"
+        return None
+
+    def _validate_basic_signatures(self) -> str | None:
         for cs in self.signatures:
             if cs.block_id_flag not in (
                 BLOCK_ID_FLAG_ABSENT,

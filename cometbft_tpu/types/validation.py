@@ -12,15 +12,23 @@ from __future__ import annotations
 
 import contextlib
 import time
+from bisect import bisect_right
 from dataclasses import dataclass, field
 from fractions import Fraction
+from itertools import accumulate, compress
+from operator import attrgetter, itemgetter, mul
 from typing import Optional
 
 from cometbft_tpu.crypto import batch as cbatch
 from cometbft_tpu.crypto import sigcache
 from cometbft_tpu.libs import tracing
 from cometbft_tpu.ops import dispatch_stats
-from cometbft_tpu.types.basic import BLOCK_ID_FLAG_ABSENT, BlockID
+from cometbft_tpu.types.basic import (
+    BLOCK_ID_FLAG_ABSENT,
+    BLOCK_ID_FLAG_COMMIT,
+    BLOCK_ID_FLAG_NIL,
+    BlockID,
+)
 from cometbft_tpu.types.block import Commit
 from cometbft_tpu.types.validator import ValidatorSet
 
@@ -42,10 +50,65 @@ class NotEnoughPowerError(CommitVerificationError):
         self.needed = needed
 
 
-def _verify_basic(vals: ValidatorSet, commit: Commit, height: int, block_id: BlockID):
+_FLAG = attrgetter("block_id_flag")
+_ADDRESS = attrgetter("validator_address")
+_SIGNATURE = attrgetter("signature")
+_FLAGS = bytes((BLOCK_ID_FLAG_ABSENT, BLOCK_ID_FLAG_COMMIT, BLOCK_ID_FLAG_NIL))
+# ``bytes.translate`` tables.  By flag: whether the entry is there and
+# whether it is for the block; by a signature's size: none, 1 to 96 bytes,
+# longer.
+_PRESENT = bytes(f != BLOCK_ID_FLAG_ABSENT for f in range(256))
+_FOR_BLOCK = bytes(f == BLOCK_ID_FLAG_COMMIT for f in range(256))
+_SIGNATURE_SIZE = bytes((n > 0) + (n > 96) for n in range(256))
+
+
+def _scan_regular(vals: ValidatorSet, commit: Commit) -> Optional[bytes]:
+    """One read of each signature's flag, address and signature size, in
+    passes that take no Python step a signature, against the set's
+    addresses.  It decides the REGULAR commit only: every signature passes
+    what ``Commit.validate_basic`` asks of it, the commit is as long as
+    the set, and each entry that is there names the validator at its index.
+    Such a commit gives its flags, one byte a signature.  Anything else
+    (ABSENT and NIL entries are regular) gives None, and the loops judge
+    that commit as they always have, error by error."""
+    sigs = commit.signatures
+    if not vals or not sigs or len(sigs) != len(vals):
+        return None
+    try:
+        flags = bytes(map(_FLAG, sigs))
+        signature_sizes = bytes(map(len, map(_SIGNATURE, sigs)))
+    except (TypeError, ValueError):  # a flag or a size past a byte
+        return None
+    present = flags.translate(_PRESENT)
+    if (
+        flags.translate(None, _FLAGS)
+        or signature_sizes.translate(_SIGNATURE_SIZE) != present
+    ):
+        return None
+    want = vals.facts().addresses
+    if BLOCK_ID_FLAG_ABSENT in flags:
+        # an absent entry has no address: b"" where its validator's would be
+        want = list(map(mul, want, present))
+    # one comparison reads each address once, its size with it
+    return flags if list(map(_ADDRESS, sigs)) == want else None
+
+
+def _verify_basic(
+    vals: ValidatorSet,
+    commit: Commit,
+    height: int,
+    block_id: BlockID,
+    sp=tracing._NULL_SPAN,
+) -> Optional[bytes]:
+    """The checks before any signature, in the reference's order.  Returns
+    the flags of a regular commit (``_scan_regular``), for
+    ``_collect_entries``; the signatures of such a commit have passed
+    ``validate_basic``'s loop."""
     if commit is None:
         raise CommitVerificationError("nil commit")
-    err = commit.validate_basic()
+    flags = _scan_regular(vals, commit)
+    sp.set(path="loop" if flags is None else "fast")
+    err = commit.validate_basic() if flags is None else commit.validate_basic_head()
     if err:
         raise CommitVerificationError(err)
     if vals is None or len(vals) == 0:
@@ -60,19 +123,15 @@ def _verify_basic(vals: ValidatorSet, commit: Commit, height: int, block_id: Blo
         raise CommitVerificationError(
             f"commit size {commit.size()} != validator set size {len(vals)}"
         )
+    return flags
 
 
-def _should_batch(vals: ValidatorSet, commit: Commit) -> bool:
+def _should_batch(vals: ValidatorSet, signatures: int) -> bool:
     """Reference: types/validation.go:15 shouldBatchVerify — >=2 signatures
-    and a batch-capable HOMOGENEOUS key type (a batch verifier handles one
-    key type; a mixed ed25519/bls set must fall back to per-signature)."""
-    non_absent = sum(0 if cs.absent() else 1 for cs in commit.signatures)
-    if non_absent < 2:
-        return False
-    types = {getattr(v.pub_key, "type_", None) for v in vals.validators}
-    if len(types) != 1:
-        return False
-    return all(cbatch.supports_batch_verifier(v.pub_key) for v in vals.validators)
+    (``signatures`` is the count to be verified) and a batch-capable
+    HOMOGENEOUS key type (a batch verifier handles one key type; a mixed
+    ed25519/bls set must fall back to per-signature), which the set keeps."""
+    return signatures >= 2 and vals.facts().batch_capable
 
 
 def _collect_entries(
@@ -81,13 +140,20 @@ def _collect_entries(
     voting_power_needed: int,
     count_all: bool,
     lookup_by_address: bool,
+    flags: Optional[bytes] = None,
 ):
     """The entry-selection half of ``_verify_commit``: which (idx, val, cs)
     triples get their signatures checked.  Shared with the pipelined
     consumers (blocksync prefetch, light-client chain sync) so speculative
     verification covers EXACTLY the entries the authoritative pass will
     query.  Returns (entries, tallied) — tallied is only meaningful for
-    count_all=False, where collection stops at the power threshold."""
+    count_all=False, where collection stops at the power threshold.
+    ``flags`` are ``_verify_basic``'s, of a regular commit by index: the
+    same entries then come from them and the set's running power."""
+    if flags is not None and not lookup_by_address:
+        return _entries_by_flags(
+            vals, commit, voting_power_needed, count_all, flags
+        )
     entries = []  # (commit_idx, validator, commit_sig)
     tallied = 0
     seen_addrs: set[bytes] = set()  # trusting mode: count each validator once
@@ -121,6 +187,34 @@ def _collect_entries(
     return entries, tallied
 
 
+def _entries_by_flags(
+    vals: ValidatorSet,
+    commit: Commit,
+    voting_power_needed: int,
+    count_all: bool,
+    flags: bytes,
+):
+    """``_collect_entries``' by-index loop on a regular commit: the stop is
+    found by bisection in the running power, which is the set's own unless
+    an ABSENT or NIL entry, which carries none to the block, is among the
+    signatures; the triples are zipped."""
+    stop = len(flags)  # one past the last signature read
+    tallied = 0
+    if not count_all:
+        facts = vals.facts()
+        running = facts.cum_power
+        if flags.count(BLOCK_ID_FLAG_COMMIT) != stop:
+            for_block = flags.translate(_FOR_BLOCK)
+            running = list(accumulate(map(mul, facts.powers, for_block)))
+        # the first entry that carries the tally past the threshold ends it
+        stop = min(bisect_right(running, voting_power_needed) + 1, stop)
+        tallied = running[stop - 1]
+    triples = zip(range(stop), vals.validators, commit.signatures)
+    if flags.find(BLOCK_ID_FLAG_ABSENT, 0, stop) >= 0:
+        triples = compress(triples, flags.translate(_PRESENT))
+    return list(triples), tallied
+
+
 def _judge_entries(entries, bits) -> None:
     """Turn per-entry accept bits into the verdict ``_verify_commit``
     reports: first failed entry names the culprit index."""
@@ -139,14 +233,16 @@ def _tally(entries, tallied: int, count_all: bool, voting_power_needed: int):
 
 
 @contextlib.contextmanager
-def _commit_span(commit: Commit, mode: str):
+def _commit_span(commit: Commit, mode: str, vals: ValidatorSet):
     """``verify.commit`` around a WHOLE public call, basic checks included;
     the verify-latency histogram is fed from the span's own readings (a
     call that raises records the span, with its error, and no sample).
     ``mode`` is ``full`` / ``light`` / ``trusting``; the trusting pass is a
     stage of its own, ``verify.commit.trusting``, so that the two passes a
     skipping light client makes over one commit are told apart in the
-    recorder's totals."""
+    recorder's totals.  ``set_facts`` says whether this call built the
+    set's record (``ValidatorSet.facts``) or found it kept."""
+    had = vals is not None and vals.has_facts()
     t0 = time.perf_counter()
     with tracing.span(
         "verify.commit.trusting" if mode == "trusting" else "verify.commit",
@@ -155,7 +251,11 @@ def _commit_span(commit: Commit, mode: str):
         count_all=mode == "full",
         mode=mode,
     ) as sp:
-        yield sp
+        try:
+            yield sp
+        finally:
+            if vals is not None and vals.has_facts():
+                sp.set(set_facts="kept" if had else "built")
     dispatch_stats.record_verify_latency(tracing.wall_seconds(sp, t0))
 
 
@@ -164,7 +264,7 @@ def _sign_bytes(chain_id: str, commit: Commit, entries) -> list:
     python per-index fallback inside."""
     with tracing.span("commit.sign_bytes", sigs=len(entries)):
         return commit.all_vote_sign_bytes(
-            chain_id, [idx for idx, _, _ in entries]
+            chain_id, list(map(itemgetter(0), entries))
         )
 
 
@@ -177,9 +277,10 @@ def _verify_commit(
     lookup_by_address: bool,
     backend: Optional[str] = None,
     sp=tracing._NULL_SPAN,
+    flags: Optional[bytes] = None,
 ) -> None:
     """Shared engine for all three public variants; ``sp`` is the caller's
-    ``verify.commit`` span.
+    ``verify.commit`` span, ``flags`` what ``_verify_basic`` returned.
 
     count_all=True  -> verify every non-absent signature (consensus safety).
     count_all=False -> stop as soon as tallied power exceeds the threshold
@@ -188,7 +289,7 @@ def _verify_commit(
                        validator set; match signatures by address.
     """
     entries, tallied = _collect_entries(
-        vals, commit, voting_power_needed, count_all, lookup_by_address
+        vals, commit, voting_power_needed, count_all, lookup_by_address, flags
     )
     # collection stops at the entry that carries the tally past the threshold
     stopped = not count_all and tallied > voting_power_needed
@@ -199,8 +300,8 @@ def _verify_commit(
     # pre-filter through the consensus-wide signature cache, so a commit
     # whose votes were verified at gossip time ships zero device work.
     if entries:
-        use_batch = _should_batch(vals, commit) and len(entries) >= 2
-        if use_batch:
+        # the entries are signatures of the commit that are not absent
+        if _should_batch(vals, len(entries)):
             bv = cbatch.create_batch_verifier(entries[0][1].pub_key, backend)
             sign_bytes = _sign_bytes(chain_id, commit, entries)
             with tracing.span("batch.verify", sigs=len(entries)) as bsp:
@@ -262,9 +363,11 @@ def prepare_commit_light(
     full ``verify_commit`` queries) — blocksync prefetches with this so
     BOTH the light frontier check and apply-time ``validate_block``'s full
     re-verification resolve from cache."""
-    _verify_basic(vals, commit, height, block_id)
+    flags = _verify_basic(vals, commit, height, block_id)
     needed = vals.total_voting_power() * 2 // 3
-    entries, tallied = _collect_entries(vals, commit, needed, count_all, False)
+    entries, tallied = _collect_entries(
+        vals, commit, needed, count_all, False, flags
+    )
     msgs = _sign_bytes(chain_id, commit, entries)
     return PreparedCommit(
         chain_id=chain_id,
@@ -329,10 +432,12 @@ def verify_commit(
 ) -> None:
     """Full verification: every signature checked, +2/3 power required
     (reference: types/validation.go:28)."""
-    with _commit_span(commit, "full") as sp:
-        _verify_basic(vals, commit, height, block_id)
+    with _commit_span(commit, "full", vals) as sp:
+        flags = _verify_basic(vals, commit, height, block_id, sp)
         needed = vals.total_voting_power() * 2 // 3
-        _verify_commit(chain_id, vals, commit, needed, True, False, backend, sp)
+        _verify_commit(
+            chain_id, vals, commit, needed, True, False, backend, sp, flags
+        )
 
 
 def verify_commit_light(
@@ -344,10 +449,12 @@ def verify_commit_light(
     backend: Optional[str] = None,
 ) -> None:
     """Light verification: stop at +2/3 (reference: types/validation.go:63)."""
-    with _commit_span(commit, "light") as sp:
-        _verify_basic(vals, commit, height, block_id)
+    with _commit_span(commit, "light", vals) as sp:
+        flags = _verify_basic(vals, commit, height, block_id, sp)
         needed = vals.total_voting_power() * 2 // 3
-        _verify_commit(chain_id, vals, commit, needed, False, False, backend, sp)
+        _verify_commit(
+            chain_id, vals, commit, needed, False, False, backend, sp, flags
+        )
 
 
 def verify_commit_light_trusting(
@@ -360,7 +467,8 @@ def verify_commit_light_trusting(
     """Trusting-period verification against a possibly different validator
     set; needs > trust_level of this set's power
     (reference: types/validation.go:129)."""
-    with _commit_span(commit, "trusting") as sp:
+    with _commit_span(commit, "trusting", vals) as sp:
+        sp.set(path="loop")  # by address: a look-up a signature
         if commit is None or not commit.signatures:
             raise CommitVerificationError("nil or empty commit")
         if trust_level.numerator * 3 < trust_level.denominator:  # < 1/3

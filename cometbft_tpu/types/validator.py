@@ -12,7 +12,8 @@ intermediate sums inside int64.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from typing import Iterable, Optional
+from itertools import accumulate
+from typing import Iterable, NamedTuple, Optional
 
 from cometbft_tpu.libs import protoenc as pe
 
@@ -62,9 +63,43 @@ class Validator:
         return replace(self)
 
 
+class SetFacts(NamedTuple):
+    """What is true of a validator set as long as its membership, order,
+    keys and powers stand, and what the commit entry would otherwise derive
+    again a commit, a signature or a miss.  It holds values only (no
+    ``Validator``), so a ``copy()`` may share its original's record."""
+
+    index: dict  # address -> position; its keys are in set order
+    addresses: list  # the 20-byte addresses in set order
+    batch_capable: bool  # one key type, and one a batch verifier takes
+    powers: list  # voting power in set order
+    cum_power: list  # and its running sum
+
+
+def _build_facts(validators: list) -> SetFacts:
+    # deferred: crypto.batch pulls in the verifier back ends
+    from cometbft_tpu.crypto import batch as cbatch
+
+    addrs = [v.address for v in validators]
+    types = {getattr(v.pub_key, "type_", None) for v in validators}
+    powers = [v.voting_power for v in validators]
+    return SetFacts(
+        index={a: i for i, a in enumerate(addrs)},
+        addresses=addrs,
+        batch_capable=len(types) == 1
+        and cbatch.supports_batch_verifier(validators[0].pub_key),
+        powers=powers,
+        cum_power=list(accumulate(powers)),
+    )
+
+
 class ValidatorSet:
     """Ordered validator set.  Validators are kept sorted by address;
     the proposer is tracked via proposer priorities."""
+
+    # the set's ``SetFacts``, built at first use; assigning ``validators``
+    # drops it (``__setattr__``), proposer priorities do not touch it
+    _facts: Optional[SetFacts] = None
 
     def __init__(self, validators: Iterable[Validator]):
         vals = [v.copy() for v in validators]
@@ -89,19 +124,30 @@ class ValidatorSet:
     def has_address(self, address: bytes) -> bool:
         return self.get_by_address(address) is not None
 
+    def __setattr__(self, name, value):
+        # every site that replaces the list (update_with_change_set, copy,
+        # the decoders that fill a set made with __new__) drops the record
+        # by assigning, so none has to remember to
+        if name == "validators":
+            self.__dict__["_facts"] = None
+        object.__setattr__(self, name, value)
+
+    def facts(self) -> SetFacts:
+        """The set's record, built whole at first use and stored with one
+        assignment: two threads that both find none each build an equal
+        record and either may stay, so no lock is taken."""
+        facts = self._facts
+        if facts is None:
+            facts = self._facts = _build_facts(self.validators)
+        return facts
+
+    def has_facts(self) -> bool:
+        return self._facts is not None
+
     def get_by_address(self, address: bytes) -> Optional[tuple[int, Validator]]:
-        idx_map = self.__dict__.get("_addr_index")
-        if idx_map is None or len(idx_map) != len(self.validators):
-            idx_map = {v.address: i for i, v in enumerate(self.validators)}
-            self.__dict__["_addr_index"] = idx_map
-        i = idx_map.get(address)
-        if i is None or self.validators[i].address != address:
-            # index stale (validators mutated in place): rebuild once
-            idx_map = {v.address: j for j, v in enumerate(self.validators)}
-            self.__dict__["_addr_index"] = idx_map
-            i = idx_map.get(address)
-            if i is None:
-                return None
+        i = self.facts().index.get(address)
+        if i is None:
+            return None
         return i, self.validators[i]
 
     def get_by_index(self, index: int) -> Optional[Validator]:
@@ -185,6 +231,7 @@ class ValidatorSet:
     def copy(self) -> "ValidatorSet":
         new = ValidatorSet.__new__(ValidatorSet)
         new.validators = [v.copy() for v in self.validators]
+        new._facts = self._facts  # same members, order, keys and powers
         new.proposer = None
         if self.proposer is not None:
             found = new.get_by_address(self.proposer.address)
@@ -217,6 +264,7 @@ class ValidatorSet:
                 raise ValueError("removal of non-existent validator")
 
         kept = [v for v in self.validators if v.address not in removals]
+        self._facts = None  # powers change in place from here on
         updated_addrs = set()
         for v in kept:
             c = by_addr.get(v.address)
@@ -245,8 +293,7 @@ class ValidatorSet:
                 kept.append(nv)
 
         kept.sort(key=lambda v: v.address)
-        self.validators = kept
-        self.__dict__.pop("_addr_index", None)
+        self.validators = kept  # drops the set's facts
         self._total_voting_power = None
         self.total_voting_power()  # validate cap
         self._shift_by_avg_proposer_priority()

@@ -137,7 +137,7 @@ class TestMixedKeySets:
             vote.signature = priv.sign(vote.sign_bytes(CHAIN_ID))
             assert vs.add_vote(vote)
         commit = vs.make_commit()
-        assert not tv._should_batch(vals, commit)
+        assert not tv._should_batch(vals, len(commit.signatures))
         verify_commit(CHAIN_ID, vals, bid, 3, commit)
 
     def test_cpu_backend_pins_bls_to_host(self):

@@ -206,6 +206,52 @@ class TestCommitSignBytes:
         monkeypatch.setattr(native, "lib", lambda: None)
         assert commit.all_vote_sign_bytes("csb-chain", [5, 1, 2]) == want
 
+    @pytest.mark.parametrize("with_native", (True, False))
+    @pytest.mark.parametrize(
+        "indices",
+        [
+            [0, 1, 2, 3],  # a light pass's head of the commit
+            [1, 3, 4, 6, 7, 9],  # a strict subset, as ABSENT entries leave
+            [9, 2, 8, 2, 0],  # out of order, one twice
+            [2, 5, 8],  # NIL votes only: no block id in what they signed
+            [4],  # one, and its timestamp is the zero time
+            [],
+            None,
+        ],
+        ids=["head", "subset", "unordered", "nil_only", "one", "none", "all"],
+    )
+    def test_inputs_built_by_passes_equal_the_encoder(
+        self, nlib, monkeypatch, indices, with_native
+    ):
+        """ISSUE 31: the native call's flags, seconds and nanos come from
+        C-level passes over the chosen signatures and its blob is cut by the
+        offsets read out once; byte for byte ``vote_sign_bytes`` an index."""
+        from cometbft_tpu.types.basic import Timestamp
+
+        commit = self._commit(10)
+        commit.signatures[1].timestamp = Timestamp(-5, 999_999_999)
+        commit.signatures[3].timestamp = Timestamp((1 << 62) + 7, 1)
+        if not with_native:
+            monkeypatch.setattr(native, "lib", lambda: None)
+        idxs = range(len(commit.signatures)) if indices is None else indices
+        want = [commit.vote_sign_bytes("csb-chain", i) for i in idxs]
+        got = commit.all_vote_sign_bytes("csb-chain", indices)
+        assert got == want
+        assert all(type(b) is bytes for b in got)
+
+    def test_a_time_past_int64_goes_to_the_encoder(self, nlib):
+        from cometbft_tpu.types.basic import Timestamp
+
+        commit = self._commit()
+        commit.signatures[2].timestamp = Timestamp(1 << 70, 0)
+        try:
+            want = [commit.vote_sign_bytes("csb-chain", i) for i in (1, 2)]
+        except Exception as e:  # noqa: BLE001 — then both refuse alike
+            with pytest.raises(type(e)):
+                commit.all_vote_sign_bytes("csb-chain", [1, 2])
+        else:
+            assert commit.all_vote_sign_bytes("csb-chain", [1, 2]) == want
+
 
 class TestBuildRace:
     """ROADMAP D12: six xdist workers on a fresh checkout all build the

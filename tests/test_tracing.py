@@ -538,6 +538,45 @@ class TestRequestTree:
         # the entry's basic checks are inside the request's span now
         assert one["verify.commit"]["attrs"]["entries"] == 4
 
+    def test_the_entry_says_its_path_and_whether_it_built_the_sets_record(
+        self, sched_env
+    ):
+        """ISSUE 31: ``path`` (``fast``: the one pass over a regular commit;
+        ``loop``: anything else, and the look-up by address) and
+        ``set_facts`` (``built`` by this call / ``kept`` from an earlier
+        one) on both of the entry's spans."""
+        from cometbft_tpu.types import validation as tv
+
+        chain_id, vals, bid, height, commit = _commit()
+        vals.validators = list(vals.validators)  # an assignment: no record
+        said = []
+        for call in (
+            lambda: tv.verify_commit_light(chain_id, vals, bid, height, commit),
+            lambda: tv.verify_commit(chain_id, vals, bid, height, commit),
+            lambda: tv.verify_commit_light_trusting(chain_id, vals, commit),
+        ):
+            tracing.get_tracer().reset()
+            call()
+            (root,) = [
+                s for s in tracing.get_tracer().tail(200)
+                if s["stage"].startswith("verify.commit")
+            ]
+            a = root["attrs"]
+            said.append((root["stage"], a["mode"], a["path"], a["set_facts"]))
+        assert said == [
+            ("verify.commit", "light", "fast", "built"),
+            ("verify.commit", "full", "fast", "kept"),
+            ("verify.commit.trusting", "trusting", "loop", "kept"),
+        ]
+        # an irregular commit is the loops' to judge, and says so
+        commit.signatures[0].validator_address = bytes(20)
+        tracing.get_tracer().reset()
+        with pytest.raises(tv.CommitVerificationError, match="address mismatch"):
+            tv.verify_commit_light(chain_id, vals, bid, height, commit)
+        (root,) = tracing.get_tracer().tail(200)
+        assert root["attrs"]["path"] == "loop"
+        assert root["attrs"]["error"] == "CommitVerificationError"
+
     def test_flush_serving_two_callers_lists_both_traces(self, sched_env):
         tracing.get_tracer().reset()
         sched = verifysched.get_scheduler()
