@@ -290,6 +290,12 @@ class ConsensusReactor(Reactor):
                 return
             if isinstance(msg, VoteMessage):
                 v = msg.vote
+                # reactor.go Receive: VoteMessage.ValidateBasic at the wire,
+                # before the vote reaches the receive routine and its set
+                err = v.validate_basic()
+                if err:
+                    self.logger.debug("bad vote message", err=err, peer=peer.id)
+                    return
                 ps.set_has_vote(v.height, v.round_, v.type_, v.validator_index)
                 self.cs.add_peer_message(msg, peer.id)
         elif chan_id == VOTE_SET_BITS_CHANNEL:

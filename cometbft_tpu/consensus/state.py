@@ -1252,7 +1252,16 @@ class ConsensusState(BaseService):
         if not self._check_vote_extension(vote, rs.validators):
             return
 
-        added = rs.votes.add_vote(vote, peer_id)
+        conflict = None
+        try:
+            added = rs.votes.add_vote(vote, peer_id)
+        except ConflictingVoteError as e:
+            if not e.added:
+                raise
+            # taken under a peer's maj23 claim: the node goes on with the
+            # vote and reports the equivocation all the same (state.go:2296
+            # returns ``added, err``)
+            added, conflict = True, e
         if not added:
             return
         if self.event_bus:
@@ -1267,6 +1276,8 @@ class ConsensusState(BaseService):
             self._check_prevotes(vote)
         else:
             self._check_precommits(vote)
+        if conflict is not None:
+            raise conflict
 
     def _check_vote_extension(self, vote: Vote, vals) -> bool:
         """Gate a received vote on the extension rules (reference:

@@ -84,7 +84,10 @@ class HeightVoteSet:
 
     def add_vote(self, vote: Vote, peer_id: str = "") -> bool:
         """Reference: height_vote_set.go AddVote — peers may push us at most
-        2 catchup rounds beyond our current one."""
+        2 catchup rounds beyond our current one; a vote of no valid type
+        is no vote of this height's."""
+        if vote.type_ not in (PREVOTE_TYPE, PRECOMMIT_TYPE):
+            return False
         vs = self.votes(vote.round_, vote.type_)
         if vs is None:
             rounds = self._peer_catchup_rounds.setdefault(peer_id, [])

@@ -77,17 +77,22 @@ class Vote:
 
         Routed through the consensus-wide signature cache AND the
         continuous-batching scheduler (consensus priority class): on an
-        accelerator-backed node, concurrent gossip-time verifications from
-        many peers coalesce into one fused device dispatch instead of each
-        paying a one-signature dispatch or host verify
-        (docs/verify-scheduler.md); elsewhere this is exactly the cached
-        host path.  Either way a precommit verified here at gossip time
-        makes the commit built from it near-free to re-verify at
-        apply/blocksync time (the CommitSig reconstructs byte-identical
-        sign bytes from the same timestamp)."""
+        accelerator-backed node, verifications submitted CONCURRENTLY
+        coalesce into one fused device dispatch; the receive routine is one
+        thread and waits for each vote, so there a vote is a flush of its
+        own (docs/verify-scheduler.md, ``val175-receive-routine``);
+        elsewhere this is exactly the cached host path.  Either way the
+        verdict is in the cache before this returns, so a precommit
+        verified here at gossip time makes the commit built from it
+        near-free to re-verify at apply/blocksync time (the CommitSig
+        reconstructs byte-identical sign bytes from the same timestamp)."""
         from cometbft_tpu import verifysched
         from cometbft_tpu.libs import tracing
 
+        # vote.go:228: the key has to be the one the vote names
+        # (ErrVoteInvalidValidatorAddress; the address is kept with the key)
+        if pub_key.address() != self.validator_address:
+            return False
         # ``hit``: the verdict came from the signature cache (set where the
         # lookup is made, ``tracing.mark``)
         with tracing.span(

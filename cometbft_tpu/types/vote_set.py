@@ -11,22 +11,64 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Optional
 
-from cometbft_tpu.types.basic import BlockID, ZERO_BLOCK_ID
+from cometbft_tpu.libs import tracing
+from cometbft_tpu.types.basic import BlockID
 from cometbft_tpu.types.vote import CommitSig, Vote
 from cometbft_tpu.types.validator import ValidatorSet
 
 
 class VoteError(Exception):
-    pass
+    """A vote the set refuses.  Each of ``VoteSet.AddVote``'s errors
+    (reference: types/vote_set.go:169, types/errors.go) is a class of its
+    own; ``outcome`` names it as ``types/voteset_reference.py`` does, for the
+    ``voteset.add`` span and for a caller that has to tell them apart."""
+
+    outcome = "error"
+
+
+class NilVoteError(VoteError):
+    outcome = "nil_vote"
+
+
+class InvalidValidatorIndexError(VoteError):
+    outcome = "invalid_validator_index"
+
+
+class InvalidValidatorAddressError(VoteError):
+    outcome = "invalid_validator_address"
+
+
+class UnexpectedStepError(VoteError):
+    outcome = "unexpected_step"
+
+
+class NonDeterministicSignatureError(VoteError):
+    """A copy of a held vote (same validator, same block id) under ANOTHER
+    signature (ErrVoteNonDeterministicSignature).  Never verified, never
+    added."""
+
+    outcome = "nondeterministic_signature"
+
+
+class VoteSignatureError(VoteError):
+    """``Vote.verify`` failed (ErrVoteInvalidSignature); never added."""
+
+    outcome = "invalid_signature"
 
 
 class ConflictingVoteError(VoteError):
-    """Equivocation: same validator, same (H,R,type), different block."""
+    """Equivocation: same validator, same (H,R,type), different block; the
+    new vote's signature has been verified.  ``added`` says whether the set
+    took the vote all the same (a peer claims +2/3 for its block): Go
+    returns ``true`` AND the error there."""
 
-    def __init__(self, existing: Vote, conflicting: Vote):
+    outcome = "conflicting"
+
+    def __init__(self, existing: Vote, conflicting: Vote, added: bool = False):
         super().__init__("conflicting votes from validator")
         self.existing = existing
         self.conflicting = conflicting
+        self.added = added
 
 
 @dataclass
@@ -62,74 +104,111 @@ class VoteSet:
     # -- adding votes -----------------------------------------------------
 
     def add_vote(self, vote: Vote, verify: bool = True) -> bool:
-        """Returns True if the vote was added.  Raises VoteError on invalid
-        votes, ConflictingVoteError on equivocation (the vote for the maj23
-        block is still admitted, mirroring the reference)."""
+        """Returns True if the vote was added, False for a byte-identical
+        copy of a held vote.  Raises a ``VoteError`` of the check's own
+        class for a vote the set refuses, ``ConflictingVoteError`` on
+        equivocation (``.added`` where the vote went in under a peer's
+        maj23 claim).  ``Vote.validate_basic`` is the wire's to run
+        (``consensus/reactor``), as in the reference.  Span ``voteset.add``
+        (``t``, ``outcome``) is the set's own work around ``Vote.verify``'s
+        ``consensus.vote``."""
+        with tracing.span("voteset.add", t=self.type_) as sp:
+            try:
+                added = self._add_vote(vote, verify)
+            except VoteError as e:
+                sp.set(outcome=e.outcome)
+                raise
+            sp.set(outcome="added" if added else "duplicate")
+            return added
+
+    def _add_vote(self, vote: Vote, verify: bool) -> bool:
+        """vote_set.go:169 addVote, check by check in its order."""
         if vote is None:
-            raise VoteError("nil vote")
-        err = vote.validate_basic()
-        if err:
-            raise VoteError(err)
+            raise NilVoteError("nil vote")
+        idx = vote.validator_index
+        if idx < 0:
+            raise InvalidValidatorIndexError("index < 0")
+        if not vote.validator_address:
+            raise InvalidValidatorAddressError("empty address")
         if (
             vote.height != self.height
             or vote.round_ != self.round_
             or vote.type_ != self.type_
         ):
-            raise VoteError(
+            raise UnexpectedStepError(
                 f"vote (H,R,T)=({vote.height},{vote.round_},{vote.type_}) "
                 f"does not match set ({self.height},{self.round_},{self.type_})"
             )
-        idx = vote.validator_index
         val = self.val_set.get_by_index(idx)
         if val is None:
-            raise VoteError(f"validator index {idx} out of range")
+            raise InvalidValidatorIndexError(
+                f"cannot find validator {idx} in a set of {len(self.val_set)}"
+            )
         if val.address != vote.validator_address:
-            raise VoteError("validator address does not match index")
+            raise InvalidValidatorAddressError(
+                "validator address does not match index"
+            )
 
-        existing = self.votes[idx]
-        if existing is not None and existing.block_id == vote.block_id:
-            return False  # duplicate
+        key = vote.block_id.key()
+        held = self._get_vote(idx, key)
+        if held is not None:
+            if held.signature == vote.signature:
+                return False  # duplicate
+            raise NonDeterministicSignatureError(
+                f"existing vote: {held}; new vote: {vote}"
+            )
 
         # Verify the signature BEFORE any conflict handling, so a forged vote
         # cannot frame an honest validator for equivocation (reference:
         # vote_set.go verifies in addVote before addVerifiedVote).
         if verify and not vote.verify(self.chain_id, val.pub_key):
-            raise VoteError("invalid signature")
+            raise VoteSignatureError("invalid signature")
 
-        if existing is not None:
-            # conflicting vote: only admit if it's for a block with peer-claimed
-            # 2/3 majority (reference: vote_set.go addVerifiedVote conflict path)
-            bv = self.votes_by_block.get(vote.block_id.key())
-            if bv is None or not bv.peer_maj23:
-                raise ConflictingVoteError(existing, vote)
-
-        self._add_verified(vote, val.voting_power)
+        added, conflicting = self._add_verified(vote, key, val.voting_power)
+        if conflicting is not None:
+            raise ConflictingVoteError(conflicting, vote, added)
         return True
 
-    def _add_verified(self, vote: Vote, power: int) -> None:
-        idx = vote.validator_index
-        key = vote.block_id.key()
+    def _get_vote(self, idx: int, key: bytes) -> Optional[Vote]:
+        """The held vote of validator ``idx`` for the block ``key``: the
+        set's own, or one kept with its block only (a conflicting vote
+        admitted under a peer's claim)."""
+        held = self.votes[idx]
+        if held is not None and held.block_id.key() == key:
+            return held
         bv = self.votes_by_block.get(key)
-        if bv is None:
-            bv = _BlockVotes()
-            self.votes_by_block[key] = bv
-        conflicting = self.votes[idx] is not None
-        if not conflicting:
+        return bv.votes.get(idx) if bv is not None else None
+
+    def _add_verified(self, vote: Vote, key: bytes, power: int):
+        """vote_set.go:243 addVerifiedVote: (added, the conflicting vote).
+        The signature is valid; the vote is no copy of a held one."""
+        idx = vote.validator_index
+        conflicting = self.votes[idx]
+        if conflicting is None:
             self.votes[idx] = vote
             self.sum += power
-        elif self.votes[idx].block_id != vote.block_id:
-            # vote switches to the peer-claimed maj23 block
-            old_key = self.votes[idx].block_id.key()
-            old_bv = self.votes_by_block.get(old_key)
-            if old_bv and idx in old_bv.votes:
-                pass  # keep historical record in old block bucket
-            self.votes[idx] = vote
+        elif self.maj23 is not None and self.maj23.key() == key:
+            self.votes[idx] = vote  # the majority's block replaces the other
+
+        bv = self.votes_by_block.get(key)
+        if bv is None:
+            if conflicting is not None:
+                return False, conflicting  # a block nobody tracks: forget it
+            bv = self.votes_by_block[key] = _BlockVotes()
+        elif conflicting is not None and not bv.peer_maj23:
+            return False, conflicting  # no peer says this block is special
+
+        before = bv.sum
+        quorum = self.val_set.total_voting_power() * 2 // 3 + 1
         if idx not in bv.votes:
             bv.votes[idx] = vote
             bv.sum += power
-            quorum = self.val_set.total_voting_power() * 2 // 3 + 1
-            if bv.sum >= quorum and self.maj23 is None:
-                self.maj23 = vote.block_id
+        if before < quorum <= bv.sum and self.maj23 is None:
+            # only the first quorum reached counts; its votes are THE votes
+            self.maj23 = vote.block_id
+            for i, v in bv.votes.items():
+                self.votes[i] = v
+        return True, conflicting
 
     # -- queries ----------------------------------------------------------
 

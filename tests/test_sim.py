@@ -693,6 +693,12 @@ class TestFleetScale:
         timeline must link every commit's verify spans back to the
         originating proposal's trace id, with per-step p50/p99 rendered."""
         monkeypatch.setenv("COMETBFT_TPU_TRACE", "1")  # timeline asserts
+        # a vote is two spans since PR 32 (``voteset.add`` around
+        # ``consensus.vote``): 5,149 spans where 4,096 held the run
+        monkeypatch.setenv("COMETBFT_TPU_TRACE_RING", "8192")
+        from cometbft_tpu.libs import tracing
+
+        tracing.reset_tracer()
         res = run_scenario(
             "fleet-churn", 3, root=tmp_path, n_vals=8,
             raise_on_violation=True,

@@ -842,6 +842,35 @@ class TestKillSwitchAndCallSites:
         assert got_off == got_on
         assert sstats.snapshot()["verdicts_total"] == 0  # pure sync path
 
+    @pytest.mark.parametrize("tampered", (False, True))
+    def test_votes_verdict_is_cached_before_vote_verify_returns(
+        self, sched_env, monkeypatch, tampered
+    ):
+        """The LastCommit built from gossiped votes is all cache hits only
+        if each vote's verdict, sound or not, is in ``crypto/sigcache``
+        BEFORE the vote's future resolves: the put is the scheduler's
+        (``_settle``), made on the fetch thread ahead of ``set_result``."""
+        chain_id = "sched-vote-cache"
+        _, vals, (vote,) = _signed_votes(1, chain_id, tamper=(0,) if tampered else ())
+        pub = vals.validators[0].pub_key
+        triple = ([pub.bytes()], [vote.sign_bytes(chain_id)], [vote.signature])
+        at_resolve = []
+        real = VerifyScheduler._finish
+
+        def finish(en, bits, now):
+            at_resolve.append(_cached(*triple))
+            return real(en, bits, now)
+
+        monkeypatch.setattr(VerifyScheduler, "_finish", staticmethod(finish))
+        assert _cached(*triple) == [None]
+        assert vote.verify(chain_id, pub) is (not tampered)
+        assert at_resolve == [[not tampered]]  # stored when the future resolved
+        assert _cached(*triple) == [not tampered]
+        st = sigcache.get_cache().stats()
+        assert (st["keys"], st["puts"]) == (1, 1)
+        assert vote.verify(chain_id, pub) is (not tampered)  # the cache answers
+        assert sstats.snapshot()["submitted"]["consensus"] == 1
+
     def test_evidence_duplicate_vote_seam_and_cache(self, sched_env):
         """evidence satellite: duplicate-vote checks go through the seam at
         evidence priority AND populate the sigcache (they were bare host
