@@ -758,7 +758,7 @@ class NodeMetrics:
         )
         self.sched_flushes = r.labeled_callback_gauge(
             "sched", "flushes", "reason", _sched("flushes"),
-            "Dispatcher flushes by trigger (deadline/full/shutdown)",
+            "Dispatcher flushes by trigger (full/idle/deadline/shutdown)",
         )
         self.sched_flush_occupancy = r.callback_gauge(
             "sched", "flush_occupancy", _sched("flush_occupancy"),

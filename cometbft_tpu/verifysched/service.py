@@ -15,9 +15,13 @@ shape from inference serving applied to signature verification
     batch verifier's whole segment that way; ``submit(pub, msg, sig,
     priority)`` is the n = 1 case of the same entry;
   * one dispatcher thread coalesces pending entries ACROSS all submitters
-    into a single ``ops/verify.dispatch_segments`` dispatch, flushing when
-    the oldest entry has waited ``COMETBFT_TPU_SCHED_FLUSH_US`` (~2000) or
-    when the queued signatures fill a padding bucket; it never waits for
+    into a single ``ops/verify.dispatch_segments`` dispatch.  The flush
+    rule is work-conserving: flush when the queued signatures fill a
+    padding bucket (``full``); else when no flush of this scheduler is in
+    flight (``idle``: there is nothing to wait behind, a lone caller never
+    sleeps); else, behind a flush in flight, when the oldest entry has
+    waited ``COMETBFT_TPU_SCHED_FLUSH_US`` (~2000, ``deadline``) or the
+    last flush out lands, whichever is first.  It never waits for
     verdicts: one completion thread fetches them (``fetch_segments``) in
     drain order, so flush i+1 is packed while flush i is on the device;
   * the sigcache is consulted before any queue slot or device lane is
@@ -305,7 +309,10 @@ class VerifyScheduler:
         notify; returns ``(futures, admitted)``: the futures of the entries
         that hold ``pubs[:admitted]``, in order (one, unless the segment is
         longer than ``MAX_DRAIN`` and was cut; ``keys`` are cut with the
-        lists).  Admission is decided once:
+        lists).  ``scalar``: the lists are independent checks, not a
+        segment, and each becomes an n = 1 entry whose future resolves to
+        its bit (``submit``, ``submit_many``): queued together, they leave
+        together.  Admission is decided once:
         consensus is always admitted whole; any other class up to
         ``queue_cap`` signatures queued, and the rest is shed to the
         caller.  Raises ``RuntimeError`` once the scheduler is stopped."""
@@ -319,8 +326,9 @@ class VerifyScheduler:
             if prio != PRIO_CONSENSUS:
                 admitted = min(n, max(self.queue_cap - self._count, 0))
             t0 = time.perf_counter()
-            for lo in range(0, admitted, MAX_DRAIN):
-                hi = min(lo + MAX_DRAIN, admitted)
+            step = 1 if scalar else MAX_DRAIN
+            for lo in range(0, admitted, step):
+                hi = min(lo + step, admitted)
                 entry = _Entry(
                     pubs[lo:hi], msgs[lo:hi], sigs[lo:hi],
                     None if keys is None else keys[lo:hi], puts,
@@ -399,6 +407,50 @@ class VerifyScheduler:
                 f"shedding class {stats.CLASS_NAMES[prio]}"
             )
         return futs[0]
+
+    def submit_many(
+        self,
+        pubs: Sequence[bytes],
+        msgs: Sequence[bytes],
+        sigs: Sequence[bytes],
+        priority: int = PRIO_CONSENSUS,
+    ) -> "list[Optional[Future[bool]]]":
+        """``submit`` for the independent checks ONE caller makes before it
+        waits on any (both signatures of a duplicate-vote evidence, an
+        envelope batch): each triple is the entry ``submit`` would queue —
+        its own key, put and scalar future — but all of them are queued
+        under one acquisition of the lock, so they leave in one flush by
+        construction: an idle dispatcher flushes what it finds the moment
+        it is woken, and must find all of them.  One future a triple, in
+        order, a cache hit's already resolved; ``None`` where admission
+        control shed the triple (the tail, for a sheddable class at the
+        cap).  Raises ``RuntimeError`` once the scheduler is stopped."""
+        prio = _clamp_prio(priority)
+        out: "list[Optional[Future[bool]]]" = [None] * len(pubs)
+        cache = sigcache.get_cache()
+        keys = hits = None
+        if cache.enabled():
+            keys = cache.hash_keys(pubs, msgs, sigs)
+            hits = cache._get_many(keys)
+        queued = []
+        for i in range(len(pubs)):
+            if hits is None or hits[i] is None:
+                queued.append(i)
+                continue
+            stats.record_submit_hit(prio)
+            out[i] = Future()
+            out[i].set_result(bool(hits[i]))
+        if queued:
+            futs, _ = self._enqueue(
+                [pubs[i] for i in queued],
+                [msgs[i] for i in queued],
+                [sigs[i] for i in queued],
+                prio, scalar=True,
+                keys=None if keys is None else [keys[i] for i in queued],
+            )
+            for i, fut in zip(queued, futs):
+                out[i] = fut
+        return out
 
     def submit_segment(
         self,
@@ -588,6 +640,15 @@ class VerifyScheduler:
                             break
                         if self._count >= full:
                             reason = "full"
+                            break
+                        if self._inflight == 0:
+                            # nothing of ours is on the device, so nothing
+                            # can land that more company should be awaited
+                            # behind: holding the queue would only idle the
+                            # chip.  Read without ``_flock``: a stale 1
+                            # costs nothing, ``_landed`` notifies this cond
+                            # after it has written the 0
+                            reason = "idle"
                             break
                         oldest = self._oldest_t0()
                         if oldest is None:
@@ -911,10 +972,7 @@ class VerifyScheduler:
                     ):
                         handle = ov.dispatch_segments(work, lane=lane)
                 except BaseException:
-                    with self._fcond:
-                        self._inflight -= 1
-                        stats.record_inflight(self._inflight)
-                        self._fcond.notify_all()
+                    self._landed()
                     raise
             fsp.set(misses=len(ordered), lanes=lanes)
 
@@ -967,20 +1025,32 @@ class VerifyScheduler:
                 if not self._fetch_queue:
                     return  # stop requested and FIFO drained
                 pf = self._fetch_queue.popleft()
-            try:
-                self._resolve_flush(pf)
-            finally:
-                with self._fcond:
-                    self._inflight = max(0, self._inflight - 1)
-                    stats.record_inflight(self._inflight)
-                    self._fcond.notify_all()
+            self._resolve_flush(pf)
+
+    def _landed(self) -> None:
+        """One flush is off the device: free its slot and, when it was the
+        last one out, wake the dispatcher for what queued behind it (reason
+        ``idle``).  Called BEFORE the flush's futures resolve, so a caller
+        that is answered and submits again finds the count already down.
+        The two locks are taken one after the other, never nested, and
+        ``_lock`` only for the notify: no submitter waits behind
+        ``_fcond``."""
+        with self._fcond:
+            self._inflight = left = max(0, self._inflight - 1)
+            stats.record_inflight(left)
+            self._fcond.notify_all()
+        if left == 0:
+            with self._cond:
+                if self._count:
+                    self._cond.notify_all()
 
     def _resolve_flush(self, pf: tuple) -> None:
-        """The completion half of one flush: fetch verdicts,
-        settle them (``_settle``), resolve every future.  Runs on the
-        completion thread in drain order; cannot leave a future
-        unresolved — a fetch that somehow escapes the supervisor's
-        degradation chain resolves the flush on the host reference."""
+        """The completion half of one flush: fetch verdicts, give the slot
+        back (``_landed``), settle them (``_settle``), resolve every
+        future.  Runs on the completion thread in drain order; cannot
+        leave a future unresolved — a fetch that somehow escapes the
+        supervisor's degradation chain resolves the flush on the host
+        reference."""
         handle, entries, bits, dups, ordered, fsp = pf
         results = None
         try:
@@ -992,6 +1062,8 @@ class VerifyScheduler:
             # the completion thread must outlive one bad flush or every
             # queued flush behind it strands its futures
             logger.exception("pipelined flush fetch failed unexpectedly")
+        finally:
+            self._landed()
 
         def settle() -> None:
             try:
@@ -1102,23 +1174,29 @@ def verify_cached(pub_key, msg: bytes, sig: bytes, priority=None) -> bool:
 def verify_many_cached(
     pub_keys, msgs: Sequence[bytes], sigs: Sequence[bytes], priority=None
 ) -> "list[bool]":
-    """Several independent checks submitted before waiting on any, so they
-    ride one flush (evidence checks both duplicate-vote signatures this
-    way).  Falls back per item on shed / inactive / non-ed25519."""
+    """Several independent checks queued in ONE hand-off (``submit_many``)
+    before waiting on any, so they ride one flush (evidence checks both
+    duplicate-vote signatures this way).  Falls back per item on shed /
+    inactive / non-ed25519."""
     prio = current_priority() if priority is None else priority
     out: "list[Optional[bool]]" = [None] * len(msgs)
     futs: "list[Optional[Future]]" = [None] * len(msgs)
     shed_ix: set = set()
     if scheduler_active():
-        sched = get_scheduler()
-        for i, (pk, m, s) in enumerate(zip(pub_keys, msgs, sigs)):
-            pub = _ed25519_pub(pk)
-            if pub is None:
-                continue
-            try:
-                futs[i] = sched.submit(pub, m, s, prio)
-            except (QueueFullError, RuntimeError):
-                futs[i] = None  # shed or torn down: sync fallback below
+        pubs = [_ed25519_pub(pk) for pk in pub_keys]
+        ed = [i for i, pub in enumerate(pubs) if pub is not None]
+        try:
+            got = get_scheduler().submit_many(
+                [pubs[i] for i in ed],
+                [msgs[i] for i in ed],
+                [sigs[i] for i in ed],
+                prio,
+            )
+        except RuntimeError:  # torn down under us: sync fallback below
+            got = [None] * len(ed)
+        for i, fut in zip(ed, got):
+            futs[i] = fut
+            if fut is None:
                 shed_ix.add(i)
     for i, (pk, m, s) in enumerate(zip(pub_keys, msgs, sigs)):
         if futs[i] is not None:

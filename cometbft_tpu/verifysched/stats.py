@@ -18,8 +18,10 @@ Counters (all guarded by one lock):
   * ``shed[class]``          — signatures rejected by admission control
     (never ``consensus``: that class is exempt from shedding by design)
   * ``queue_depth``          — items currently pending (gauge-style)
-  * ``flushes[reason]``      — dispatcher flushes by trigger:
-    ``deadline`` / ``full`` / ``shutdown``
+  * ``flushes[reason]``      — dispatcher flushes by trigger: ``full`` (a
+    padding bucket's worth queued) / ``idle`` (nothing of the scheduler's
+    in flight: no company to wait for) / ``deadline`` (held the longest
+    the scheduler holds an entry, behind a flush in flight) / ``shutdown``
   * ``flush_items``          — items drained across all flushes
   * ``flush_misses``         — unique cache-missing items shipped to the
     verify seam (<= flush_items: duplicates and fresh cache hits resolve
@@ -55,7 +57,7 @@ import threading
 from cometbft_tpu.libs.histo import Histo
 
 CLASS_NAMES = ("consensus", "evidence_light", "bulk")
-FLUSH_REASONS = ("deadline", "full", "shutdown")
+FLUSH_REASONS = ("deadline", "full", "idle", "shutdown")
 
 _LOCK = threading.Lock()
 

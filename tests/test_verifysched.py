@@ -10,7 +10,9 @@ One smoke test exercises a single real dispatch through the full stack.
 """
 
 import functools
+import contextlib
 import hashlib
+import sys
 import threading
 import time
 
@@ -92,6 +94,50 @@ def _oracle(pubs, msgs, sigs):
         and bool(ref.verify_zip215(p, m, s))
         for p, m, s in zip(pubs, msgs, sigs)
     ]
+
+
+class _HeldDevice:
+    """The device stand-in held on an ``Event``: what is dispatched stays
+    in flight until ``release()``.  ``hold(sched)`` puts one flush (a lone
+    vote, reason ``idle``) out and returns once it is in flight."""
+
+    def __init__(self, runner=_oracle_runner):
+        self.runner = runner
+        self.gate = threading.Event()
+        self.entered = threading.Event()
+        supervisor.set_device_runner(self)
+
+    def __call__(self, backend, pubs, msgs, sigs, lanes):
+        self.entered.set()
+        self.gate.wait(30)
+        return self.runner(backend, pubs, msgs, sigs, lanes)
+
+    def hold(self, sched):
+        (pub,), (msg,), (sig,) = _make_sigs(1, b"held")
+        fut = sched.submit(pub, msg, sig)
+        assert self.entered.wait(30)
+        assert sstats.snapshot()["inflight_depth"] == 1
+        return fut
+
+    def release(self):
+        self.gate.set()
+
+
+@contextlib.contextmanager
+def _switching_every(seconds):
+    """The interpreter hands the GIL on every ``seconds``: a thread that
+    is woken runs at once, so a race a test is about is really run."""
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(seconds)
+    try:
+        yield
+    finally:
+        sys.setswitchinterval(interval)
+
+
+def _flushes(**counts):
+    """``stats.snapshot()["flushes"]`` with every reason not named at 0."""
+    return {r: counts.get(r, 0) for r in sstats.FLUSH_REASONS}
 
 
 # ----------------------------------------------------------------------
@@ -201,16 +247,25 @@ class TestSchedulerCore:
             assert sstats.snapshot()["flushes"]["full"] >= 1
         finally:
             sched.close()
-        # deadline: a single item can only flush on the deadline
+        # deadline: what decides behind a flush in flight (an idle
+        # scheduler holds nothing): the held vote left ``idle``, the one
+        # queued behind it leaves after its millisecond, into the free slot
         sstats.reset()
+        dev = _HeldDevice()
         sched = VerifyScheduler(flush_us=1000)
         try:
+            held = dev.hold(sched)
             pubs, msgs, sigs = _make_sigs(1, b"dl")
-            assert sched.submit(pubs[0], msgs[0], sigs[0]).result(30) is True
-            snap = sstats.snapshot()
-            assert snap["flushes"]["deadline"] == 1
-            assert snap["flushes"]["full"] == 0
+            fut = sched.submit(pubs[0], msgs[0], sigs[0])
+            deadline = time.perf_counter() + 30
+            while sstats.snapshot()["inflight_depth"] < 2:
+                assert time.perf_counter() < deadline
+                threading.Event().wait(0.002)
+            assert sstats.snapshot()["flushes"] == _flushes(idle=1, deadline=1)
+            dev.release()
+            assert held.result(30) is True and fut.result(30) is True
         finally:
+            dev.release()
             sched.close()
 
     @pytest.mark.parametrize("n", [1, 6], ids=["vote", "segment"])
@@ -257,6 +312,215 @@ class TestSchedulerCore:
         assert sstats.snapshot()["flushes"]["shutdown"] >= 1
         with pytest.raises(RuntimeError):
             sched.submit(pubs[0], msgs[0], b"\x00" * 64)
+
+
+# ----------------------------------------------------------------------
+# the flush rule is work-conserving: hold the queue only behind a flush in
+# flight (docs/verify-scheduler.md "The flush rule")
+# ----------------------------------------------------------------------
+
+LONG_US = 5_000_000  # a deadline no test waits out: its waits give up at 4 s
+
+
+class TestIdleFlush:
+    @pytest.mark.parametrize("n", [1, 117], ids=["vote", "commit-117"])
+    def test_lone_caller_never_waits_out_the_deadline(self, sched_env, n):
+        """Nothing in flight, one caller blocked on its own entry: nobody
+        can join, so the entry leaves at once, reason ``idle``."""
+        supervisor.set_device_runner(_lib_runner)
+        pubs, msgs, sigs = _lib_sigs(n, b"lone")
+        sched = VerifyScheduler(flush_us=LONG_US)
+        sched._full_target = 128  # the chip's smallest bucket (32 on the CPU)
+        try:
+            if n == 1:
+                got = [sched.submit(pubs[0], msgs[0], sigs[0]).result(4)]
+            else:
+                got = _verdicts(_segment(sched, pubs, msgs, sigs), 4)
+            assert got == [True] * n
+            snap = sstats.snapshot()
+            assert snap["flushes"] == _flushes(idle=1)
+            assert snap["flush_items"] == n
+        finally:
+            sched.close()
+
+    def test_closed_loop_caller_finds_nothing_in_flight(
+        self, sched_env, monkeypatch
+    ):
+        """The count of flushes in flight goes down BEFORE the futures
+        resolve: a caller that is answered and submits its next vote at
+        once never finds its own last flush still counted (it would wait
+        behind nothing)."""
+        supervisor.set_device_runner(_lib_runner)
+        pubs, msgs, sigs = _lib_sigs(60, b"loop")
+        sched = VerifyScheduler(flush_us=LONG_US)
+        at_resolve = []
+        real = VerifyScheduler._finish
+
+        def finish(en, bits, now):
+            at_resolve.append(sched._inflight)
+            return real(en, bits, now)
+
+        monkeypatch.setattr(VerifyScheduler, "_finish", staticmethod(finish))
+        try:
+            for p, m, s in zip(pubs, msgs, sigs):
+                assert sched.submit(p, m, s).result(4) is True
+        finally:
+            sched.close()
+        assert at_resolve == [0] * 60
+        assert sstats.snapshot()["flushes"] == _flushes(idle=60)
+
+    def test_concurrent_votes_behind_a_flush_leave_together(self, sched_env):
+        """Coalescing under concurrency survives: while one flush is in
+        flight, 8 senders' votes queue, and leave in ONE flush of 8 when it
+        lands, not in eight flushes of one."""
+        dev = _HeldDevice(_lib_runner)
+        pubs, msgs, sigs = _lib_sigs(8, b"eight")
+        sched = VerifyScheduler(flush_us=LONG_US)
+        got = [None] * 8
+
+        def sender(i):
+            got[i] = sched.submit(pubs[i], msgs[i], sigs[i]).result(30)
+
+        threads = [threading.Thread(target=sender, args=(i,)) for i in range(8)]
+        try:
+            held = dev.hold(sched)
+            for th in threads:
+                th.start()
+            deadline = time.perf_counter() + 30
+            while sched.pending() < 8:
+                assert time.perf_counter() < deadline
+                threading.Event().wait(0.002)
+            # all eight are queued and none has left: one slot of the two
+            # is free, but something is in flight
+            assert sstats.snapshot()["flushes"] == _flushes(idle=1)
+            dev.release()
+            for th in threads:
+                th.join(30)
+            assert held.result(30) is True and got == [True] * 8
+        finally:
+            dev.release()
+            sched.close()
+        snap = sstats.snapshot()
+        assert sum(snap["flushes"].values()) == 2  # the held one, then 8
+        assert snap["flush_items"] == 9
+        assert snap["segments"]["consensus"] == 9
+
+    def test_late_vote_leaves_when_the_flush_in_flight_lands(self, sched_env):
+        """The completion thread wakes the dispatcher when the last flush
+        lands: a vote queued behind it leaves THEN (reason ``idle``), not
+        at its deadline."""
+        dev = _HeldDevice()
+        (pub,), (msg,), (sig,) = _make_sigs(1, b"late")
+        sched = VerifyScheduler(flush_us=LONG_US)
+        try:
+            held = dev.hold(sched)
+            late = sched.submit(pub, msg, sig)
+            threading.Event().wait(0.05)  # the dispatcher is asleep on it
+            assert sched.pending() == 1 and not late.done()
+            dev.release()
+            assert held.result(4) is True and late.result(4) is True
+        finally:
+            dev.release()
+            sched.close()
+        assert sstats.snapshot()["flushes"] == _flushes(idle=2)
+
+    def test_senders_in_closed_loops_lose_no_wakeup(self, sched_env):
+        """More senders than cores, each in a closed loop, the interpreter
+        switching threads every 10 us: every vote is answered long before
+        the deadline (a lost wake-up would sit it out), every signature is
+        flushed once, and the flushes' sizes follow the load."""
+        supervisor.set_device_runner(_lib_runner)
+        n_threads, per = 16, 12
+        pubs, msgs, sigs = _lib_sigs(n_threads * per, b"stress")
+        sched = VerifyScheduler(flush_us=60_000_000)
+        sched._full_target = 128
+        bad = []
+
+        def sender(t):
+            try:
+                for i in range(t * per, (t + 1) * per):
+                    if sched.submit(pubs[i], msgs[i], sigs[i]).result(20) is not True:
+                        bad.append(i)
+            except Exception as e:  # noqa: BLE001 — reported below
+                bad.append(e)
+
+        threads = [
+            threading.Thread(target=sender, args=(t,)) for t in range(n_threads)
+        ]
+        try:
+            with _switching_every(1e-5):
+                for th in threads:
+                    th.start()
+                for th in threads:
+                    th.join(60)
+            assert not any(th.is_alive() for th in threads)
+        finally:
+            sched.close()
+        assert bad == []
+        snap = sstats.snapshot()
+        assert snap["flush_items"] == n_threads * per
+        assert snap["flushes"]["deadline"] == snap["flushes"]["shutdown"] == 0
+        assert snap["queue_depth"] == 0 and snap["inflight_depth"] == 0
+        # 16 senders behind one device: the flushes carried more than one
+        assert sum(snap["flushes"].values()) < n_threads * per
+
+    @pytest.mark.parametrize("n", [2, 40], ids=["evidence-2", "envelopes-40"])
+    def test_verify_many_cached_is_one_hand_off(self, sched_env, n):
+        """What one caller submits before it waits is queued under one
+        acquisition of the lock, so it rides ONE flush by construction: an
+        idle dispatcher (warm, woken by the first entry, the interpreter
+        switching threads at once) cannot take the first and leave the
+        rest."""
+        supervisor.set_device_runner(_lib_runner)
+        sched = verifysched.get_scheduler()
+        sched._full_target = 128
+        (pub,), (msg,), (sig,) = _lib_sigs(1, b"warm")
+        assert sched.submit(pub, msg, sig).result(30) is True
+        sstats.reset()
+        hand_offs = []
+        real = sched._enqueue
+        sched._enqueue = lambda *a, **kw: hand_offs.append(a) or real(*a, **kw)
+        pubs, msgs, sigs = _lib_sigs(n, b"many")
+        sigs = _tampered(sigs, n - 1)
+        with _switching_every(1e-6):
+            got = verifysched.verify_many_cached(
+                [Ed25519PubKey(p) for p in pubs], msgs, sigs,
+                priority=verifysched.PRIO_EVIDENCE,
+            )
+        assert got == [i != n - 1 for i in range(n)]
+        assert len(hand_offs) == 1
+        snap = sstats.snapshot()
+        assert snap["flushes"] == _flushes(idle=1)
+        assert snap["flush_items"] == n
+        assert snap["segments"]["evidence_light"] == n  # a future each
+        assert _cached(pubs, msgs, sigs) == got  # and a put each
+
+    def test_submit_many_takes_hits_and_sheds_the_tail(self, sched_env):
+        """``submit_many``: a cached triple is answered without a queue
+        slot, the misses are an n = 1 entry each, and what admission
+        control sheds of a sheddable class comes back ``None``."""
+        pubs, msgs, sigs = _make_sigs(6, b"sm")
+        sigcache.get_cache().put(pubs[1], msgs[1], sigs[1], True)
+        sched = VerifyScheduler(flush_us=1000, queue_cap=3)
+        try:
+            sched.pause()
+            futs = sched.submit_many(
+                pubs, msgs, sigs, verifysched.PRIO_BLOCKSYNC
+            )
+            assert futs[1].done() and futs[1].result() is True
+            assert [f is None for f in futs] == [
+                False, False, False, False, True, True,
+            ]
+            assert sched.pending() == 3
+            assert [en.n for en in sched._queues[2]] == [1, 1, 1]
+            sched.resume()
+            assert [f.result(30) for f in futs[:4]] == [True] * 4
+            snap = sstats.snapshot()
+            assert snap["submit_hits"]["bulk"] == 1
+            assert snap["shed"]["bulk"] == 2
+            assert sum(snap["flushes"].values()) == 1
+        finally:
+            sched.close()
 
 
 # ----------------------------------------------------------------------
@@ -345,8 +609,9 @@ class TestSegmentUnit:
             assert _verdicts(futs, 60) == [True] * 1500
         finally:
             sched.close()
+        # ``full`` outranks ``idle``: nothing was in flight either
         assert sstats.snapshot()["flushes"] == {
-            "deadline": 0, "full": 1, "shutdown": 0,
+            "deadline": 0, "full": 1, "idle": 0, "shutdown": 0,
         }
         (flush,) = [
             sp
@@ -1189,7 +1454,7 @@ class TestMetricsAndTooling:
         assert 'cometbft_sched_shed{class="consensus"} 0' in out
         assert "cometbft_sched_queue_depth 0" in out
         assert "cometbft_sched_verdicts 3" in out
-        for reason in ("deadline", "full", "shutdown"):
+        for reason in ("deadline", "full", "idle", "shutdown"):
             assert 'cometbft_sched_flushes{reason="%s"}' % reason in out
         # in-flight pipeline: everything resolved, so depth is back to 0
         # but the flush above rode the pipeline and left per-lane tallies
@@ -1201,8 +1466,6 @@ class TestMetricsAndTooling:
         """The CI lint (tier-1-wired): no direct verify_batch/
         verify_segments call sites outside the sanctioned seams."""
         import pathlib
-        import sys
-
         sys.path.insert(
             0, str(pathlib.Path(__file__).resolve().parent.parent / "scripts")
         )
