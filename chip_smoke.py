@@ -13,7 +13,8 @@ and exiting 0, which is right for a node and wrong for this script.
     python chip_smoke.py            one chip: known-answer vectors, a 175- and
                                     a 10,240-validator commit, 350 votes
     python chip_smoke.py --chips 4  the 10,240-validator commit over the
-                                    four-chip mesh, and nothing else
+                                    four-chip mesh (scheduler on, one
+                                    mesh-wide launch a flush), nothing else
 
 One process, no children that need the chip.  Prints one JSON line per phase
 (times are for the reader, not results) and, as its last line,
@@ -197,22 +198,6 @@ def _full_mesh():
     from cometbft_tpu.parallel import mesh as pmesh
 
     return Mesh(np.array(jax.devices()), (pmesh.SIG_AXIS,))
-
-
-def warm_mesh(lane_counts) -> dict:
-    """The mesh-wide executables the commit is expected to use, resolved in
-    the foreground like ``warm_buckets`` (the dispatch path finds them in
-    ``parallel.mesh``'s memo; a shape not foreseen here compiles on first
-    use and shows in the cache counts)."""
-    from cometbft_tpu.parallel import mesh as pmesh
-
-    m = _full_mesh()
-    out = {}
-    for lanes in sorted(lane_counts):
-        t0 = time.perf_counter()
-        _, info = pmesh.sharded_verify_call(m, lanes)
-        out[str(lanes)] = {**info, "seconds": round(time.perf_counter() - t0, 1)}
-    return out
 
 
 def cache_counts() -> dict:
@@ -603,37 +588,41 @@ def run_one_chip() -> None:
 
 
 def run_four_chips() -> None:
-    """Only the mesh: the 10,240-validator commit through
-    ``verify_commit_light`` with the elastic mesh enabled by the program
-    itself.  The scheduler's kill switch is set for this run: with it on,
-    a commit reaches the device as flushes of arbitrary size pinned to one
-    lane each, and the mesh-wide executable with its collective — what
-    this run is for — is never dispatched."""
+    """Only the mesh, on the path a node takes: the 10,240-validator commit
+    through ``verify_commit_light`` with the elastic mesh enabled by the
+    program itself and the scheduler ON.  A commit's segment is one flush,
+    and a flush the mesh takes is ONE mesh-wide launch
+    (``ops/supervisor.dispatch_verify``): the sharded executable with its
+    collective is what every commit dispatch of this run goes through.
+    ``bucket_executable`` resolves that executable beside the one-chip one
+    on a mesh-active host, so ``warm_buckets`` warms both."""
     import jax
 
+    from cometbft_tpu import verifysched
     from cometbft_tpu.crypto import batch as cbatch
     from cometbft_tpu.ops import dispatch_stats
     from cometbft_tpu.ops import verify as ov
     from cometbft_tpu.parallel import elastic
 
-    os.environ["COMETBFT_TPU_VERIFY_SCHED"] = "0"
     width = len(jax.devices())
     t_build, chain = build_chains((("commit_r2", R2_VALIDATORS),))["commit_r2"]
-    # the light path verifies the +2/3 prefix of equal-power validators;
-    # the accept-bit pass then sends the rest
-    light = 2 * R2_VALIDATORS // 3 + 1
-    t_warm, warmed = _timed(
-        warm_mesh, {ov.bucket_size(light), ov.bucket_size(R2_VALIDATORS - light)}
+    backend = cbatch.default_backend()
+    tier = ov.select_impl()
+    buckets = reachable_buckets(R2_VALIDATORS)
+    health = Health("tpu", {"pallas"}, set(buckets))
+    t_warm, warmed = _timed(warm_buckets, buckets, tier)
+    _require(
+        verifysched.scheduler_active(),
+        "the verify scheduler is not active on the trusted backend",
     )
     emit({
-        "phase": "setup", "native": native_sidecar(), "build_s": t_build,
-        "backend": cbatch.default_backend(), "scheduler": "off",
-        "warm_s": t_warm, "mesh_executables": warmed,
-        "cache": cache_counts(),
+        "phase": "setup", "tier": tier, "native": native_sidecar(),
+        "build_s": t_build, "backend": backend, "scheduler": "on",
+        "warm_s": t_warm, "buckets": warmed, "cache": cache_counts(),
     })
     # the self-check's two signatures run on one chip's smallest bucket;
     # every commit dispatch after it is mesh-wide
-    health = Health("tpu", {"pallas"}, set(reachable_buckets(R2_VALIDATORS)))
+    health.mark_warm()
     health.check()
     rec = phase_commit(chain)
     mesh_lanes = {

@@ -57,10 +57,17 @@ def _zero() -> dict:
         "mesh_width": 0,
         "mesh_shrinks": 0,
         "mesh_restores": 0,
+        # mesh-wide launches (ops/supervisor._launch_verify over a mesh)
+        # and the shards they were divided into
+        "mesh_dispatches": 0,
+        "mesh_shards": 0,
         # in-flight pipeline (docs/verify-scheduler.md "In-flight
         # pipeline"): dispatches whose fetch has not resolved yet, the
         # high-water mark since reset, and per-lane dispatch/lane-usage
-        # tallies (lane = mesh ordinal or supervisor backend name)
+        # tallies.  A lane is who verified the signatures: a supervisor
+        # backend's name (a mesh-wide launch counts under its TIER; the
+        # chips' own tallies are ``shard_hist``), or a mesh ordinal for a
+        # small batch pinned at one chip
         "inflight_depth": 0,
         "inflight_hwm": 0,
         "lane_dispatches": {},  # lane (str) -> dispatches routed there
@@ -120,6 +127,13 @@ def record_mesh_width(width: int) -> None:
         _STATS["mesh_width"] = int(width)
 
 
+def record_mesh_dispatch(shards: int) -> None:
+    """One mesh-wide launch, divided over ``shards`` chips."""
+    with _LOCK:
+        _STATS["mesh_dispatches"] += 1
+        _STATS["mesh_shards"] += int(shards)
+
+
 def record_mesh_shrink() -> None:
     with _LOCK:
         _STATS["mesh_shrinks"] += 1
@@ -158,8 +172,9 @@ def inflight_hwm() -> int:
 
 
 def record_lane_dispatch(lane: str, lanes_total: int, lanes_used: int) -> None:
-    """Per-lane routing tally for the in-flight pipeline: ``lane`` is a
-    mesh ordinal (str) or a supervisor backend name.  Occupancy per lane
+    """Per-lane tally of who verified what: ``lane`` is a supervisor
+    backend name (the tier, for a mesh-wide launch too) or the mesh
+    ordinal (str) a small batch was pinned at.  Occupancy per lane
     (lanes_used / lanes_total) derives at snapshot time, rendered as
     ``cometbft_crypto_lane_occupancy{lane=}``."""
     key = str(lane)

@@ -39,13 +39,18 @@ standard shim (modes: raise / hang / wrong_shape / flap) driven by
 counters, and the sim scenarios ``backend_brownout`` / ``backend_wedge`` /
 ``backend_flap`` install it at virtual times (cometbft_tpu/sim/scenarios).
 
-One launch, one fetch: the single-chip dispatch is written once, as
+One launch, one fetch: the device dispatch is written once, as
 ``_launch_verify`` (pack, injector, executable, call: one watchdog deadline)
 and ``_fetch_launched`` (the watchdogged copy back, validation, the accept
-bits).  ``dispatch_verify`` / ``fetch_verify`` are the async primitive over
-them, with the breaker and the degradation around each half;
-``verify_supervised`` walks the chain with launch-then-fetch (``_attempt``)
-and bisects.  There is no unsupervised path.
+bits).  Over one chip it calls the bucket's executable; over an elastic mesh
+(``h.mesh``, the ordinals) it pads the packed batch to the width, places one
+shard a chip and calls the ONE sharded executable, and the fetch pulls shard
+by shard (``parallel/mesh.fetch_sharded``).  ``dispatch_verify`` /
+``fetch_verify`` are the async primitive over them, with the breaker and the
+degradation around each half; ``verify_supervised`` walks the chain with
+launch-then-fetch (``_attempt``) and bisects, and the mesh's shrink ladder
+(``parallel/elastic.verify_elastic``) does the same over the mesh.  There is
+no unsupervised path.
 """
 
 from __future__ import annotations
@@ -352,6 +357,19 @@ def _launch(backend: str, lanes: int, arrays: dict):
     return call(**{k: jnp.asarray(v) for k, v in arrays.items()})
 
 
+def _launch_mesh(backend: str, lanes: int, arrays: dict, mesh, put):
+    """The same over a mesh: resolve the sharded executable for this
+    width, place one shard a chip (timed by ``put``), call ONCE.  Returns
+    the UNFETCHED sharded accept bits; the ``psum`` of the per-shard
+    counts stays on the devices.  Runs on the watchdog worker."""
+    from cometbft_tpu.parallel import mesh as pmesh
+
+    call, _ = pmesh.sharded_verify_call(mesh, lanes, backend)
+    with put:
+        placed = pmesh.device_put_args(arrays, mesh)
+    return call(*placed)[0]
+
+
 def _validate_accept(accept, lanes: int) -> np.ndarray:
     """Wrong-shape/dtype output is an infrastructure failure (a kernel
     regression or memory corruption), never a verdict."""
@@ -368,9 +386,16 @@ class _InflightVerify:
     """One supervised verify between its launch and its fetch.
 
     Kinds:
-      * ``lane``       — routed at one healthy mesh ordinal
-        (``elastic.dispatch_lane``; the shard runs at fetch time on the
-        completion pool, under the shard watchdog);
+      * ``mesh``       — ONE launch over the elastic mesh's admitted
+        ordinals (``mesh``), already in the devices' queues: the fetch
+        pulls shard by shard and is the first rung of the shrink ladder
+        (``elastic.verify_elastic``).  With the mesh-runner seam installed
+        (sim/tests) nothing is launched and the whole ladder runs at fetch;
+      * ``lane``       — pinned at one healthy mesh ordinal, for a batch
+        too small for the mesh to take (``elastic.dispatch_lane`` only
+        records the routing; the shard's pack, transfer, kernel and copy
+        back all run at fetch time on the completion pool, under the shard
+        watchdog);
       * ``chip``       — a real async device dispatch already in the
         device queue (unfetched device array + injector transform);
       * ``deferred``   — the device-runner seam is installed (sim/tests):
@@ -387,10 +412,10 @@ class _InflightVerify:
     __slots__ = (
         "kind", "pubs", "msgs", "sigs", "n", "lanes", "backend",
         "lane", "lane_handle", "dev", "transform", "structural", "skip",
-        "error",
+        "error", "mesh",
     )
 
-    def __init__(self, pubs, msgs, sigs, backend=None):
+    def __init__(self, pubs, msgs, sigs, backend=None, mesh=()):
         self.kind = "supervised"
         self.pubs = pubs
         self.msgs = msgs
@@ -405,6 +430,7 @@ class _InflightVerify:
         self.structural = None
         self.skip = ()
         self.error = None
+        self.mesh = tuple(mesh)  # ordinals of a mesh-wide launch
 
     def give_up(self) -> None:
         """Leave what is in flight on the device where it is: the fetch
@@ -427,15 +453,28 @@ def _launch_verify(h: _InflightVerify, **attrs) -> None:
     dispatch ordinal, that an anomaly dump attributes a watchdog fire to."""
     backend, pubs, msgs, sigs = h.backend, h.pubs, h.msgs, h.sigs
     arrays, n, h.structural = _pack(pubs, msgs, sigs, _min_bucket(backend))
+    mesh = None
+    if h.mesh:
+        from cometbft_tpu.parallel import mesh as pmesh
+
+        mesh = pmesh.mesh_of(h.mesh)
+        arrays = pmesh.pad_to_mesh(arrays, mesh)
+        attrs["mesh"] = len(h.mesh)
     h.lanes = lanes = arrays["s_ok"].shape[0]
-    inj = _FAULT_INJECTOR
+    # a mesh's faults are per ordinal and surface at the shards' fetch
+    # (``elastic.set_fault_injector``); this one is the single chip's
+    inj = _FAULT_INJECTOR if mesh is None else None
     runner = _DEVICE_RUNNER
     launched = tracing.lap("verify.launch")
+    put = tracing.lap("mesh.put")
 
     def run():
         transform = inj(backend, pubs, msgs, sigs) if inj is not None else None
         dispatch_stats.record_dispatch(lanes, n)
         with launched:
+            if mesh is not None:
+                dispatch_stats.record_mesh_dispatch(len(h.mesh))
+                return _launch_mesh(backend, lanes, arrays, mesh, put), None
             if runner is not None:
                 # device-runner seam (sim/tests): a synchronous stand-in,
                 # whose fetch is then a no-op
@@ -451,14 +490,24 @@ def _launch_verify(h: _InflightVerify, **attrs) -> None:
             "verify.dispatch", tier=backend, lanes=lanes, n=n, **attrs
         ) as dsp:
             h.dev, h.transform = watchdog_call(
-                run, backend=backend, note_anomaly=False
+                run,
+                backend=backend if mesh is None else "mesh",
+                note_anomaly=False,
             )
             # timed inside the watchdog closure, recorded by the calling
             # thread once ``watchdog_call`` has returned: an abandoned
             # worker writes no span
-            launched.record(
-                parent=dsp, tier=backend, lanes=lanes, bytes=_nbytes(arrays)
-            )
+            size = _nbytes(arrays)
+            if mesh is None:
+                launched.record(
+                    parent=dsp, tier=backend, lanes=lanes, bytes=size
+                )
+            else:
+                lsp = launched.record(
+                    parent=dsp, tier=backend, lanes=lanes, bytes=size,
+                    mesh=len(h.mesh),
+                )
+                put.record(parent=lsp, shards=len(h.mesh), bytes=size)
     except DispatchTimeoutError:
         # the failed span is already in the ring (the with-block closed),
         # so the dump this triggers shows it as its most recent entry
@@ -481,22 +530,45 @@ def _fetch_launched(h: _InflightVerify) -> np.ndarray:
     with tracing.span(
         "verify.fetch", tier=h.backend, lanes=h.lanes, n=h.n
     ) as fsp:
-        got = watchdog_call(fetch, backend=h.backend)
+        if h.mesh:
+            # shard by shard, each pull under the shard watchdog with the
+            # per-ordinal injector consulted: a sick chip raises
+            # ``elastic.ShardFailure`` naming its ordinal
+            from cometbft_tpu.parallel import elastic
+            from cometbft_tpu.parallel import mesh as pmesh
+
+            got = pmesh.fetch_sharded(
+                h.dev, pmesh.mesh_of(h.mesh), h.backend, h.lanes,
+                injector=elastic.fault_injector(), watchdog=True,
+            )
+        else:
+            got = watchdog_call(fetch, backend=h.backend)
     dispatch_stats.record_dispatch_time(
         h.backend, h.lanes, tracing.wall_seconds(fsp, t0)
     )
-    return (_validate_accept(got, h.lanes) & h.structural)[: h.n]
+    # the mesh pads past the bucket where the width does not divide it
+    real = len(h.structural)
+    return (_validate_accept(got, h.lanes)[:real] & h.structural)[: h.n]
 
 
-def _attempt(backend: str, pubs, msgs, sigs) -> np.ndarray:
-    """One supervised dispatch on one device backend, launch then fetch.
-    Raises ``DispatchTimeoutError`` / ``BackendOutputError`` / whatever the
-    kernel raised; never returns partial results."""
-    h = _InflightVerify(pubs, msgs, sigs, backend)
+def _attempt(
+    backend: str, pubs, msgs, sigs, mesh: tuple = (), tally: bool = False
+) -> np.ndarray:
+    """One supervised dispatch on one device backend, launch then fetch:
+    on one chip, or over the mesh of the ordinals ``mesh``
+    (``parallel/mesh.dispatch_elastic``).  Raises ``DispatchTimeoutError``
+    / ``BackendOutputError`` / ``elastic.ShardFailure`` / whatever the
+    kernel raised; never returns partial results.  ``tally`` counts the
+    batch's signatures under ``backend`` once it is answered
+    (``lane_lanes_used``: who verified what)."""
+    h = _InflightVerify(pubs, msgs, sigs, backend, mesh)
     # the ordinal this dispatch will record (single dispatch in flight per
     # attempt; concurrent attempts only skew the label, never the verdict)
     _launch_verify(h, dispatch=dispatch_stats.dispatch_count() + 1)
-    return _fetch_launched(h)
+    bits = _fetch_launched(h)
+    if tally:
+        dispatch_stats.record_lane_dispatch(backend, h.lanes, h.n)
+    return bits
 
 
 def host_verify(pubs, msgs, sigs) -> np.ndarray:
@@ -601,7 +673,7 @@ def verify_supervised(
     if mesh and not skip:
         from cometbft_tpu.parallel import elastic
 
-        if elastic.active() and len(pubs) >= elastic.min_batch():
+        if elastic.takes(len(pubs)):
             return elastic.verify_elastic(pubs, msgs, sigs)
     n = len(pubs)
     reg = backend_health.registry()
@@ -613,7 +685,7 @@ def verify_supervised(
             if not br.allow():
                 continue
             try:
-                bits = _attempt(backend, pubs, msgs, sigs)
+                bits = _attempt(backend, pubs, msgs, sigs, tally=True)
             except Exception as e:  # noqa: BLE001 — any dispatch error
                 # demotes
                 if (
@@ -649,13 +721,14 @@ def verify_supervised(
 # -- in-flight dispatch/fetch seam (docs/verify-scheduler.md) -----------------
 #
 # The async half of ``verify_supervised``: ``dispatch_verify`` routes one
-# batch toward a mesh lane or the single-chip chain WITHOUT blocking on its
-# verdict, and ``fetch_verify`` resolves it later (the verifysched
-# completion pool, ``ops.verify.verify_pipelined`` and
-# ``verify_batches_overlapped``).  Every failure mode at fetch time
-# degrades exactly like the synchronous path — a wedged or failed
-# lane/backend is demoted alone and the batch re-verifies on the
-# single-chip chain (host floor), so accept bits stay definitive verdicts.
+# batch over the whole mesh, toward one mesh lane or down the single-chip
+# chain WITHOUT blocking on its verdict, and ``fetch_verify`` resolves it
+# later (the verifysched completion pool, ``ops.verify.verify_pipelined``
+# and ``verify_batches_overlapped``).  Every failure mode at fetch time
+# degrades exactly like the synchronous path — a lost shard shrinks the
+# mesh and the batch is verified again at the next width, a wedged or
+# failed lane/backend is demoted alone and the batch re-verifies on the
+# single-chip chain (host floor) — so accept bits stay definitive verdicts.
 
 
 def _demote(h: _InflightVerify, e: BaseException, when: str) -> None:
@@ -675,31 +748,73 @@ def _demote(h: _InflightVerify, e: BaseException, when: str) -> None:
     h.skip = (h.backend,)
 
 
+def _dispatch_mesh(h: _InflightVerify) -> bool:
+    """The mesh-wide kind of ``dispatch_verify``: ONE launch over the
+    ordinals the elastic mesh admits (a half-open chip earns its place by
+    the one-bucket probe first).  False where fewer than two are admitted:
+    the single-chip chain takes the batch.  A launch that fails has no
+    ordinal to blame (lowering, placement, the one deadline): as in
+    ``verify_elastic`` the batch then goes down the single-chip chain, at
+    fetch."""
+    from cometbft_tpu.parallel import elastic
+
+    if elastic.mesh_runner() is not None:
+        # mesh-runner seam (sim/tests): the stand-in runs synchronously,
+        # so the whole ladder is deferred to the completion pool
+        h.kind = "mesh"
+        return True
+    devs = elastic.admit_ordinals()
+    if len(devs) < 2:
+        return False
+    from cometbft_tpu.parallel import mesh as pmesh
+
+    h.mesh = tuple(devs)
+    try:
+        h.backend = pmesh.tier_of(devs)
+        _launch_verify(h, pipelined=True)
+    except Exception as e:  # noqa: BLE001 — the mesh itself failed
+        logger.warning(
+            "mesh-wide dispatch failed without shard attribution (%r); "
+            "the batch goes down the single-chip chain",
+            e,
+        )
+        h.error, h.mesh, h.backend = e, (), None
+        return True
+    h.kind = "mesh"
+    # under the TIER's name: the chips' own tallies are the shard
+    # histograms (``record_shard_time``)
+    dispatch_stats.record_lane_dispatch(h.backend, h.lanes, h.n)
+    return True
+
+
 def dispatch_verify(pubs, msgs, sigs, lane=None) -> _InflightVerify:
-    """Route one batch without blocking on its verdict.  ``lane`` (a mesh
-    ordinal) pins it at that lane when the elastic mesh is active and the
-    lane is healthy; otherwise the first breaker-allowed device backend
-    takes it.  Pair every handle with exactly one ``fetch_verify`` —
-    in-flight depth accounting (``dispatch_stats``) balances on fetch."""
+    """Route one batch without blocking on its verdict.  With no ``lane``,
+    a batch the elastic mesh takes (``elastic.takes``: the mesh is active
+    and the batch reaches ``min_batch()``, the rule ``verify_supervised``
+    applies) is ONE launch over every admitted chip.  ``lane`` (a mesh
+    ordinal) pins a batch at that lane when the mesh is active and the
+    lane is healthy: the scheduler pins what the mesh does not take.
+    Otherwise the first breaker-allowed device backend takes it.  Pair
+    every handle with exactly one ``fetch_verify`` — in-flight depth
+    accounting (``dispatch_stats``) balances on fetch."""
     from cometbft_tpu.ops import verify as ov
+    from cometbft_tpu.parallel import elastic
 
     pubs, msgs, sigs = list(pubs), list(msgs), list(sigs)
     h = _InflightVerify(pubs, msgs, sigs)
     n = h.n
     dispatch_stats.record_inflight_enter()
     try:
-        if lane is not None:
-            from cometbft_tpu.parallel import elastic
-
-            if elastic.active() and int(lane) in elastic.healthy_ordinals():
-                h.kind = "lane"
-                h.lane = int(lane)
-                h.lane_handle = elastic.dispatch_lane(
-                    h.lane, pubs, msgs, sigs
-                )
-                h.lanes = h.lane_handle.lanes
-                dispatch_stats.record_lane_dispatch(str(h.lane), h.lanes, n)
+        if lane is None:
+            if elastic.takes(n) and _dispatch_mesh(h):
                 return h
+        elif elastic.active() and int(lane) in elastic.healthy_ordinals():
+            h.kind = "lane"
+            h.lane = int(lane)
+            h.lane_handle = elastic.dispatch_lane(h.lane, pubs, msgs, sigs)
+            h.lanes = h.lane_handle.lanes
+            dispatch_stats.record_lane_dispatch(str(h.lane), h.lanes, n)
+            return h
         reg = backend_health.registry()
         for b in device_chain():
             if reg.breaker(b).allow():
@@ -736,10 +851,17 @@ def fetch_verify(h: _InflightVerify) -> np.ndarray:
     for infrastructure reasons — every failure mode degrades the guilty
     lane/backend alone and re-verifies on the single-chip chain, whose
     floor is the host ZIP-215 oracle."""
-    try:
-        if h.kind == "lane":
-            from cometbft_tpu.parallel import elastic
+    from cometbft_tpu.parallel import elastic
 
+    try:
+        if h.kind == "mesh":
+            # the shrink ladder, its first rung the launch in flight: a
+            # lost shard is verified again at the next width, below two
+            # chips on the single-chip chain
+            return elastic.verify_elastic(
+                h.pubs, h.msgs, h.sigs, launched=h if h.mesh else None
+            )
+        if h.kind == "lane":
             try:
                 return elastic.fetch_lane(h.lane_handle)
             except Exception as e:  # noqa: BLE001 — lane degrades alone

@@ -275,7 +275,15 @@ def bucket_executable(
     A lowering or compile failure is LOUD: logged at error with the
     compiler's message, counted (``warm_stats`` ``compile_failures``),
     latched in ``_AOT_BROKEN`` and raised as ``TierCompileError`` — the
-    supervised callers demote the tier through its breaker."""
+    supervised callers demote the tier through its breaker.
+
+    On a host whose elastic mesh is active, a bucket's FIRST resolution
+    also resolves what the served path launches for it there: the
+    mesh-wide executable at the host's width, for every bucket the mesh
+    takes (``info["mesh"]``; ``_resolve_mesh_wide``).  So whoever warms a
+    bucket before serving (a node's start, ``chip_smoke.py``, the
+    benchmark's set-up) leaves nothing to compile inside a request, under
+    the watchdog's deadline."""
     if donated is None:
         donated = donation_enabled()
     key = (impl, lanes, bool(donated))
@@ -285,6 +293,11 @@ def bucket_executable(
     if memo is not None:
         return memo, {"exec_cache": "memo"}
     from cometbft_tpu.ops import aot_cache
+
+    # the served path launches the default form (``donation_enabled``)
+    wide = None
+    if bool(donated) == donation_enabled():
+        wide = _resolve_mesh_wide(impl, lanes, bool(donated))
 
     try:
         call, info = aot_cache.load_or_compile(
@@ -299,7 +312,41 @@ def bucket_executable(
     with _EXEC_LOCK:
         # two racing compilers: first writer wins, both results correct
         call = _EXEC_CACHE.setdefault(key, call)
+    if wide:
+        info = {**info, "mesh": wide}
     return call, info
+
+
+def _resolve_mesh_wide(
+    impl: str, lanes: int, donated: bool
+) -> "Optional[dict]":
+    """The mesh-wide executable the served path launches for a batch of
+    this bucket (``ops/supervisor._launch_mesh``), resolved through the
+    executable cache: {tag: info}, or None where no mesh-wide launch can
+    reach the bucket (one chip, a mesh of another tier, a bucket under
+    ``elastic.min_batch()``).  Only the full width: a shrunken mesh's
+    executables are ``ops/warmboot``'s (``COMETBFT_TPU_WARMBOOT_MESH_SHRINK``).
+    A failure is loud and latched like any compile failure, and raised
+    nowhere: the supervisor meets it again at the launch and takes the
+    single-chip chain."""
+    from cometbft_tpu.parallel import elastic
+
+    _maybe_enable_mesh()
+    if not elastic.active() or lanes < bucket_size(elastic.min_batch(), 1):
+        return None
+    from cometbft_tpu.parallel import mesh as pmesh
+
+    ordinals = elastic.configured_ordinals()
+    if pmesh.tier_of(ordinals) != impl:
+        return None
+    m = pmesh.mesh_of(ordinals)
+    padded = lanes + (-lanes) % len(ordinals)
+    tag = pmesh.mesh_tag(impl, len(ordinals), padded, donated)
+    try:
+        _, info = pmesh.sharded_verify_call(m, padded, impl, donated)
+    except TierCompileError as e:
+        return {tag: {"error": str(e)}}
+    return {tag: dict(info)}
 
 
 def reset_executable_memo() -> None:
@@ -594,9 +641,10 @@ class _SegmentsHandle:
 
 def dispatch_segments(work, lane=None) -> _SegmentsHandle:
     """Async half of ``verify_segments``: returns a handle whose verdicts
-    ``fetch_segments`` resolves later.  ``lane`` pins the fused dispatch
-    at one elastic-mesh ordinal (round-robined by the scheduler) so K
-    concurrent flushes spread across lanes instead of piling onto one.
+    ``fetch_segments`` resolves later.  With no ``lane``, a fused batch
+    the elastic mesh takes is ONE launch over every healthy chip; ``lane``
+    pins a smaller one at one elastic-mesh ordinal (round-robined by the
+    scheduler).
     Shapes with no single fused dispatch (empty, or overflowing the
     largest bucket) resolve synchronously at fetch time."""
     from cometbft_tpu.ops import supervisor

@@ -45,7 +45,10 @@ switch ``COMETBFT_TPU_MESH_SUPERVISOR=0`` restores the raw sharded call
 Deliberately free of jax imports at module level: metrics scrapes and the
 verifysched dispatcher read ``healthy_width()`` and must never be the
 thing that initializes an accelerator backend.  The real device path is
-imported lazily inside the dispatch (``parallel/mesh.dispatch_elastic``).
+imported lazily inside the dispatch (``parallel/mesh.dispatch_elastic``,
+which is the supervisor's one launch and one fetch over a mesh: the same
+body the scheduler's mesh-wide flush runs through
+``ops/supervisor.dispatch_verify``).
 """
 
 from __future__ import annotations
@@ -134,10 +137,15 @@ def configured() -> bool:
     return _ORDINALS is not None
 
 
+def configured_ordinals() -> "tuple[int, ...]":
+    """Full configured membership (breakers ignored); empty when the mesh
+    is inactive."""
+    return _ORDINALS or ()
+
+
 def total_width() -> int:
-    """Full configured membership (breakers ignored)."""
-    o = _ORDINALS
-    return len(o) if o is not None else 0
+    """Size of the full configured membership (breakers ignored)."""
+    return len(configured_ordinals())
 
 
 def active() -> bool:
@@ -167,6 +175,14 @@ def min_batch() -> int:
         )
     except ValueError:
         return DEFAULT_MIN_BATCH
+
+
+def takes(n: int) -> bool:
+    """THE routing rule, for the synchronous path, the in-flight seam and
+    the scheduler alike: a batch of ``n`` signatures goes mesh-wide when
+    the mesh is active and the batch reaches ``min_batch()``.  What is
+    smaller stays on one chip (a pinned lane, or the single-chip chain)."""
+    return active() and n >= min_batch()
 
 
 def healthy_width() -> int:
@@ -260,6 +276,10 @@ def clear_mesh_runner() -> None:
     set_mesh_runner(None)
 
 
+def mesh_runner() -> Optional[Callable]:
+    return _RUNNER
+
+
 def set_fault_injector(fn: Optional[Callable]) -> None:
     """Install ``fn(ordinal, pubs, msgs, sigs) -> Optional[transform]``,
     consulted once per shard per dispatch (and per re-admission probe).
@@ -275,6 +295,10 @@ def set_fault_injector(fn: Optional[Callable]) -> None:
 
 def clear_fault_injector() -> None:
     set_fault_injector(None)
+
+
+def fault_injector() -> Optional[Callable]:
+    return _FAULT_INJECTOR
 
 
 class FaultyDevice:
@@ -495,9 +519,7 @@ def _attempt(devs: "list[int]", pubs, msgs, sigs) -> np.ndarray:
     if runner is None:
         from cometbft_tpu.parallel import mesh as pmesh
 
-        return pmesh.dispatch_elastic(
-            devs, pubs, msgs, sigs, injector=_FAULT_INJECTOR
-        )
+        return pmesh.dispatch_elastic(devs, pubs, msgs, sigs)
 
     # runner seam (sim/tests): host-side sharding mirrors the mesh layout
     # — bucket-padded lanes split contiguously across the width, one
@@ -558,11 +580,15 @@ def _attempt(devs: "list[int]", pubs, msgs, sigs) -> np.ndarray:
 
 class _LaneHandle:
     """One lane's deferred shard work (docs/verify-scheduler.md
-    "In-flight pipeline").  ``run_single_shard`` blocks on the device
-    result inside its jitted call, so the device work itself executes at
-    ``fetch_lane`` time on the completion pool — the dispatch records the
-    routing decision and returns immediately, which is what lets the
-    dispatcher keep K lanes busy concurrently."""
+    "In-flight pipeline"): the pin of a batch too small for the mesh to
+    take.  At dispatch NOTHING runs: ``dispatch_lane`` records the routing
+    (the lane, the bucket, the dispatch count) and returns.  At fetch, on
+    the scheduler's one completion thread, ``run_single_shard`` does all
+    of it: the host pack, a plain ``jax.jit`` for the pinned device (not
+    the executable cache, so its first use of a device compiles), the
+    transfer, the kernel and the blocking copy back.  Lanes therefore run
+    one after the other, not side by side; a batch the mesh takes never
+    comes here (``ops/supervisor.dispatch_verify``)."""
 
     __slots__ = ("ordinal", "pubs", "msgs", "sigs", "n", "lanes", "t0")
 
@@ -665,28 +691,41 @@ def note_lane_failure(ordinal: int, err: BaseException, width: int) -> None:
 # -- the elastic verify entry -------------------------------------------------
 
 
-def verify_elastic(pubs, msgs, sigs) -> np.ndarray:
+def verify_elastic(pubs, msgs, sigs, launched=None) -> np.ndarray:
     """Mesh-sharded supervised verify with the shrink ladder: returns
     (n,) bool accept bits and cannot raise for infrastructure reasons —
     every failure mode either shrinks the mesh and re-dispatches or falls
     into the single-chip degradation chain (whose floor is the host
     ZIP-215 oracle).  ``banned`` is per-call: a failed ordinal is out of
     THIS batch immediately regardless of its breaker's threshold, while
-    the breaker decides when future dispatches stop probing it."""
+    the breaker decides when future dispatches stop probing it.
+
+    ``launched`` is the served path's first rung: a mesh-wide launch of
+    this batch already in flight (``ops/supervisor.dispatch_verify``'s
+    ``mesh`` kind), whose fetch takes the place of the first attempt, over
+    the ordinals it was launched on.  Every later rung is launch and fetch
+    together, as on the synchronous path."""
+    from cometbft_tpu.ops import supervisor
+
     pubs, msgs, sigs = list(pubs), list(msgs), list(sigs)
     reg = backend_health.registry()
     banned: set = set()
     while True:
-        devs = _membership(banned)
+        if launched is not None:
+            devs = list(launched.mesh)
+        else:
+            devs = _membership(banned)
         _note_width(len(devs))
         if len(devs) < 2:
             # the bottom of the ladder: the existing single-chip chain
             # (pallas -> xla -> host) takes the whole batch
-            from cometbft_tpu.ops import supervisor
-
             return supervisor.verify_supervised(pubs, msgs, sigs, mesh=False)
         try:
-            bits = _attempt(devs, pubs, msgs, sigs)
+            if launched is not None:
+                first, launched = launched, None
+                bits = supervisor._fetch_launched(first)
+            else:
+                bits = _attempt(devs, pubs, msgs, sigs)
             # a clean dispatch resets every participant's consecutive-
             # failure count (flap bursts below the threshold must not
             # accumulate across healthy dispatches)
@@ -701,8 +740,6 @@ def verify_elastic(pubs, msgs, sigs) -> np.ndarray:
             # failure (lowering, collective, compile): no ordinal to
             # blame, so the whole batch falls to the single-chip chain —
             # degraded, never a wrong verdict
-            from cometbft_tpu.ops import supervisor
-
             logger.warning(
                 "mesh dispatch failed without shard attribution (%r); "
                 "falling back to the single-chip chain for this batch",

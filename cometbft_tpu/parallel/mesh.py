@@ -99,6 +99,18 @@ def device_for_ordinal(ordinal: int):
     return _DEVICE_BY_ORDINAL.get(int(ordinal))
 
 
+def mesh_of(ordinals: "Sequence[int]") -> Mesh:
+    """The 1-D mesh over the devices with the given stable ordinals, in
+    that order."""
+    _ensure_base_registry()
+    return make_mesh([_DEVICE_BY_ORDINAL[int(o)] for o in ordinals])
+
+
+def tier_of(ordinals: "Sequence[int]") -> str:
+    """The kernel tier a mesh over these ordinals runs (``select_impl``)."""
+    return ov.select_impl(mesh_of(ordinals).devices.flat)
+
+
 def _verify_shard(a_bytes, r_bytes, s_bytes, m_bytes, s_ok, *, impl: str):
     """Per-device body: verify the local shard through the SAME kernel the
     single-chip path selects (Pallas on TPU meshes, XLA elsewhere —
@@ -419,51 +431,22 @@ def dispatch_elastic(
     pubs: Sequence[bytes],
     msgs: Sequence[bytes],
     sigs: Sequence[bytes],
-    injector=None,
 ) -> np.ndarray:
     """One supervised mesh dispatch over the devices with the given
-    stable ordinals.  Raises ``parallel.elastic.ShardFailure`` on any
-    ordinal-attributable problem (injected fault, fetch-time error,
-    malformed shard, shard watchdog fire) — the elastic supervisor
-    shrinks and re-dispatches; any other exception means the mesh itself
-    is broken (lowering, collective) and the caller falls to the
-    single-chip chain."""
+    stable ordinals: the supervisor's one launch and one fetch
+    (``ops/supervisor._launch_verify`` / ``_fetch_launched``, the body the
+    scheduler's mesh-wide flush runs too), one after the other.  Raises
+    ``parallel.elastic.ShardFailure`` on any ordinal-attributable problem
+    (injected fault, fetch-time error, malformed shard, shard watchdog
+    fire) — the elastic supervisor shrinks and re-dispatches; any other
+    exception means the mesh itself is broken (lowering, collective) and
+    the caller falls to the single-chip chain."""
     from cometbft_tpu.ops import supervisor
 
-    _ensure_base_registry()
-    devices = [_DEVICE_BY_ORDINAL[int(o)] for o in ordinals]
-    m = Mesh(np.array(devices), (SIG_AXIS,))
-    impl = ov.select_impl(m.devices.flat)
-    arrays, n, structural = ov.prepare_batch(pubs, msgs, sigs)
-    arrays = pad_to_mesh(arrays, m)
-    lanes = arrays["s_ok"].shape[0]
-    dispatch_stats.record_dispatch(lanes, n)
-    seq = dispatch_stats.dispatch_count()
-    t0 = time.perf_counter()
-    with tracing.span(
-        "verify.dispatch",
-        tier=impl,
-        lanes=lanes,
-        n=n,
-        dispatch=seq,
-        mesh=len(devices),
-    ):
-
-        def dispatch():
-            # executable resolution (exec-cache load or AOT compile) runs
-            # INSIDE the watchdog worker, like the single-chip supervised
-            # path: a wedged compile is abandoned like a wedged dispatch
-            call, _ = sharded_verify_call(m, lanes, impl)
-            return call(*device_put_args(arrays, m))
-
-        accept, _ = supervisor.watchdog_call(
-            dispatch, backend="mesh", note_anomaly=False
-        )
-        host = fetch_sharded(
-            accept, m, impl, lanes, injector=injector, watchdog=True
-        )
-    dispatch_stats.record_dispatch_time(impl, lanes, time.perf_counter() - t0)
-    return (host[: len(structural)] & structural)[:n]
+    return supervisor._attempt(
+        tier_of(ordinals), pubs, msgs, sigs,
+        mesh=tuple(int(o) for o in ordinals), tally=True,
+    )
 
 
 def run_single_shard(
@@ -504,9 +487,7 @@ def warm_shrink_shape(width: int, lanes: int) -> dict:
     satellite (``COMETBFT_TPU_WARMBOOT_MESH_SHRINK``): the first
     post-shrink dispatch must meet a resident executable, not a cold
     compile mid-consensus.  Returns the exec-cache info dict."""
-    _ensure_base_registry()
-    devices = [_DEVICE_BY_ORDINAL[o] for o in range(int(width))]
-    m = Mesh(np.array(devices), (SIG_AXIS,))
+    m = mesh_of(range(int(width)))
     impl = ov.select_impl(m.devices.flat)
     padded = int(lanes) + (-int(lanes)) % int(width)
     _, info = sharded_verify_call(m, padded, impl)

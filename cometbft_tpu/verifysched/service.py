@@ -131,12 +131,20 @@ def scheduler_active() -> bool:
 
 
 def inflight_target() -> int:
-    """Bound on concurrently dispatched flushes: explicit
+    """Bound K on concurrently dispatched flushes: explicit
     ``COMETBFT_TPU_SCHED_INFLIGHT`` wins; the default is the LIVE elastic
-    mesh width (each healthy lane carries its own dispatch, and the bound
-    follows shrinks/restores automatically — ``healthy_width`` is
-    jax-free) with a floor of 2 on a single chip, where the depth buys
-    host-prep/device-compute overlap rather than lane parallelism."""
+    mesh width (the bound follows shrinks/restores automatically —
+    ``healthy_width`` is jax-free) with a floor of 2 on a single chip.
+
+    What K buys depends on what a flush holds.  A flush the mesh takes
+    (``elastic.takes``) is ONE launch over EVERY healthy chip, so K such
+    flushes do not run side by side: they queue on the same chips in
+    order, and K is the depth of that queue, which buys what the floor of
+    2 buys on one chip: the host prepares flush i+1 while the devices
+    compute flush i.  Only flushes too small for the mesh are pinned one
+    a lane, and their work runs at fetch on the one completion thread
+    (``elastic._LaneHandle``).  With one caller a request is one segment
+    and one flush, so never more than 1 is in flight whatever K is."""
     env = os.environ.get("COMETBFT_TPU_SCHED_INFLIGHT")
     if env:
         try:
@@ -297,7 +305,7 @@ class VerifyScheduler:
         self._inflight = 0
         self._fetch_thread: Optional[threading.Thread] = None
         self._fetch_stop = False
-        self._lane_rr = 0  # round-robin over healthy mesh ordinals
+        self._lane_rr = 0  # round-robin of SMALL flushes over mesh ordinals
 
     # -- submission -------------------------------------------------------
 
@@ -913,8 +921,9 @@ class VerifyScheduler:
         work) + ONE fused dispatch (``ops.verify.dispatch_segments``),
         then hand the in-flight handle to the completion thread and
         return to draining — up to ``inflight_target()`` flushes ride
-        the device concurrently, round-robined across healthy mesh
-        lanes."""
+        the device concurrently.  On a mesh, a flush the mesh takes is
+        not pinned: the supervisor launches it over every healthy chip;
+        only a smaller one is pinned at one lane, round-robin."""
         n = sum(en.n for en in entries)
         interval = self._flush_interval()
 
@@ -951,17 +960,22 @@ class VerifyScheduler:
                 try:
                     from cometbft_tpu.parallel import elastic
 
-                    # the probe-ADMITTING membership walk, not the
-                    # read-only healthy list: a half-open chip re-earns
-                    # its lane via the one-bucket probe here, exactly as
-                    # it would under a mesh-wide dispatch.  Below 2 lanes
-                    # the mesh rule says single-chip: lane=None falls
-                    # into the pallas→xla→host chain, which keeps THOSE
-                    # breakers probed and re-promoted too.
-                    ords = elastic.admit_ordinals()
-                    if len(ords) >= 2:
-                        lane = ords[self._lane_rr % len(ords)]
-                        self._lane_rr += 1
+                    # a flush the mesh takes holds EVERY healthy chip:
+                    # the supervisor launches it mesh-wide and walks the
+                    # membership itself, so nothing is pinned.  A smaller
+                    # one is pinned, by the probe-ADMITTING membership
+                    # walk, not the read-only healthy list: a half-open
+                    # chip re-earns its lane via the one-bucket probe
+                    # here, exactly as it would under a mesh-wide
+                    # dispatch.  Below 2 lanes the mesh rule says
+                    # single-chip: lane=None falls into the
+                    # pallas→xla→host chain, which keeps THOSE breakers
+                    # probed and re-promoted too.
+                    if not elastic.takes(len(ordered)):
+                        ords = elastic.admit_ordinals()
+                        if len(ords) >= 2:
+                            lane = ords[self._lane_rr % len(ords)]
+                            self._lane_rr += 1
                 except Exception:  # noqa: BLE001 — lane pinning is an
                     # optimization, never load-bearing
                     lane = None

@@ -357,6 +357,42 @@ class TestElasticMesh:
                 assert st["failures_total"] >= 1, (mode, ordinal, st)
                 elastic.clear_fault_injector()
 
+    @pytest.mark.parametrize("mode", ["raise", "wrong_shape", "flap"])
+    @pytest.mark.parametrize("ordinal", range(4))
+    def test_fault_matrix_through_the_served_path(
+        self, monkeypatch, mode, ordinal
+    ):
+        """The same matrix with the scheduler on: a flush the mesh takes
+        (``verify_segment_sync``) whose shard fails resolves every future
+        with the oracle's verdicts at the next width, the failure on the
+        injected ordinal's breaker."""
+        from cometbft_tpu import verifysched
+        from cometbft_tpu.crypto import backend_health, sigcache
+        from cometbft_tpu.ops import dispatch_stats
+        from cometbft_tpu.parallel import elastic
+        from cometbft_tpu.verifysched import service
+
+        monkeypatch.setenv("COMETBFT_TPU_CRYPTO_BACKEND", "tpu")
+        monkeypatch.setenv("COMETBFT_TPU_MESH_MIN_BATCH", "1")
+        monkeypatch.delenv("COMETBFT_TPU_VERIFY_SCHED", raising=False)
+        verifysched.reset_scheduler()
+        sigcache.reset_cache()
+        pubs, msgs, sigs, expected = self._mixed_batch(7, 23)
+        elastic.set_fault_injector(
+            elastic.FaultyDevice(mode, ordinals=(ordinal,), fail_n=2, pass_n=1)
+        )
+        try:
+            got = service.verify_segment_sync(pubs, msgs, sigs)
+        finally:
+            verifysched.reset_scheduler()
+            sigcache.reset_cache()
+        assert got == [bool(b) for b in expected], (mode, ordinal)
+        st = backend_health.registry().breaker(f"mesh_dev{ordinal}").stats()
+        assert st["failures_total"] >= 1, (mode, ordinal, st)
+        snap = dispatch_stats.snapshot()
+        assert snap["mesh_shrinks"] == 1 and snap["inflight_depth"] == 0
+        assert dispatch_stats.mesh_width() == self.WIDTH - 1
+
     def test_hang_mode_shard_watchdog_fires(self, monkeypatch):
         """A wedged shard: the shard watchdog abandons it, the anomaly
         taxonomy records shard_watchdog_fire with the ordinal, and the
@@ -637,3 +673,322 @@ class TestElasticMesh:
         # kill switch: the mesh supervisor being off empties the matrix
         monkeypatch.setenv("COMETBFT_TPU_MESH_SUPERVISOR", "0")
         assert warmboot.mesh_shrink_matrix() == []
+
+
+# ----------------------------------------------------------------------
+# the served path over the mesh (ISSUE 34): scheduler on, a flush the mesh
+# takes is ONE mesh-wide launch through the supervisor's one launch and
+# one fetch.  On the real XLA-CPU device path unless a test says otherwise.
+# ----------------------------------------------------------------------
+
+WIDTHS = (2, 4, 8)
+BUCKET = 32  # the XLA tier's smallest: every batch below pads to it
+
+
+def _signed(tag: bytes, n: int):
+    import hashlib
+
+    pubs, msgs, sigs = [], [], []
+    for i in range(n):
+        seed = hashlib.sha256(b"%s/%d" % (tag, i)).digest()
+        msgs.append(b"%s-msg-%d" % (tag, i))
+        pubs.append(ref.pubkey_from_seed(seed))
+        sigs.append(ref.sign(seed, msgs[-1]))
+    return pubs, msgs, sigs
+
+
+def _forge(sigs, i):
+    sigs[i] = sigs[i][:32] + bytes([sigs[i][32] ^ 1]) + sigs[i][33:]
+
+
+def _oracle(pubs, msgs, sigs):
+    return [
+        len(p) == 32 and len(s) == 64 and bool(ref.verify_zip215(p, m, s))
+        for p, m, s in zip(pubs, msgs, sigs)
+    ]
+
+
+def _spans(stage):
+    from cometbft_tpu.libs import tracing
+
+    return [
+        s for s in tracing.get_tracer().tail(0) if s["stage"] == stage
+    ]
+
+
+@pytest.fixture(scope="module")
+def commit_of_27():
+    """(chain id, set, block id, height, commit) of 27 validators, whose
+    light prefix is 19 signatures.  Module-scoped so that it is built
+    before ``served`` turns the device backend on: the vote set verifies
+    every vote on its way in."""
+    from tests.test_types import (
+        CHAIN_ID, _block_id, _make_commit, _mk_validators,
+    )
+
+    privs, vals, _ = _mk_validators(27)
+    bid = _block_id()
+    return CHAIN_ID, vals, bid, 34, _make_commit(privs, vals, bid, height=34)
+
+
+@pytest.fixture
+def served(monkeypatch):
+    """A scheduler-active node whose elastic mesh is configured by the
+    test: ``served(width)`` gives the mesh that many of the suite's CPU
+    devices and lets it take any batch of 8 signatures or more."""
+    from cometbft_tpu import verifysched
+    from cometbft_tpu.crypto import backend_health, sigcache
+    from cometbft_tpu.libs import tracing
+    from cometbft_tpu.ops import device_health, dispatch_stats
+    from cometbft_tpu.parallel import elastic
+    from cometbft_tpu.verifysched import stats as sstats
+
+    def reset():
+        verifysched.reset_scheduler()
+        elastic.clear()
+        backend_health.reset()
+        device_health.reset()
+        sigcache.reset_cache()
+        sstats.reset()
+        dispatch_stats.reset()
+        tracing.reset_tracer()
+
+    monkeypatch.setenv("COMETBFT_TPU_CRYPTO_BACKEND", "tpu")
+    monkeypatch.setenv("COMETBFT_TPU_MESH_MIN_BATCH", "8")
+    monkeypatch.setenv("COMETBFT_TPU_BREAKER_THRESHOLD", "1")
+    monkeypatch.delenv("COMETBFT_TPU_VERIFY_SCHED", raising=False)
+    monkeypatch.delenv("COMETBFT_TPU_MESH_SUPERVISOR", raising=False)
+    reset()
+
+    def configure(width: int):
+        pmesh.register_devices(jax.devices("cpu"))
+        elastic.configure(range(width))
+        return elastic
+
+    yield configure
+    reset()
+
+
+def _bad_lanes(case: str, width: int, n: int) -> "list[int]":
+    per = BUCKET // width
+    return {
+        "a_shards_first_lane": [per],
+        "a_shards_last_lane": [per - 1],
+        "the_last_real_lane_before_the_padding": [n - 1],
+        "two_shards_at_once": [per - 1, per, n - 1],
+    }[case]
+
+
+class TestServedMesh:
+    N = 27  # not a multiple of 2, 4 or 8; pads to 32 lanes
+
+    @pytest.mark.parametrize("width", WIDTHS)
+    @pytest.mark.parametrize("case", [
+        "a_shards_first_lane", "a_shards_last_lane",
+        "the_last_real_lane_before_the_padding", "two_shards_at_once",
+    ])
+    def test_a_bad_signature_keeps_its_index_whatever_shard_holds_it(
+        self, served, width, case
+    ):
+        from cometbft_tpu.verifysched import service
+
+        served(width)
+        pubs, msgs, sigs = _signed(b"served/%s/%d" % (case.encode(), width), self.N)
+        bad = _bad_lanes(case, width, self.N)
+        for i in bad:
+            _forge(sigs, i)
+        got = service.verify_segment_sync(pubs, msgs, sigs)
+        assert got == _oracle(pubs, msgs, sigs)
+        assert [i for i, ok in enumerate(got) if not ok] == bad
+        (disp,) = _spans("verify.dispatch")
+        assert disp["attrs"]["mesh"] == width and disp["attrs"]["lanes"] == BUCKET
+        assert disp["attrs"]["pipelined"] is True
+
+    @pytest.mark.parametrize("width", WIDTHS)
+    def test_a_seeded_mix_equals_the_reference_bit_for_bit(self, served, width):
+        """Forged, degenerate and structurally invalid signatures at seeded
+        places, n not a multiple of the width: through
+        ``verify_segment_sync`` the verdicts are ``ed25519_ref``'s."""
+        from cometbft_tpu.ops import dispatch_stats
+        from cometbft_tpu.verifysched import service
+
+        served(width)
+        pubs, msgs, sigs, expected = TestElasticMesh._mixed_batch(40 + width, self.N)
+        got = service.verify_segment_sync(pubs, msgs, sigs)
+        assert got == [bool(b) for b in expected]
+        assert 0 < sum(got) < self.N
+        snap = dispatch_stats.snapshot()
+        assert snap["mesh_dispatches"] == 1 and snap["mesh_shards"] == width
+        shards = _spans("mesh.shard")
+        assert sorted(s["attrs"]["device"] for s in shards) == list(range(width))
+        (fetch,) = _spans("verify.fetch")
+        assert all(s["parent"] == fetch["span"] for s in shards)
+        (put,) = _spans("mesh.put")
+        (launch,) = _spans("verify.launch")
+        assert put["parent"] == launch["span"] and put["attrs"]["shards"] == width
+        assert launch["attrs"]["mesh"] == width
+
+    @pytest.mark.parametrize("width", WIDTHS)
+    def test_the_parts_add_up(self, width):
+        """The accept bits each shard gave, concatenated in ordinal order,
+        are the one-device answer, and the psum count is their sum."""
+        pmesh.register_devices(jax.devices("cpu"))
+        m = pmesh.mesh_of(range(width))
+        pubs, msgs, sigs = _signed(b"parts/%d" % width, self.N)
+        for i in (0, BUCKET // width, self.N - 1):
+            _forge(sigs, i)
+        arrays, n, structural = ov.prepare_batch(pubs, msgs, sigs, BUCKET)
+        assert arrays["s_ok"].shape[0] == BUCKET and n == self.N
+        call, _ = pmesh.sharded_verify_call(m, BUCKET, "xla")
+        accept, n_ok = call(*pmesh.device_put_args(arrays, m))
+        parts = sorted(
+            accept.addressable_shards,
+            key=lambda s: pmesh.stable_ordinal(s.device),
+        )
+        assert len(parts) == width
+        assert [s.index[0].start or 0 for s in parts] == [
+            k * (BUCKET // width) for k in range(width)
+        ]
+        joined = np.concatenate([np.asarray(s.data) for s in parts])
+        one = np.asarray(ov.bucket_executable("xla", BUCKET, False)[0](**{
+            k: np.asarray(v) for k, v in arrays.items()
+        }))
+        assert (joined == one).all()
+        assert int(n_ok) == int(joined.sum()) == sum(
+            int(np.asarray(s.data).sum()) for s in parts
+        )
+        assert (joined[:n] & structural[:n]).tolist() == _oracle(pubs, msgs, sigs)
+
+    @pytest.mark.parametrize("width", WIDTHS)
+    def test_a_tampered_commit_names_the_same_index_mesh_on_and_off(
+        self, commit_of_27, served, width
+    ):
+        import copy
+
+        from cometbft_tpu.crypto import sigcache
+        from cometbft_tpu.parallel import elastic
+        from cometbft_tpu.types import validation
+
+        chain_id, vals, bid, height, commit = commit_of_27
+        commit = copy.deepcopy(commit)
+        tampered = BUCKET // width  # the second shard's first lane
+        sig = commit.signatures[tampered].signature
+        commit.signatures[tampered].signature = (
+            sig[:32] + bytes([sig[32] ^ 1]) + sig[33:]
+        )
+        named = []
+        for mesh_on in (True, False):
+            sigcache.reset_cache()
+            served(width) if mesh_on else elastic.clear()
+            with pytest.raises(validation.InvalidSignatureError) as err:
+                validation.verify_commit_light(chain_id, vals, bid, height, commit)
+            named.append(err.value.index)
+        assert named == [tampered, tampered]
+        widths = [s["attrs"].get("mesh") for s in _spans("verify.dispatch")]
+        assert widths == [width, None]
+
+    @pytest.mark.parametrize("width", WIDTHS)
+    def test_the_mesh_takes_a_batch_from_min_batch_up(self, served, width):
+        """One under ``min_batch()`` launches on one device, one at it
+        launches mesh-wide: the ``verify.dispatch`` span says which."""
+        from cometbft_tpu.ops import supervisor
+
+        elastic = served(width)
+        assert elastic.min_batch() == 8
+        for n, want in ((7, None), (8, width)):
+            pubs, msgs, sigs = _signed(b"rule/%d/%d" % (width, n), n)
+            h = supervisor.dispatch_verify(pubs, msgs, sigs)
+            assert h.kind == ("mesh" if want else "chip")
+            assert supervisor.fetch_verify(h).all()
+            assert _spans("verify.dispatch")[-1]["attrs"].get("mesh") == want
+        assert len(_spans("mesh.shard")) == width
+
+    @pytest.mark.parametrize("width", WIDTHS)
+    def test_nothing_compiles_in_a_served_flush_after_the_warm_seam(
+        self, served, width
+    ):
+        """What a node calls at start (``bucket_executable`` for every
+        bucket its traffic can reach) leaves the mesh-wide executable of
+        the host's width resident: a served flush compiles nothing."""
+        from cometbft_tpu.ops import warm_stats
+        from cometbft_tpu.verifysched import service
+
+        served(width)
+        ov.reset_executable_memo()  # a node's start: nothing resolved yet
+        low = ov._min_bucket()
+        reachable = [
+            b for b in ov._BUCKETS if low <= b <= ov.bucket_size(self.N, low)
+        ]
+        assert reachable == [BUCKET]
+        for lanes in reachable:
+            _, info = ov.bucket_executable("xla", lanes)
+            assert list(info["mesh"]) == [pmesh.mesh_tag("xla", width, lanes)]
+        before = warm_stats.snapshot()
+        for lanes in reachable:
+            pubs, msgs, sigs = _signed(b"warm/%d/%d" % (width, lanes), lanes - 5)
+            assert all(service.verify_segment_sync(pubs, msgs, sigs))
+        after = warm_stats.snapshot()
+        assert after["compiles"] == before["compiles"]
+        assert after["exec_misses"] == before["exec_misses"]
+        assert [s["attrs"]["mesh"] for s in _spans("verify.dispatch")] == [width]
+
+    def test_a_bucket_the_mesh_does_not_take_warms_no_mesh_executable(
+        self, served, monkeypatch
+    ):
+        monkeypatch.setenv("COMETBFT_TPU_MESH_MIN_BATCH", "64")
+        served(2)
+        ov.reset_executable_memo()
+        _, info = ov.bucket_executable("xla", BUCKET)
+        assert "mesh" not in info
+
+    def test_a_lost_shard_at_fetch_is_verified_again_and_never_half_answered(
+        self, served
+    ):
+        """Width 2, real devices: ordinal 1's shard fails at the fetch of a
+        served flush.  Every future resolves with the reference's verdicts
+        (the batch verified again, whole, on the one-chip chain: below two
+        chips the ladder ends there), the failure is ordinal 1's alone."""
+        from cometbft_tpu.crypto import backend_health
+        from cometbft_tpu.ops import dispatch_stats
+        from cometbft_tpu.verifysched import service
+
+        elastic = served(2)
+        elastic.set_fault_injector(elastic.FaultyDevice("raise", ordinals=(1,)))
+        pubs, msgs, sigs = _signed(b"lost-shard", self.N)
+        _forge(sigs, 2)
+        _forge(sigs, 20)  # one bad signature in each shard
+        got = service.verify_segment_sync(pubs, msgs, sigs)
+        assert got == _oracle(pubs, msgs, sigs)
+        reg = backend_health.registry()
+        assert reg.breaker("mesh_dev1").stats()["failures_total"] == 1
+        assert reg.breaker("mesh_dev0").stats()["failures_total"] == 0
+        snap = dispatch_stats.snapshot()
+        assert snap["mesh_shrinks"] == 1 and snap["mesh_dispatches"] == 1
+        assert snap["inflight_depth"] == 0
+        # the first launch over the mesh, the second on one device
+        assert [s["attrs"].get("mesh") for s in _spans("verify.dispatch")] == [2, None]
+        # the lost launch's signatures under the tier's name, and the
+        # re-verification's under the chain's (both ``xla`` on this host)
+        assert snap["lane_lanes_used"] == {"xla": 2 * self.N}
+        assert snap["lane_dispatches"] == {"xla": 2}
+        # the next flush finds ordinal 1's breaker open: one device
+        pubs, msgs, sigs = _signed(b"after-the-shrink", self.N)
+        assert all(service.verify_segment_sync(pubs, msgs, sigs))
+        assert _spans("verify.dispatch")[-1]["attrs"].get("mesh") is None
+
+    def test_a_clean_mesh_wide_flush_is_tallied_under_the_tiers_name(self, served):
+        """``offtier_sigs_pct`` looks for the tier's name: a mesh-wide
+        dispatch counts there, the chips' own tallies are the shard
+        histograms."""
+        from cometbft_tpu.ops import dispatch_stats
+        from cometbft_tpu.verifysched import service
+
+        served(4)
+        pubs, msgs, sigs = _signed(b"tally", self.N)
+        assert all(service.verify_segment_sync(pubs, msgs, sigs))
+        snap = dispatch_stats.snapshot()
+        assert snap["lane_lanes_used"] == {"xla": self.N}
+        assert snap["lane_lanes_total"] == {"xla": BUCKET}
+        assert sorted(snap["shard_hist"]) == ["0", "1", "2", "3"]
+        assert (snap["mesh_dispatches"], snap["mesh_shards"]) == (1, 4)
+        assert snap["dispatches"] == 1 and snap["lanes_used"] == self.N

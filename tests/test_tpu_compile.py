@@ -6,7 +6,8 @@ chip that is described and not attached.  Interpret-mode Pallas
 concatenate of the merged decompress passed every interpret-mode test and
 failed to lower for a v5e — so the executables production selects on a TPU
 are compiled here for a described ``v5e:2x2``: one chip at the 128- and
-10,240-lane buckets, and the four-device ``shard_map`` form at 10,240.
+10,240-lane buckets, and the four-device ``shard_map`` form at 10,240,
+8,192 and 256.
 Nothing runs, so this says nothing about verdicts or times; chip_smoke.py
 does that on the chip.
 
@@ -74,15 +75,19 @@ def test_pallas_bucket_compiles_for_one_chip(topo, lanes):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_pallas_mesh_compiles_for_four_chips(topo):
-    """What ``sharded_verify_call`` builds on a four-chip host at the
-    10,240-lane commit bucket: the kernel inside ``shard_map`` and the one
-    ``psum`` as an all-reduce."""
+@pytest.mark.parametrize("lanes", [10240, 8192, 256])
+def test_pallas_mesh_compiles_for_four_chips(topo, lanes):
+    """What ``sharded_verify_call`` builds on a four-chip host: the kernel
+    inside ``shard_map`` and the one ``psum`` as an all-reduce.  At the
+    10,240-lane commit bucket; at 8,192, the light prefix of that commit
+    and what the served path launches for it (2,048 lanes a chip); and at
+    256, the smallest bucket the mesh takes (``elastic.min_batch()``), 64
+    lanes a chip, under the kernel's tile."""
     mesh = Mesh(np.array(topo.devices), (pmesh.SIG_AXIS,))
     assert mesh.devices.size == 4
     jitted, _ = pmesh.sharded_verify_fn(mesh, impl="pallas", donated=True)
     two_d, one_d = pmesh.mesh_shardings(mesh)
-    shapes = _shapes(10240, two_d, one_d)
+    shapes = _shapes(lanes, two_d, one_d)
     compiled = jitted.lower(*(shapes[k] for k in pmesh.ARG_ORDER)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text

@@ -1419,6 +1419,44 @@ class TestInflightPipeline:
         snap = tracing.get_tracer().snapshot()
         assert snap["anomalies"].get("shard_watchdog_fire", 0) >= 1
 
+    @pytest.mark.parametrize("n,pinned", [(5, True), (6, False), (14, False)])
+    def test_only_a_flush_the_mesh_does_not_take_is_pinned(
+        self, lane_mesh, monkeypatch, n, pinned
+    ):
+        """ISSUE 34: a flush that reaches ``elastic.min_batch()`` holds
+        every healthy chip, so the scheduler pins nothing (``lane=None``)
+        and the supervisor routes it mesh-wide (here the whole ladder on
+        the oracle runner seam, at fetch); a smaller one is pinned at one
+        lane, round-robin, as before."""
+        from cometbft_tpu.ops import verify as ov
+
+        monkeypatch.setenv("COMETBFT_TPU_MESH_MIN_BATCH", "6")
+        seen = []
+        real = ov.dispatch_segments
+
+        def spy(work, lane=None):
+            h = real(work, lane=lane)
+            seen.append((lane, h.sup.kind))
+            return h
+
+        monkeypatch.setattr(ov, "dispatch_segments", spy)
+        pubs, msgs, sigs = _make_sigs(n, b"pin-%d" % n, invalid_every=4)
+        sched = VerifyScheduler(flush_us=300)
+        try:
+            got = _verdicts(_segment(sched, pubs, msgs, sigs), 60)
+            rr = sched._lane_rr
+        finally:
+            sched.close()
+        assert got == _oracle(pubs, msgs, sigs)
+        assert seen == [(0, "lane")] if pinned else seen == [(None, "mesh")]
+        assert rr == (1 if pinned else 0)
+        snap = dispatch_stats.snapshot()
+        assert snap["inflight_depth"] == 0
+        # a pinned flush is one shard, a mesh-wide one a shard a lane
+        assert sum(h["count"] for h in snap["shard_hist"].values()) == (
+            1 if pinned else self.WIDTH
+        )
+
     def test_bucket_target_fallback_clamps_to_bucket(
         self, sched_env, monkeypatch
     ):
