@@ -33,7 +33,8 @@ per dispatch, never per signature):
     dispatcher thread (``sched.flush`` lists the ``traces`` it serves);
     ``sched.fetch``, ``sched.resolve`` — the completion thread, children
     of the flush; ``sched.shed_fallback``
-  * ``verify.pack`` / ``verify.batch`` / ``verify.dispatch`` >
+  * ``verify.pack`` (> ``verify.pack.glue``, ``verify.pack.native``) /
+    ``verify.batch`` / ``verify.dispatch`` >
     ``verify.launch`` / ``verify.fetch`` — bucket dispatch (the dispatch
     span carries bucket lanes + tier + dispatch seq: the triple an anomaly
     dump attributes a watchdog fire to)

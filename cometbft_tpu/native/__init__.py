@@ -121,8 +121,30 @@ def lib() -> Optional[ctypes.CDLL]:
         cdll.sha512.restype = None
         cdll.sha512.argtypes = [ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p]
         try:
-            # newer symbol — a prebuilt .so from before it existed must
-            # still serve the WAL/packer paths (callers getattr-check)
+            # newer symbols: a prebuilt .so from before they existed must
+            # still serve the WAL paths (callers getattr-check, and
+            # ``prepare_batch`` then packs in Python).  Array arguments
+            # are addresses (``ops/verify._address``), None for no index.
+            vp = ctypes.c_void_p
+            pack_into = [
+                ctypes.c_char_p,  # pubs
+                ctypes.c_char_p,  # sigs
+                ctypes.c_char_p,  # msgs
+                vp,  # message lengths, int64 [n]
+                ctypes.c_int64,  # n
+                vp,  # idx, int64 [n], or None: row i
+                ctypes.c_int64,  # rows of each table
+                vp, vp, vp, vp, vp,  # a, r, s, m [rows, 32]; s_ok [rows]
+            ]
+            cdll.ed25519_pack_into.restype = ctypes.c_int
+            cdll.ed25519_pack_into.argtypes = pack_into
+            cdll.ed25519_pack_into_lanes.restype = ctypes.c_int
+            cdll.ed25519_pack_into_lanes.argtypes = pack_into + [ctypes.c_int]
+            cdll.ed25519_mod_l.restype = None
+            cdll.ed25519_mod_l.argtypes = [ctypes.c_char_p, ctypes.c_char_p]
+        except AttributeError:
+            pass
+        try:
             cdll.commit_sign_bytes.restype = ctypes.c_int64
             cdll.commit_sign_bytes.argtypes = [
                 ctypes.c_char_p, ctypes.c_int64,   # chain_id

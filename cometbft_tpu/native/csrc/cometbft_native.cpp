@@ -8,17 +8,26 @@
 //    SHA-512(R||A||m) mod L and scalar complement per signature
 //    (reference: the curve25519-voi batch preparation the Go code runs
 //    per-signature on the CPU) — C++ so 10k-signature commits don't pay a
-//    Python loop before the kernel launch.
+//    Python loop before the kernel launch.  One pass from the joined
+//    inputs into the padded tables the launch hands to the device
+//    (ed25519_pack_into); SHA-512 four signatures at a time in AVX2
+//    registers where the CPU has them, h mod L by folding at 2^252.
+//    One thread: the caller's.
 //
-// Build: g++ -O3 -shared -fPIC (driven by cometbft_tpu/native/build.py).
+// Build: g++ -O3 -shared -fPIC -std=c++17, no -march (driven by
+// cometbft_tpu/native/__init__.py); what needs AVX2 says so itself.
 
 #include <cstdint>
 #include <cstdio>
 #include <cstring>
 #include <mutex>
+#include <vector>
 #include <cstdlib>
 #include <fcntl.h>
 #include <unistd.h>
+#if defined(__x86_64__) && defined(__GNUC__)
+#include <immintrin.h>  // the four-lane SHA-512 below
+#endif
 
 // ---------------------------------------------------------------------------
 // CRC32 (zlib polynomial, matches Python's zlib.crc32)
@@ -156,156 +165,464 @@ static const uint64_t K512[80] = {
     0x431d67c49c100d4cULL, 0x4cc5d4becb3e42b6ULL, 0x597f299cfc657e2aULL,
     0x5fcb6fab3ad6faecULL, 0x6c44198c4a475817ULL};
 
+static const uint64_t SHA512_IV[8] = {
+    0x6a09e667f3bcc908ULL, 0xbb67ae8584caa73bULL, 0x3c6ef372fe94f82bULL,
+    0xa54ff53a5f1d36f1ULL, 0x510e527fade682d1ULL, 0x9b05688c2b3e6c1fULL,
+    0x1f83d9abfb41bd6bULL, 0x5be0cd19137e2179ULL};
+
+static inline uint64_t load_le64(const uint8_t* p) {
+    uint64_t v;
+    memcpy(&v, p, 8);
+#if __BYTE_ORDER__ == __ORDER_BIG_ENDIAN__
+    v = __builtin_bswap64(v);
+#endif
+    return v;
+}
+
+static inline void store_le64(uint8_t* p, uint64_t v) {
+#if __BYTE_ORDER__ == __ORDER_BIG_ENDIAN__
+    v = __builtin_bswap64(v);
+#endif
+    memcpy(p, &v, 8);
+}
+
+static inline uint64_t load_be64(const uint8_t* p) {
+    return __builtin_bswap64(load_le64(p));
+}
+
 static inline uint64_t rotr64(uint64_t x, int n) {
     return (x >> n) | (x << (64 - n));
 }
 
-struct Sha512Ctx {
-    uint64_t h[8];
-    uint8_t buf[128];
-    size_t buf_len;
-    uint64_t total;
+// one round, the eight working variables named by the caller so that a
+// group of eight rounds rotates them by renaming and moves nothing
+#define SHA512_ROUND(a, b, c, d, e, f, g, h, kw)                            \
+    do {                                                                    \
+        uint64_t t1 = h + (rotr64(e, 14) ^ rotr64(e, 18) ^ rotr64(e, 41)) + \
+                      ((e & f) ^ (~e & g)) + (kw);                          \
+        uint64_t t2 = (rotr64(a, 28) ^ rotr64(a, 34) ^ rotr64(a, 39)) +     \
+                      ((a & b) ^ (a & c) ^ (b & c));                        \
+        d += t1;                                                            \
+        h = t1 + t2;                                                        \
+    } while (0)
+
+// the compression function over one 128-byte block, read where it lies
+static void sha512_block(uint64_t st[8], const uint8_t* p) {
+    uint64_t w[16];
+    for (int i = 0; i < 16; i++) w[i] = load_be64(p + 8 * i);
+    uint64_t a = st[0], b = st[1], c = st[2], d = st[3];
+    uint64_t e = st[4], f = st[5], g = st[6], h = st[7];
+    for (int i = 0; i < 80; i += 8) {
+        if (i >= 16) {
+            for (int j = 0; j < 8; j++) {
+                uint64_t w15 = w[(i + j + 1) & 15], w2 = w[(i + j + 14) & 15];
+                w[(i + j) & 15] +=
+                    (rotr64(w15, 1) ^ rotr64(w15, 8) ^ (w15 >> 7)) +
+                    w[(i + j + 9) & 15] +
+                    (rotr64(w2, 19) ^ rotr64(w2, 61) ^ (w2 >> 6));
+            }
+        }
+        const uint64_t* k = K512 + i;
+        const uint64_t* x = w + (i & 15);
+        SHA512_ROUND(a, b, c, d, e, f, g, h, k[0] + x[0]);
+        SHA512_ROUND(h, a, b, c, d, e, f, g, k[1] + x[1]);
+        SHA512_ROUND(g, h, a, b, c, d, e, f, k[2] + x[2]);
+        SHA512_ROUND(f, g, h, a, b, c, d, e, k[3] + x[3]);
+        SHA512_ROUND(e, f, g, h, a, b, c, d, k[4] + x[4]);
+        SHA512_ROUND(d, e, f, g, h, a, b, c, k[5] + x[5]);
+        SHA512_ROUND(c, d, e, f, g, h, a, b, k[6] + x[6]);
+        SHA512_ROUND(b, c, d, e, f, g, h, a, k[7] + x[7]);
+    }
+    st[0] += a; st[1] += b; st[2] += c; st[3] += d;
+    st[4] += e; st[5] += f; st[6] += g; st[7] += h;
+}
+
+// The padded input of one hash, block by block: an optional 64-byte head
+// in two halves (R, A), the body, then the FIPS padding.  The length is
+// known up front, so the padding is laid down in one step and a block
+// that lies whole in the body is hashed where it is.
+struct Padded {
+    const uint8_t* h0;  // head: 32 + 32 bytes, or null for none
+    const uint8_t* h1;
+    const uint8_t* body;
+    size_t head_len;  // 0 or 64
+    size_t body_len;
+    size_t total;  // head_len + body_len
+    size_t nblocks;
 };
 
-static void sha512_init(Sha512Ctx* c) {
-    static const uint64_t iv[8] = {
-        0x6a09e667f3bcc908ULL, 0xbb67ae8584caa73bULL, 0x3c6ef372fe94f82bULL,
-        0xa54ff53a5f1d36f1ULL, 0x510e527fade682d1ULL, 0x9b05688c2b3e6c1fULL,
-        0x1f83d9abfb41bd6bULL, 0x5be0cd19137e2179ULL};
-    memcpy(c->h, iv, sizeof(iv));
-    c->buf_len = 0;
-    c->total = 0;
+static inline Padded padded_of(const uint8_t* h0, const uint8_t* h1,
+                               const uint8_t* body, size_t body_len) {
+    Padded p;
+    p.h0 = h0;
+    p.h1 = h1;
+    p.body = body;
+    p.head_len = h0 ? 64 : 0;
+    p.body_len = body_len;
+    p.total = p.head_len + body_len;
+    p.nblocks = (p.total + 1 + 16 + 127) / 128;  // 0x80, 128-bit length
+    return p;
 }
 
-static void sha512_block(Sha512Ctx* c, const uint8_t* p) {
-    uint64_t w[80];
-    for (int i = 0; i < 16; i++) {
-        w[i] = 0;
-        for (int j = 0; j < 8; j++) w[i] = (w[i] << 8) | p[i * 8 + j];
+// Block k of the padded input: a pointer into the body where the whole
+// block lies in it, else the block assembled in ``scratch``.
+static inline const uint8_t* padded_block(const Padded& p, size_t k,
+                                          uint8_t scratch[128]) {
+    size_t s = 128 * k;  // offset of the block in the unpadded input
+    if (s >= p.head_len && s - p.head_len + 128 <= p.body_len)
+        return p.body + (s - p.head_len);
+    size_t fill = 0;
+    if (s < p.head_len) {  // k == 0 under a head
+        memcpy(scratch, p.h0, 32);
+        memcpy(scratch + 32, p.h1, 32);
+        fill = 64;
     }
-    for (int i = 16; i < 80; i++) {
-        uint64_t s0 = rotr64(w[i - 15], 1) ^ rotr64(w[i - 15], 8) ^ (w[i - 15] >> 7);
-        uint64_t s1 = rotr64(w[i - 2], 19) ^ rotr64(w[i - 2], 61) ^ (w[i - 2] >> 6);
-        w[i] = w[i - 16] + s0 + w[i - 7] + s1;
+    size_t boff = s + fill - p.head_len;
+    if (boff < p.body_len) {
+        size_t take = p.body_len - boff;
+        if (take > 128 - fill) take = 128 - fill;
+        memcpy(scratch + fill, p.body + boff, take);
+        fill += take;
     }
-    uint64_t a = c->h[0], b = c->h[1], cc = c->h[2], d = c->h[3];
-    uint64_t e = c->h[4], f = c->h[5], g = c->h[6], hh = c->h[7];
-    for (int i = 0; i < 80; i++) {
-        uint64_t S1 = rotr64(e, 14) ^ rotr64(e, 18) ^ rotr64(e, 41);
-        uint64_t ch = (e & f) ^ (~e & g);
-        uint64_t t1 = hh + S1 + ch + K512[i] + w[i];
-        uint64_t S0 = rotr64(a, 28) ^ rotr64(a, 34) ^ rotr64(a, 39);
-        uint64_t maj = (a & b) ^ (a & cc) ^ (b & cc);
-        uint64_t t2 = S0 + maj;
-        hh = g; g = f; f = e; e = d + t1;
-        d = cc; cc = b; b = a; a = t1 + t2;
-    }
-    c->h[0] += a; c->h[1] += b; c->h[2] += cc; c->h[3] += d;
-    c->h[4] += e; c->h[5] += f; c->h[6] += g; c->h[7] += hh;
-}
-
-static void sha512_update(Sha512Ctx* c, const uint8_t* data, size_t len) {
-    c->total += len;
-    while (len > 0) {
-        size_t take = 128 - c->buf_len;
-        if (take > len) take = len;
-        memcpy(c->buf + c->buf_len, data, take);
-        c->buf_len += take;
-        data += take;
-        len -= take;
-        if (c->buf_len == 128) {
-            sha512_block(c, c->buf);
-            c->buf_len = 0;
+    if (fill < 128) {
+        memset(scratch + fill, 0, 128 - fill);
+        if (s + fill == p.total) scratch[fill] = 0x80;
+        if (k + 1 == p.nblocks) {
+            uint64_t bits = (uint64_t)p.total * 8;
+            for (int i = 0; i < 8; i++)
+                scratch[127 - i] = (uint8_t)(bits >> (8 * i));
         }
     }
+    return scratch;
 }
 
-static void sha512_final(Sha512Ctx* c, uint8_t out[64]) {
-    uint64_t bits = c->total * 8;
-    uint8_t pad = 0x80;
-    sha512_update(c, &pad, 1);
-    uint8_t zero = 0;
-    while (c->buf_len != 112) sha512_update(c, &zero, 1);
-    uint8_t lenbuf[16] = {0};
-    for (int i = 0; i < 8; i++) lenbuf[15 - i] = (bits >> (8 * i)) & 0xFF;
-    sha512_update(c, lenbuf, 16);
+// the hash state of one padded input
+static void sha512_padded(const Padded& p, uint64_t st[8]) {
+    uint8_t scratch[128];
+    memcpy(st, SHA512_IV, sizeof(SHA512_IV));
+    for (size_t k = 0; k < p.nblocks; k++)
+        sha512_block(st, padded_block(p, k, scratch));
+}
+
+// Four inputs of one block count hashed side by side, one 64-bit lane of
+// an AVX2 register each.  Compiled for AVX2 whatever the build's flags
+// are and called only where the CPU has it (``cpu_has_wide``).
+#if defined(__x86_64__) && defined(__GNUC__)
+#define SHA512_HAVE_X4 1
+
+#define X4_TARGET __attribute__((target("avx2")))
+
+#define x4_rotr(x, n) \
+    _mm256_or_si256(_mm256_srli_epi64(x, n), _mm256_slli_epi64(x, 64 - (n)))
+
+X4_TARGET static inline __m256i x4_xor3(__m256i a, __m256i b, __m256i c) {
+    return _mm256_xor_si256(_mm256_xor_si256(a, b), c);
+}
+
+#define X4_ROUND(a, b, c, d, e, f, g, h, kw)                                 \
+    do {                                                                     \
+        __m256i S1 = x4_xor3(x4_rotr(e, 14), x4_rotr(e, 18), x4_rotr(e, 41)); \
+        __m256i ch = _mm256_xor_si256(                                       \
+            g, _mm256_and_si256(e, _mm256_xor_si256(f, g)));                 \
+        __m256i t1 = _mm256_add_epi64(                                       \
+            _mm256_add_epi64(h, S1), _mm256_add_epi64(ch, (kw)));            \
+        __m256i S0 = x4_xor3(x4_rotr(a, 28), x4_rotr(a, 34), x4_rotr(a, 39)); \
+        __m256i maj = _mm256_or_si256(                                       \
+            _mm256_and_si256(a, b),                                          \
+            _mm256_and_si256(c, _mm256_or_si256(a, b)));                     \
+        d = _mm256_add_epi64(d, t1);                                         \
+        h = _mm256_add_epi64(t1, _mm256_add_epi64(S0, maj));                 \
+    } while (0)
+
+X4_TARGET static void sha512_block_x4(__m256i st[8],
+                                      const uint8_t* const p[4]) {
+    // big-endian words of four blocks, transposed: w[i] holds word i of
+    // each block
+    const __m256i bswap = _mm256_setr_epi8(
+        7, 6, 5, 4, 3, 2, 1, 0, 15, 14, 13, 12, 11, 10, 9, 8,
+        7, 6, 5, 4, 3, 2, 1, 0, 15, 14, 13, 12, 11, 10, 9, 8);
+    __m256i w[16];
+    for (int q = 0; q < 4; q++) {
+        __m256i x0 = _mm256_shuffle_epi8(
+            _mm256_loadu_si256((const __m256i*)(p[0] + 32 * q)), bswap);
+        __m256i x1 = _mm256_shuffle_epi8(
+            _mm256_loadu_si256((const __m256i*)(p[1] + 32 * q)), bswap);
+        __m256i x2 = _mm256_shuffle_epi8(
+            _mm256_loadu_si256((const __m256i*)(p[2] + 32 * q)), bswap);
+        __m256i x3 = _mm256_shuffle_epi8(
+            _mm256_loadu_si256((const __m256i*)(p[3] + 32 * q)), bswap);
+        __m256i t0 = _mm256_unpacklo_epi64(x0, x1);
+        __m256i t1 = _mm256_unpackhi_epi64(x0, x1);
+        __m256i t2 = _mm256_unpacklo_epi64(x2, x3);
+        __m256i t3 = _mm256_unpackhi_epi64(x2, x3);
+        w[4 * q + 0] = _mm256_permute2x128_si256(t0, t2, 0x20);
+        w[4 * q + 1] = _mm256_permute2x128_si256(t1, t3, 0x20);
+        w[4 * q + 2] = _mm256_permute2x128_si256(t0, t2, 0x31);
+        w[4 * q + 3] = _mm256_permute2x128_si256(t1, t3, 0x31);
+    }
+    __m256i a = st[0], b = st[1], c = st[2], d = st[3];
+    __m256i e = st[4], f = st[5], g = st[6], h = st[7];
+    for (int i = 0; i < 80; i += 8) {
+        if (i >= 16) {
+            for (int j = 0; j < 8; j++) {
+                __m256i w15 = w[(i + j + 1) & 15], w2 = w[(i + j + 14) & 15];
+                __m256i s0 = x4_xor3(x4_rotr(w15, 1), x4_rotr(w15, 8),
+                                     _mm256_srli_epi64(w15, 7));
+                __m256i s1 = x4_xor3(x4_rotr(w2, 19), x4_rotr(w2, 61),
+                                     _mm256_srli_epi64(w2, 6));
+                w[(i + j) & 15] = _mm256_add_epi64(
+                    _mm256_add_epi64(w[(i + j) & 15], s0),
+                    _mm256_add_epi64(w[(i + j + 9) & 15], s1));
+            }
+        }
+        const uint64_t* k = K512 + i;
+        const __m256i* x = w + (i & 15);
+#define X4_KW(j) \
+    _mm256_add_epi64(_mm256_set1_epi64x((long long)k[j]), x[j])
+        X4_ROUND(a, b, c, d, e, f, g, h, X4_KW(0));
+        X4_ROUND(h, a, b, c, d, e, f, g, X4_KW(1));
+        X4_ROUND(g, h, a, b, c, d, e, f, X4_KW(2));
+        X4_ROUND(f, g, h, a, b, c, d, e, X4_KW(3));
+        X4_ROUND(e, f, g, h, a, b, c, d, X4_KW(4));
+        X4_ROUND(d, e, f, g, h, a, b, c, X4_KW(5));
+        X4_ROUND(c, d, e, f, g, h, a, b, X4_KW(6));
+        X4_ROUND(b, c, d, e, f, g, h, a, X4_KW(7));
+#undef X4_KW
+    }
+    st[0] = _mm256_add_epi64(st[0], a);
+    st[1] = _mm256_add_epi64(st[1], b);
+    st[2] = _mm256_add_epi64(st[2], c);
+    st[3] = _mm256_add_epi64(st[3], d);
+    st[4] = _mm256_add_epi64(st[4], e);
+    st[5] = _mm256_add_epi64(st[5], f);
+    st[6] = _mm256_add_epi64(st[6], g);
+    st[7] = _mm256_add_epi64(st[7], h);
+}
+
+// the hash states of four padded inputs of the SAME block count
+X4_TARGET static void sha512_padded_x4(const Padded p[4], uint64_t st[4][8]) {
+    uint8_t scratch[4][128];
+    __m256i v[8];
     for (int i = 0; i < 8; i++)
-        for (int j = 0; j < 8; j++)
-            out[i * 8 + j] = (c->h[i] >> (56 - 8 * j)) & 0xFF;
-}
-
-// ---------------------------------------------------------------------------
-// mod-L arithmetic (L = 2^252 + 27742317777372353535851937790883648493)
-// ---------------------------------------------------------------------------
-
-// 5-limb little-endian u64 bignum (320 bits of headroom)
-typedef uint64_t bn5[5];
-
-static const bn5 L_BN = {0x5812631a5cf5d3edULL, 0x14def9dea2f79cd6ULL,
-                         0x0000000000000000ULL, 0x1000000000000000ULL, 0};
-
-static int bn_cmp(const bn5 a, const bn5 b) {
-    for (int i = 4; i >= 0; i--) {
-        if (a[i] < b[i]) return -1;
-        if (a[i] > b[i]) return 1;
+        v[i] = _mm256_set1_epi64x((long long)SHA512_IV[i]);
+    for (size_t k = 0; k < p[0].nblocks; k++) {
+        const uint8_t* blk[4];
+        for (int j = 0; j < 4; j++)
+            blk[j] = padded_block(p[j], k, scratch[j]);
+        sha512_block_x4(v, blk);
     }
-    return 0;
+    uint64_t lanes[8][4];
+    for (int i = 0; i < 8; i++)
+        _mm256_storeu_si256((__m256i*)lanes[i], v[i]);
+    for (int j = 0; j < 4; j++)
+        for (int i = 0; i < 8; i++) st[j][i] = lanes[i][j];
 }
 
-static void bn_sub(bn5 a, const bn5 b) {  // a -= b (a >= b)
-    unsigned __int128 borrow = 0;
-    for (int i = 0; i < 5; i++) {
-        unsigned __int128 d =
-            (unsigned __int128)a[i] - b[i] - borrow;
+static bool cpu_has_wide() {
+    static const bool has = __builtin_cpu_supports("avx2");
+    return has;
+}
+#else
+static bool cpu_has_wide() { return false; }
+#endif
+
+// ---------------------------------------------------------------------------
+// mod-L arithmetic (L = 2^252 + c, c = 27742317777372353535851937790883648493)
+// ---------------------------------------------------------------------------
+
+// little-endian u64 limbs throughout; c is 125 bits, two limbs
+static const uint64_t LC0 = 0x5812631a5cf5d3edULL;
+static const uint64_t LC1 = 0x14def9dea2f79cd6ULL;
+static const uint64_t L_LIMBS[4] = {LC0, LC1, 0, 0x1000000000000000ULL};
+static const uint64_t MASK60 = 0x0fffffffffffffffULL;
+
+typedef unsigned __int128 u128;
+
+static inline bool ge256(const uint64_t a[4], const uint64_t b[4]) {
+    for (int i = 3; i >= 0; i--)
+        if (a[i] != b[i]) return a[i] > b[i];
+    return true;
+}
+
+static inline void add256(uint64_t a[4], const uint64_t b[4]) {  // mod 2^256
+    u128 carry = 0;
+    for (int i = 0; i < 4; i++) {
+        carry += (u128)a[i] + b[i];
+        a[i] = (uint64_t)carry;
+        carry >>= 64;
+    }
+}
+
+static inline void sub256(uint64_t a[4], const uint64_t b[4]) {  // mod 2^256
+    uint64_t borrow = 0;
+    for (int i = 0; i < 4; i++) {
+        u128 d = (u128)a[i] - b[i] - borrow;
         a[i] = (uint64_t)d;
-        borrow = (d >> 64) ? 1 : 0;
+        borrow = (uint64_t)(d >> 64) & 1;
     }
 }
 
-static void bn_mul_small(bn5 out, const bn5 a, uint64_t k) {  // out = a*k
-    unsigned __int128 carry = 0;
-    for (int i = 0; i < 5; i++) {
-        unsigned __int128 p = (unsigned __int128)a[i] * k + carry;
-        out[i] = (uint64_t)p;
-        carry = p >> 64;
+// out[N + 2] = a[N] * c
+template <int N>
+static inline void mul_c(const uint64_t* a, uint64_t* out) {
+    u128 carry = 0;
+    for (int i = 0; i < N; i++) {
+        carry += (u128)a[i] * LC0;
+        out[i] = (uint64_t)carry;
+        carry >>= 64;
     }
+    out[N] = (uint64_t)carry;
+    carry = 0;
+    for (int i = 0; i < N; i++) {
+        carry += (u128)a[i] * LC1 + out[i + 1];
+        out[i + 1] = (uint64_t)carry;
+        carry >>= 64;
+    }
+    out[N + 1] = (uint64_t)carry;
 }
 
-// r = r*256 + byte, then reduce mod L (r stays < L)
-static void bn_horner_step(bn5 r, uint8_t byte) {
-    // shift left 8 bits
-    uint64_t carry = byte;
-    for (int i = 0; i < 5; i++) {
-        unsigned __int128 v = ((unsigned __int128)r[i] << 8) | carry;
-        r[i] = (uint64_t)v;
-        carry = (uint64_t)(v >> 64);
-    }
-    // r < 256*L < 2^261; estimate q = r >> 252 and subtract q*L.  Since
-    // L > 2^252 the estimate can overshoot by one — detect and back off.
-    uint64_t q = (r[3] >> 60) | (r[4] << 4);
-    if (q) {
-        bn5 qL;
-        bn_mul_small(qL, L_BN, q);
-        if (bn_cmp(r, qL) < 0) bn_mul_small(qL, L_BN, q - 1);
-        bn_sub(r, qL);
-    }
-    while (bn_cmp(r, L_BN) >= 0) bn_sub(r, L_BN);
+// x[N] cut at bit 252: lo[4] the low 252 bits, hi[N - 3] the rest
+template <int N>
+static inline void split252(const uint64_t* x, uint64_t lo[4], uint64_t* hi) {
+    lo[0] = x[0];
+    lo[1] = x[1];
+    lo[2] = x[2];
+    lo[3] = x[3] & MASK60;
+    for (int j = 0; j < N - 4; j++) hi[j] = (x[3 + j] >> 60) | (x[4 + j] << 4);
+    hi[N - 4] = x[N - 1] >> 60;
 }
 
-static void bn_from_le64(bn5 r, const uint8_t h[64]) {  // h mod L
-    memset(r, 0, sizeof(bn5));
-    for (int i = 63; i >= 0; i--) bn_horner_step(r, h[i]);
+// r = x mod L for a 512-bit x, in three folds at 2^252: with
+// x = hi * 2^252 + lo and 2^252 = -c (mod L), x = lo - hi * c.  Each fold
+// takes 127 bits off the product (260 -> 133 -> 6 bits of ``hi``), so
+//   x = lo1 - lo2 + lo3 - hi3 * c   (mod L),
+// every term under 2^252 and the last under 2^131; 2L is added first so
+// that the sum stays positive, and what is left is under 4L.
+static void mod_l_512(const uint64_t x[8], uint64_t r[4]) {
+    uint64_t lo1[4], hi1[5], p1[7];
+    split252<8>(x, lo1, hi1);
+    mul_c<5>(hi1, p1);  // under 2^385
+    uint64_t lo2[4], hi2[4], p2[5];
+    split252<7>(p1, lo2, hi2);  // hi2 under 2^133: hi2[3] is 0
+    mul_c<3>(hi2, p2);  // under 2^258
+    uint64_t lo3[4], hi3[2], p3[4];
+    split252<5>(p2, lo3, hi3);  // hi3 under 2^6: hi3[1] is 0
+    mul_c<1>(hi3, p3);  // under 2^131
+    p3[3] = 0;
+    memcpy(r, L_LIMBS, sizeof(L_LIMBS));
+    add256(r, L_LIMBS);
+    add256(r, lo1);
+    add256(r, lo3);
+    sub256(r, lo2);
+    sub256(r, p3);
+    while (ge256(r, L_LIMBS)) sub256(r, L_LIMBS);
 }
 
-static void bn_to_le32(const bn5 r, uint8_t out[32]) {
-    for (int i = 0; i < 4; i++)
-        for (int j = 0; j < 8; j++)
-            out[i * 8 + j] = (r[i] >> (8 * j)) & 0xFF;
+// the digest as the little-endian integer ed25519 reads it: limb j is
+// state word j with its bytes in the other order
+static inline void digest_limbs(const uint64_t st[8], uint64_t x[8]) {
+    for (int i = 0; i < 8; i++) x[i] = __builtin_bswap64(st[i]);
 }
 
 // ---------------------------------------------------------------------------
 // Ed25519 batch packer
 // ---------------------------------------------------------------------------
+
+namespace {
+
+// Where one signature's outputs go: five row-major tables of 32-byte rows
+// (``ok``: one byte a row), ``a`` and ``r`` optional.
+struct PackOut {
+    uint8_t* a;
+    uint8_t* r;
+    uint8_t* s;
+    uint8_t* m;
+    uint8_t* ok;
+};
+
+// everything of one signature but the hash: ``st`` is SHA-512(R || A || msg)
+static inline void pack_finish(const uint8_t* pub, const uint8_t* sig,
+                               const uint64_t st[8], const PackOut& o,
+                               int64_t row) {
+    if (o.a) memcpy(o.a + row * 32, pub, 32);
+    if (o.r) memcpy(o.r + row * 32, sig, 32);
+
+    // s < L, else the lane carries s = 0 and fails
+    uint64_t s[4];
+    for (int i = 0; i < 4; i++) s[i] = load_le64(sig + 32 + 8 * i);
+    bool s_ok = !ge256(s, L_LIMBS);
+    o.ok[row] = (uint8_t)s_ok;
+    if (s_ok)
+        memcpy(o.s + row * 32, sig + 32, 32);
+    else
+        memset(o.s + row * 32, 0, 32);
+
+    // m = (L - h) mod L, h = digest mod L
+    uint64_t x[8], h[4], m[4] = {0, 0, 0, 0};
+    digest_limbs(st, x);
+    mod_l_512(x, h);
+    if (h[0] | h[1] | h[2] | h[3]) {
+        memcpy(m, L_LIMBS, sizeof(m));
+        sub256(m, h);
+    }
+    for (int i = 0; i < 4; i++) store_le64(o.m + row * 32 + 8 * i, m[i]);
+}
+
+// The pack: signature i (pubs i*32, sigs i*64, the next len[i] bytes of
+// msgs) to row idx[i], or row i without an index.  ``wide``: hash four
+// neighbours of one block count side by side where the CPU can.  -1,
+// before anything is written, where a row would lie outside the tables.
+static int pack_rows(const uint8_t* pubs, const uint8_t* sigs,
+                     const uint8_t* msgs, const int64_t* len, int64_t n,
+                     const int64_t* idx, int64_t rows, const PackOut& o,
+                     bool wide) {
+    if (n < 0 || rows < 0 || (!idx && n > rows)) return -1;
+    for (int64_t i = 0; i < n; i++) {
+        if (len[i] < 0) return -1;
+        if (idx && (idx[i] < 0 || idx[i] >= rows)) return -1;
+    }
+    auto input = [&](int64_t i, int64_t at) {
+        return padded_of(sigs + i * 64, pubs + i * 32, msgs + at,
+                         (size_t)len[i]);
+    };
+    auto finish = [&](int64_t i, const uint64_t st[8]) {
+        pack_finish(pubs + i * 32, sigs + i * 64, st, o, idx ? idx[i] : i);
+    };
+    int64_t i = 0, at = 0;  // message i starts at msgs + at
+#ifdef SHA512_HAVE_X4
+    if (wide && cpu_has_wide()) {
+        while (i + 4 <= n) {
+            int64_t at1 = at + len[i], at2 = at1 + len[i + 1],
+                    at3 = at2 + len[i + 2];
+            Padded p[4] = {input(i, at), input(i + 1, at1),
+                           input(i + 2, at2), input(i + 3, at3)};
+            uint64_t st[4][8];
+            if (p[1].nblocks == p[0].nblocks && p[2].nblocks == p[0].nblocks &&
+                p[3].nblocks == p[0].nblocks) {
+                sha512_padded_x4(p, st);
+                for (int j = 0; j < 4; j++) finish(i + j, st[j]);
+                at = at3 + len[i + 3];
+                i += 4;
+            } else {  // a neighbour of another length: this one alone
+                sha512_padded(p[0], st[0]);
+                finish(i, st[0]);
+                at = at1;
+                i += 1;
+            }
+        }
+    }
+#else
+    (void)wide;
+#endif
+    for (; i < n; at += len[i], i++) {
+        uint64_t st[8];
+        sha512_padded(input(i, at), st);
+        finish(i, st);
+    }
+    return 0;
+}
+
+}  // namespace
 
 extern "C" {
 
@@ -316,52 +633,55 @@ extern "C" {
 int ed25519_pack(const uint8_t* pubs, const uint8_t* sigs,
                  const uint8_t* msgs, const int64_t* msg_off, int64_t n,
                  uint8_t* s_out, uint8_t* m_out, uint8_t* s_ok_out) {
-    for (int64_t i = 0; i < n; i++) {
-        const uint8_t* pub = pubs + i * 32;
-        const uint8_t* r_enc = sigs + i * 64;
-        const uint8_t* s_enc = sigs + i * 64 + 32;
+    if (n <= 0) return n < 0 ? -1 : 0;
+    std::vector<int64_t> len((size_t)n);
+    for (int64_t i = 0; i < n; i++) len[i] = msg_off[i + 1] - msg_off[i];
+    PackOut o = {nullptr, nullptr, s_out, m_out, s_ok_out};
+    return pack_rows(pubs, sigs, msgs + msg_off[0], len.data(), n, nullptr, n,
+                     o, true);
+}
 
-        // s < L check (little-endian compare)
-        bn5 s_bn = {0, 0, 0, 0, 0};
-        for (int w = 0; w < 4; w++)
-            for (int b = 7; b >= 0; b--)
-                s_bn[w] = (s_bn[w] << 8) | s_enc[w * 8 + (b)];
-        int s_ok = bn_cmp(s_bn, L_BN) < 0;
-        s_ok_out[i] = (uint8_t)s_ok;
-        if (s_ok)
-            memcpy(s_out + i * 32, s_enc, 32);
-        else
-            memset(s_out + i * 32, 0, 32);
+// The same pack written where the launch reads it: signature i, whose
+// message is the next msg_len[i] bytes of msgs, goes to row idx[i] (row i
+// where ``idx`` is null) of the caller's zeroed ``rows`` x 32 tables a
+// (the key), r, s (zero where s >= L), m and of ``s_ok`` (``rows``
+// bytes); rows no signature names stay as they were.  Returns -1, with
+// nothing written, where a row lies outside the tables.
+int ed25519_pack_into(const uint8_t* pubs, const uint8_t* sigs,
+                      const uint8_t* msgs, const int64_t* msg_len, int64_t n,
+                      const int64_t* idx, int64_t rows, uint8_t* a_rows,
+                      uint8_t* r_rows, uint8_t* s_rows, uint8_t* m_rows,
+                      uint8_t* s_ok_rows) {
+    PackOut o = {a_rows, r_rows, s_rows, m_rows, s_ok_rows};
+    return pack_rows(pubs, sigs, msgs, msg_len, n, idx, rows, o, true);
+}
 
-        // h = SHA512(R || A || m) mod L;  m_scalar = (L - h) mod L
-        Sha512Ctx ctx;
-        sha512_init(&ctx);
-        sha512_update(&ctx, r_enc, 32);
-        sha512_update(&ctx, pub, 32);
-        sha512_update(&ctx, msgs + msg_off[i],
-                      (size_t)(msg_off[i + 1] - msg_off[i]));
-        uint8_t digest[64];
-        sha512_final(&ctx, digest);
-        bn5 h_bn;
-        bn_from_le64(h_bn, digest);
-        bn5 m_bn;
-        memcpy(m_bn, L_BN, sizeof(bn5));
-        if (h_bn[0] | h_bn[1] | h_bn[2] | h_bn[3] | h_bn[4]) {
-            bn_sub(m_bn, h_bn);
-        } else {
-            memset(m_bn, 0, sizeof(bn5));
-        }
-        bn_to_le32(m_bn, m_out + i * 32);
-    }
-    return 0;
+// for tests: ``ed25519_pack_into`` with the block function named: lanes 1
+// the scalar one, 4 the four-lane one; -2 where this CPU has no such one
+int ed25519_pack_into_lanes(const uint8_t* pubs, const uint8_t* sigs,
+                            const uint8_t* msgs, const int64_t* msg_len,
+                            int64_t n, const int64_t* idx, int64_t rows,
+                            uint8_t* a_rows, uint8_t* r_rows, uint8_t* s_rows,
+                            uint8_t* m_rows, uint8_t* s_ok_rows, int lanes) {
+    if (lanes != 1 && !(lanes == 4 && cpu_has_wide())) return -2;
+    PackOut o = {a_rows, r_rows, s_rows, m_rows, s_ok_rows};
+    return pack_rows(pubs, sigs, msgs, msg_len, n, idx, rows, o, lanes == 4);
+}
+
+// for tests: x (64 bytes, little-endian) mod L, 32 bytes little-endian
+void ed25519_mod_l(const uint8_t* x64, uint8_t* out32) {
+    uint64_t x[8], r[4];
+    for (int i = 0; i < 8; i++) x[i] = load_le64(x64 + 8 * i);
+    mod_l_512(x, r);
+    for (int i = 0; i < 4; i++) store_le64(out32 + 8 * i, r[i]);
 }
 
 // standalone SHA-512 for tests
 void sha512(const uint8_t* data, int64_t len, uint8_t* out64) {
-    Sha512Ctx c;
-    sha512_init(&c);
-    sha512_update(&c, data, (size_t)len);
-    sha512_final(&c, out64);
+    uint64_t st[8];
+    sha512_padded(padded_of(nullptr, nullptr, data, (size_t)len), st);
+    for (int i = 0; i < 8; i++)
+        store_le64(out64 + 8 * i, __builtin_bswap64(st[i]));
 }
 
 }  // extern "C"

@@ -4,8 +4,9 @@
 // Reference discipline being mirrored: the Go repo runs its whole test
 // suite with -race (tests.mk:56); the C++ surface here gets the TSAN
 // equivalent — hammer the WAL handle from multiple threads (append,
-// sync, size) and the batch packer concurrently, then verify the WAL
-// contents are a clean sequence of CRC-framed records.
+// sync, size) and the batch packer, the old entry point and the in-place
+// one, concurrently, then verify the WAL contents are a clean sequence of
+// CRC-framed records.
 //
 // Exit code 0 = no sanitizer report and all invariants held.
 
@@ -26,6 +27,11 @@ void wal_close(void* h);
 int ed25519_pack(const uint8_t* pubs, const uint8_t* sigs, const uint8_t* msgs,
                  const int64_t* offs, int64_t n, uint8_t* s_out,
                  uint8_t* m_out, uint8_t* ok_out);
+int ed25519_pack_into(const uint8_t* pubs, const uint8_t* sigs,
+                      const uint8_t* msgs, const int64_t* msg_len, int64_t n,
+                      const int64_t* idx, int64_t rows, uint8_t* a_rows,
+                      uint8_t* r_rows, uint8_t* s_rows, uint8_t* m_rows,
+                      uint8_t* s_ok_rows);
 }
 
 static std::atomic<int> failures{0};
@@ -76,6 +82,71 @@ static void packer(int tid, int iters) {
   }
 }
 
+// The in-place pack beside the old one, into tables allocated at EXACTLY
+// the padded size (the sanitizer's red zone starts at the last row's last
+// byte): message lengths either side of every block-count edge so groups
+// of four, lone neighbours and tails all run; straight rows, then an
+// index that names the first and the last row; a guard row in the middle
+// that no signature names must stay zero, the old symbol must agree on
+// s, m and s_ok, and a row outside the tables must be refused unwritten.
+static void packer_into(int tid, int iters) {
+  const int64_t n = 37, rows = 64;
+  std::vector<int64_t> len(n), idx(n);
+  std::vector<int64_t> offs(n + 1, 0);
+  for (int64_t i = 0; i < n; i++) {
+    static const int64_t edges[] = {0, 47, 48, 122, 122, 122, 122, 175,
+                                    176, 192, 320, 1};
+    len[i] = edges[(i + tid) % 12];
+    offs[i + 1] = offs[i] + len[i];
+    idx[i] = i == 0 ? rows - 1 : i == n - 1 ? 0 : i + 1;  // row 1 unnamed
+  }
+  std::vector<uint8_t> pubs(n * 32), sigs(n * 64), msgs(offs[n] + 1);
+  for (size_t i = 0; i < pubs.size(); i++) pubs[i] = (uint8_t)(i * 7 + tid);
+  for (size_t i = 0; i < sigs.size(); i++) sigs[i] = (uint8_t)(i * 13 + tid);
+  for (size_t i = 0; i < msgs.size(); i++) msgs[i] = (uint8_t)(i * 31 + tid);
+  msgs.resize(offs[n]);  // exactly the bytes the lengths name
+  msgs.shrink_to_fit();
+  std::vector<uint8_t> s_old(n * 32), m_old(n * 32), ok_old(n);
+  for (int it = 0; it < iters; it++) {
+    const bool indexed = it & 1;
+    std::vector<uint8_t> a(rows * 32), r(rows * 32), s(rows * 32),
+        m(rows * 32), ok(rows);
+    if (ed25519_pack_into(pubs.data(), sigs.data(), msgs.data(), len.data(), n,
+                          indexed ? idx.data() : nullptr, rows, a.data(),
+                          r.data(), s.data(), m.data(), ok.data()) != 0 ||
+        ed25519_pack(pubs.data(), sigs.data(), msgs.data(), offs.data(), n,
+                     s_old.data(), m_old.data(), ok_old.data()) != 0) {
+      failures++;
+      continue;
+    }
+    for (int64_t i = 0; i < n; i++) {
+      const int64_t row = indexed ? idx[i] : i;
+      if (std::memcmp(&a[row * 32], &pubs[i * 32], 32) ||
+          std::memcmp(&r[row * 32], &sigs[i * 64], 32) ||
+          std::memcmp(&s[row * 32], &s_old[i * 32], 32) ||
+          std::memcmp(&m[row * 32], &m_old[i * 32], 32) ||
+          ok[row] != ok_old[i])
+        failures++;
+    }
+    // rows no signature names: row 1 under the index, rows n.. without
+    for (int64_t row = indexed ? 1 : n; row < (indexed ? 2 : rows); row++)
+      for (int b = 0; b < 32; b++)
+        if (a[row * 32 + b] | r[row * 32 + b] | s[row * 32 + b] |
+            m[row * 32 + b] | ok[row])
+          failures++;
+    // a row outside the tables: refused, and nothing written
+    std::vector<uint8_t> z(rows * 32), zok(rows);
+    std::vector<int64_t> outside(idx);
+    outside[n / 2] = rows;
+    if (ed25519_pack_into(pubs.data(), sigs.data(), msgs.data(), len.data(), n,
+                          outside.data(), rows, z.data(), z.data(), z.data(),
+                          z.data(), zok.data()) != -1)
+      failures++;
+    for (uint8_t v : z)
+      if (v) failures++;
+  }
+}
+
 int main(int argc, char** argv) {
   const char* path = argc > 1 ? argv[1] : "/tmp/native_stress.wal";
   std::remove(path);
@@ -88,6 +159,7 @@ int main(int argc, char** argv) {
   const int kThreads = 8, kIters = 500;
   for (int t = 0; t < kThreads; t++) ts.emplace_back(wal_writer, h, t, kIters);
   for (int t = 0; t < 4; t++) ts.emplace_back(packer, t, 200);
+  for (int t = 0; t < 4; t++) ts.emplace_back(packer_into, t, 100);
   for (auto& t : ts) t.join();
   wal_sync(h);
   int64_t size = wal_size(h);

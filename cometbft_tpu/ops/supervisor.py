@@ -327,12 +327,22 @@ def supervised_device_call(
 
 
 def _pack(pubs, msgs, sigs, min_b: int):
-    """``prepare_batch`` (host pack, SHA-512) under its span."""
+    """``prepare_batch`` (host pack, SHA-512) under its span, which says
+    which ``path`` packed (``native`` / ``python``) and holds the stage's
+    two halves as children: ``verify.pack.glue`` (buffers, joins, lengths;
+    the whole loop on the Python path) and ``verify.pack.native`` (the
+    sidecar's call)."""
     from cometbft_tpu.ops import verify as ov
 
     with tracing.span("verify.pack", n=len(pubs)) as sp:
-        arrays, n, structural = ov.prepare_batch(pubs, msgs, sigs, min_b)
-        sp.set(lanes=arrays["s_ok"].shape[0], bytes=_nbytes(arrays))
+        arrays, n, structural, how = ov.pack_batch(pubs, msgs, sigs, min_b)
+        sp.set(
+            lanes=arrays["s_ok"].shape[0],
+            bytes=_nbytes(arrays),
+            path=how["path"],
+        )
+        for lp in how["laps"]:
+            lp.record(parent=sp)
     return arrays, n, structural
 
 

@@ -21,6 +21,7 @@ transfer minimal and the host prep trivial.
 from __future__ import annotations
 
 import contextlib
+import ctypes
 import hashlib
 import logging
 import os
@@ -40,6 +41,7 @@ warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable"
 )
 
+from cometbft_tpu.libs import tracing
 from cometbft_tpu.ops import dispatch_stats
 from cometbft_tpu.ops import fe25519 as fe
 from cometbft_tpu.ops import ed25519_point as ep
@@ -374,83 +376,122 @@ def prepare_batch(
     the small-bucket floor.
 
     The per-signature SHA-512 + mod-L math runs in the C++ sidecar when
-    available (cometbft_tpu/native — the host half of the verify pipeline);
-    the Python loop below is the fallback and the differential oracle for it.
+    available (cometbft_tpu/native — the host half of the verify pipeline),
+    one call from the caller's lists into the padded buffers; the Python
+    loop (``_pack_python``) is the fallback and the differential oracle for
+    it.
     """
+    arrays, n, structural, _ = pack_batch(pubs, msgs, sigs, min_bucket)
+    return arrays, n, structural
+
+
+def pack_batch(pubs, msgs, sigs, min_bucket: int = _PALLAS_MIN_BUCKET):
+    """``prepare_batch`` and how it went: a fourth value
+    ``{"path": "native" | "python", "laps": (glue, native)}``, the two
+    halves of the stage timed where they ran (``tracing.lap``; the second
+    never opened on the Python path) for whoever holds the stage's span to
+    record under it (``ops/supervisor._pack``)."""
     n = len(pubs)
     b = bucket_size(max(n, 1), min_bucket)
-    pub_arr = np.zeros((b, 32), np.uint8)
-    r_arr = np.zeros((b, 32), np.uint8)
-    s_bytes = np.zeros((b, 32), np.uint8)
-    m_bytes = np.zeros((b, 32), np.uint8)
-    s_ok = np.zeros((b,), bool)
-    structural = np.zeros((b,), bool)
-
-    native_done = False
-    from cometbft_tpu import native as _native
-
-    nlib = _native.lib()
-    if nlib is not None and n > 0:
-        ok_idx = [
-            i
-            for i in range(n)
-            if len(pubs[i]) == 32 and len(sigs[i]) == 64
-        ]
-        if ok_idx:
-            import ctypes
-
-            k = len(ok_idx)
-            pub_cat = b"".join(pubs[i] for i in ok_idx)
-            sig_cat = b"".join(sigs[i] for i in ok_idx)
-            msg_cat = b"".join(msgs[i] for i in ok_idx)
-            offs = [0]
-            for i in ok_idx:
-                offs.append(offs[-1] + len(msgs[i]))
-            off_arr = (ctypes.c_int64 * (k + 1))(*offs)
-            s_buf = ctypes.create_string_buffer(k * 32)
-            m_buf = ctypes.create_string_buffer(k * 32)
-            ok_buf = ctypes.create_string_buffer(k)
-            rc = nlib.ed25519_pack(
-                pub_cat, sig_cat, msg_cat, off_arr, k, s_buf, m_buf, ok_buf
-            )
-            if rc == 0:
-                idx = np.asarray(ok_idx)
-                structural[idx] = True
-                pub_arr[idx] = np.frombuffer(pub_cat, np.uint8).reshape(k, 32)
-                sig_view = np.frombuffer(sig_cat, np.uint8).reshape(k, 64)
-                r_arr[idx] = sig_view[:, :32]
-                s_bytes[idx] = np.frombuffer(s_buf.raw, np.uint8).reshape(k, 32)
-                m_bytes[idx] = np.frombuffer(m_buf.raw, np.uint8).reshape(k, 32)
-                s_ok[idx] = np.frombuffer(ok_buf.raw, np.uint8).astype(bool)
-                native_done = True
-
-    if not native_done:
-        for i in range(n):
-            pub, msg, sig = pubs[i], msgs[i], sigs[i]
-            if len(pub) != 32 or len(sig) != 64:
-                continue
-            structural[i] = True
-            r_enc, s_enc = sig[:32], sig[32:]
-            s = int.from_bytes(s_enc, "little")
-            s_ok[i] = s < L_INT
-            h = int.from_bytes(
-                hashlib.sha512(r_enc + pub + msg).digest(), "little"
-            ) % L_INT
-            m = (L_INT - h) % L_INT
-            pub_arr[i] = np.frombuffer(pub, np.uint8)
-            r_arr[i] = np.frombuffer(r_enc, np.uint8)
-            if s_ok[i]:
-                s_bytes[i] = np.frombuffer(s_enc, np.uint8)
-            m_bytes[i] = np.frombuffer(m.to_bytes(32, "little"), np.uint8)
-
+    glue = tracing.lap("verify.pack.glue")
+    call = tracing.lap("verify.pack.native")
+    pack_into = _native_pack_into() if n else None
+    with glue:
+        # one allocation, four tables: a, r, s, m
+        bufs = (*np.zeros((4, b, 32), np.uint8), np.zeros((b,), bool))
+        structural = np.zeros((b,), bool)
+        if pack_into is None:
+            _pack_python(pubs, msgs, sigs, bufs, structural)
+        else:
+            args, _alive = _native_args(pubs, msgs, sigs, bufs, structural)
+    path = "python"
+    if pack_into is not None:
+        with call:
+            rc = pack_into(*args)
+        if rc == 0:
+            path = "native"
+        else:  # refused before anything was written: no row can be outside
+            structural[:] = False
+            _pack_python(pubs, msgs, sigs, bufs, structural)
     arrays = dict(
-        a_bytes=pub_arr,
-        r_bytes=r_arr,
-        s_bytes=s_bytes,
-        m_bytes=m_bytes,
-        s_ok=s_ok,
+        zip(("a_bytes", "r_bytes", "s_bytes", "m_bytes", "s_ok"), bufs)
     )
-    return arrays, n, structural
+    return arrays, n, structural, {"path": path, "laps": (glue, call)}
+
+
+def _native_pack_into():
+    """The sidecar's in-place pack, or None: no toolchain, the library
+    switched off, or a library built before the symbol existed."""
+    from cometbft_tpu import native
+
+    return getattr(native.lib(), "ed25519_pack_into", None)
+
+
+def _native_args(pubs, msgs, sigs, bufs, structural) -> tuple:
+    """The arguments of ``ed25519_pack_into`` for n >= 1 triples, and
+    ``structural`` marked.  Where every key is 32 and every signature 64
+    bytes long (asked of the whole lists at once; every commit and vote a
+    node builds) the lists are joined as they are and signature i goes to
+    row i; else the entries of a right length are, with their rows.  The
+    arrays go as addresses: the second value holds what must outlive the
+    call."""
+    n = len(pubs)
+    if (
+        len(msgs) == n
+        and len(sigs) == n
+        and set(map(len, pubs)) == {32}
+        and set(map(len, sigs)) == {64}
+    ):
+        k, rows = n, None
+        structural[:n] = True
+    else:
+        ok = [i for i in range(n) if len(pubs[i]) == 32 and len(sigs[i]) == 64]
+        k, rows = len(ok), np.asarray(ok, np.int64)
+        structural[rows] = True
+        pubs = [pubs[i] for i in ok]
+        msgs = [msgs[i] for i in ok]
+        sigs = [sigs[i] for i in ok]
+    lens = np.fromiter(map(len, msgs), np.int64, k)
+    args = (
+        b"".join(pubs),
+        b"".join(sigs),
+        b"".join(msgs),
+        _address(lens) if k else None,
+        k,
+        None if rows is None or not k else _address(rows),
+        structural.shape[0],
+        *map(_address, bufs),
+    )
+    return args, (lens, rows)
+
+
+def _address(arr: np.ndarray) -> int:
+    """Where a writable array's first byte lies (``arr.ctypes.data`` at a
+    third of its price: the single vote pays this seven times)."""
+    return ctypes.addressof(ctypes.c_char.from_buffer(arr))
+
+
+def _pack_python(pubs, msgs, sigs, bufs, structural) -> None:
+    """The pack a signature at a time: the fallback, and the oracle the
+    sidecar is tested against."""
+    pub_arr, r_arr, s_bytes, m_bytes, s_ok = bufs
+    for i in range(len(pubs)):
+        pub, msg, sig = pubs[i], msgs[i], sigs[i]
+        if len(pub) != 32 or len(sig) != 64:
+            continue
+        structural[i] = True
+        r_enc, s_enc = sig[:32], sig[32:]
+        s = int.from_bytes(s_enc, "little")
+        s_ok[i] = s < L_INT
+        h = int.from_bytes(
+            hashlib.sha512(r_enc + pub + msg).digest(), "little"
+        ) % L_INT
+        m = (L_INT - h) % L_INT
+        pub_arr[i] = np.frombuffer(pub, np.uint8)
+        r_arr[i] = np.frombuffer(r_enc, np.uint8)
+        if s_ok[i]:
+            s_bytes[i] = np.frombuffer(s_enc, np.uint8)
+        m_bytes[i] = np.frombuffer(m.to_bytes(32, "little"), np.uint8)
 
 
 _MESH_PROBED = [False]

@@ -6,8 +6,10 @@
 #   scripts/sanitize_native.sh thread     # one sanitizer only
 #
 # Builds csrc/{cometbft_native,native_stress}.cpp into a standalone
-# binary per sanitizer and runs the concurrent stress driver; any data
-# race / UB report fails the script via the sanitizer's nonzero exit.
+# binary per sanitizer and runs the concurrent stress driver (WAL
+# appends; the batch packer's old entry point and, into tables of exactly
+# the padded size, the in-place one); any data race / out-of-bounds /
+# UB report fails the script via the sanitizer's nonzero exit.
 set -euo pipefail
 cd "$(dirname "$0")/../cometbft_tpu/native/csrc"
 

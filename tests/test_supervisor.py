@@ -326,10 +326,18 @@ class TestFaultDifferential:
         assert list(supervisor.fetch_verify(h)) == _oracle(pubs, msgs, sigs)
         assert len(deadlines) == 2 and launches == [("xla", 32)]
         spans = tracing.get_tracer().tail(20)
-        assert [sp["stage"] for sp in spans] == [
+        # ISSUE 35: the pack says which path packed and how it splits
+        from cometbft_tpu.ops import verify as ov
+
+        path = "python" if ov._native_pack_into() is None else "native"
+        halves = ["verify.pack.glue", "verify.pack.native"][: 1 + (path == "native")]
+        assert [sp["stage"] for sp in spans] == halves + [
             "verify.pack", "verify.launch", "verify.dispatch", "verify.fetch",
         ]
         by = {sp["stage"]: sp for sp in spans}
+        assert by["verify.pack"]["attrs"]["path"] == path
+        for half in halves:
+            assert by[half]["parent"] == by["verify.pack"]["span"]
         assert by["verify.launch"]["parent"] == by["verify.dispatch"]["span"]
         assert by["verify.dispatch"]["attrs"]["pipelined"] is True
         snap = dispatch_stats.snapshot()
