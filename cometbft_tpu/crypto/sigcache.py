@@ -55,6 +55,8 @@ import threading
 from collections import OrderedDict
 from typing import NamedTuple, Optional
 
+from cometbft_tpu.libs import tracing
+
 DEFAULT_CAPACITY = 65536
 
 
@@ -266,18 +268,21 @@ def partition_misses(
         sigs = [sigs[i] for i in cand]
     if not cache.enabled():  # one env read per batch, not per sig
         return Partition(bits, cand, None, 0)
-    keys = cache.hash_keys(pubs, msgs, sigs)
-    got = cache._get_many(keys)
-    if got.count(None) == len(got):  # every fresh commit: nothing to sort
-        return Partition(bits, cand, keys, len(keys))
-    miss: list = []
-    miss_keys: list = []
-    for i, k, hit in zip(cand, keys, got):
-        if hit is None:
-            miss.append(i)
-            miss_keys.append(k)
-        else:
-            bits[i] = hit
+    # one span each a CALL (never a signature): the seam's parts by name
+    with tracing.span("batch.keys"):
+        keys = cache.hash_keys(pubs, msgs, sigs)
+    with tracing.span("batch.lookup"):
+        got = cache._get_many(keys)
+        if got.count(None) == len(got):  # every fresh commit: nothing to sort
+            return Partition(bits, cand, keys, len(keys))
+        miss: list = []
+        miss_keys: list = []
+        for i, k, hit in zip(cand, keys, got):
+            if hit is None:
+                miss.append(i)
+                miss_keys.append(k)
+            else:
+                bits[i] = hit
     return Partition(bits, miss, miss_keys, len(keys))
 
 
@@ -294,17 +299,18 @@ def writeback(part: Partition, results) -> None:
     cache a possibly-valid signature forever, so the hole is left in
     ``bits`` for the caller to surface as an error, never as a verdict."""
     bits, keys = part.bits, part.keys
-    got = [None if r is None else bool(r) for r in results]
-    for i, r in zip(part.miss, got):
-        if r is not None:
-            bits[i] = r
-    if keys is None:
-        return
-    if None in got:
-        judged = [(k, r) for k, r in zip(keys, got) if r is not None]
-        keys, got = [k for k, _ in judged], [r for _, r in judged]
-    if got:
-        get_cache()._put_many(keys, got)
+    with tracing.span("batch.writeback"):
+        got = [None if r is None else bool(r) for r in results]
+        for i, r in zip(part.miss, got):
+            if r is not None:
+                bits[i] = r
+        if keys is None:
+            return
+        if None in got:
+            judged = [(k, r) for k, r in zip(keys, got) if r is not None]
+            keys, got = [k for k, _ in judged], [r for _, r in judged]
+        if got:
+            get_cache()._put_many(keys, got)
 
 
 def verify_with_cache(pub_key, msg: bytes, sig: bytes) -> bool:
@@ -318,8 +324,6 @@ def verify_with_cache(pub_key, msg: bytes, sig: bytes) -> bool:
     (k,) = cache.hash_keys((pub,), (msg,), (sig,))  # once, for get and put
     hit = cache._get(k)
     if hit is not None:
-        from cometbft_tpu.libs import tracing
-
         tracing.mark(hit=True)
         return hit
     ok = bool(pub_key.verify_signature(msg, sig))

@@ -26,18 +26,30 @@ Stage taxonomy (dotted, coarse on the hot path — one span per batch or
 per dispatch, never per signature):
 
   * ``txingest.flush`` / ``txingest.shed_sync``  — batched tx admission
-  * ``verify.commit`` > ``commit.sign_bytes``, ``batch.verify`` >
-    ``sched.segment`` > ``sched.submit``, ``sched.wait`` — the caller
-    thread of one commit verification, public entry to verdict
+  * ``verify.commit`` > ``commit.sign_bytes``, ``batch.verify`` (>
+    ``batch.add``, ``batch.keys``, ``batch.lookup``, ``batch.writeback``:
+    the seam's parts, one span each a call) > ``sched.segment`` >
+    ``sched.submit``, ``sched.wait`` — the caller thread of one commit
+    verification, public entry to verdict
   * ``sched.flush`` > ``sched.slot_wait``, ``sched.dispatch`` — the
-    dispatcher thread (``sched.flush`` lists the ``traces`` it serves);
-    ``sched.fetch``, ``sched.resolve`` — the completion thread, children
-    of the flush; ``sched.shed_fallback``
+    dispatcher thread; ``sched.fetch``, ``sched.landed``,
+    ``sched.resolve`` — the completion thread, children of the flush;
+    ``sched.shed_fallback``
+  * the hand-offs between those threads, each a completed span with one
+    stamp from either thread (``now`` / ``handoff``), recorded by the
+    thread that takes the work up: ``sched.queue`` (callers →
+    dispatcher, child of the flush it ends at), ``sched.handoff.fetch``
+    (dispatcher → completion thread), ``sched.handoff.wake`` (completion
+    thread → the caller back from ``result()``, child of its
+    ``sched.wait``)
   * ``verify.pack`` (> ``verify.pack.glue``, ``verify.pack.native``) /
-    ``verify.batch`` / ``verify.dispatch`` >
-    ``verify.launch`` / ``verify.fetch`` — bucket dispatch (the dispatch
-    span carries bucket lanes + tier + dispatch seq: the triple an anomaly
-    dump attributes a watchdog fire to)
+    ``verify.batch`` / ``verify.dispatch`` > ``verify.launch`` (>
+    ``verify.launch.lookup``, ``verify.launch.put`` or ``mesh.put``,
+    ``verify.launch.call``) / ``verify.fetch`` > ``verify.fetch.pull`` —
+    bucket dispatch (the dispatch span carries bucket lanes + tier +
+    dispatch seq: the triple an anomaly dump attributes a watchdog fire
+    to); what the two watchdogged spans hold beyond their laps is the
+    watchdog's fresh thread and two switches
   * ``supervisor.host_fallback`` / ``supervisor.bisect``
   * ``consensus.vote`` / ``consensus.proposal`` / ``consensus.vote_ext``
     (per height-round)
@@ -60,7 +72,25 @@ sum of durations by the whole second in which its spans ended (the last
 reads them over an interval after the fact, and ``stage_summary`` and the
 ``/debug/verify_trace`` document read the same store.
 
-Profiler bridge: while a ``with`` span is open the tracer also holds a
+Host pressure: when a span's end opens a new second of that store the
+recorder takes one sample of what the machine did to the process (no
+thread of its own, outside the ring lock, nothing with the recorder off or
+on an injected clock) and stores the difference from the last sample under
+pseudo-stages of the same store, ``[1, difference]`` in the second(s) that
+passed: ``host.runq_wait`` (seconds the long-lived threads that opened a
+span, the caller, the dispatcher and the completion thread, spent runnable
+and not running; NOT the watchdog's workers, which live a millisecond and
+take their ``schedstat`` with them), ``host.cpu`` (CPU seconds of the WHOLE
+process, every thread, the workers too), ``host.throttled`` (the cgroup's
+``cpu.stat``), ``host.switches`` and ``host.faults`` (``getrusage``, whole
+process: involuntary switches, minor faults).  A source the host lacks is
+left out, not zero.  ``stage_totals`` and ``stage_seconds`` carry
+them with the stages; ``host_summary`` (the ``host`` of the
+``/debug/verify_trace`` document) gives them apart, and ``stage_summary``
+holds durations only.
+
+Profiler bridge: while a ``with`` span is open AND a profiler is capturing
+(``TraceMe.is_enabled()``: nothing is built otherwise) the tracer also holds a
 ``jax.profiler.TraceAnnotation`` named ``tpubft/<stage>``, so a device
 trace taken over the same interval has every program span on the clock
 of ``XLA Ops``.  The class is taken from ``sys.modules`` when jax is
@@ -69,8 +99,9 @@ watchdog thread is timed there with ``lap`` (which enters the annotation)
 and recorded by the calling thread.
 
 Kill switch: ``COMETBFT_TPU_TRACE=0`` compiles spans down to no-ops (a
-shared null context manager; one env read per span site) — bench.py
-``--obs`` pins the disabled overhead at ≤1% of the sched bench.
+shared null context manager; one look-up in the environment's own mapping
+per span site, one clock read per hand-off) — bench.py ``--obs`` pins the
+disabled overhead at ≤1% of the sched bench.
 
 Cross-node correlation (docs/observability.md "Cross-node tracing"): a
 ``TraceContext`` is the compact (trace_id, span_id, origin-node) triple a
@@ -96,6 +127,7 @@ the thing that initializes an accelerator backend.
 
 from __future__ import annotations
 
+import itertools
 import json
 import logging
 import os
@@ -138,11 +170,21 @@ ANOMALY_KINDS = (
 )
 
 
+# ``os.environ.get`` encodes the key and decodes the value at every call
+# (0.9 us, a fifth of a span, at some thirty span sites a request); the
+# mapping underneath holds both encoded (``os.fsencode``), and a look-up
+# there is a plain dict's.  Read from ``os.environ`` at every call, so a
+# switch flipped while the process runs, or an environment replaced whole,
+# still holds (``tests/test_tracing.py`` pins both).
+_TRACE_KEY = os.fsencode("COMETBFT_TPU_TRACE")
+_TRACE_OFF = os.fsencode("0")
+
+
 def enabled() -> bool:
     """``COMETBFT_TPU_TRACE=0`` is the kill switch; default on.  One dict
     lookup — the only cost a disabled span site pays besides the null
     context manager."""
-    return os.environ.get("COMETBFT_TPU_TRACE", "1") != "0"
+    return os.environ._data.get(_TRACE_KEY) != _TRACE_OFF
 
 
 # -- durable sinks (libs/blackbox.py) -----------------------------------------
@@ -202,14 +244,17 @@ def xnode_enabled() -> bool:
 
 
 _ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is loaded
+_ANNOTATING = None  # its ``is_enabled``: whether any profiler is capturing
 
 
 def _annotate(stage: str):
     """An ENTERED profiler annotation ``tpubft/<stage>`` on this thread, or
-    None while jax is not loaded.  Costs an atomic load when no profiler
-    is open.  Never imports jax: the forensic surfaces that read this
-    module must not be what initializes a backend."""
-    global _ANNOTATION
+    None while jax is not loaded or no profiler is capturing (one call of
+    ``TraceMe.is_enabled``, 0.1 us, where building, entering and leaving an
+    annotation that nothing records costs 0.8).  Never imports jax: the
+    forensic surfaces that read this module must not be what initializes a
+    backend."""
+    global _ANNOTATION, _ANNOTATING
     cls = _ANNOTATION
     if cls is None:
         jax = sys.modules.get("jax")
@@ -217,9 +262,148 @@ def _annotate(stage: str):
         if cls is None:
             return None
         _ANNOTATION = cls
+        _ANNOTATING = getattr(cls, "is_enabled", None)
+    if _ANNOTATING is not None and not _ANNOTATING():
+        return None
     ann = cls(ANNOTATION_PREFIX + stage)
     ann.__enter__()
     return ann
+
+
+# -- host pressure (what the machine did to the process) ----------------------
+#
+# One sample when the per-second store opens a new second, on whichever
+# thread's span opened it: no thread of its own, nothing read while the
+# recorder is off.  Each source is a cumulative reading; the difference from
+# the last sample is stored under a pseudo-stage ``[1, difference]`` in the
+# second(s) that passed, so ``stage_totals`` / ``stage_seconds`` carry it
+# beside the spans: a slow second of ``verify.fetch`` reads next to what the
+# kernel says of the same second.  A source the host lacks is left out, not
+# zero: a sandboxed kernel (gVisor) keeps no ``schedstat``, no ``cpu.stat``
+# and answers ``getrusage`` with zeros, and of the five only the process's
+# CPU time is left there.
+
+HOST_PREFIX = "host."
+# threads whose run-queue wait is read (those that opened a span); a bound,
+# not a census: a node's long-lived verify threads register in its first
+# second
+HOST_THREADS_MAX = 16
+
+
+def _runq_wait_of(tid: int) -> float:
+    """Seconds thread ``tid`` has spent runnable and not running: field 2 of
+    its ``schedstat`` (nanoseconds; needs ``CONFIG_SCHED_INFO``)."""
+    with open(f"/proc/self/task/{tid}/schedstat") as f:
+        return int(f.read().split()[1]) / 1e9
+
+
+class _PerThread:
+    """A per-thread reading summed over the registered threads, cumulative
+    over the DIFFERENCES of each thread's own readings: a thread counts from
+    the sample after it registered (not for its whole life), and one whose
+    reading fails (it has exited) counts no further."""
+
+    def __init__(self, read_one: Callable[[int], float]):
+        self.read_one = read_one
+        self.last: dict = {}
+        self.total = 0.0
+
+    def __call__(self, tids) -> Optional[float]:
+        seen = False
+        for tid in list(tids):
+            try:
+                v = self.read_one(tid)
+            except (OSError, ValueError, IndexError):
+                self.last.pop(tid, None)
+                continue
+            seen = True
+            if tid in self.last:
+                self.total += v - self.last[tid]
+            self.last[tid] = v
+        return self.total if seen else None
+
+
+def _cgroup_cpu_stat() -> "Optional[tuple[str, str, float]]":
+    """``(path, key, seconds per unit)`` of the cgroup's ``cpu.stat`` that
+    holds this process's throttled time: v2 (``throttled_usec``) under the
+    unified mount, v1 (``throttled_time``, nanoseconds) under the ``cpu``
+    controller's; None where neither is there."""
+    rel = {}
+    try:
+        with open("/proc/self/cgroup") as f:
+            for line in f:
+                _, ctrls, path = line.rstrip("\n").split(":", 2)
+                for c in ctrls.split(","):
+                    rel[c] = path
+    except (OSError, ValueError):
+        return None
+    tries = []
+    if "" in rel:
+        tries += [
+            ("/sys/fs/cgroup" + rel[""], "throttled_usec", 1e-6),
+            ("/sys/fs/cgroup/unified" + rel[""], "throttled_usec", 1e-6),
+        ]
+    if "cpu" in rel:
+        tries += [
+            (root + rel["cpu"], "throttled_time", 1e-9)
+            for root in ("/sys/fs/cgroup/cpu", "/sys/fs/cgroup/cpu,cpuacct")
+        ]
+    for d, key, unit in tries:
+        path = os.path.join(d, "cpu.stat")
+        try:
+            with open(path) as f:
+                if any(ln.split()[:1] == [key] for ln in f):
+                    return path, key, unit
+        except OSError:
+            continue
+    return None
+
+
+def _default_host_readers() -> dict:
+    """``{pseudo-stage: reader(tids) -> cumulative value or None}``, only the
+    sources THIS host has; built at the first sample, never at import."""
+    readers: dict = {}
+    try:
+        _runq_wait_of(threading.get_native_id())
+        readers[HOST_PREFIX + "runq_wait"] = _PerThread(_runq_wait_of)
+    except Exception:  # noqa: BLE001 — not this kernel's
+        pass
+    # the WHOLE process's CPU clock: the watchdog's fresh workers (where a
+    # launch's transfers and a fetch's pull run) live a millisecond each and
+    # no once-a-second reading of a thread's own clock would catch them
+    readers[HOST_PREFIX + "cpu"] = lambda tids: time.process_time()
+    try:
+        import resource
+
+        def rusage(field):
+            return lambda tids: float(
+                getattr(resource.getrusage(resource.RUSAGE_SELF), field)
+            )
+
+        # a process that has run this far has faulted pages in: a kernel
+        # that says none keeps no count, of switches either
+        if resource.getrusage(resource.RUSAGE_SELF).ru_minflt > 0:
+            readers[HOST_PREFIX + "switches"] = rusage("ru_nivcsw")
+            readers[HOST_PREFIX + "faults"] = rusage("ru_minflt")
+    except ImportError:  # not a POSIX host
+        pass
+    found = _cgroup_cpu_stat()
+    if found is not None:
+        path, key, unit = found
+
+        def throttled(tids):
+            try:
+                with open(path) as f:
+                    for ln in f:
+                        k, _, v = ln.partition(" ")
+                        if k == key:
+                            return int(v) * unit
+            except (OSError, ValueError):
+                pass
+            return None
+
+        readers[HOST_PREFIX + "throttled"] = throttled
+    return readers
 
 
 class TraceContext:
@@ -462,7 +646,7 @@ class Tracer:
         self._ring: "deque[Span]" = deque(maxlen=max(int(ring_size), 16))
         self._clock: Callable[[], float] = clock or time.perf_counter
         self._tls = threading.local()
-        self._next_id = 1
+        self._ids = itertools.count(1)  # ``next`` is atomic: no lock
         self._recorded = 0
         self._dropped = 0
         self._anomalies: dict = {}
@@ -472,6 +656,15 @@ class Tracer:
         self._overhead_s = 0.0
         # second in which a span ended -> {stage: [count, seconds]}
         self._totals: dict = {}
+        # host pressure: the readers (this host's, built at the first sample
+        # on the default clock; or injected ones), the last sample
+        # ``(second, {stage: cumulative})``, the native ids of the threads
+        # that opened a span
+        self._host_readers: Optional[dict] = None
+        self._host_injected: Optional[dict] = None
+        self._host_last: Optional[tuple] = None
+        self._host_tids: set = set()
+        self._host_lock = threading.Lock()
         # process-LIFETIME aggregates: reset() (sim per-run hygiene) does
         # not clear these, so the tier1-trace summary line still reports
         # the whole test run's span volume and recorder overhead
@@ -487,6 +680,9 @@ class Tracer:
         stack = getattr(self._tls, "stack", None)
         if stack is None:
             stack = self._tls.stack = []
+            # once a thread: its run-queue wait is read from here on
+            if len(self._host_tids) < HOST_THREADS_MAX:
+                self._host_tids.add(threading.get_native_id())
         return stack
 
     def span(self, stage: str, parent=None, **attrs):
@@ -498,9 +694,7 @@ class Tracer:
         no-op span."""
         if not enabled():
             return _NULL_SPAN
-        with self._lock:
-            sid = self._next_id
-            self._next_id += 1
+        sid = next(self._ids)
         if parent is None or parent is _NULL_SPAN:
             stack = self._stack()
             parent = stack[-1] if stack else None
@@ -554,9 +748,7 @@ class Tracer:
         every other explicit-API call accepts None as a no-op."""
         if not enabled():
             return None
-        with self._lock:
-            sid = self._next_id
-            self._next_id += 1
+        sid = next(self._ids)
         if parent is not None:
             trace_id, parent_id = parent.trace_id, parent.span_id
         elif ctx is not None:
@@ -618,9 +810,7 @@ class Tracer:
         machine only knows a step's duration once the next step begins."""
         if not enabled():
             return None
-        with self._lock:
-            sid = self._next_id
-            self._next_id += 1
+        sid = next(self._ids)
         if parent is not None and parent is not _NULL_SPAN:
             trace_id, parent_id = parent.trace_id, parent.span_id
         else:
@@ -648,6 +838,7 @@ class Tracer:
 
     def _append(self, sp: Span) -> None:
         t0 = time.perf_counter()
+        opened = None
         with self._lock:
             if len(self._ring) == self._ring.maxlen:
                 self._dropped += 1
@@ -659,6 +850,7 @@ class Tracer:
             bucket = self._totals.get(sec)
             if bucket is None:
                 bucket = self._totals[sec] = {}
+                opened = sec
                 if len(self._totals) > TOTALS_KEEP_S:
                     for old in [
                         k for k in self._totals if k <= sec - TOTALS_KEEP_S
@@ -676,6 +868,8 @@ class Tracer:
             dt = time.perf_counter() - t0
             self._overhead_s += dt
             self._life_overhead_s += dt
+        if opened is not None:
+            self._sample_host(opened)
         sink = _SINKS["span"]
         if sink is not None:
             # outside the ring lock: the journal enqueue has its own lock
@@ -684,6 +878,82 @@ class Tracer:
                 sink(sp)
             except Exception:  # noqa: BLE001
                 pass
+
+    # -- host pressure -----------------------------------------------------
+
+    def set_host_readers(self, readers: Optional[dict]) -> None:
+        """Swap the host sources (tests): ``{pseudo-stage: reader(tids) ->
+        cumulative value or None}``; ``{}`` samples nothing, None restores
+        this host's own.  Forgets the last sample."""
+        with self._host_lock:
+            self._host_injected = readers
+            self._host_last = None
+
+    def _sample_host(self, sec: int) -> None:
+        """One sample as second ``sec`` opens; the difference from the last
+        sample belongs to the seconds since that one opened and is stored
+        there, ``[1, difference]`` a second (a gap of several seconds, a
+        pause of the whole process, shares it out evenly).  Outside the ring
+        lock but for the store itself; a second sampler at the same moment
+        steps aside, and the next sample covers its seconds."""
+        if not self._host_lock.acquire(blocking=False):
+            return
+        try:
+            readers = self._host_injected
+            if readers is None:
+                if self._clock is not time.perf_counter:
+                    # an injected (virtual) clock: its seconds are not the
+                    # host's, and a sim's record stays a function of its seed
+                    return
+                readers = self._host_readers
+                if readers is None:
+                    readers = self._host_readers = _default_host_readers()
+            now = {}
+            for stage, read in readers.items():
+                try:
+                    v = read(self._host_tids)
+                except Exception:  # noqa: BLE001 — a source, never a failure
+                    v = None
+                if v is not None:
+                    now[stage] = v
+            last, self._host_last = self._host_last, (sec, now)
+            if last is None or sec <= last[0]:
+                return
+            sec0, before = last
+            first = max(sec0, sec - TOTALS_KEEP_S + 1)
+            with self._lock:
+                for stage, v in now.items():
+                    if stage not in before:
+                        continue
+                    share = (v - before[stage]) / (sec - sec0)
+                    for s in range(first, sec):
+                        self._totals.setdefault(s, {})[stage] = [1, share]
+        finally:
+            self._host_lock.release()
+
+    def host_summary(self) -> dict:
+        """``{source: {"seconds", "total", "last"}}`` over the kept seconds:
+        the path's threads' run-queue wait, the process's CPU time and the
+        cgroup's throttled time in seconds, the process's involuntary
+        switches and minor faults as counts; ``last`` is the newest sampled second's.
+        Only the sources this host has."""
+        out: dict = {}
+        with self._lock:
+            for sec in sorted(self._totals):
+                for stage, (n, v) in self._totals[sec].items():
+                    if stage.startswith(HOST_PREFIX):
+                        got = out.setdefault(
+                            stage[len(HOST_PREFIX):],
+                            {"seconds": 0, "total": 0.0, "last": 0.0},
+                        )
+                        got["seconds"] += n
+                        got["total"] += v
+                        got["last"] = v
+        return {
+            k: {"seconds": g["seconds"], "total": round(g["total"], 6),
+                "last": round(g["last"], 6)}
+            for k, g in sorted(out.items())
+        }
 
     # -- anomaly forensics -------------------------------------------------
 
@@ -843,6 +1113,8 @@ class Tracer:
             by_stage.setdefault(sp.stage, []).append(sp.t_end - sp.t_start)
         out = {}
         for stage, (count, seconds) in sorted(self.stage_totals().items()):
+            if stage.startswith(HOST_PREFIX):
+                continue  # not durations: ``host_summary``
             durs = sorted(by_stage.get(stage, ()))
             n = len(durs)
             out[stage] = {
@@ -1016,6 +1288,8 @@ class Tracer:
         span times are virtual and deterministic); None restores
         ``time.perf_counter``."""
         self._clock = clock or time.perf_counter
+        with self._host_lock:
+            self._host_last = None  # no difference across two clocks
 
     def dump_state(self) -> dict:
         """Snapshot of the anomaly-dump latch (first-per-kind set, dump
@@ -1042,7 +1316,7 @@ class Tracer:
         (and therefore dump bytes) are a pure function of the seed."""
         with self._lock:
             self._ring.clear()
-            self._next_id = 1
+            self._ids = itertools.count(1)
             self._recorded = 0
             self._dropped = 0
             self._anomalies = {}
@@ -1051,6 +1325,7 @@ class Tracer:
             self._dumps = []
             self._overhead_s = 0.0
             self._totals = {}
+            self._host_last = None
 
 
 _TRACER: Optional[Tracer] = None
@@ -1087,6 +1362,25 @@ def lap(stage: str) -> Lap:
 
 def current() -> Optional[Span]:
     return get_tracer().current() if enabled() else None
+
+
+def now() -> float:
+    """A stamp on the tracer's clock: the one thing a hand-off between two
+    threads costs with the recorder off.  The thread that takes the work up
+    turns it into a span with ``handoff``."""
+    return get_tracer().time()
+
+
+def handoff(stage: str, t_start: float, parent=None, **attrs):
+    """The hand-off that began at ``t_start`` (``now()`` on the thread that
+    gave the work away) ends here, on the thread that holds it: a completed
+    span, recorded by the receiver."""
+    if not enabled():
+        return None
+    tracer = get_tracer()
+    return tracer.record_span(
+        stage, t_start, tracer.time(), parent=parent, **attrs
+    )
 
 
 def mark(**attrs) -> None:
@@ -1153,6 +1447,8 @@ def trace_document(
     doc = {
         "tracing": tracer.snapshot(),
         "stages": tracer.stage_summary(),
+        # what the machine did to the process in the same seconds
+        "host": tracer.host_summary(),
         # last-K merged consensus-round timelines (cross-node when the
         # fabric propagates contexts); rounds <= 0 skips the section body
         "rounds": tracer.rounds_report(last_k=max(0, int(rounds)) or None)

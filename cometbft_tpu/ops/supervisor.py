@@ -356,28 +356,37 @@ def _nbytes(arrays: dict) -> int:
     return sum(v.nbytes for v in arrays.values())
 
 
-def _launch(backend: str, lanes: int, arrays: dict):
+def _launch(backend: str, lanes: int, arrays: dict, laps):
     """Resolve the bucket's executable, transfer, call: returns the
-    UNFETCHED device array.  Runs on the watchdog worker."""
+    UNFETCHED device array.  Runs on the watchdog worker; ``laps`` time the
+    three (``verify.launch.lookup`` / ``.put`` / ``.call``)."""
     import jax.numpy as jnp
 
     from cometbft_tpu.ops import verify as ov
 
-    call, _ = ov.bucket_executable(backend, lanes)
-    return call(**{k: jnp.asarray(v) for k, v in arrays.items()})
+    lookup, put, called = laps
+    with lookup:
+        call, _ = ov.bucket_executable(backend, lanes)
+    with put:
+        placed = {k: jnp.asarray(v) for k, v in arrays.items()}
+    with called:
+        return call(**placed)
 
 
-def _launch_mesh(backend: str, lanes: int, arrays: dict, mesh, put):
+def _launch_mesh(backend: str, lanes: int, arrays: dict, mesh, laps):
     """The same over a mesh: resolve the sharded executable for this
-    width, place one shard a chip (timed by ``put``), call ONCE.  Returns
-    the UNFETCHED sharded accept bits; the ``psum`` of the per-shard
-    counts stays on the devices.  Runs on the watchdog worker."""
+    width, place one shard a chip (its lap is ``mesh.put``), call ONCE.
+    Returns the UNFETCHED sharded accept bits; the ``psum`` of the
+    per-shard counts stays on the devices.  Runs on the watchdog worker."""
     from cometbft_tpu.parallel import mesh as pmesh
 
-    call, _ = pmesh.sharded_verify_call(mesh, lanes, backend)
+    lookup, put, called = laps
+    with lookup:
+        call, _ = pmesh.sharded_verify_call(mesh, lanes, backend)
     with put:
         placed = pmesh.device_put_args(arrays, mesh)
-    return call(*placed)[0]
+    with called:
+        return call(*placed)[0]
 
 
 def _validate_accept(accept, lanes: int) -> np.ndarray:
@@ -476,7 +485,13 @@ def _launch_verify(h: _InflightVerify, **attrs) -> None:
     inj = _FAULT_INJECTOR if mesh is None else None
     runner = _DEVICE_RUNNER
     launched = tracing.lap("verify.launch")
-    put = tracing.lap("mesh.put")
+    # the launch's three parts, timed where they run; the placement keeps
+    # the name its reader knows on a mesh
+    laps = (
+        tracing.lap("verify.launch.lookup"),
+        tracing.lap("verify.launch.put" if mesh is None else "mesh.put"),
+        tracing.lap("verify.launch.call"),
+    )
 
     def run():
         transform = inj(backend, pubs, msgs, sigs) if inj is not None else None
@@ -484,7 +499,7 @@ def _launch_verify(h: _InflightVerify, **attrs) -> None:
         with launched:
             if mesh is not None:
                 dispatch_stats.record_mesh_dispatch(len(h.mesh))
-                return _launch_mesh(backend, lanes, arrays, mesh, put), None
+                return _launch_mesh(backend, lanes, arrays, mesh, laps), None
             if runner is not None:
                 # device-runner seam (sim/tests): a synchronous stand-in,
                 # whose fetch is then a no-op
@@ -493,7 +508,7 @@ def _launch_verify(h: _InflightVerify, **attrs) -> None:
             # runs INSIDE the watchdog worker: a wedged compile is
             # abandoned like a wedged dispatch, and the device-runner
             # seam above never pays a compile at all
-            return _launch(backend, lanes, arrays), transform
+            return _launch(backend, lanes, arrays, laps), transform
 
     try:
         with tracing.span(
@@ -508,16 +523,17 @@ def _launch_verify(h: _InflightVerify, **attrs) -> None:
             # thread once ``watchdog_call`` has returned: an abandoned
             # worker writes no span
             size = _nbytes(arrays)
+            on_mesh = {} if mesh is None else {"mesh": len(h.mesh)}
+            lsp = launched.record(
+                parent=dsp, tier=backend, lanes=lanes, bytes=size, **on_mesh
+            )
+            lookup, put, called = laps
+            lookup.record(parent=lsp)
             if mesh is None:
-                launched.record(
-                    parent=dsp, tier=backend, lanes=lanes, bytes=size
-                )
+                put.record(parent=lsp)
             else:
-                lsp = launched.record(
-                    parent=dsp, tier=backend, lanes=lanes, bytes=size,
-                    mesh=len(h.mesh),
-                )
                 put.record(parent=lsp, shards=len(h.mesh), bytes=size)
+            called.record(parent=lsp)
     except DispatchTimeoutError:
         # the failed span is already in the ring (the with-block closed),
         # so the dump this triggers shows it as its most recent entry
@@ -532,8 +548,11 @@ def _fetch_launched(h: _InflightVerify) -> np.ndarray:
     device-to-host copy, then (n,) accept bits.  Raises; never returns
     partial results.  ``dispatch_hist`` is fed the fetch wait."""
 
+    pulled = tracing.lap("verify.fetch.pull")
+
     def fetch():
-        a = np.asarray(h.dev)
+        with pulled:
+            a = np.asarray(h.dev)
         return h.transform(a) if h.transform is not None else a
 
     t0 = time.perf_counter()
@@ -553,6 +572,9 @@ def _fetch_launched(h: _InflightVerify) -> np.ndarray:
             )
         else:
             got = watchdog_call(fetch, backend=h.backend)
+            # the copy itself, timed on the watchdog worker: the span less
+            # this lap is what the watchdog's thread and two switches cost
+            pulled.record(parent=fsp)
     dispatch_stats.record_dispatch_time(
         h.backend, h.lanes, tracing.wall_seconds(fsp, t0)
     )

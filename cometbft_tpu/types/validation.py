@@ -305,8 +305,9 @@ def _verify_commit(
             bv = cbatch.create_batch_verifier(entries[0][1].pub_key, backend)
             sign_bytes = _sign_bytes(chain_id, commit, entries)
             with tracing.span("batch.verify", sigs=len(entries)) as bsp:
-                for (idx, val, cs), sb in zip(entries, sign_bytes):
-                    bv.add(val.pub_key, sb, cs.signature)
+                with tracing.span("batch.add"):
+                    for (idx, val, cs), sb in zip(entries, sign_bytes):
+                        bv.add(val.pub_key, sb, cs.signature)
                 ok, bits = bv.verify()
                 bsp.set(
                     hits=getattr(bv, "cache_hits", 0),

@@ -206,7 +206,9 @@ class _Entry:
     # time — recorded as separate histograms so queue pressure and device
     # slowness are distinguishable regressions (docs/observability.md)
     # ctx = the submitter's innermost open span (None outside any): the
-    # flush that serves the entry joins its trace
+    # flush that serves the entry joins its trace.  q0 / q1 = the same two
+    # moments as t0 / t_drain on the TRACER's clock (virtual in sim): the
+    # ends of the ``sched.queue`` span the dispatcher records
     # keys = the triples' sigcache keys as the submitter's look-up hashed
     # them, riding along so that the dedup hashes nothing; None until
     # ``_plan`` keys an entry that brought none (then with None at the
@@ -217,10 +219,12 @@ class _Entry:
     # keys it sent along
     __slots__ = (
         "pubs", "msgs", "sigs", "keys", "puts", "n", "prio", "future", "t0",
-        "t_drain", "ctx", "scalar",
+        "t_drain", "ctx", "scalar", "q0", "q1",
     )
 
-    def __init__(self, pubs, msgs, sigs, keys, puts, prio, t0, ctx, scalar):
+    def __init__(
+        self, pubs, msgs, sigs, keys, puts, prio, t0, ctx, scalar, q0
+    ):
         self.pubs = pubs
         self.msgs = msgs
         self.sigs = sigs
@@ -233,6 +237,7 @@ class _Entry:
         self.t_drain = t0
         self.ctx = ctx
         self.scalar = scalar
+        self.q0 = self.q1 = q0
 
 
 def _ref_bit(pub: bytes, msg: bytes, sig: bytes) -> bool:
@@ -334,13 +339,14 @@ class VerifyScheduler:
             if prio != PRIO_CONSENSUS:
                 admitted = min(n, max(self.queue_cap - self._count, 0))
             t0 = time.perf_counter()
+            q0 = tracing.now()
             step = 1 if scalar else MAX_DRAIN
             for lo in range(0, admitted, step):
                 hi = min(lo + step, admitted)
                 entry = _Entry(
                     pubs[lo:hi], msgs[lo:hi], sigs[lo:hi],
                     None if keys is None else keys[lo:hi], puts,
-                    prio, t0, ctx, scalar,
+                    prio, t0, ctx, scalar, q0,
                 )
                 self._queues[prio].append(entry)
                 stats.record_submit(prio, hi - lo)
@@ -606,10 +612,12 @@ class VerifyScheduler:
         out: "list[_Entry]" = []
         n = 0
         now = time.perf_counter()
+        q1 = tracing.now()
         for q in self._queues:  # consensus first
             while q and (not out or n + q[0].n <= MAX_DRAIN):
                 e = q.popleft()
                 e.t_drain = now
+                e.q1 = q1
                 out.append(e)
                 n += e.n
             if q:
@@ -732,26 +740,17 @@ class VerifyScheduler:
             lo += en.n
 
     @staticmethod
-    def _flush_span(reason: str, entries: "list[_Entry]", n: int):
-        """``sched.flush``, saying where its signatures came from: the
-        trace ids it serves (a flush may serve several requests), the
-        first submitter's span as its parent, and how long the oldest
-        entry waited in the queue."""
-        if not tracing.enabled():
-            return tracing.span("sched.flush")
-        ctxs = {en.ctx for en in entries}
-        ctxs.discard(None)
-        return tracing.span(
-            "sched.flush",
-            parent=entries[0].ctx,
-            reason=reason,
-            items=n,
-            segments=len(entries),
-            traces=sorted({c.trace_id for c in ctxs}),
-            queue_wait_s=round(
-                entries[0].t_drain - min(en.t0 for en in entries), 9
-            ),
-        )
+    def _queue_span(entries: "list[_Entry]", fsp) -> None:
+        """``sched.queue``: the hand-off from the callers to the dispatcher,
+        from the oldest entry's enqueue to the drain, as a child of the
+        flush it ends at (a causal link: it lies BEFORE its parent)."""
+        if tracing.enabled():
+            tracing.get_tracer().record_span(
+                "sched.queue",
+                min(en.q0 for en in entries),
+                entries[0].q1,
+                parent=fsp,
+            )
 
     @staticmethod
     def _key_entry(en: "_Entry") -> None:
@@ -843,9 +842,13 @@ class VerifyScheduler:
     def _finish(en: "_Entry", bits, now: float) -> None:
         """Resolve one entry: n signatures' worth of statistics in one
         weighted observation, then its one ``set_result`` — the waiter
-        wakes to bookkeeping already done."""
+        wakes to bookkeeping already done.  The stamp left on the future is
+        on the tracer's clock: from it to the waiter back from ``result()``
+        is the hand-off ``sched.handoff.wake`` (``_record_wait``)."""
         if en.future.done():
             return
+        # where ``sched.handoff.wake`` begins: the waiter records it
+        en.future.t_set = tracing.now()
         stats.record_verdict(
             en.prio,
             now - en.t0,
@@ -928,8 +931,15 @@ class VerifyScheduler:
         interval = self._flush_interval()
 
         # flush span (closed BEFORE futures resolve, like the stats below,
-        # so a deterministic sim's ring order cannot race its waiters)
-        with self._flush_span(reason, entries, n) as fsp:
+        # so a deterministic sim's ring order cannot race its waiters), a
+        # child of the first entry's submitter: the flush is in THAT
+        # request's tree (another request it serves finds it by the time
+        # its ``sched.wait`` spans)
+        with tracing.span(
+            "sched.flush", parent=entries[0].ctx, reason=reason, items=n,
+            segments=len(entries),
+        ) as fsp:
+            self._queue_span(entries, fsp)
             bits, dups, ordered, work, lanes = self._plan(entries)
             handle = None
             if work:
@@ -980,10 +990,7 @@ class VerifyScheduler:
                     # optimization, never load-bearing
                     lane = None
                 try:
-                    with tracing.span(
-                        "sched.dispatch", reason=reason, items=n,
-                        lanes=lanes,
-                    ):
+                    with tracing.span("sched.dispatch"):
                         handle = ov.dispatch_segments(work, lane=lane)
                 except BaseException:
                     self._landed()
@@ -1005,10 +1012,11 @@ class VerifyScheduler:
             self._resolve(entries, bits, fsp)
             return
         with self._fcond:
-            # the flush span rides along: the completion thread's spans
-            # are its children
+            # the flush span rides along (the completion thread's spans
+            # are its children), and the stamp at which
+            # ``sched.handoff.fetch`` begins
             self._fetch_queue.append(
-                (handle, entries, bits, dups, ordered, fsp)
+                (handle, entries, bits, dups, ordered, fsp, tracing.now())
             )
             self._fcond.notify_all()
 
@@ -1059,13 +1067,15 @@ class VerifyScheduler:
                     self._cond.notify_all()
 
     def _resolve_flush(self, pf: tuple) -> None:
-        """The completion half of one flush: fetch verdicts, give the slot
-        back (``_landed``), settle them (``_settle``), resolve every
-        future.  Runs on the completion thread in drain order; cannot
-        leave a future unresolved — a fetch that somehow escapes the
-        supervisor's degradation chain resolves the flush on the host
-        reference."""
-        handle, entries, bits, dups, ordered, fsp = pf
+        """The completion half of one flush: take it up (the hand-off
+        ``sched.handoff.fetch`` ends here), fetch verdicts, give the slot
+        back (``_landed``, under ``sched.landed``), settle them
+        (``_settle``), resolve every future.  Runs on the completion thread
+        in drain order; cannot leave a future unresolved — a fetch that
+        somehow escapes the supervisor's degradation chain resolves the
+        flush on the host reference."""
+        handle, entries, bits, dups, ordered, fsp, handed = pf
+        tracing.handoff("sched.handoff.fetch", handed, parent=fsp)
         results = None
         try:
             from cometbft_tpu.ops import verify as ov
@@ -1077,7 +1087,8 @@ class VerifyScheduler:
             # queued flush behind it strands its futures
             logger.exception("pipelined flush fetch failed unexpectedly")
         finally:
-            self._landed()
+            with tracing.span("sched.landed", parent=fsp):
+                self._landed()
 
         def settle() -> None:
             try:
@@ -1157,6 +1168,21 @@ def _clamp_prio(priority: int) -> int:
     return min(max(int(priority), 0), N_CLASSES - 1)
 
 
+def _record_wait(waited: "tracing.Lap", parent, futs) -> None:
+    """The caller's ``sched.wait`` lap and, inside it, the last hand-off of
+    a request: ``sched.handoff.wake``, from the stamp the completion thread
+    left on the (last) future before resolving it (``_finish``: the
+    statistics, ``set_result`` and the wake-up lie after it) to the caller
+    back from ``result()``.  A future answered before the wait began, or
+    from the cache, has no hand-off to show."""
+    wsp = waited.record(parent=parent, futures=len(futs))
+    t_set = getattr(futs[-1], "t_set", None) if futs else None
+    if wsp is not None and t_set is not None and t_set >= waited.t0:
+        tracing.get_tracer().record_span(
+            "sched.handoff.wake", t_set, waited.t1, parent=wsp
+        )
+
+
 def verify_cached(pub_key, msg: bytes, sig: bytes, priority=None) -> bool:
     """THE drop-in for ``sigcache.verify_with_cache`` on scheduler-wired
     call sites (gossip-time ``Vote.verify``, proposal and vote-extension
@@ -1176,7 +1202,7 @@ def verify_cached(pub_key, msg: bytes, sig: bytes, priority=None) -> bool:
                     ok = bool(fut.result())
                 parent = tracing.current()
                 submitted.record(parent=parent, items=1, shed=0)
-                waited.record(parent=parent, futures=1)
+                _record_wait(waited, parent, [fut])
                 return ok
             except (QueueFullError, RuntimeError):
                 # shed, or scheduler torn down under us (reset race):
@@ -1271,5 +1297,5 @@ def verify_segment_sync(
                 out.extend(f.result())
         out.extend(direct)
         submitted.record(parent=seg, items=n, shed=n - admitted)
-        waited.record(parent=seg, futures=len(futs))
+        _record_wait(waited, seg, futs)
     return out

@@ -827,6 +827,18 @@ class TestServedMesh:
         (launch,) = _spans("verify.launch")
         assert put["parent"] == launch["span"] and put["attrs"]["shards"] == width
         assert launch["attrs"]["mesh"] == width
+        # ISSUE 36: the launch's parts on a mesh are the lookup, ``mesh.put``
+        # in the one-chip transfer's place, and the call; the fetch has no
+        # pull of its own (each shard's is inside its ``mesh.shard``)
+        (lookup,), (called,) = (
+            _spans("verify.launch.lookup"), _spans("verify.launch.call"),
+        )
+        assert lookup["parent"] == called["parent"] == launch["span"]
+        assert lookup["t1"] <= put["t0"] <= put["t1"] <= called["t0"]
+        assert not _spans("verify.launch.put") and not _spans("verify.fetch.pull")
+        for stage in ("sched.queue", "sched.handoff.fetch", "sched.landed",
+                      "sched.handoff.wake"):
+            assert len(_spans(stage)) == 1, stage
 
     @pytest.mark.parametrize("width", WIDTHS)
     def test_the_parts_add_up(self, width):

@@ -289,6 +289,57 @@ class TestKeyHashedOnce:
         assert (st["keys"], st["puts"], st["hits"]) == (2, 1, 1)
 
 
+class TestSeamParts:
+    """ISSUE 36: the seam's parts are spans of the CALL, never of a
+    signature: one ``batch.keys``, one ``batch.lookup`` and one
+    ``batch.writeback`` whatever the segment's length, nested under
+    whatever span the caller has open, and none with the recorder off."""
+
+    @staticmethod
+    def _stages(tracer):
+        return [s["stage"] for s in tracer.tail(100)]
+
+    @pytest.mark.parametrize("n", [1, 50])
+    def test_one_span_each_a_call(self, n):
+        from cometbft_tpu.libs import tracing
+
+        tracing.reset_tracer()
+        tr = tracing.get_tracer()
+        pubs, msgs, sigs = TestKeyHashedOnce._triples(n, b"parts")
+        with tr.span("batch.verify") as seam:
+            part = sigcache.partition_misses(pubs, msgs, sigs)
+            sigcache.writeback(part, [True] * n)
+        assert self._stages(tr) == [
+            "batch.keys", "batch.lookup", "batch.writeback", "batch.verify",
+        ]
+        assert {s.get("parent") for s in tr.tail(100)[:3]} == {seam.span_id}
+        # every look-up a hit: no write-back is made, and none is recorded
+        tr.reset()
+        part = sigcache.partition_misses(pubs, msgs, sigs)
+        assert part.miss == [] and all(part.bits)
+        assert self._stages(tr) == ["batch.keys", "batch.lookup"]
+        tracing.reset_tracer()
+
+    def test_cache_off_or_recorder_off_records_no_part(self, monkeypatch):
+        from cometbft_tpu.libs import tracing
+
+        tracing.reset_tracer()
+        tr = tracing.get_tracer()
+        pubs, msgs, sigs = TestKeyHashedOnce._triples(3, b"off")
+        monkeypatch.setenv("COMETBFT_TPU_SIGCACHE", "0")
+        part = sigcache.partition_misses(pubs, msgs, sigs)
+        sigcache.writeback(part, [True] * 3)
+        # nothing hashed, nothing looked up: only the write-back's one span
+        assert self._stages(tr) == ["batch.writeback"]
+        monkeypatch.delenv("COMETBFT_TPU_SIGCACHE")
+        monkeypatch.setenv("COMETBFT_TPU_TRACE", "0")
+        tr.reset()
+        part = sigcache.partition_misses(pubs, msgs, sigs)
+        sigcache.writeback(part, [True] * 3)
+        assert part.bits == [True] * 3 and self._stages(tr) == []
+        tracing.reset_tracer()
+
+
 class TestMetricsExposition:
     def test_callback_gauges_scrape_without_jax(self):
         """The verify-stream gauges read live counters at scrape time and a

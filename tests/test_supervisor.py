@@ -95,11 +95,12 @@ class _SlowArray:
 
 
 def _install_fake_chip(monkeypatch, batches, sleep_s=0.0):
-    """The ``chip`` kind without a compile: ``_launch`` (executable,
-    transfer, call) is replaced by a host stand-in that answers each lane
-    it was handed with the oracle's bit for the triple packed there, as an
-    unfetched "device array".  Pack, both watchdog calls, the spans and the
-    breaker handling above it run as on a chip.  Returns the launches."""
+    """The ``chip`` kind without a compile: the bucket's EXECUTABLE is
+    replaced by a host stand-in that answers each lane it was handed with
+    the oracle's bit for the triple packed there, as an unfetched "device
+    array".  ``_launch`` itself (lookup, the five transfers, the call, each
+    under its lap), pack, both watchdog calls, the spans and the breaker
+    handling above it run as on a chip.  Returns the launches."""
     known = {}
     for pubs, msgs, sigs in batches:
         for p, m, g in zip(pubs, msgs, sigs):
@@ -107,15 +108,20 @@ def _install_fake_chip(monkeypatch, batches, sleep_s=0.0):
                 known[(p, g[:32])] = ref.verify_zip215(p, m, g)
     launches = []
 
-    def fake_launch(backend, lanes, arrays):
-        out = np.zeros(lanes, dtype=bool)
-        for i in range(lanes):
-            key = (arrays["a_bytes"][i].tobytes(), arrays["r_bytes"][i].tobytes())
-            out[i] = known.get(key, False)
-        launches.append((backend, lanes))
-        return _SlowArray(out, sleep_s)
+    from cometbft_tpu.ops import verify as ov
 
-    monkeypatch.setattr(supervisor, "_launch", fake_launch)
+    def fake_executable(backend, lanes):
+        def call(**arrays):
+            a, r = np.asarray(arrays["a_bytes"]), np.asarray(arrays["r_bytes"])
+            out = np.zeros(lanes, dtype=bool)
+            for i in range(lanes):
+                out[i] = known.get((a[i].tobytes(), r[i].tobytes()), False)
+            launches.append((backend, lanes))
+            return _SlowArray(out, sleep_s)
+
+        return call, None
+
+    monkeypatch.setattr(ov, "bucket_executable", fake_executable)
     return launches
 
 
@@ -331,20 +337,74 @@ class TestFaultDifferential:
 
         path = "python" if ov._native_pack_into() is None else "native"
         halves = ["verify.pack.glue", "verify.pack.native"][: 1 + (path == "native")]
+        # ISSUE 36: the launch's three parts and the fetch's copy, each timed
+        # on its watchdog worker and recorded by the caller, in this order
+        parts = [
+            "verify.launch.lookup", "verify.launch.put", "verify.launch.call",
+        ]
         assert [sp["stage"] for sp in spans] == halves + [
-            "verify.pack", "verify.launch", "verify.dispatch", "verify.fetch",
+            "verify.pack", "verify.launch", *parts, "verify.dispatch",
+            "verify.fetch.pull", "verify.fetch",
         ]
         by = {sp["stage"]: sp for sp in spans}
         assert by["verify.pack"]["attrs"]["path"] == path
         for half in halves:
             assert by[half]["parent"] == by["verify.pack"]["span"]
         assert by["verify.launch"]["parent"] == by["verify.dispatch"]["span"]
+        launch = by["verify.launch"]
+        for part in parts:
+            assert by[part]["parent"] == launch["span"]
+        # one after the other inside the launch, and they fill it
+        edges = [launch["t0"]] + [
+            t for part in parts for t in (by[part]["t0"], by[part]["t1"])
+        ] + [launch["t1"]]
+        assert edges == sorted(edges)
+        assert sum(by[part]["dur_ms"] for part in parts) == pytest.approx(
+            launch["dur_ms"], abs=1.0
+        )
+        pull = by["verify.fetch.pull"]
+        assert pull["parent"] == by["verify.fetch"]["span"]
+        assert pull["dur_ms"] >= 20.0  # the stand-in's copy sleeps in it
+        assert by["verify.fetch"]["dur_ms"] >= pull["dur_ms"]
         assert by["verify.dispatch"]["attrs"]["pipelined"] is True
         snap = dispatch_stats.snapshot()
         assert (snap["dispatches"], snap["lanes_total"]) == (1, 32)
         assert snap["lanes_used"] == 9 and snap["inflight_depth"] == 0
         hist = snap["dispatch_hist"]["xla-32"]
         assert hist["count"] == 1 and hist["sum"] >= 0.02  # the fetch wait
+
+    def test_abandoned_launch_worker_records_no_lap(self, monkeypatch):
+        """ISSUE 36: the launch's laps are closed on the watchdog worker and
+        recorded by the caller only after ``watchdog_call`` returned: a
+        worker abandoned inside the executable's call leaves nothing, not
+        even the two laps (lookup, put) it had already closed."""
+        from cometbft_tpu.libs import tracing
+        from cometbft_tpu.ops import verify as ov
+
+        monkeypatch.setenv("COMETBFT_TPU_DISPATCH_TIMEOUT_MS", "40")
+        released = threading.Event()
+
+        def wedged_executable(backend, lanes):
+            def call(**arrays):
+                time.sleep(0.3)
+                released.set()
+                return np.zeros(lanes, dtype=bool)
+
+            return call, None
+
+        monkeypatch.setattr(ov, "bucket_executable", wedged_executable)
+        pubs, msgs, sigs = _mixed_batch(np.random.default_rng(7), 5)
+        tracing.get_tracer().reset()
+        h = supervisor.dispatch_verify(pubs, msgs, sigs)
+        assert h.kind == "supervised"
+        assert isinstance(h.error, supervisor.DispatchTimeoutError)
+        assert list(supervisor.fetch_verify(h)) == _oracle(pubs, msgs, sigs)
+        assert released.wait(10)
+        time.sleep(0.05)  # the worker has closed its last lap by now
+        tr = tracing.get_tracer()
+        recorded = {sp["stage"] for sp in tr.tail(100)} | set(tr.stage_totals())
+        assert not {st for st in recorded if st.startswith("verify.launch")}
+        assert "verify.dispatch" in recorded
 
     def test_verify_segments_under_fault(self):
         from cometbft_tpu.ops import verify as ov

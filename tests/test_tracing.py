@@ -117,6 +117,31 @@ class TestSpans:
             sp.set(anything=1)
         assert tr.snapshot()["spans_recorded"] == 0
 
+    def test_the_switch_is_read_live_from_the_environment(self, monkeypatch):
+        """``enabled()`` looks the switch up in the environment's own
+        mapping: set, changed, deleted or the whole ``os.environ`` replaced
+        while the process runs, the next span site sees it."""
+        import os
+
+        monkeypatch.delenv("COMETBFT_TPU_TRACE", raising=False)
+        assert tracing.enabled()
+        monkeypatch.setenv("COMETBFT_TPU_TRACE", "0")
+        assert not tracing.enabled()
+        assert tracing.now() > 0 and tracing.handoff("sched.queue", 0.0) is None
+        monkeypatch.setenv("COMETBFT_TPU_TRACE", "1")
+        assert tracing.enabled()
+        os.environ["COMETBFT_TPU_TRACE"] = "0"
+        assert not tracing.enabled()
+        monkeypatch.delenv("COMETBFT_TPU_TRACE")
+        assert tracing.enabled()
+        other = type(os.environ)(
+            {tracing._TRACE_KEY: tracing._TRACE_OFF},
+            os.environ.encodekey, os.environ.decodekey,
+            os.environ.encodevalue, os.environ.decodevalue,
+        )
+        monkeypatch.setattr(os, "environ", other)
+        assert not tracing.enabled()
+
     def test_stage_summary_percentiles(self):
         tr = tracing.get_tracer()
         t = [0.0]
@@ -522,6 +547,18 @@ class TestRequestTree:
             "verify.pack": "sched.fetch",
             "verify.dispatch": "sched.fetch",
             "verify.launch": "verify.dispatch",
+            # ISSUE 36: the hand-offs between the three threads ...
+            "sched.queue": "sched.flush",
+            "sched.handoff.fetch": "sched.flush",
+            "sched.landed": "sched.flush",
+            "sched.handoff.wake": "sched.wait",
+            # ... the copy inside the fetch's watchdog, the seam's parts
+            "verify.fetch": "sched.fetch",
+            "verify.fetch.pull": "verify.fetch",
+            "batch.add": "batch.verify",
+            "batch.keys": "batch.verify",
+            "batch.lookup": "batch.verify",
+            "batch.writeback": "batch.verify",
         }
         assert set(parents) <= set(one), sorted(by)
         root = one["verify.commit"]
@@ -529,9 +566,13 @@ class TestRequestTree:
         for stage, parent in parents.items():
             want = one[parent]["span"] if parent else None
             assert one[stage].get("parent") == want, stage
-        flush = one["sched.flush"]["attrs"]
-        assert flush["traces"] == [root["span"]]
-        assert flush["queue_wait_s"] >= 0
+        # the flush's link to the request it serves is its parent (above);
+        # the queue wait is a span of its own that ends where the flush
+        # begins (the ``queue_wait_s`` attribute it replaced said as much)
+        flush, queue = one["sched.flush"], one["sched.queue"]
+        assert flush["attrs"]["segments"] == 1
+        assert 0 <= queue["dur_ms"] and queue["t1"] <= flush["t0"]
+        assert one["sched.submit"]["t0"] <= queue["t0"] <= queue["t1"]
         assert one["batch.verify"]["attrs"] == {"sigs": 4, "hits": 0, "keys": 4}
         assert one["verify.launch"]["attrs"]["lanes"] >= 4
         assert one["verify.pack"]["attrs"]["bytes"] > 0
@@ -611,11 +652,22 @@ class TestRequestTree:
         by = _by_stage(tracing.get_tracer().tail(200))
         (flush,) = by["sched.flush"]
         assert flush["attrs"]["items"] == 2
-        assert flush["attrs"]["traces"] == sorted(roots.values())
+        assert flush["attrs"]["segments"] == 2
         assert len(set(roots.values())) == 2
-        # the first item's submitter is the parent; the fetch follows it
+        # the first item's submitter is the parent: the flush is in THAT
+        # request's tree, and the other request finds it by the one
+        # ``sched.queue`` span, which begins at the older of the two enqueues
         segments = {s["span"]: s["trace"] for s in by["sched.segment"]}
         assert flush["trace"] == segments[flush["parent"]]
+        assert flush["trace"] in roots.values()
+        (queue,) = by["sched.queue"]
+        assert queue["parent"] == flush["span"]
+        assert queue["t0"] <= min(s["t1"] for s in by["sched.submit"])
+        # each caller records its own wake, under its own wait
+        waits = {s["span"]: s["trace"] for s in by["sched.wait"]}
+        assert sorted(waits[s["parent"]] for s in by["sched.handoff.wake"]) == (
+            sorted(roots.values())
+        )
         assert by["sched.resolve"][0]["parent"] == flush["span"]
 
     def test_caller_stages_sum_to_the_request(self, sched_env):
@@ -675,6 +727,436 @@ class TestRequestTree:
         assert by["verify.dispatch"][0]["attrs"]["error"] == (
             "DispatchTimeoutError"
         )
+
+
+class TestHandoffs:
+    """ISSUE 36: every hand-off between the path's threads is a completed
+    span with one stamp from each of the two threads it joins, recorded by
+    the receiving thread, in the request's tree."""
+
+    @staticmethod
+    def _threads_by_stage(monkeypatch):
+        """Which thread lands each stage in the ring."""
+        seen = []
+        real = tracing.Tracer._append
+
+        def noting(self, sp):
+            seen.append((sp.stage, threading.current_thread().name))
+            real(self, sp)
+
+        monkeypatch.setattr(tracing.Tracer, "_append", noting)
+        return seen
+
+    def test_each_handoff_on_its_thread_in_the_works_order(
+        self, sched_env, monkeypatch
+    ):
+        from cometbft_tpu.types.validation import verify_commit_light
+
+        args = _commit()
+        seen = self._threads_by_stage(monkeypatch)
+        verify_commit_light(*args)
+        me = threading.current_thread().name
+        threads = dict(seen)
+        assert len(threads) == len(seen)  # one span a stage in this request
+        want = {
+            # the receiver records: the dispatcher the queue, the completion
+            # thread what the dispatcher handed it, the caller its wake
+            "sched.queue": "verify-sched",
+            "sched.flush": "verify-sched",
+            "sched.handoff.fetch": "verify-sched-fetch",
+            "verify.fetch.pull": "verify-sched-fetch",
+            "sched.fetch": "verify-sched-fetch",
+            "sched.landed": "verify-sched-fetch",
+            "sched.resolve": "verify-sched-fetch",
+            "sched.wait": me,
+            "sched.handoff.wake": me,
+            "batch.add": me, "batch.keys": me, "batch.lookup": me,
+            "batch.writeback": me,
+        }
+        assert {k: threads.get(k) for k in want} == want
+        order = [stage for stage, _ in seen]
+        # a function of the work: the seam's first parts, the flush with its
+        # queue first, the completion thread's stages, then the caller's
+        chain = [
+            "batch.add", "batch.keys", "batch.lookup",
+            "sched.queue", "sched.dispatch", "sched.flush",
+            "sched.handoff.fetch", "verify.fetch.pull", "verify.fetch",
+            "sched.fetch", "sched.landed", "sched.resolve",
+            "sched.submit", "sched.wait", "sched.handoff.wake",
+            "sched.segment", "batch.writeback", "batch.verify",
+            "verify.commit",
+        ]
+        assert [st for st in order if st in chain] == chain
+        # the hand-offs lie where the table says: fetch between the flush
+        # and the fetch, landed between fetch and resolve, the wake at the
+        # end of the wait
+        by = {s["stage"]: s for s in tracing.get_tracer().tail(200)}
+        assert by["sched.flush"]["t1"] <= by["sched.handoff.fetch"]["t0"]
+        assert by["sched.handoff.fetch"]["t1"] <= by["sched.fetch"]["t0"]
+        assert by["sched.fetch"]["t1"] <= by["sched.landed"]["t0"]
+        assert by["sched.landed"]["t1"] <= by["sched.resolve"]["t0"]
+        assert by["sched.resolve"]["t1"] <= by["sched.handoff.wake"]["t0"]
+        assert by["sched.handoff.wake"]["t1"] == by["sched.wait"]["t1"]
+
+    def test_the_named_pieces_of_a_wait_add_up_to_the_wait(self, sched_env):
+        """``wait_unseen_ms`` by hand: queue + flush + hand-off + fetch +
+        landed + resolve + wake is the caller's wait.  On a clock that the
+        device stand-in moves by 50 ms and every READING of it by a
+        microsecond (so the result is the work's, not the machine's: a
+        loaded test host cannot stretch a gap), what lies between two
+        neighbours is the recorder's own few readings: under 1% of the
+        wait."""
+        t = [5000.0]
+
+        def ticking():
+            t[0] += 1e-6
+            return t[0]
+
+        def slow_runner(*a):
+            t[0] += 0.05
+            return _oracle_runner(*a)
+
+        supervisor.set_device_runner(slow_runner)
+        tr = tracing.get_tracer()
+        tr.reset()
+        tr.set_clock(ticking)
+        try:
+            pub, msg, sig = _triple(3, b"sum")
+            with tracing.span("verify.commit"):
+                assert verifysched.verify_segment_sync(
+                    [pub], [msg], [sig]
+                ) == [True]
+        finally:
+            tr.set_clock(None)
+        dur = {s["stage"]: s["dur_ms"] for s in tr.tail(100)}
+        pieces = sum(
+            dur[st] for st in (
+                "sched.queue", "sched.flush", "sched.handoff.fetch",
+                "sched.fetch", "sched.landed", "sched.resolve",
+                "sched.handoff.wake",
+            )
+        )
+        assert dur["sched.wait"] > 50.0
+        assert pieces == pytest.approx(dur["sched.wait"], rel=0.01)
+
+    def test_a_votes_wake_hangs_under_its_wait(self, sched_env):
+        from cometbft_tpu.crypto.keys import Ed25519PubKey
+
+        tracing.get_tracer().reset()
+        pub, msg, sig = _triple(4, b"vote")
+        with tracing.span("consensus.vote") as vote:
+            assert verifysched.verify_cached(Ed25519PubKey(pub), msg, sig)
+            # answered from the cache the second time: no queue, no hand-off
+            assert verifysched.verify_cached(Ed25519PubKey(pub), msg, sig)
+        by = _by_stage(tracing.get_tracer().tail(100))
+        assert len(by["sched.wait"]) == 2 and len(by["sched.handoff.wake"]) == 1
+        (wake,), first_wait = by["sched.handoff.wake"], by["sched.wait"][0]
+        assert wake["parent"] == first_wait["span"]
+        assert first_wait["parent"] == vote.span_id
+        assert wake["trace"] == vote.trace_id
+        assert first_wait["t0"] <= wake["t0"] <= wake["t1"] == first_wait["t1"]
+
+    def test_same_seed_runs_on_an_injected_clock_dump_the_same_bytes(
+        self, sched_env, tmp_path, monkeypatch
+    ):
+        """The stamps are the TRACER's clock: on an injected one that only
+        the device stand-in moves, two runs of the same requests give the
+        same ring and the same anomaly dump, byte for byte."""
+        from cometbft_tpu.types.validation import verify_commit_light
+
+        args = _commit()
+
+        def replay(sub):
+            d = tmp_path / sub
+            monkeypatch.setenv("COMETBFT_TPU_TRACE_DIR", str(d))
+            verifysched.reset_scheduler()
+            sigcache.reset_cache()
+            dispatch_stats.reset()  # the dispatch ordinal is in the spans
+            tr = tracing.get_tracer()
+            tr.reset()
+            t = [1000.0]
+
+            def runner(*a):
+                t[0] += 0.004
+                return _oracle_runner(*a)
+
+            supervisor.set_device_runner(runner)
+            tr.set_clock(lambda: t[0])
+            try:
+                for _ in range(3):
+                    sigcache.reset_cache()
+                    verify_commit_light(*args)
+                    t[0] += 0.5
+                path = tracing.record_anomaly("queue_shed", cls="bulk")
+                ring = [json.dumps(s, sort_keys=True) for s in tr.tail(0)]
+            finally:
+                tr.set_clock(None)
+            assert "host.runq_wait" not in tr.stage_totals()
+            return ring, open(path, "rb").read()
+
+        first, second = replay("a"), replay("b")
+        assert first == second
+        stages = {json.loads(line)["stage"] for line in first[0]}
+        assert {"sched.queue", "sched.handoff.fetch", "sched.landed",
+                "sched.handoff.wake", "verify.fetch.pull"} <= stages
+
+    def test_recorder_off_leaves_one_clock_read_a_handoff(
+        self, sched_env, monkeypatch
+    ):
+        """``COMETBFT_TPU_TRACE=0``: no span, no host sample, no file of the
+        host's read; what is left of this PR on the path is the stamp each
+        hand-off begins with (the enqueue, the drain, the append to the
+        completion thread's queue, the resolve)."""
+        import builtins
+
+        from cometbft_tpu.crypto.keys import Ed25519PubKey
+
+        monkeypatch.setenv("COMETBFT_TPU_TRACE", "0")
+        tr = tracing.get_tracer()
+        reads = [0]
+
+        def clock():
+            reads[0] += 1
+            return 5.0
+
+        sampled = []
+        tr.set_clock(clock)
+        tr.set_host_readers({"host.switches": lambda tids: sampled.append(1)})
+        opened = []
+        real_open = builtins.open
+
+        def noting_open(path, *a, **kw):
+            if str(path).startswith(("/proc", "/sys")):
+                opened.append(path)
+            return real_open(path, *a, **kw)
+
+        monkeypatch.setattr(builtins, "open", noting_open)
+        try:
+            pub, msg, sig = _triple(5, b"off")
+            assert verifysched.verify_cached(Ed25519PubKey(pub), msg, sig)
+            assert reads[0] == 4
+            pub, msg, sig = _triple(6, b"off")
+            assert verifysched.verify_segment_sync([pub], [msg], [sig]) == [True]
+            assert reads[0] == 8
+        finally:
+            tr.set_clock(None)
+        assert tr.snapshot()["spans_recorded"] == 0
+        assert tr.stage_totals() == {} and tr.host_summary() == {}
+        assert not sampled and not opened and not tr._host_tids
+
+
+class TestHostPressure:
+    """ISSUE 36: what the machine did to the process, by second, under
+    pseudo-stages of the per-second store."""
+
+    @staticmethod
+    def _tracer(sources):
+        """A tracer on a hand-moved clock whose host sources are the
+        cumulative values in ``sources`` (a missing key: a source this host
+        lacks)."""
+        tr = tracing.Tracer(ring_size=64)
+        t = [50.25]
+        tr.set_clock(lambda: t[0])
+        tr.set_host_readers(
+            {k: (lambda tids, k=k: sources.get(k)) for k in (
+                "host.runq_wait", "host.switches", "host.faults",
+                "host.throttled",
+            )}
+        )
+
+        def tick(to):
+            t[0] = to
+            with tr.span("verify.commit"):
+                pass
+
+        return tr, tick
+
+    def test_differences_land_in_the_second_that_passed(self):
+        src = {"host.runq_wait": 1.0, "host.switches": 10.0}
+        tr, tick = self._tracer(src)
+        tick(50.25)  # the first sample: a baseline, nothing stored
+        assert not [k for b in tr.stage_seconds().values() for k in b
+                    if k.startswith("host.")]
+        src.update({"host.runq_wait": 1.125, "host.switches": 13.0})
+        tick(50.75)  # the same second: no sample
+        tick(51.5)  # second 51 opens: what passed belongs to second 50
+        src.update({"host.runq_wait": 1.25, "host.switches": 13.0})
+        tick(52.01)
+        seconds = tr.stage_seconds()
+        assert seconds[50]["host.runq_wait"] == (1, pytest.approx(0.125))
+        assert seconds[50]["host.switches"] == (1, pytest.approx(3.0))
+        assert seconds[51]["host.runq_wait"] == (1, pytest.approx(0.125))
+        assert seconds[51]["host.switches"] == (1, pytest.approx(0.0))
+        assert "host.switches" not in seconds[52]  # still open
+        # a source this host lacks is left out, not zero
+        assert "host.throttled" not in seconds[50]
+        assert "host.faults" not in tr.stage_totals()
+        # the interval reader carries them with the stages, no new code
+        got = tr.stage_totals(50.0, 52.0)
+        assert got["host.switches"] == (2, pytest.approx(3.0))
+        assert got["verify.commit"][0] == 3
+
+    def test_a_gap_of_several_seconds_is_shared_out(self):
+        """A pause of the whole process: nothing ends for four seconds, and
+        the next sample's difference is theirs in equal parts."""
+        src = {"host.throttled": 0.0}
+        tr, tick = self._tracer(src)
+        tick(50.25)
+        src["host.throttled"] = 2.0
+        tick(54.5)
+        seconds = tr.stage_seconds()
+        assert [seconds[s]["host.throttled"] for s in (50, 51, 52, 53)] == [
+            (1, pytest.approx(0.5))
+        ] * 4
+        assert "verify.commit" not in seconds[52]  # a second of its own
+        assert tr.stage_totals(50.0, 54.0)["host.throttled"] == (
+            4, pytest.approx(2.0)
+        )
+
+    def test_a_source_that_comes_and_goes_and_one_that_raises(self):
+        def boom(tids):
+            raise OSError("gone")
+
+        src = {"host.faults": 5.0}
+        tr, tick = self._tracer(src)
+        tr.set_host_readers({
+            "host.faults": lambda tids: src.get("host.faults"),
+            "host.throttled": boom,
+        })
+        tick(50.25)
+        del src["host.faults"]  # the file went away
+        tick(51.25)
+        src["host.faults"] = 9.0  # and came back: a new baseline
+        tick(52.25)
+        src["host.faults"] = 10.0
+        tick(53.25)
+        seconds = tr.stage_seconds()
+        assert "host.faults" not in seconds[50]
+        assert "host.faults" not in seconds[51]
+        assert seconds[52]["host.faults"] == (1, pytest.approx(1.0))
+        assert not [k for b in seconds.values() for k in b
+                    if k == "host.throttled"]
+
+    def test_summary_and_document_keep_hosts_counters_apart(self):
+        src = {"host.runq_wait": 0.0, "host.switches": 0.0}
+        tr, tick = self._tracer(src)
+        tick(50.25)
+        src.update({"host.runq_wait": 0.03, "host.switches": 7.0})
+        tick(51.25)
+        src.update({"host.runq_wait": 0.04, "host.switches": 7.0})
+        tick(52.25)
+        assert set(tr.stage_summary()) == {"verify.commit"}  # durations only
+        assert tr.host_summary() == {
+            "runq_wait": {"seconds": 2, "total": 0.04, "last": 0.01},
+            "switches": {"seconds": 2, "total": 7.0, "last": 0.0},
+        }
+        tr.reset()
+        assert tr.host_summary() == {}
+
+    def test_trace_document_has_the_host_beside_the_stages(self):
+        tr = tracing.get_tracer()
+        src = {"host.throttled": 0.0}
+        t = [10.5]
+        tr.set_clock(lambda: t[0])
+        tr.set_host_readers({"host.throttled": lambda tids: src["host.throttled"]})
+        try:
+            for to, v in ((10.5, 0.0), (11.5, 0.25)):
+                t[0], src["host.throttled"] = to, v
+                with tracing.span("verify.fetch"):
+                    pass
+            doc = tracing.trace_document(max_spans=4, rounds=0)
+        finally:
+            tr.set_clock(None)
+        assert doc["host"] == {
+            "throttled": {"seconds": 1, "total": 0.25, "last": 0.25}
+        }
+        assert "verify.fetch" in doc["stages"]
+        assert "host.throttled" not in doc["stages"]
+        json.dumps(doc)
+
+    def test_an_injected_clock_reads_nothing_of_this_host(self, monkeypatch):
+        """Virtual seconds are not the host's: with no readers injected a
+        tracer on an injected clock samples nothing (a sim's record stays a
+        function of its seed)."""
+        monkeypatch.setattr(
+            tracing, "_default_host_readers",
+            lambda: pytest.fail("the host was read on an injected clock"),
+        )
+        tr = tracing.Tracer(ring_size=16)
+        t = [0.0]
+        tr.set_clock(lambda: t[0])
+        for _ in range(3):
+            with tr.span("consensus.vote"):
+                t[0] += 0.75
+        assert set(tr.stage_totals()) == {"consensus.vote"}
+
+    def test_a_thread_counts_from_its_second_reading_and_leaves_when_gone(self):
+        """The per-thread source (run-queue wait) is cumulative over each
+        thread's OWN differences: a thread that registers late does not
+        bring the wait of its whole life, and one whose reading fails (it
+        has exited) counts no further."""
+        clocks = {11: 5.0, 12: 100.0}
+
+        def read_one(tid):
+            if tid not in clocks:
+                raise OSError("no such thread")
+            return clocks[tid]
+
+        tids = {11}
+        per = tracing._PerThread(read_one)
+        assert per(tids) == 0.0  # a baseline, not five seconds
+        clocks[11] = 5.25
+        tids.add(12)  # registers with a hundred seconds behind it
+        assert per(tids) == pytest.approx(0.25)
+        clocks.update({11: 5.5, 12: 100.5})
+        assert per(tids) == pytest.approx(1.0)
+        del clocks[11]  # exited
+        clocks[12] = 101.0
+        assert per(tids) == pytest.approx(1.5)
+        del clocks[12]
+        assert per(tids) is None  # nothing to read: left out, not zero
+
+    @pytest.mark.skipif(
+        not sys.platform.startswith("linux"), reason="the kernel's own counters"
+    )
+    def test_this_hosts_own_sources(self, monkeypatch):
+        """The default readers on the real clock: each source this host has
+        gives a number that does not fall; a kernel that answers
+        ``getrusage`` with zeros (a sandbox) has its two counters left out,
+        not zero; a real tracer takes the readers up by itself and
+        registers the thread that opens a span."""
+        import resource
+
+        readers = tracing._default_host_readers()
+        assert "host.cpu" in readers
+        assert {"host.switches", "host.faults"} <= set(readers)
+        tids = {threading.get_native_id()}
+        first = {k: r(tids) for k, r in readers.items()}
+        sum(i * i for i in range(300000))
+        second = {k: r(tids) for k, r in readers.items()}
+        assert all(v is not None and v >= 0 for v in first.values())
+        assert all(second[k] >= first[k] for k in first)
+        assert second["host.cpu"] > first["host.cpu"]
+        # the WHOLE process's clock: a thread that never opened a span (the
+        # watchdog's workers) is in it
+        worker = threading.Thread(
+            target=lambda: sum(i * i for i in range(600000))
+        )
+        worker.start()
+        worker.join()
+        third = readers["host.cpu"](tids)
+        assert third - second["host.cpu"] > 0.5 * (
+            second["host.cpu"] - first["host.cpu"]
+        )
+        zeros = type("ru", (), {"ru_minflt": 0, "ru_nivcsw": 0})()
+        monkeypatch.setattr(resource, "getrusage", lambda who: zeros)
+        stub = tracing._default_host_readers()
+        assert "host.switches" not in stub and "host.faults" not in stub
+        monkeypatch.undo()
+        tr = tracing.Tracer(ring_size=16)
+        with tr.span("verify.commit"):
+            pass
+        assert tr._host_readers and tr._host_tids == {threading.get_native_id()}
 
 
 class TestStageTotals:

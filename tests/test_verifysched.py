@@ -236,6 +236,47 @@ class TestSchedulerCore:
         assert sstats.snapshot()["submit_hits"]["consensus"] == 1
         sched.resume()
 
+    def test_handoff_stamps_ride_the_entry_and_the_future(self, sched_env):
+        """ISSUE 36: the two ends of ``sched.queue`` are on the entries (the
+        oldest entry's enqueue, the drain), the start of
+        ``sched.handoff.wake`` on the future; a future answered from the
+        cache was never handed off and has no stamp.  One ``sched.queue``
+        a flush, however many entries it drained."""
+        from cometbft_tpu.libs import tracing
+
+        tracing.reset_tracer()
+        tr = tracing.get_tracer()
+        pubs, msgs, sigs = _make_sigs(3, b"stamp")
+        sigcache.get_cache().put(pubs[2], msgs[2], sigs[2], True)
+        sched = verifysched.get_scheduler()
+        sched.pause()
+        t_before = tr.time()
+        first = sched.submit(pubs[0], msgs[0], sigs[0])
+        time.sleep(0.02)
+        second = sched.submit(pubs[1], msgs[1], sigs[1])
+        hit = sched.submit(pubs[2], msgs[2], sigs[2])
+        t_queued = tr.time()
+        time.sleep(0.02)
+        sched.resume()
+        assert first.result(timeout=30) and second.result(timeout=30)
+        assert hit.result() is True and not hasattr(hit, "t_set")
+        by = {}
+        for sp in tr.tail(100):
+            by.setdefault(sp["stage"], []).append(sp)
+        (queue,), (flush,) = by["sched.queue"], by["sched.flush"]
+        assert flush["attrs"]["segments"] == 2
+        assert queue["parent"] == flush["span"]
+        # from the OLDEST entry's enqueue to the drain, which the pause held
+        assert t_before <= queue["t0"] <= t_queued - 0.02
+        assert queue["t1"] >= t_queued + 0.02 and queue["t1"] <= flush["t0"]
+        assert queue["dur_ms"] >= 40.0
+        resolve_end = by["sched.resolve"][0]["t1"]
+        # the first entry is finished inside ``sched.resolve``, the last
+        # one after it; both stamps are the tracer's clock
+        assert flush["t1"] <= first.t_set <= resolve_end <= second.t_set
+        assert second.t_set <= tr.time()
+        tracing.reset_tracer()
+
     def test_flush_reasons_full_and_deadline(self, sched_env):
         # full: a long deadline that cannot be the trigger; the 32-lane
         # padding bucket fills first
