@@ -49,7 +49,9 @@ per dispatch, never per signature):
     bucket dispatch (the dispatch span carries bucket lanes + tier +
     dispatch seq: the triple an anomaly dump attributes a watchdog fire
     to); what the two watchdogged spans hold beyond their laps is the
-    watchdog's fresh thread and two switches
+    watchdog's trip, two wakes between live threads, and each says which
+    ``worker`` took its call (``parked``, or ``fresh`` where a thread was
+    started; ``mesh.shard`` too)
   * ``supervisor.host_fallback`` / ``supervisor.bisect``
   * ``consensus.vote`` / ``consensus.proposal`` / ``consensus.vote_ext``
     (per height-round)
@@ -79,11 +81,12 @@ on an injected clock) and stores the difference from the last sample under
 pseudo-stages of the same store, ``[1, difference]`` in the second(s) that
 passed: ``host.runq_wait`` (seconds the long-lived threads that opened a
 span, the caller, the dispatcher and the completion thread, spent runnable
-and not running; NOT the watchdog's workers, which live a millisecond and
-take their ``schedstat`` with them), ``host.cpu`` (CPU seconds of the WHOLE
-process, every thread, the workers too), ``host.throttled`` (the cgroup's
-``cpu.stat``), ``host.switches`` and ``host.faults`` (``getrusage``, whole
-process: involuntary switches, minor faults).  A source the host lacks is
+and not running; NOT the watchdog's workers, which park between calls but
+open no span, so their ids are not known here), ``host.cpu`` (CPU seconds
+of the WHOLE process, every thread, the workers too), ``host.throttled``
+(the cgroup's ``cpu.stat``), ``host.switches`` and ``host.faults``
+(``getrusage``, whole process: involuntary switches, minor faults).  A
+source the host lacks is
 left out, not zero.  ``stage_totals`` and ``stage_seconds`` carry
 them with the stages; ``host_summary`` (the ``host`` of the
 ``/debug/verify_trace`` document) gives them apart, and ``stage_summary``
@@ -368,9 +371,9 @@ def _default_host_readers() -> dict:
         readers[HOST_PREFIX + "runq_wait"] = _PerThread(_runq_wait_of)
     except Exception:  # noqa: BLE001 — not this kernel's
         pass
-    # the WHOLE process's CPU clock: the watchdog's fresh workers (where a
-    # launch's transfers and a fetch's pull run) live a millisecond each and
-    # no once-a-second reading of a thread's own clock would catch them
+    # the WHOLE process's CPU clock: the watchdog's workers (where a
+    # launch's transfers and a fetch's pull run) open no span, and the
+    # runtime's own threads are none of the program's
     readers[HOST_PREFIX + "cpu"] = lambda tids: time.process_time()
     try:
         import resource

@@ -13,6 +13,8 @@ Counters:
   * ``fused_segments``   — segments that rode in a fused dispatch
   * ``verify_calls`` / ``verify_seconds`` — commit-verification latency
     aggregate (observed by types/validation)
+  * ``watchdog_calls[parked|fresh]`` — calls under the dispatch watchdog
+    by the worker that served them (a parked one, or a thread started)
 
 Histograms (docs/observability.md) — real distributions on /metrics, not
 just cumulative sums:
@@ -73,6 +75,10 @@ def _zero() -> dict:
         "lane_dispatches": {},  # lane (str) -> dispatches routed there
         "lane_lanes_total": {},  # lane (str) -> padded lanes shipped
         "lane_lanes_used": {},  # lane (str) -> lanes carrying a signature
+        # calls under the dispatch watchdog (ops/supervisor._Watchdog) by
+        # the worker that served them: one that was parked, or a thread
+        # started for the call.  Steady traffic reads ``fresh`` level
+        "watchdog_calls": {"parked": 0, "fresh": 0},
     }
 
 
@@ -185,6 +191,13 @@ def record_lane_dispatch(lane: str, lanes_total: int, lanes_used: int) -> None:
         t[key] = t.get(key, 0) + int(lanes_total)
         u = _STATS["lane_lanes_used"]
         u[key] = u.get(key, 0) + int(lanes_used)
+
+
+def record_watchdog_call(worker: str) -> None:
+    """One call under the dispatch watchdog, served by a ``parked`` worker
+    or by a ``fresh`` thread."""
+    with _LOCK:
+        _STATS["watchdog_calls"][worker] += 1
 
 
 def record_fused(n_segments: int) -> None:

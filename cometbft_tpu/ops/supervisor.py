@@ -19,11 +19,13 @@ set, so degradation is verdict-preserving by construction: the host tier is
 the differential oracle the device kernels are tested against
 (tests/test_supervisor.py pins bitwise equality under every fault mode).
 
-Watchdog: dispatches run on a dedicated worker thread with a deadline
+Watchdog: dispatches run on a worker thread with a deadline
 (``COMETBFT_TPU_DISPATCH_TIMEOUT_MS``, default 120000; 0 disables) so a
 wedged XLA call cannot block the consensus thread — the wedged worker is
-abandoned (it exits when it unwedges) and a fresh one serves later
-dispatches.
+abandoned (it exits when it unwedges, and never serves again) and another
+serves later dispatches.  A worker whose call returned parks and takes
+the next call; a thread is started only when none is parked, so no call
+ever waits behind another (``_Watchdog``).
 
 Bisection: a *single poisoned input* that reproducibly kills the kernel
 (a lowering edge case, a driver-crashing encoding) would otherwise demote
@@ -57,6 +59,7 @@ from __future__ import annotations
 
 import logging
 import os
+import queue
 import threading
 import time
 from typing import Callable, Optional, Sequence
@@ -211,21 +214,59 @@ class FaultyBackend:
 # -- watchdog ----------------------------------------------------------------
 
 
+# the most workers kept parked (a worker that finds this many parked when
+# its call returns exits instead), and how long a parked one waits for a
+# call before it exits: a validator's votes come every few milliseconds
+# and its heights every few seconds, so the served path's worker lives on
+_PARKED_MAX = 8
+_PARK_IDLE_S = 10.0
+
+
+class _Job:
+    """One call between its caller and its worker: the worker puts
+    ``(val, err)`` into ``out`` unless the caller, its deadline past, has
+    marked the job ``abandoned``."""
+
+    __slots__ = ("fn", "out", "abandoned")
+
+    def __init__(self, fn: Callable):
+        self.fn = fn
+        self.out: "queue.SimpleQueue" = queue.SimpleQueue()
+        self.abandoned = False
+
+
 class _Watchdog:
-    """Per-call dispatch thread with a deadline.
+    """Dispatch threads with a deadline; a worker that has finished a call
+    PARKS and takes the next one.
 
-    ``call(fn, timeout_s)`` runs ``fn`` on a fresh daemon thread and waits
-    up to the deadline.  One thread PER CALL (spawn cost ~100 us, well
-    under any dispatch's cost) rather than a shared worker queue: with a
-    shared worker, queueing behind another caller's healthy-but-slow
-    dispatch would count against this caller's deadline and misattribute
-    concurrency as a device wedge, demoting a healthy backend.  Concurrent
-    dispatches run concurrently (jax execution is thread-safe).
+    ``call(fn, timeout_s)`` hands ``fn`` to the most recently parked
+    worker (the warmest), or starts a daemon thread at once when none is
+    parked, and waits up to the deadline, counted from its own entry.
+    Starting a thread is what this avoids: priced at ~100 us when it was
+    written, it costs 0.5 ms on the chip's host (a sandboxed kernel: 0.54
+    ms of a launch, 0.48 of a fetch, twice a dispatch; PERF.md PR 36),
+    where a wake between two live threads costs 0.07-0.10.
 
-    On timeout the thread is abandoned — it finishes (or stays wedged) in
-    the background and its result is discarded.  Abandoned threads are
+    NO call ever waits behind another: a worker parks only AFTER its
+    ``fn`` has returned, so a parked worker is never inside a device call,
+    and a call that finds none parked spawns and never queues.  That keeps
+    what a shared worker QUEUE would lose: queueing behind another
+    caller's healthy-but-slow dispatch would count against this caller's
+    deadline and misattribute concurrency as a device wedge, demoting a
+    healthy backend.  Concurrent dispatches run concurrently (jax
+    execution is thread-safe), each on a worker of its own.
+
+    On timeout the worker is ABANDONED and never reused: it finishes (or
+    stays wedged) in the background, finds its job abandoned, discards
+    value or exception and exits without parking.  Abandoned threads are
     bounded by the circuit breaker: after ``threshold`` timeouts the
-    backend stops being dispatched until a half-open probe.
+    backend stops being dispatched until a half-open probe.  Parked ones
+    are bounded by ``_PARKED_MAX`` and exit after ``_PARK_IDLE_S`` idle.
+
+    Which of the two served a call is marked on the caller's open span
+    (``worker`` = ``parked`` / ``fresh`` on ``verify.dispatch``,
+    ``verify.fetch``, ``mesh.shard``) and counted in
+    ``dispatch_stats["watchdog_calls"]``; the workers open no span.
 
     Known cosmetic limitation: if the PROCESS exits while an abandoned
     thread is still inside a wedged C++ (XLA) call, the runtime may abort
@@ -234,30 +275,69 @@ class _Watchdog:
     This only occurs on exit immediately after a real device wedge, a
     state where the operator is restarting the node anyway."""
 
-    @staticmethod
-    def _run(fn: Callable, box: dict, done: threading.Event) -> None:
-        try:
-            box["val"] = fn()
-        except BaseException as e:  # noqa: BLE001 — relayed to caller
-            box["err"] = e
-        done.set()
+    def __init__(self):
+        self._lock = threading.Lock()
+        # the parked workers' inboxes; LIFO: the last is the warmest
+        self._parked: "list[queue.SimpleQueue]" = []
+
+    def _serve(self, inbox: "queue.SimpleQueue") -> None:
+        while True:
+            try:
+                job = inbox.get(timeout=_PARK_IDLE_S)
+            except queue.Empty:
+                with self._lock:
+                    if inbox in self._parked:
+                        self._parked.remove(inbox)
+                        return
+                # a call took us as the wait ran out: its job is coming
+                job = inbox.get()
+            val = err = None
+            try:
+                val = job.fn()
+            except BaseException as e:  # noqa: BLE001 — relayed to caller
+                err = e
+            with self._lock:
+                if job.abandoned:
+                    return  # late value or exception reaches nobody
+                park = len(self._parked) < _PARKED_MAX
+                if park:
+                    # BEFORE the caller wakes: its next call finds us
+                    self._parked.append(inbox)
+            job.out.put((val, err))
+            if not park:
+                return
+            del job, val, err  # a parked worker holds nobody's arrays
 
     def call(self, fn: Callable, timeout_s: float):
-        done = threading.Event()
-        box: dict = {}
-        threading.Thread(
-            target=self._run,
-            args=(fn, box, done),
-            name="crypto-dispatch",
-            daemon=True,
-        ).start()
-        if not done.wait(timeout_s):
+        t0 = time.monotonic()
+        job = _Job(fn)
+        with self._lock:
+            inbox = self._parked.pop() if self._parked else None
+        how = "fresh" if inbox is None else "parked"
+        if inbox is None:
+            inbox = queue.SimpleQueue()
+            threading.Thread(
+                target=self._serve,
+                args=(inbox,),
+                name="crypto-dispatch",
+                daemon=True,
+            ).start()
+        inbox.put(job)
+        # while the worker runs
+        dispatch_stats.record_watchdog_call(how)
+        tracing.mark(worker=how)
+        left = timeout_s - (time.monotonic() - t0)
+        try:
+            val, err = job.out.get(timeout=max(left, 0.0))
+        except queue.Empty:
+            with self._lock:
+                job.abandoned = True
             raise DispatchTimeoutError(
                 f"device dispatch exceeded {timeout_s:.3f}s watchdog deadline"
-            )
-        if "err" in box:
-            raise box["err"]
-        return box["val"]
+            ) from None
+        if err is not None:
+            raise err
+        return val
 
 
 _WATCHDOG = _Watchdog()

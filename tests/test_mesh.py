@@ -840,6 +840,23 @@ class TestServedMesh:
                       "sched.handoff.wake"):
             assert len(_spans(stage)) == 1, stage
 
+    def test_every_shard_pull_says_which_worker_served_it(self, served):
+        """ISSUE 37: the launch and each shard's pull are watchdog calls in
+        turn, so once the launch's worker has parked every pull finds it:
+        five calls a dispatch, four of them at least on a parked worker."""
+        from cometbft_tpu.ops import dispatch_stats
+        from cometbft_tpu.verifysched import service
+
+        served(4)
+        pubs, msgs, sigs = _signed(b"served/worker", self.N)
+        assert all(service.verify_segment_sync(pubs, msgs, sigs))
+        (disp,) = _spans("verify.dispatch")
+        assert disp["attrs"]["worker"] in ("fresh", "parked")
+        shards = _spans("mesh.shard")
+        assert [s["attrs"]["worker"] for s in shards] == ["parked"] * 4
+        calls = dispatch_stats.snapshot()["watchdog_calls"]
+        assert calls["parked"] >= 4 and calls["parked"] + calls["fresh"] == 5
+
     @pytest.mark.parametrize("width", WIDTHS)
     def test_the_parts_add_up(self, width):
         """The accept bits each shard gave, concatenated in ordinal order,

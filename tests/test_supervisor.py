@@ -249,6 +249,232 @@ class TestWatchdog:
         )
         assert tid == threading.get_ident()
 
+    # ISSUE 37: a worker that has finished a call parks and takes the next
+
+    @pytest.fixture
+    def wd(self, monkeypatch):
+        """A watchdog of this test's own: nobody else's worker is parked."""
+        wd = supervisor._Watchdog()
+        monkeypatch.setattr(supervisor, "_WATCHDOG", wd)
+        dispatch_stats.reset()
+        return wd
+
+    @staticmethod
+    def _calls():
+        return dispatch_stats.snapshot()["watchdog_calls"]
+
+    @staticmethod
+    def _dispatch_threads():
+        return {
+            t.ident for t in threading.enumerate()
+            if t.name == "crypto-dispatch"
+        }
+
+    def test_two_calls_in_turn_run_on_the_same_thread(self, wd):
+        from cometbft_tpu.libs import tracing
+
+        tracing.get_tracer().reset()
+        idents = []
+        for _ in range(2):
+            with tracing.span("verify.dispatch"):
+                idents.append(
+                    supervisor.watchdog_call(threading.get_ident, timeout_s=5.0)
+                )
+        assert idents[0] == idents[1] != threading.get_ident()
+        marks = [sp["attrs"]["worker"] for sp in tracing.get_tracer().tail(2)]
+        assert marks == ["fresh", "parked"]
+        assert self._calls() == {"fresh": 1, "parked": 1}
+        assert len(wd._parked) == 1
+
+    @pytest.mark.parametrize("late", ["value", "exception"])
+    def test_abandoned_worker_never_serves_again(self, wd, late):
+        release = threading.Event()
+        seen = {}
+
+        def wedge():
+            seen["ident"] = threading.get_ident()
+            release.wait(5.0)
+            if late == "exception":
+                raise RuntimeError("late")
+            return "late"
+
+        t0 = time.monotonic()
+        with pytest.raises(bh.DispatchTimeoutError):
+            supervisor.watchdog_call(wedge, timeout_s=0.05, backend="xla")
+        assert 0.05 <= time.monotonic() - t0 < 2.0  # at the deadline
+        release.set()
+        got, served = [], set()
+
+        def fn(i):
+            served.add(threading.get_ident())
+            return i
+
+        for i in range(20):
+            got.append(
+                supervisor.watchdog_call(lambda i=i: fn(i), timeout_s=5.0)
+            )
+        # the late value and the late exception reached nobody
+        assert got == list(range(20))
+        assert seen["ident"] not in served
+        # it exited instead of parking
+        for _ in range(200):
+            if seen["ident"] not in self._dispatch_threads():
+                break
+            time.sleep(0.01)
+        assert seen["ident"] not in self._dispatch_threads()
+        assert len(wd._parked) == 1 and len(served) <= 2
+        assert self._calls()["fresh"] == 2  # the wedged one's, then one more
+
+    def test_concurrent_calls_never_queue(self, wd):
+        """Eight calls held on a barrier, ONE worker parked: all eight are
+        inside ``fn`` before any returns (a queue would break the barrier),
+        each on a thread of its own under its own deadline."""
+        supervisor.watchdog_call(lambda: None, timeout_s=5.0)
+        assert len(wd._parked) == 1
+        barrier = threading.Barrier(8, timeout=5.0)
+        out, errs = [], []
+
+        def fn():
+            barrier.wait()
+            return threading.get_ident()
+
+        def caller():
+            try:
+                out.append(supervisor.watchdog_call(fn, timeout_s=5.0))
+            except BaseException as e:  # noqa: BLE001 — the assert shows it
+                errs.append(e)
+
+        callers = [threading.Thread(target=caller) for _ in range(8)]
+        for t in callers:
+            t.start()
+        for t in callers:
+            t.join(10.0)
+        assert errs == [] and len(set(out)) == 8
+        assert self._calls() == {"fresh": 8, "parked": 1}
+
+    def test_a_raising_fn_leaves_its_worker_reusable(self, wd):
+        seen = []
+
+        def boom():
+            seen.append(threading.get_ident())
+            raise KeyboardInterrupt("a BaseException too")
+
+        with pytest.raises(KeyboardInterrupt):
+            supervisor.watchdog_call(boom, timeout_s=5.0)
+        assert supervisor.watchdog_call(
+            threading.get_ident, timeout_s=5.0
+        ) == seen[0]
+        assert self._calls() == {"fresh": 1, "parked": 1}
+
+    def test_parked_workers_are_bounded_and_an_idle_one_exits(
+        self, wd, monkeypatch
+    ):
+        monkeypatch.setattr(supervisor, "_PARKED_MAX", 2)
+        monkeypatch.setattr(supervisor, "_PARK_IDLE_S", 0.1)
+
+        class Peak(list):
+            peak = 0
+
+            def append(self, w):
+                super().append(w)
+                self.peak = max(self.peak, len(self))
+
+        wd._parked = Peak()
+        before = self._dispatch_threads()
+        barrier = threading.Barrier(5, timeout=5.0)
+        callers = [
+            threading.Thread(
+                target=supervisor.watchdog_call,
+                args=(barrier.wait,),
+                kwargs={"timeout_s": 5.0},
+            )
+            for _ in range(5)
+        ]
+        for t in callers:
+            t.start()
+        for t in callers:
+            t.join(10.0)
+        # five workers came home at once: two parked, three exited
+        assert self._calls() == {"fresh": 5, "parked": 0}
+        assert wd._parked.peak == 2
+        for _ in range(500):
+            if not wd._parked and self._dispatch_threads() <= before:
+                break
+            time.sleep(0.01)
+        assert wd._parked == [] and self._dispatch_threads() <= before
+        # and the next call starts a thread again
+        assert supervisor.watchdog_call(lambda: 8, timeout_s=5.0) == 8
+        assert self._calls() == {"fresh": 6, "parked": 0}
+
+    def test_many_callers_each_get_their_own_answer(self, wd):
+        """Stress, time-bounded: more callers than cores hand jobs to and take
+        workers from the one parked list at a short switch interval; a lost
+        update there would cross two calls' answers or lose a call."""
+        import sys
+
+        callers_n, each = 24, 150
+        wrong, errs = [], []
+
+        def caller(k):
+            try:
+                for i in range(each):
+                    got = supervisor.watchdog_call(
+                        lambda k=k, i=i: (k, i), timeout_s=10.0
+                    )
+                    if got != (k, i):
+                        wrong.append((k, i, got))
+            except BaseException as e:  # noqa: BLE001 — the assert shows it
+                errs.append(e)
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            callers = [
+                threading.Thread(target=caller, args=(k,))
+                for k in range(callers_n)
+            ]
+            for t in callers:
+                t.start()
+            for t in callers:
+                t.join(60.0)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in callers)
+        assert errs == [] and wrong == []
+        calls = self._calls()
+        assert calls["fresh"] + calls["parked"] == callers_n * each
+        assert calls["parked"] > calls["fresh"]
+        assert len(wd._parked) <= supervisor._PARKED_MAX
+
+    def test_zero_timeout_takes_no_worker(self, wd):
+        assert supervisor.watchdog_call(lambda: 3, timeout_s=0) == 3
+        assert supervisor.watchdog_call(lambda: 4, timeout_s=-1.0) == 4
+        assert self._calls() == {"fresh": 0, "parked": 0}
+        assert wd._parked == []
+
+    def test_a_dispatch_marks_its_spans_and_is_parked_after_the_first(self, wd):
+        """Through ``_launch_verify`` / ``_fetch_launched``: ``verify.dispatch``
+        and ``verify.fetch`` say which worker served them, and of ten
+        dispatches' twenty calls only the first two at most start a thread."""
+        from cometbft_tpu.libs import tracing
+
+        pubs, msgs, sigs = _mixed_batch(np.random.default_rng(37), 9)
+        runner = _CountingRunner()
+        supervisor.set_device_runner(runner)
+        tracing.get_tracer().reset()
+        for _ in range(10):
+            h = supervisor.dispatch_verify(pubs, msgs, sigs)
+            assert list(supervisor.fetch_verify(h)) == _oracle(pubs, msgs, sigs)
+        assert runner.calls == 10
+        marks = [
+            sp["attrs"]["worker"] for sp in tracing.get_tracer().tail(0)
+            if sp["stage"] in ("verify.dispatch", "verify.fetch")
+        ]
+        assert len(marks) == 20 and set(marks[2:]) == {"parked"}
+        calls = self._calls()
+        assert calls["fresh"] <= 2 and calls["fresh"] + calls["parked"] == 20
+        assert tracing.trace_document(0, 0)["dispatch"]["watchdog_calls"] == calls
+
 
 # -- differential: fault modes vs host oracle --------------------------------
 
