@@ -590,6 +590,7 @@ def cmd_inspect(args) -> int:
 def cmd_light(args) -> int:
     """Reference: cmd light — run a light-client RPC proxy daemon."""
     from cometbft_tpu.light import (
+        SEQUENTIAL,
         SKIPPING,
         HTTPProvider,
         LightClient,
@@ -623,7 +624,10 @@ def cmd_light(args) -> int:
     store = LightStore(
         SqliteKV(os.path.join(args.home, "light", "trust.db"), surface="light")
     )
-    client = LightClient(args.chain_id, opts, primary, witnesses, store)
+    client = LightClient(
+        args.chain_id, opts, primary, witnesses, store,
+        mode=SEQUENTIAL if args.sequential else SKIPPING,
+    )
     proxy = LightProxy(client, args.primary, laddr=args.laddr)
     proxy.start()
     print(f"Light client proxy listening on {args.laddr} "
@@ -902,6 +906,12 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("--trust-height", type=int, default=0)
     sp.add_argument("--trust-hash", default="")
     sp.add_argument("--trust-period", type=int, default=168 * 3600)
+    sp.add_argument(
+        "--sequential",
+        action="store_true",
+        help="verify every header from the trusted one to the target "
+        "(sequential), not by skipping",
+    )
     sp.set_defaults(fn=cmd_light)
 
     sp = sub.add_parser("confix", help="migrate config.toml to this version")

@@ -55,7 +55,11 @@ per dispatch, never per signature):
   * ``supervisor.host_fallback`` / ``supervisor.bisect``
   * ``consensus.vote`` / ``consensus.proposal`` / ``consensus.vote_ext``
     (per height-round)
-  * ``blocksync.prefetch`` / ``light.chain``     — speculative windows
+  * ``blocksync.prefetch``                       — speculative window
+  * ``light.sync`` > ``light.store`` (``op`` load / save), ``light.chain``
+    > ``light.chain.prep`` (> ``light.checks``, ``commit.sign_bytes``,
+    ``sched.segment``), ``light.chain.wait`` — the light client's request,
+    and the sequential client's window of headers on the served path
   * ``warmboot.shape`` / ``warmboot.run``        — warm-boot progress
 
 Anomaly forensics: ``record_anomaly(kind, **attrs)`` counts every anomaly
@@ -724,6 +728,28 @@ class Tracer:
     def lap(self, stage: str) -> Lap:
         return Lap(self if enabled() else None, stage)
 
+    def start(self, stage: str, **attrs) -> Optional[Span]:
+        """An unfinished span, child of this thread's innermost open one,
+        and on no thread's stack: a stage the caller hands away and takes
+        back later (a queued segment, ``verifysched.submit_segment_async``)
+        opens here, ``under`` makes it the parent where it must be, and
+        ``finish`` lands it.  The caller's own spans meanwhile are not its
+        children.  Unlike ``begin`` it writes no open record: it is a stage
+        of the hot path, not an anchor a crash has to leave behind."""
+        if not enabled():
+            return None
+        stack = self._stack()
+        parent = stack[-1] if stack else None
+        sid = next(self._ids)
+        return Span(
+            parent.trace_id if parent is not None else sid,
+            sid,
+            parent.span_id if parent is not None else None,
+            stage,
+            self._clock(),
+            attrs,
+        )
+
     def time(self) -> float:
         """The tracer's clock (virtual in sim).  Event-driven callers use
         it for retroactive ``record_span`` timestamps so span times always
@@ -1174,12 +1200,13 @@ class Tracer:
                 e = g["nodes"][node] = {"node": node, "steps": {}}
             return e
 
-        # a light client's step is a trace of its own (``light.verify`` is
-        # the root of its commit passes), as standalone as a bare call
+        # a light client's request is a trace of its own (``light.sync``,
+        # or ``light.verify`` called bare, is the root of its commit
+        # passes), as standalone as a bare call
         light_traces = {
             sp.trace_id
             for sp in ring
-            if sp.stage == "light.verify" and sp.parent_id is None
+            if sp.stage in ("light.sync", "light.verify") and sp.parent_id is None
         }
         for sp in ring:
             if sp.t_end is None:

@@ -2,10 +2,13 @@
 
 Header-sync client: initialize from trust options (height + hash inside
 the trusting period), then verify target headers either sequentially
-(``verifySequential``, :608) or by skipping with bisection
-(``verifySkipping``, :701).  A witness ``detector`` (reference:
-light/detector.go) cross-checks every newly verified header against
-secondary providers; divergence yields light-client-attack evidence
+(``verifySequential``, :608: every header from the trusted one to the
+target, each commit queued at light priority while the next header is
+prepared) or by skipping with bisection (``verifySkipping``, :701).  As
+upstream's, the client holds its latest trusted block in memory, verifies
+forward from it, and saves the target only.  A witness ``detector``
+(reference: light/detector.go) cross-checks every newly verified header
+against secondary providers; divergence yields light-client-attack evidence
 reported to both sides.
 """
 
@@ -16,6 +19,7 @@ from fractions import Fraction
 from typing import Optional
 
 from cometbft_tpu.libs import log as liblog
+from cometbft_tpu.libs import tracing
 from cometbft_tpu.light import verifier as lv
 from cometbft_tpu.light.provider import (
     ErrLightBlockNotFound,
@@ -66,6 +70,9 @@ class LightClient:
         self.max_clock_drift_s = max_clock_drift_s
         self.logger = logger or liblog.nop_logger()
         self.now_fn = now_fn
+        # the newest verified block, the one to verify forward from
+        # (reference: ``c.latestTrustedBlock``); the store is read at start
+        self.latest_trusted_block: Optional[LightBlock] = None
 
         trust_options.validate()
         self._initialize()
@@ -75,7 +82,9 @@ class LightClient:
     def _initialize(self) -> None:
         existing = self.store.latest()
         if existing is not None and existing.height >= self.trust_options.height:
-            return  # already initialized at/after the trust height
+            # already initialized at/after the trust height
+            self.latest_trusted_block = existing
+            return
         lb = self.primary.light_block(self.trust_options.height)
         if lb.hash() != self.trust_options.hash:
             raise LightClientError(
@@ -96,19 +105,29 @@ class LightClient:
             lb.height,
             lb.signed_header.commit,
         )
-        self.store.save_light_block(lb)
+        self._update_trusted(lb)
+
+    def _update_trusted(self, lb: LightBlock) -> None:
+        """Save a verified block and, where it is the newest, hold it as the
+        one to verify forward from (reference: client.go
+        updateTrustedLightBlock, ``c.latestTrustedBlock``)."""
+        with tracing.span("light.store", op="save", height=lb.height):
+            self.store.save_light_block(lb)
+        latest = self.latest_trusted_block
+        if latest is None or lb.height > latest.height:
+            self.latest_trusted_block = lb
 
     # -- public API --------------------------------------------------------
 
     def trusted_light_block(self, height: int = 0) -> Optional[LightBlock]:
         if height == 0:
-            return self.store.latest()
+            return self.latest_trusted_block
         return self.store.light_block(height)
 
     def update(self, now: Optional[float] = None) -> Optional[LightBlock]:
         """Verify the primary's latest header (reference: client.go:431)."""
         latest = self.primary.light_block(0)
-        trusted = self.store.latest()
+        trusted = self.latest_trusted_block
         if trusted is not None and latest.height <= trusted.height:
             return trusted
         return self.verify_light_block_at_height(latest.height, now)
@@ -116,53 +135,55 @@ class LightClient:
     def verify_light_block_at_height(
         self, height: int, now: Optional[float] = None
     ) -> LightBlock:
-        """Reference: client.go:469 VerifyLightBlockAtHeight."""
+        """Reference: client.go:469 VerifyLightBlockAtHeight.  A height
+        above the latest trusted block is verified forward from that block,
+        held in memory; a lower one that is not stored, from the newest
+        stored block below it.  Only the target is saved, after the
+        witnesses agree (reference: verifyLightBlock → detectDivergence →
+        updateTrustedLightBlock); a failed request saves nothing."""
         now = self.now_fn() if now is None else now
-        got = self.store.light_block(height)
-        if got is not None:
-            return got
-        trusted = self.store.light_block_before(height + 1)
-        if trusted is None:
-            raise LightClientError("store empty: client not initialized")
-        if trusted.height > height:
-            raise LightClientError(
-                f"cannot verify height {height} below trusted root "
-                f"{trusted.height} (use a store with earlier blocks)"
-            )
-        target = self.primary.light_block(height)
-        # verify first (collecting the chain of newly trusted blocks), then
-        # cross-check against witnesses, and only THEN persist: a header the
-        # witnesses dispute must never enter the trusted store (reference:
-        # detector runs before the store write, client.go:522-534)
-        verified: list[LightBlock] = []
-        if self.mode == SEQUENTIAL:
-            self._verify_sequential(trusted, target, now, verified)
-        else:
-            self._verify_skipping(trusted, target, now, verified)
-        self._detect_divergence(target, now)
-        for lb in verified:
-            self.store.save_light_block(lb)
-        return target
+        with tracing.span("light.sync", to=height) as sp:
+            with tracing.span("light.store", op="load", height=height):
+                got = self.store.light_block(height)
+            if got is not None:
+                return got
+            trusted = self.latest_trusted_block
+            if trusted is None:
+                raise LightClientError("client not initialized")
+            if height < trusted.height:
+                with tracing.span("light.store", op="load", height=height):
+                    trusted = self.store.light_block_before(height)
+                if trusted is None:
+                    raise LightClientError(
+                        f"cannot verify height {height} below the trusted "
+                        f"root (use a store with earlier blocks)"
+                    )
+            sp.set(**{"from": trusted.height, "headers": height - trusted.height})
+            target = self.primary.light_block(height)
+            if self.mode == SEQUENTIAL:
+                self._verify_sequential(trusted, target, now)
+            else:
+                self._verify_skipping(trusted, target, now)
+            self._detect_divergence(target, now)
+            self._update_trusted(target)
+            return target
 
     # -- sequential (reference: client.go:608) -----------------------------
 
-    # headers per pipelined window: enough to amortize the per-dispatch
-    # floor, small enough to bound wasted work past a bad header
+    # headers a call of ``verify_adjacent_chain``: how far past a bad header
+    # the client may have fetched and queued, and where the verdicts of a
+    # window must all be in before the next is fetched
     SEQ_WINDOW = 8
 
     def _verify_sequential(
-        self,
-        trusted: LightBlock,
-        target: LightBlock,
-        now: float,
-        verified: list,
+        self, trusted: LightBlock, target: LightBlock, now: float
     ) -> None:
-        """Windows of up to SEQ_WINDOW headers go through
-        ``verify_adjacent_chain``: next-header host prep overlaps the
-        in-flight commit dispatch (``ops.verify.verify_batches_overlapped``)
-        instead of blocking on one height at a time.  Error behavior per
-        header is that of ``verify_adjacent``; nothing from a failed window
-        is appended to ``verified``."""
+        """Every header from trusted+1 to the target, in windows of up to
+        SEQ_WINDOW through ``verify_adjacent_chain``: each header's commit
+        is queued at light priority while the next one is prepared, and a
+        rejection is the first bad header's (``ErrVerificationFailed``).
+        Interim headers are verified, not saved (upstream keeps them in the
+        detector's trace only)."""
         current = trusted
         heights = list(range(trusted.height + 1, target.height + 1))
         for w in range(0, len(heights), self.SEQ_WINDOW):
@@ -180,17 +201,12 @@ class LightClient:
                 now,
                 self.max_clock_drift_s,
             )
-            verified.extend(chunk)
             current = chunk[-1]
 
     # -- skipping / bisection (reference: client.go:701) -------------------
 
     def _verify_skipping(
-        self,
-        trusted: LightBlock,
-        target: LightBlock,
-        now: float,
-        verified: list,
+        self, trusted: LightBlock, target: LightBlock, now: float
     ) -> None:
         current = trusted
         pending = [target]
@@ -215,7 +231,6 @@ class LightClient:
                     )
                 pending.append(self.primary.light_block(mid))
                 continue
-            verified.append(candidate)
             current = candidate
             pending.pop()
 

@@ -5,13 +5,17 @@ header's next-validators hash; ``verify_non_adjacent`` (:30) checks an
 arbitrary later header by requiring >1/3 (trust level) of the TRUSTED
 validator set to have signed it, then +2/3 of its own set.  Both commit
 checks route through the batch-verifier seam (the TPU path).
+``verify_adjacent_chain`` is the sequential client's run of adjacent steps
+(light/client.go:608): each header's misses queued at light priority while
+the next header is prepared.
 """
 
 from __future__ import annotations
 
-import time
+from collections import deque
 from dataclasses import dataclass
 from fractions import Fraction
+from typing import NamedTuple
 
 from cometbft_tpu.libs import tracing
 from cometbft_tpu.types import validation
@@ -35,6 +39,25 @@ class ErrOldHeaderExpired(VerificationError):
 
 class ErrInvalidHeader(VerificationError):
     pass
+
+
+class ErrVerificationFailed(VerificationError):
+    """Header ``to`` failed against the trusted header ``from_height``;
+    ``reason`` is the error of that one step (reference: light/errors.go
+    ErrVerificationFailed, as verifySequential wraps it)."""
+
+    def __init__(self, from_height: int, to: int, reason: Exception):
+        super().__init__(
+            f"verify from #{from_height} to #{to} failed: {reason}"
+        )
+        self.from_height = from_height
+        self.to = to
+        self.reason = reason
+
+
+# what a step's verdict is raised as; anything else (a backend that gave no
+# definitive verdict) is no verdict on the header and is not wrapped
+_VERDICTS = (VerificationError, validation.CommitVerificationError)
 
 
 @dataclass
@@ -155,92 +178,129 @@ def verify_adjacent_chain(
     now: float,
     max_clock_drift_s: float = 10.0,
 ) -> None:
-    """Verify a consecutive run of headers (trusted+1, trusted+2, ...) with
-    host/device overlap: every header's host work (adjacency + validator-
-    hash link + sign-bytes construction) runs up front, then all commit
-    batches are dispatched through ``ops.verify.verify_batches_overlapped``
-    — header i+1's host prep overlaps header i's in-flight dispatch, and on
-    backends that queue dispatches the kernels pipeline.  Judgement stays
-    strictly in order, so the raised error class matches what sequential
-    ``verify_adjacent`` raises for that header (when several headers are
-    independently bad, the chain may surface a later header's *structural*
-    error before an earlier header's *signature* error — either way the
-    sync aborts and nothing is trusted).
+    """Verify a consecutive run of headers (trusted+1, trusted+2, ...) as a
+    loop of ``verify_adjacent`` does (reference: light/client.go:608
+    verifySequential), on the served path: each header's host pass (its
+    checks and set root, sign-bytes, cache look-up) ends by queueing its
+    cache misses as ONE segment at ``PRIO_LIGHT``, and the caller goes on
+    to the next header's host pass while that segment flies.  Verdicts are
+    taken back in height order: each header's as soon as it has landed, the
+    rest at the end.  What leaves the queue together is the scheduler's
+    rule; nothing here calls ``ops`` or the supervisor.
 
-    Falls back to the plain sequential loop when the accelerator batch
-    backend is off or a validator set is not uniformly ed25519."""
-    from cometbft_tpu.crypto import sigcache
-    from cometbft_tpu.types import validation
+    A rejection is that of the FIRST bad header by height, with that
+    header's own class: ``ErrVerificationFailed`` whose ``reason`` is what
+    ``verify_adjacent`` raises for it.  A header that fails its host pass
+    is held until every header before it has been judged.  Nothing of a
+    failed run is trusted, and the segments queued past the bad header are
+    not waited for.
+
+    With no trusted device, the scheduler off, or a set that is not
+    uniformly ed25519, the plain loop of ``verify_adjacent`` serves, with
+    the same verdicts."""
+    from cometbft_tpu import verifysched
 
     if not news:
         return
-
-    def _sequential() -> None:
+    if not (
+        validation.fused_verify_eligible(lb.validator_set for lb in news)
+        and verifysched.scheduler_active()
+    ):
         current = trusted
         for lb in news:
-            verify_adjacent(
-                chain_id, current, lb, trusting_period_s, now, max_clock_drift_s
-            )
+            try:
+                verify_adjacent(
+                    chain_id, current, lb, trusting_period_s, now,
+                    max_clock_drift_s,
+                )
+            except _VERDICTS as e:
+                raise ErrVerificationFailed(current.height, lb.height, e) from e
             current = lb
+        return
 
-    # shared eligibility gate (types/validation.fused_verify_eligible):
-    # trusted accelerator + live device tier (with every breaker open the
-    # sequential path host-verifies per header — same verdicts, no fused
-    # batches to build) + uniformly-ed25519 validator sets
-    if len(news) < 2 or not validation.fused_verify_eligible(
-        lb.validator_set for lb in news
-    ):
-        return _sequential()
+    from cometbft_tpu.crypto import sigcache
 
-    # host pass: adjacency checks + entry collection for every header
-    prepared = []
-    current = trusted
-    for lb in news:
-        _check_adjacent_headers(
-            chain_id, current, lb, trusting_period_s, now, max_clock_drift_s
-        )
-        prepared.append(
-            validation.prepare_commit_light(
-                chain_id,
-                lb.validator_set,
-                lb.signed_header.commit.block_id,
-                lb.height,
-                lb.signed_header.commit,
-            )
-        )
-        current = lb
-
-    # device pass: ship only cache misses, one overlapped batch per header
-    per_header = [  # (prepared, its sigcache.Partition: bits with None holes)
-        (p, sigcache.partition_misses(p.pubs, p.msgs, p.sigs))
-        for p in prepared
-    ]
-    from cometbft_tpu.ops import verify as ov
-
-    work = [
-        (
-            [p.pubs[j] for j in part.miss],
-            [p.msgs[j] for j in part.miss],
-            [p.sigs[j] for j in part.miss],
-        )
-        for p, part in per_header
-        if part.miss
-    ]
-    from cometbft_tpu.libs import tracing
-
+    queued: "deque[_Queued]" = deque()
+    current, sent = trusted, 0
     with tracing.span(
-        "light.chain",
-        headers=len(news),
-        h0=news[0].height,
-        sigs=sum(len(part.miss) for _, part in per_header),
-    ):
-        fresh = iter(ov.verify_batches_overlapped(work) if work else [])
+        "light.chain", headers=len(news), h0=news[0].height
+    ) as chain_span:
+        try:
+            for lb in news:
+                try:
+                    with tracing.span("light.chain.prep", height=lb.height):
+                        with tracing.span("light.checks"):
+                            _check_adjacent_headers(
+                                chain_id, current, lb, trusting_period_s, now,
+                                max_clock_drift_s,
+                            )
+                        p = validation.prepare_commit_light(
+                            chain_id,
+                            lb.validator_set,
+                            lb.signed_header.commit.block_id,
+                            lb.height,
+                            lb.signed_header.commit,
+                        )
+                        part = sigcache.partition_misses(p.pubs, p.msgs, p.sigs)
+                        pending = None
+                        if part.miss:
+                            pending = verifysched.submit_segment_async(
+                                [p.pubs[j] for j in part.miss],
+                                [p.msgs[j] for j in part.miss],
+                                [p.sigs[j] for j in part.miss],
+                                verifysched.PRIO_LIGHT,
+                                part.keys,
+                            )
+                            sent += len(part.miss)
+                except _VERDICTS as e:
+                    _judge(queued, block=True)  # an earlier header's comes first
+                    raise ErrVerificationFailed(current.height, lb.height, e) from e
+                queued.append(_Queued(current.height, lb.height, p, part, pending))
+                _judge(queued, block=False)
+                current = lb
+            _judge(queued, block=True)
+        finally:
+            chain_span.set(sigs=sent)
 
-    # judge strictly in order
-    for p, part in per_header:
-        if part.miss:
-            sigcache.writeback(part, next(fresh))
-        validation.finish_commit_light(p, part.bits)
+
+class _Queued(NamedTuple):
+    """One header of ``verify_adjacent_chain`` between its host pass and its
+    judgement."""
+
+    trusted_height: int
+    height: int
+    prepared: "validation.PreparedCommit"
+    part: object  # its ``sigcache.Partition``
+    pending: object  # its ``verifysched.PendingSegment``; None: all hits
+
+
+def _judge(queued: "deque[_Queued]", block: bool) -> None:
+    """Judge the queued headers in height order, writing each one's fresh
+    verdicts back first; without ``block``, stop at the first whose segment
+    has not landed."""
+    from cometbft_tpu import verifysched
+    from cometbft_tpu.crypto import sigcache
+
+    while queued:
+        q = queued[0]
+        if q.pending is not None:
+            if not block and not q.pending.done():
+                return
+            with tracing.span("light.chain.wait", height=q.height):
+                got = verifysched.wait_segment(q.pending)
+            sigcache.writeback(q.part, got)
+        queued.popleft()
+        if None in q.part.bits:
+            from cometbft_tpu.crypto import backend_health
+
+            raise backend_health.BackendError(
+                "the scheduler gave no definitive verdict for some entries "
+                "(infrastructure failure, not a signature verdict)"
+            )
+        try:
+            validation.finish_commit_light(q.prepared, q.part.bits)
+        except _VERDICTS as e:
+            raise ErrVerificationFailed(q.trusted_height, q.height, e) from e
 
 
 def verify_non_adjacent(

@@ -24,9 +24,11 @@ from cometbft_tpu.verifysched.service import (  # noqa: F401
     priority_class,
     reset_scheduler,
     scheduler_active,
+    submit_segment_async,
     verify_cached,
     verify_many_cached,
     verify_now,
     verify_segment_sync,
+    wait_segment,
 )
 from cometbft_tpu.verifysched import stats  # noqa: F401

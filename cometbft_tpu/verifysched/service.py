@@ -1248,24 +1248,45 @@ def verify_many_cached(
     return out
 
 
-def verify_segment_sync(
+class PendingSegment:
+    """A segment queued by ``submit_segment_async`` and not yet waited on:
+    its ``sched.segment`` span (open, on no thread's stack), the submit's
+    lap, the futures, and the shed tail's verdicts."""
+
+    __slots__ = ("seg", "submitted", "futs", "direct", "n", "shed")
+
+    def __init__(self, seg, submitted, futs, direct, n: int, shed: int):
+        self.seg = seg
+        self.submitted = submitted
+        self.futs = futs
+        self.direct = direct
+        self.n = n
+        self.shed = shed
+
+    def done(self) -> bool:
+        """Every verdict has landed: ``wait_segment`` will not block."""
+        return all(f.done() for f in self.futs)
+
+
+def submit_segment_async(
     pubs: Sequence[bytes],
     msgs: Sequence[bytes],
     sigs: Sequence[bytes],
     priority=None,
     keys: Optional[Sequence[bytes]] = None,
-) -> "list[bool]":
-    """The batch-verifier bridge: submit a pre-partitioned segment of raw
-    ed25519 triples (the caller — ``_CollectingVerifier`` — already took
-    its cache hits) as ONE queue entry and wait on its one future.  The
-    tail that admission control shed is verified in one direct supervised
-    dispatch instead, so the call never blocks on queue capacity.  ``keys``
-    are the triples' sigcache keys where the caller's look-up hashed them
-    (``submit_segment``): the caller then stores every verdict this
-    returns, the shed tail's too, and the scheduler none."""
+) -> PendingSegment:
+    """The first half of ``verify_segment_sync``: queue a pre-partitioned
+    segment as ONE entry and return without waiting, so that the caller
+    can do other work while it flies (the sequential light client prepares
+    its next header).  The tail that admission control shed is verified
+    here, in one direct supervised dispatch, so the call never blocks on
+    queue capacity.  ``wait_segment`` takes the segment back; the
+    ``sched.segment`` span runs from here to the end of that wait."""
     prio = current_priority() if priority is None else priority
     n = len(pubs)
-    with tracing.span("sched.segment", items=n) as seg:
+    tracer = tracing.get_tracer()
+    seg = tracer.start("sched.segment", items=n)
+    with tracer.under(seg):
         # submit and wait end while the dispatcher writes its own spans:
         # timed here, recorded together after the wait (``tracing.Lap``)
         with tracing.lap("sched.submit") as submitted:
@@ -1291,11 +1312,36 @@ def verify_segment_sync(
                 prio, time.perf_counter() - t0, n - admitted
             )
             direct = [bool(b) for b in got]
-        out: "list[bool]" = []
-        with tracing.lap("sched.wait") as waited:
-            for f in futs:
-                out.extend(f.result())
-        out.extend(direct)
-        submitted.record(parent=seg, items=n, shed=n - admitted)
-        _record_wait(waited, seg, futs)
+    return PendingSegment(seg, submitted, futs, direct, n, n - admitted)
+
+
+def wait_segment(pending: PendingSegment) -> "list[bool]":
+    """The second half: wait on the segment's futures and return its
+    verdicts by index, the shed tail's last."""
+    out: "list[bool]" = []
+    with tracing.lap("sched.wait") as waited:
+        for f in pending.futs:
+            out.extend(f.result())
+    out.extend(pending.direct)
+    pending.submitted.record(
+        parent=pending.seg, items=pending.n, shed=pending.shed
+    )
+    _record_wait(waited, pending.seg, pending.futs)
+    tracing.get_tracer().finish(pending.seg)
     return out
+
+
+def verify_segment_sync(
+    pubs: Sequence[bytes],
+    msgs: Sequence[bytes],
+    sigs: Sequence[bytes],
+    priority=None,
+    keys: Optional[Sequence[bytes]] = None,
+) -> "list[bool]":
+    """The batch-verifier bridge: submit a pre-partitioned segment of raw
+    ed25519 triples (the caller — ``_CollectingVerifier`` — already took
+    its cache hits) as ONE queue entry and wait on its one future.  ``keys``
+    are the triples' sigcache keys where the caller's look-up hashed them
+    (``submit_segment``): the caller then stores every verdict this
+    returns, the shed tail's too, and the scheduler none."""
+    return wait_segment(submit_segment_async(pubs, msgs, sigs, priority, keys))

@@ -65,8 +65,12 @@ class TestLightClient:
         )
         lb = client.verify_light_block_at_height(5)
         assert lb.height == 5
-        # every intermediate header was verified + stored
-        assert client.store.heights() == [1, 2, 3, 4, 5]
+        # every intermediate header was verified, and the target stored
+        # (reference: light/client.go verifySequential keeps the interim
+        # headers in the detector's trace; updateTrustedLightBlock saves
+        # the target), which the client now verifies forward from
+        assert client.store.heights() == [1, 5]
+        assert client.trusted_light_block() is lb
 
     def test_skipping_verification(self, chain_node):
         primary = NodeProvider(chain_node)

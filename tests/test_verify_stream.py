@@ -1,6 +1,7 @@
 """The fused verification stream (ISSUE 3): ``verify_segments`` bitwise
 equivalence + dispatch accounting, blocksync window prefetch semantics
-(including bad-block redo/ban), and the light-client pipelined chain sync.
+(including bad-block redo/ban), and the light client's sequential chain on
+the served path.
 
 Device-dispatch budget matters on the CPU-XLA CI host (~10 s per launch):
 the equivalence test doubles as the fewer-dispatches smoke check, and the
@@ -332,7 +333,7 @@ class TestBlocksyncFusedPrefetch:
 
 
 # ---------------------------------------------------------------------------
-# light-client pipelined chain sync
+# the light client's sequential chain, on the served path
 # ---------------------------------------------------------------------------
 
 
@@ -369,49 +370,71 @@ def _make_light_chain(n_headers, n_vals=3):
     return privs, vals, lbs
 
 
-def _oracle_overlapped(record):
-    def fake(work):
-        record.append([len(p) for p, _, _ in work])
-        return [
-            np.asarray(
-                [
-                    len(pub) == 32
-                    and len(sig) == 64
-                    and ref.verify_zip215(pub, msg, sig)
-                    for pub, msg, sig in zip(p, m, s)
-                ]
-            )
-            for p, m, s in work
-        ]
+@pytest.fixture
+def served(monkeypatch):
+    """The served path with the device stubbed as the scheduler's tests do:
+    a trusted ``tpu`` backend whose device runner is the host oracle,
+    recording the lanes of every dispatch."""
+    from cometbft_tpu import verifysched
+    from cometbft_tpu.crypto import backend_health
+    from cometbft_tpu.ops import sha256_tree, supervisor
+    from cometbft_tpu.verifysched import stats as sstats
 
-    return fake
+    dispatched = []
+
+    def oracle(backend, pubs, msgs, sigs, lanes):
+        dispatched.append(len(pubs))
+        out = np.zeros(lanes, dtype=bool)
+        out[: len(pubs)] = [
+            ref.verify_zip215(p, m, s) for p, m, s in zip(pubs, msgs, sigs)
+        ]
+        return out
+
+    monkeypatch.setenv("COMETBFT_TPU_CRYPTO_BACKEND", "tpu")
+    monkeypatch.delenv("COMETBFT_TPU_VERIFY_SCHED", raising=False)
+    supervisor.set_device_runner(oracle)
+    sha256_tree.set_tree_runner(sha256_tree.host_tree_runner)
+    for reset in (sstats.reset, dispatch_stats.reset, backend_health.reset,
+                  verifysched.reset_scheduler):
+        reset()
+    yield dispatched
+    verifysched.reset_scheduler()
+    supervisor.clear_device_runner()
+    sha256_tree.clear_tree_runner()
+    backend_health.reset()
+    sstats.reset()
 
 
 class TestLightChainSync:
+    """``verify_adjacent_chain`` on the served path (ISSUE 38): each
+    header's misses one segment at light priority, judged in height
+    order."""
+
     NOW = 1_700_000_500.0
 
-    def test_chain_matches_sequential_and_uses_overlap(self, monkeypatch):
+    def test_chain_matches_sequential_and_uses_the_scheduler(self, served):
         import cometbft_tpu.light.verifier as lv
+        from cometbft_tpu.verifysched import stats as sstats
 
         privs, vals, lbs = _make_light_chain(4)
-        record = []
-        monkeypatch.setattr(cbatch, "default_backend", lambda: "tpu")
-        monkeypatch.setattr(
-            ov, "verify_batches_overlapped", _oracle_overlapped(record)
-        )
         lv.verify_adjacent_chain(
             CHAIN_ID, lbs[0], lbs[1:], 10_000, self.NOW
         )
-        # one overlapped dispatch train covering all three headers
-        assert record == [[3, 3, 3]]
+        # one train of three headers: a segment of three a header, at
+        # light priority, every signature on the device
+        ss = sstats.snapshot()
+        assert ss["segments"] == {"consensus": 0, "evidence_light": 3, "bulk": 0}
+        assert ss["submitted"]["evidence_light"] == 9
+        assert sum(served) == 9
         # cache now holds the verdicts: a re-sync ships nothing
-        record.clear()
+        served.clear()
         lv.verify_adjacent_chain(
             CHAIN_ID, lbs[0], lbs[1:], 10_000, self.NOW
         )
-        assert record == []
+        assert served == []
+        assert sstats.snapshot()["segments"]["evidence_light"] == 3
 
-    def test_chain_failure_matches_sequential_error(self, monkeypatch):
+    def test_chain_failure_matches_sequential_error(self, served):
         import cometbft_tpu.light.verifier as lv
 
         privs, vals, lbs = _make_light_chain(4)
@@ -421,7 +444,7 @@ class TestLightChainSync:
             [cs.signature[32] ^ 1]
         ) + cs.signature[33:]
 
-        # sequential (cpu backend) verdict
+        # the verdict of a loop of verify_adjacent
         with pytest.raises(validation.CommitVerificationError) as seq_err:
             cur = lbs[0]
             for lb in lbs[1:]:
@@ -429,27 +452,22 @@ class TestLightChainSync:
                 cur = lb
 
         sigcache.reset_cache()
-        record = []
-        monkeypatch.setattr(cbatch, "default_backend", lambda: "tpu")
-        monkeypatch.setattr(
-            ov, "verify_batches_overlapped", _oracle_overlapped(record)
-        )
-        with pytest.raises(type(seq_err.value)) as chain_err:
+        served.clear()
+        with pytest.raises(lv.ErrVerificationFailed) as chain_err:
             lv.verify_adjacent_chain(
                 CHAIN_ID, lbs[0], lbs[1:], 10_000, self.NOW
             )
-        assert record  # the pipelined path was exercised
-        assert str(chain_err.value) == str(seq_err.value)
+        assert served  # the served path was exercised
+        failed = chain_err.value
+        assert (failed.from_height, failed.to) == (2, 3)
+        assert type(failed.reason) is type(seq_err.value)
+        assert str(failed.reason) == str(seq_err.value)
 
-    def test_non_ed25519_sets_fall_back_sequential(self, monkeypatch):
+    def test_non_ed25519_sets_fall_back_sequential(self, served, monkeypatch):
         import cometbft_tpu.light.verifier as lv
 
         privs, vals, lbs = _make_light_chain(3)
-        monkeypatch.setattr(cbatch, "default_backend", lambda: "tpu")
         seen = []
-        monkeypatch.setattr(
-            ov, "verify_batches_overlapped", _oracle_overlapped(seen)
-        )
         # masquerade the key type so the eligibility gate trips
         monkeypatch.setattr(
             lv, "verify_adjacent", lambda *a, **k: seen.append("seq")
@@ -459,3 +477,4 @@ class TestLightChainSync:
         )
         lv.verify_adjacent_chain(CHAIN_ID, lbs[0], lbs[1:], 10_000, self.NOW)
         assert seen == ["seq", "seq"]  # sequential per header, no device
+        assert served == []
