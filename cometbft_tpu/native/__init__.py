@@ -161,6 +161,26 @@ def lib() -> Optional[ctypes.CDLL]:
             ]
         except AttributeError:
             pass
+        try:
+            # the validator set's root (``proofserve/plane.valset_root``,
+            # which takes the Python path where a prebuilt .so lacks it)
+            vp = ctypes.c_void_p
+            root = [
+                ctypes.c_char_p,  # keys, n x 32 bytes
+                vp,  # voting powers, int64 [n]
+                ctypes.c_int64,  # n
+                ctypes.c_char_p,  # the root, 32 bytes
+            ]
+            cdll.valset_root_ed25519.restype = ctypes.c_int
+            cdll.valset_root_ed25519.argtypes = root
+            cdll.valset_root_ed25519_ni.restype = ctypes.c_int
+            cdll.valset_root_ed25519_ni.argtypes = root + [ctypes.c_int]
+            cdll.sha256_ni.restype = ctypes.c_int
+            cdll.sha256_ni.argtypes = [
+                ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, ctypes.c_int,
+            ]
+        except AttributeError:
+            pass
         _lib = cdll
         return _lib
 

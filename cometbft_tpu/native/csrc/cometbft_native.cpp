@@ -13,6 +13,10 @@
 //    (ed25519_pack_into); SHA-512 four signatures at a time in AVX2
 //    registers where the CPU has them, h mod L by folding at 2^252.
 //    One thread: the caller's.
+//  * The validator set's Merkle root: every SimpleValidator leaf of an
+//    ed25519 set encoded and the RFC 6962 tree reduced in one call
+//    (valset_root_ed25519), SHA-256 with the SHA extensions where the CPU
+//    has them.
 //
 // Build: g++ -O3 -shared -fPIC -std=c++17, no -march (driven by
 // cometbft_tpu/native/__init__.py); what needs AVX2 says so itself.
@@ -26,7 +30,7 @@
 #include <fcntl.h>
 #include <unistd.h>
 #if defined(__x86_64__) && defined(__GNUC__)
-#include <immintrin.h>  // the four-lane SHA-512 below
+#include <immintrin.h>  // the four-lane SHA-512 and SHA-NI SHA-256 below
 #endif
 
 // ---------------------------------------------------------------------------
@@ -815,6 +819,280 @@ int64_t commit_sign_bytes(
     }
     out_off[n] = o.len;
     return o.len;
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
+// SHA-256 (FIPS 180-4) and the validator set's Merkle root (reference:
+// types/validator_set.go Hash -> crypto/merkle/tree.go; byte-exact mirror
+// of Validator.simple_encode + crypto/merkle.hash_from_byte_slices,
+// differential-tested in tests/test_valset_root_native.py)
+// ---------------------------------------------------------------------------
+
+static const uint32_t K256[64] = {
+    0x428a2f98u, 0x71374491u, 0xb5c0fbcfu, 0xe9b5dba5u, 0x3956c25bu,
+    0x59f111f1u, 0x923f82a4u, 0xab1c5ed5u, 0xd807aa98u, 0x12835b01u,
+    0x243185beu, 0x550c7dc3u, 0x72be5d74u, 0x80deb1feu, 0x9bdc06a7u,
+    0xc19bf174u, 0xe49b69c1u, 0xefbe4786u, 0x0fc19dc6u, 0x240ca1ccu,
+    0x2de92c6fu, 0x4a7484aau, 0x5cb0a9dcu, 0x76f988dau, 0x983e5152u,
+    0xa831c66du, 0xb00327c8u, 0xbf597fc7u, 0xc6e00bf3u, 0xd5a79147u,
+    0x06ca6351u, 0x14292967u, 0x27b70a85u, 0x2e1b2138u, 0x4d2c6dfcu,
+    0x53380d13u, 0x650a7354u, 0x766a0abbu, 0x81c2c92eu, 0x92722c85u,
+    0xa2bfe8a1u, 0xa81a664bu, 0xc24b8b70u, 0xc76c51a3u, 0xd192e819u,
+    0xd6990624u, 0xf40e3585u, 0x106aa070u, 0x19a4c116u, 0x1e376c08u,
+    0x2748774cu, 0x34b0bcb5u, 0x391c0cb3u, 0x4ed8aa4au, 0x5b9cca4fu,
+    0x682e6ff3u, 0x748f82eeu, 0x78a5636fu, 0x84c87814u, 0x8cc70208u,
+    0x90befffau, 0xa4506cebu, 0xbef9a3f7u, 0xc67178f2u};
+
+static const uint32_t SHA256_IV[8] = {0x6a09e667u, 0xbb67ae85u, 0x3c6ef372u,
+                                      0xa54ff53au, 0x510e527fu, 0x9b05688cu,
+                                      0x1f83d9abu, 0x5be0cd19u};
+
+static inline uint32_t load_be32(const uint8_t* p) {
+    return ((uint32_t)p[0] << 24) | ((uint32_t)p[1] << 16) |
+           ((uint32_t)p[2] << 8) | (uint32_t)p[3];
+}
+
+static inline void store_be32(uint8_t* p, uint32_t v) {
+    p[0] = (uint8_t)(v >> 24);
+    p[1] = (uint8_t)(v >> 16);
+    p[2] = (uint8_t)(v >> 8);
+    p[3] = (uint8_t)v;
+}
+
+static inline uint32_t rotr32(uint32_t x, int n) {
+    return (x >> n) | (x << (32 - n));
+}
+
+// the compression function over ``nblocks`` consecutive 64-byte blocks
+typedef void (*Sha256Block)(uint32_t st[8], const uint8_t* p, size_t nblocks);
+
+static void sha256_block_scalar(uint32_t st[8], const uint8_t* p,
+                                size_t nblocks) {
+    for (; nblocks; nblocks--, p += 64) {
+        uint32_t w[64];
+        for (int i = 0; i < 16; i++) w[i] = load_be32(p + 4 * i);
+        for (int i = 16; i < 64; i++) {
+            uint32_t s0 = rotr32(w[i - 15], 7) ^ rotr32(w[i - 15], 18) ^
+                          (w[i - 15] >> 3);
+            uint32_t s1 = rotr32(w[i - 2], 17) ^ rotr32(w[i - 2], 19) ^
+                          (w[i - 2] >> 10);
+            w[i] = w[i - 16] + s0 + w[i - 7] + s1;
+        }
+        uint32_t a = st[0], b = st[1], c = st[2], d = st[3];
+        uint32_t e = st[4], f = st[5], g = st[6], h = st[7];
+        for (int i = 0; i < 64; i++) {
+            uint32_t t1 = h + (rotr32(e, 6) ^ rotr32(e, 11) ^ rotr32(e, 25)) +
+                          ((e & f) ^ (~e & g)) + K256[i] + w[i];
+            uint32_t t2 = (rotr32(a, 2) ^ rotr32(a, 13) ^ rotr32(a, 22)) +
+                          ((a & b) ^ (a & c) ^ (b & c));
+            h = g;
+            g = f;
+            f = e;
+            e = d + t1;
+            d = c;
+            c = b;
+            b = a;
+            a = t1 + t2;
+        }
+        st[0] += a; st[1] += b; st[2] += c; st[3] += d;
+        st[4] += e; st[5] += f; st[6] += g; st[7] += h;
+    }
+}
+
+// The same blocks with the SHA extensions: compiled for them whatever the
+// build's flags are and called only where the CPU has them
+// (``cpu_has_sha``).  The state lives as ABEF / CDGH, the instructions'
+// order; each group of four rounds adds four message words to their
+// constants and makes two ``sha256rnds2``.
+#if defined(__x86_64__) && defined(__GNUC__)
+#define SHA256_HAVE_NI 1
+
+#define NI_TARGET __attribute__((target("sha,sse4.1,ssse3")))
+
+NI_TARGET static void sha256_block_ni(uint32_t st[8], const uint8_t* p,
+                                      size_t nblocks) {
+    const __m128i bswap =
+        _mm_set_epi64x(0x0c0d0e0f08090a0bLL, 0x0405060700010203LL);
+    __m128i t = _mm_loadu_si128((const __m128i*)&st[0]);     // DCBA
+    __m128i s1 = _mm_loadu_si128((const __m128i*)&st[4]);    // HGFE
+    t = _mm_shuffle_epi32(t, 0xB1);                          // CDAB
+    s1 = _mm_shuffle_epi32(s1, 0x1B);                        // EFGH
+    __m128i s0 = _mm_alignr_epi8(t, s1, 8);                  // ABEF
+    s1 = _mm_blend_epi16(s1, t, 0xF0);                       // CDGH
+    for (size_t k = 0; k < nblocks; k++, p += 64) {
+        const __m128i abef = s0, cdgh = s1;
+        __m128i w[4];  // the last four groups of message words, a ring
+        for (int q = 0; q < 4; q++)
+            w[q] = _mm_shuffle_epi8(
+                _mm_loadu_si128((const __m128i*)(p + 16 * q)), bswap);
+#pragma GCC unroll 16
+        for (int g = 0; g < 16; g++) {
+            if (g >= 4)  // group g from groups g-4 .. g-1
+                w[g & 3] = _mm_sha256msg2_epu32(
+                    _mm_add_epi32(
+                        _mm_sha256msg1_epu32(w[g & 3], w[(g + 1) & 3]),
+                        _mm_alignr_epi8(w[(g + 3) & 3], w[(g + 2) & 3], 4)),
+                    w[(g + 3) & 3]);
+            __m128i kw = _mm_add_epi32(
+                w[g & 3], _mm_loadu_si128((const __m128i*)(K256 + 4 * g)));
+            s1 = _mm_sha256rnds2_epu32(s1, s0, kw);
+            s0 = _mm_sha256rnds2_epu32(s0, s1, _mm_shuffle_epi32(kw, 0x0E));
+        }
+        s0 = _mm_add_epi32(s0, abef);
+        s1 = _mm_add_epi32(s1, cdgh);
+    }
+    t = _mm_shuffle_epi32(s0, 0x1B);                         // FEBA
+    s1 = _mm_shuffle_epi32(s1, 0xB1);                        // DCHG
+    _mm_storeu_si128((__m128i*)&st[0], _mm_blend_epi16(t, s1, 0xF0));  // DCBA
+    _mm_storeu_si128((__m128i*)&st[4], _mm_alignr_epi8(s1, t, 8));     // HGFE
+}
+
+static bool cpu_has_sha() {
+    static const bool has = __builtin_cpu_supports("sha");
+    return has;
+}
+#else
+static bool cpu_has_sha() { return false; }
+#endif
+
+// The block function ``ni`` names: 1 the SHA-NI one, 0 the scalar one, -1
+// the best this CPU has; null where it names one the CPU lacks.
+static Sha256Block sha256_block_fn(int ni) {
+#ifdef SHA256_HAVE_NI
+    if (ni != 0 && cpu_has_sha()) return sha256_block_ni;
+#endif
+    return ni == 1 ? nullptr : sha256_block_scalar;
+}
+
+static inline void sha256_out(const uint32_t st[8], uint8_t out[32]) {
+    for (int i = 0; i < 8; i++) store_be32(out + 4 * i, st[i]);
+}
+
+// SHA-256 of one message: its whole blocks where they lie, the tail and
+// the padding (one or two blocks) on the stack
+static void sha256_msg(Sha256Block blk, const uint8_t* data, size_t len,
+                       uint8_t out[32]) {
+    uint32_t st[8];
+    memcpy(st, SHA256_IV, sizeof(st));
+    size_t whole = len / 64, rem = len % 64;
+    if (whole) blk(st, data, whole);
+    uint8_t tail[128];
+    memset(tail, 0, sizeof(tail));
+    if (rem) memcpy(tail, data + 64 * whole, rem);
+    tail[rem] = 0x80;
+    size_t tlen = rem + 1 + 8 <= 64 ? 64 : 128;
+    uint64_t bits = (uint64_t)len * 8;
+    for (int i = 0; i < 8; i++) tail[tlen - 1 - i] = (uint8_t)(bits >> (8 * i));
+    blk(st, tail, tlen / 64);
+    sha256_out(st, out);
+}
+
+// The leaf hash of one SimpleValidator with an ed25519 key:
+// SHA-256(0x00 || 0a 22 0a 20 || key || [10 || uvarint(power)]), the power
+// left out where it is 0 and a negative one its 64-bit two's complement
+// (``pe.t_varint``).  At most 48 bytes: one block, padded in place.
+static void valset_leaf(Sha256Block blk, const uint8_t key[32], int64_t power,
+                        uint8_t out[32]) {
+    static const uint8_t head[5] = {0x00, 0x0a, 0x22, 0x0a, 0x20};
+    uint8_t b[64];
+    memset(b, 0, sizeof(b));
+    memcpy(b, head, 5);
+    memcpy(b + 5, key, 32);
+    size_t len = 37;
+    if (power != 0) {
+        b[len++] = 0x10;  // field 2, varint
+        uint64_t v = (uint64_t)power;
+        for (; v >= 0x80; v >>= 7) b[len++] = (uint8_t)(v | 0x80);
+        b[len++] = (uint8_t)v;
+    }
+    b[len] = 0x80;
+    b[62] = (uint8_t)((len * 8) >> 8);
+    b[63] = (uint8_t)(len * 8);
+    uint32_t st[8];
+    memcpy(st, SHA256_IV, sizeof(st));
+    blk(st, b, 1);
+    sha256_out(st, out);
+}
+
+// An inner node: SHA-256(0x01 || left || right), 65 bytes, two blocks
+// padded in place.  Reads both children before it writes ``out``, which
+// may be ``left``.
+static void merkle_inner(Sha256Block blk, const uint8_t* left,
+                         const uint8_t* right, uint8_t out[32]) {
+    uint8_t b[128];
+    memset(b, 0, sizeof(b));
+    b[0] = 0x01;
+    memcpy(b + 1, left, 32);
+    memcpy(b + 33, right, 32);
+    b[65] = 0x80;
+    b[126] = (uint8_t)((65 * 8) >> 8);
+    b[127] = (uint8_t)(65 * 8);
+    uint32_t st[8];
+    memcpy(st, SHA256_IV, sizeof(st));
+    blk(st, b, 2);
+    sha256_out(st, out);
+}
+
+// The root over n leaf digests, level by level in place: neighbours paired,
+// an odd last one carried up.  The same tree as the reference's split at
+// the largest power of two below n: that split leaves the left part a
+// whole power of two, whose pairs never straddle it.
+static void merkle_reduce(Sha256Block blk, uint8_t* d, size_t n) {
+    while (n > 1) {
+        size_t half = n / 2;
+        for (size_t i = 0; i < half; i++)
+            merkle_inner(blk, d + 64 * i, d + 64 * i + 32, d + 32 * i);
+        if (n & 1) memmove(d + 32 * half, d + 32 * (n - 1), 32);
+        n = half + (n & 1);
+    }
+}
+
+static int valset_root(const uint8_t* keys32, const int64_t* powers, int64_t n,
+                       uint8_t* out32, Sha256Block blk) {
+    if (n < 0) return -1;
+    if (n == 0) {
+        sha256_msg(blk, nullptr, 0, out32);
+        return 0;
+    }
+    uint8_t* d = static_cast<uint8_t*>(malloc((size_t)n * 32));
+    if (!d) return -1;
+    for (int64_t i = 0; i < n; i++)
+        valset_leaf(blk, keys32 + 32 * i, powers[i], d + 32 * i);
+    merkle_reduce(blk, d, (size_t)n);
+    memcpy(out32, d, 32);
+    free(d);
+    return 0;
+}
+
+extern "C" {
+
+// The Merkle root of a validator set whose keys are all ed25519: keys32
+// n x 32 bytes and powers n int64 in set order, the root to out32.  The
+// leaves encoded and the tree reduced in one pass, SHA-NI where the CPU has
+// it; one allocation, of n digests.  -1 for n < 0 or no memory.
+int valset_root_ed25519(const uint8_t* keys32, const int64_t* powers,
+                        int64_t n, uint8_t* out32) {
+    return valset_root(keys32, powers, n, out32, sha256_block_fn(-1));
+}
+
+// for tests: ``valset_root_ed25519`` with the block function named: ni 0
+// the scalar one, 1 the SHA-NI one; -2 where this CPU has no such one
+int valset_root_ed25519_ni(const uint8_t* keys32, const int64_t* powers,
+                           int64_t n, uint8_t* out32, int ni) {
+    Sha256Block blk = (ni == 0 || ni == 1) ? sha256_block_fn(ni) : nullptr;
+    if (!blk) return -2;
+    return valset_root(keys32, powers, n, out32, blk);
+}
+
+// for tests: SHA-256 of one message with the block function named as above
+int sha256_ni(const uint8_t* data, int64_t len, uint8_t* out32, int ni) {
+    Sha256Block blk = (ni == 0 || ni == 1) ? sha256_block_fn(ni) : nullptr;
+    if (!blk) return -2;
+    if (len < 0) return -1;
+    sha256_msg(blk, data, (size_t)len, out32);
+    return 0;
 }
 
 }  // extern "C"

@@ -4,9 +4,9 @@
 // Reference discipline being mirrored: the Go repo runs its whole test
 // suite with -race (tests.mk:56); the C++ surface here gets the TSAN
 // equivalent — hammer the WAL handle from multiple threads (append,
-// sync, size) and the batch packer, the old entry point and the in-place
-// one, concurrently, then verify the WAL contents are a clean sequence of
-// CRC-framed records.
+// sync, size), the batch packer, the old entry point and the in-place
+// one, and the validator set's root, concurrently, then verify the WAL
+// contents are a clean sequence of CRC-framed records.
 //
 // Exit code 0 = no sanitizer report and all invariants held.
 
@@ -32,6 +32,11 @@ int ed25519_pack_into(const uint8_t* pubs, const uint8_t* sigs,
                       const int64_t* idx, int64_t rows, uint8_t* a_rows,
                       uint8_t* r_rows, uint8_t* s_rows, uint8_t* m_rows,
                       uint8_t* s_ok_rows);
+int valset_root_ed25519(const uint8_t* keys32, const int64_t* powers,
+                        int64_t n, uint8_t* out32);
+int valset_root_ed25519_ni(const uint8_t* keys32, const int64_t* powers,
+                           int64_t n, uint8_t* out32, int ni);
+int sha256_ni(const uint8_t* data, int64_t len, uint8_t* out32, int ni);
 }
 
 static std::atomic<int> failures{0};
@@ -147,6 +152,82 @@ static void packer_into(int tid, int iters) {
   }
 }
 
+// The validator set's root over random sets, keys and powers in buffers of
+// EXACTLY n entries (n = 0 included, up to 10,240): the library's root
+// (the best block function, then each one by name) against a root built
+// here from the leaves' bytes, the split at the largest power of two below
+// n, and the library's scalar SHA-256 alone.
+static void leaf_bytes(const uint8_t* key, int64_t power, std::vector<uint8_t>& b) {
+  static const uint8_t head[5] = {0x00, 0x0a, 0x22, 0x0a, 0x20};
+  b.assign(head, head + 5);
+  b.insert(b.end(), key, key + 32);
+  if (power != 0) {
+    b.push_back(0x10);
+    for (uint64_t v = (uint64_t)power;; v >>= 7) {
+      if (v < 0x80) { b.push_back((uint8_t)v); break; }
+      b.push_back((uint8_t)(v | 0x80));
+    }
+  }
+}
+
+static std::vector<uint8_t> split_root(const std::vector<std::vector<uint8_t>>& h,
+                                       size_t lo, size_t hi) {
+  if (hi - lo == 1) return h[lo];
+  size_t k = 1;
+  while (k * 2 < hi - lo) k *= 2;
+  std::vector<uint8_t> b(1, 0x01), l = split_root(h, lo, lo + k),
+                                   r = split_root(h, lo + k, hi);
+  b.insert(b.end(), l.begin(), l.end());
+  b.insert(b.end(), r.begin(), r.end());
+  std::vector<uint8_t> out(32);
+  sha256_ni(b.data(), (int64_t)b.size(), out.data(), 0);
+  return out;
+}
+
+static void valset_roots(int tid, int iters) {
+  uint64_t x = 0x9e3779b97f4a7c15ULL * (uint64_t)(tid + 1);
+  auto next = [&x] {  // xorshift64
+    x ^= x << 13; x ^= x >> 7; x ^= x << 17;
+    return x;
+  };
+  static const int64_t sizes[] = {0, 1, 2, 3, 5, 8, 9, 100, 1000, 1001, 10240};
+  const int nsizes = sizeof(sizes) / sizeof(sizes[0]);
+  for (int it = 0; it < iters; it++) {
+    const int64_t n =
+        it < nsizes ? sizes[(it + tid) % nsizes] : (int64_t)(next() % 2049);
+    std::vector<uint8_t> keys((size_t)n * 32);
+    std::vector<int64_t> powers((size_t)n);
+    for (auto& k : keys) k = (uint8_t)next();
+    for (auto& p : powers) {
+      // 0 a quarter of the time, else any width, negative ones included
+      p = next() % 4 == 0 ? 0 : (int64_t)(next() >> (next() % 64));
+    }
+    std::vector<uint8_t> want(32);
+    if (n == 0) {
+      sha256_ni(nullptr, 0, want.data(), 0);
+    } else {
+      std::vector<std::vector<uint8_t>> h((size_t)n, std::vector<uint8_t>(32));
+      std::vector<uint8_t> b;
+      for (int64_t i = 0; i < n; i++) {
+        leaf_bytes(&keys[(size_t)i * 32], powers[(size_t)i], b);
+        sha256_ni(b.data(), (int64_t)b.size(), h[(size_t)i].data(), 0);
+      }
+      want = split_root(h, 0, (size_t)n);
+    }
+    uint8_t got[32];
+    if (valset_root_ed25519(keys.data(), powers.data(), n, got) != 0 ||
+        std::memcmp(got, want.data(), 32))
+      failures++;
+    for (int ni = 0; ni < 2; ni++) {
+      int rc = valset_root_ed25519_ni(keys.data(), powers.data(), n, got, ni);
+      if (rc == -2 && ni == 1) continue;  // no SHA extensions on this CPU
+      if (rc != 0 || std::memcmp(got, want.data(), 32)) failures++;
+    }
+  }
+  uint8_t out[32];
+  if (valset_root_ed25519(nullptr, nullptr, -1, out) != -1) failures++;
+}
+
 int main(int argc, char** argv) {
   const char* path = argc > 1 ? argv[1] : "/tmp/native_stress.wal";
   std::remove(path);
@@ -160,6 +241,7 @@ int main(int argc, char** argv) {
   for (int t = 0; t < kThreads; t++) ts.emplace_back(wal_writer, h, t, kIters);
   for (int t = 0; t < 4; t++) ts.emplace_back(packer, t, 200);
   for (int t = 0; t < 4; t++) ts.emplace_back(packer_into, t, 100);
+  for (int t = 0; t < 4; t++) ts.emplace_back(valset_roots, t, 16);
   for (auto& t : ts) t.join();
   wal_sync(h);
   int64_t size = wal_size(h);

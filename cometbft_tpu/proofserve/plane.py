@@ -21,13 +21,20 @@ decides the path:
     which itself supervises device→host degradation behind the
     ``merkle_device`` breaker.
 
+The validator set's root has a front door of its own, ``valset_root``:
+under the same gate, a host-tier set of ed25519 keys is one native call
+from keys and powers to the root (leaves encoded in C++), and every other
+set is ``tree_hash`` over ``simple_encode``.
+
 jax-free at import: the sha256_tree import happens only past the size
 gate, and that module imports jax lazily in turn.
 """
 
 from __future__ import annotations
 
+import ctypes
 import os
+from array import array
 
 from cometbft_tpu.crypto import merkle
 
@@ -84,6 +91,49 @@ def tree_hash(items) -> bytes:
     from cometbft_tpu.ops import sha256_tree
 
     return sha256_tree.tree_root(items)
+
+
+def valset_root(validators) -> "tuple[bytes, str]":
+    """The Merkle root of SimpleValidator encodings in set order
+    (reference: types/validator_set.go Hash) and the path that computed
+    it, ``native`` or ``python``.  On the host tier a set whose keys are
+    all ed25519 is ONE sidecar call: every leaf encoded and the tree
+    reduced in C++, SHA-NI where the CPU has it, the GIL released for the
+    call (ctypes does).  Every other set, the kill switch, a tree for the
+    device tier and a process without the library take ``simple_encode``
+    and ``tree_hash``, the oracle.  Computed from the set as it stands on
+    every call: nothing is kept."""
+    if enabled() and len(validators) < min_batch():
+        root = _native_valset_root(validators)
+        if root is not None:
+            return root, "native"
+    return tree_hash([v.simple_encode() for v in validators]), "python"
+
+
+def _native_valset_root(validators) -> "bytes | None":
+    """The sidecar's root, or None where it cannot serve the set: no
+    library or no such symbol, a key of another type, a power outside
+    int64."""
+    from cometbft_tpu import native
+    from cometbft_tpu.crypto.keys import Ed25519PubKey
+
+    fn = getattr(native.lib(), "valset_root_ed25519", None)
+    if fn is None:
+        return None
+    keys = [v.pub_key for v in validators]
+    if any(type(k) is not Ed25519PubKey for k in keys):
+        return None
+    blob = b"".join([k.data for k in keys])
+    if len(blob) != 32 * len(keys):
+        return None
+    try:
+        powers = array("q", [v.voting_power for v in validators])
+    except OverflowError:
+        return None
+    out = ctypes.create_string_buffer(32)
+    if fn(blob, powers.buffer_info()[0], len(keys), out) != 0:
+        return None
+    return out.raw
 
 
 def tree_proofs(items):

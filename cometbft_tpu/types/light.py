@@ -82,10 +82,15 @@ class LightBlock:
         """The set's Merkle root under a ``valset.hash`` span: the one hash
         of a light client's request that grows with the set.  The span is
         here and not in ``ValidatorSet.hash``, which a node calls several
-        times a block."""
+        times a block.  ``path`` says what computed it: ``native`` (one
+        sidecar call) or ``python``."""
         from cometbft_tpu.libs import tracing
         from cometbft_tpu.proofserve import plane
 
         n = len(self.validator_set)
-        with tracing.span("valset.hash", leaves=n, tier=plane.tier_for(n)):
-            return self.validator_set.hash()
+        with tracing.span(
+            "valset.hash", leaves=n, tier=plane.tier_for(n)
+        ) as sp:
+            root, path = self.validator_set.hash_with_path()
+            sp.set(path=path)
+            return root

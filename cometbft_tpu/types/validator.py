@@ -309,8 +309,11 @@ class ValidatorSet:
     def hash(self) -> bytes:
         """Merkle root of SimpleValidator encodings in set order
         (reference: types/validator_set.go Hash)."""
+        return self.hash_with_path()[0]
+
+    def hash_with_path(self) -> tuple[bytes, str]:
+        """The root and the path that computed it, ``native`` or
+        ``python`` (``proofserve/plane.valset_root``)."""
         from cometbft_tpu.proofserve import plane
 
-        return plane.tree_hash(
-            [v.simple_encode() for v in self.validators]
-        )
+        return plane.valset_root(self.validators)

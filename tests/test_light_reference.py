@@ -287,15 +287,17 @@ def test_second_pass_hits_what_the_first_pass_verified(device_stub, n):
     assert spans["verify.commit.trusting"]["scanned"] == picked[-1] + 1
     assert spans["verify.commit.trusting"]["skipped"] == picked[-1] + 1 - len(picked)
     assert spans["verify.commit"]["mode"] == "light"
-    assert spans["valset.hash"] == {"leaves": n, "tier": "host"}
+    assert spans["valset.hash"] == {"leaves": n, "tier": "host", "path": "native"}
     assert spans["light.verify"]["adjacent"] is False
 
 
 def test_valset_hash_is_computed_once_a_call(device_stub, monkeypatch):
     trusted, new, now_s, _ = _case("ordinary_skip", 16)
     calls = []
-    real = ValidatorSet.hash
-    monkeypatch.setattr(ValidatorSet, "hash", lambda self: calls.append(1) or real(self))
+    real = ValidatorSet.hash_with_path
+    monkeypatch.setattr(
+        ValidatorSet, "hash_with_path", lambda self: calls.append(1) or real(self)
+    )
     assert program_verdict(trusted, new, now_s) == ("accepted",)
     assert len(calls) == 1
 
