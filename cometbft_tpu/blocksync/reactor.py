@@ -6,7 +6,15 @@ block download, then the two-block verification pipeline —
 ``verify_commit_light`` on block H using block H+1's LastCommit routes
 through the batch-verifier seam (the TPU path), making catchup the
 biggest batch-verification consumer in the system (SURVEY.md §2.2).
-On completion it hands off to consensus (SwitchToConsensus).
+Ahead of the frontier, a window of commits rides the verify scheduler as
+one segment at ``PRIO_BLOCKSYNC`` while the heights before it are applied
+(``_prefetch_window``).  On completion it hands off to consensus
+(SwitchToConsensus).
+
+Spans (``libs/tracing``): ``blocksync.tick`` (``height``, ``outcome``) >
+``blocksync.window`` (``commits``, ``sigs``), ``blocksync.wait``
+(``height``), ``blocksync.validate``, ``blocksync.apply``; on the thread
+that receives, ``blocksync.receive`` (``height``, ``bytes``).
 """
 
 from __future__ import annotations
@@ -21,6 +29,7 @@ from cometbft_tpu.blocksync.pool import BlockPool
 from cometbft_tpu.crypto import sigcache
 from cometbft_tpu.libs import log as liblog
 from cometbft_tpu.libs import protoenc as pe
+from cometbft_tpu.libs import tracing
 from cometbft_tpu.p2p.conn import ChannelDescriptor
 from cometbft_tpu.p2p.reactor import Reactor
 from cometbft_tpu.state.execution import InvalidBlockError
@@ -47,8 +56,8 @@ _POOL_TICK = 0.02
 # chain / isolated node and run consensus (COMETBFT_TPU_BSYNC_SOLO_GRACE).
 _SOLO_GRACE = 10.0
 
-# Fused-verification window: how many frontier commits may share one device
-# dispatch (COMETBFT_TPU_BLOCKSYNC_WINDOW; <2 disables the prefetch).
+# Verification window: k blocks, whose k-1 commits leave in one segment
+# (COMETBFT_TPU_BLOCKSYNC_WINDOW; <2 disables the prefetch).
 _DEFAULT_WINDOW = 8
 
 
@@ -70,6 +79,29 @@ def _solo_grace() -> float:
 
 def _enc(kind: int, body: bytes = b"") -> bytes:
     return bytes([kind]) + body
+
+
+class _Window:
+    """The commits of one window, queued as ONE segment: the pending
+    segment and, for each commit, its partition and its slice of the
+    segment's verdicts."""
+
+    __slots__ = ("pending", "parts", "settled")
+
+    def __init__(self, pending, parts):
+        self.pending = pending  # verifysched.PendingSegment
+        self.parts = parts  # [(sigcache.Partition, start, end)]
+        self.settled = False
+
+    def settle(self) -> None:
+        """Wait for the segment once and write every commit's verdicts
+        back, so that the frontier's own checks find them in the cache."""
+        from cometbft_tpu import verifysched
+
+        self.settled = True
+        bits = verifysched.wait_segment(self.pending)
+        for part, a, b in self.parts:
+            sigcache.writeback(part, bits[a:b])
 
 
 class BlocksyncReactor(Reactor):
@@ -107,9 +139,10 @@ class BlocksyncReactor(Reactor):
         self._last_status_retry = float("-inf")
         self._status_req_at: Optional[float] = None
         self._last_switch_check = float("-inf")
-        # fused-prefetch memo: commit fingerprint -> height, so a window is
-        # dispatched once and apply/redo ticks never re-dispatch it
-        self._fused: dict[bytes, int] = {}
+        # window memo: commit fingerprint -> (height, the window it rides,
+        # or None once its verdicts are in the cache), so a commit is
+        # queued once and apply/redo ticks never queue it again
+        self._fused: dict[bytes, tuple[int, Optional[_Window]]] = {}
 
     def get_channels(self) -> list[ChannelDescriptor]:
         return [
@@ -206,11 +239,13 @@ class BlocksyncReactor(Reactor):
                     _enc(_MSG_NO_BLOCK_RESPONSE, pe.t_varint(1, height)),
                 )
         elif kind == _MSG_BLOCK_RESPONSE:
-            f = pe.fields_dict(body)
-            block = codec.decode_block(f[1][-1])
-            ec = (
-                codec.decode_extended_commit(f[2][-1]) if 2 in f else None
-            )
+            with tracing.span("blocksync.receive", bytes=len(msg_bytes)) as sp:
+                f = pe.fields_dict(body)
+                block = codec.decode_block(f[1][-1])
+                ec = (
+                    codec.decode_extended_commit(f[2][-1]) if 2 in f else None
+                )
+                sp.set(height=block.header.height)
             self.pool.add_block(peer.id, block, ec)
         elif kind == _MSG_NO_BLOCK_RESPONSE:
             f = pe.fields_dict(body)
@@ -252,6 +287,12 @@ class BlocksyncReactor(Reactor):
         work may be immediately available).  The wall-clock thread loop
         wraps this; the deterministic sim drives it directly off the
         virtual clock (sim/blocksync.py)."""
+        with tracing.span(
+            "blocksync.tick", height=self.pool.height, outcome="waiting"
+        ):
+            return self._tick()
+
+    def _tick(self) -> bool:
         now = self._clock()
         if now - self._last_status > _STATUS_INTERVAL:
             self._last_status = now
@@ -277,6 +318,7 @@ class BlocksyncReactor(Reactor):
         if now - self._last_switch_check > _SWITCH_TO_CONSENSUS_INTERVAL:
             self._last_switch_check = now
             if self._maybe_switch_to_consensus():
+                tracing.mark(outcome="switched")
                 return False
         self.pool.make_next_requests()
         return self._process_blocks()
@@ -290,17 +332,16 @@ class BlocksyncReactor(Reactor):
                 self.logger.error("blocksync pool error", err=repr(e))
                 time.sleep(0.5)
 
-    # -- fused window prefetch --------------------------------------------
+    # -- the verification window ------------------------------------------
 
     @staticmethod
     def _commit_fingerprint(height: int, commit) -> bytes:
         """Cheap per-tick memo key: O(1) in validator count (hashing all
         10k signatures every 20 ms pool tick would be ~MBs of SHA-256 per
-        tick once the window is already fused).  A redo replaces the whole
-        served commit, so height + block id + round + size + the first and
-        last signatures distinguish every case that matters; a collision
-        merely skips a SPECULATIVE prefetch — the authoritative sequential
-        verification is unaffected."""
+        tick).  A redo replaces the whole served commit, so height + block
+        id + round + size + the first and last signatures distinguish every
+        case that matters; a collision merely skips a SPECULATIVE window —
+        the authoritative sequential verification is unaffected."""
         h = hashlib.sha256()
         h.update(height.to_bytes(8, "little", signed=True))
         h.update(commit.block_id.hash)
@@ -315,15 +356,31 @@ class BlocksyncReactor(Reactor):
         return h.digest()
 
     def _prefetch_window(self) -> None:
-        """Speculatively verify a window of frontier commits in ONE fused
-        device dispatch (ops.verify.verify_segments), seeding the signature
-        cache so the authoritative per-height ``verify_commit_light`` in
-        ``_process_blocks`` resolves without re-dispatching.
+        """Queue the next window of frontier commits on the verify
+        scheduler, so that its verdicts land in the signature cache while
+        the frontier applies the heights before it.
+
+        A window is the k - 1 commits of k received blocks (k =
+        ``_window_k()``), each prepared for the full check
+        (``count_all``: the frontier's light check of H and
+        ``validate_block``'s full check of H's LastCommit both read them)
+        and partitioned against the cache; the misses of all of them go to
+        the scheduler as ONE segment at ``PRIO_BLOCKSYNC``, in one hand-off,
+        so they leave in one flush.  Nothing waits here: the frontier waits
+        on the segment only if it has not landed when it gets there
+        (``_settle``).  The reactor looks two windows ahead of the frontier
+        and queues the next window as soon as it is whole, so that it flies
+        while the heights before it are applied; a smaller one only where
+        the frontier's own checks need a commit no window holds (at the
+        start, after a redo).  The memo (``_fused``) keeps one commit from
+        being queued twice.  With the scheduler switched off the window is
+        one synchronous call through the batch seam, as every other
+        caller's is.
 
         Safety: verdicts are keyed on the full (pub, msg, sig) triple.  A
         misprediction (validator set changed mid-window) caches triples the
-        real verification never queries — it degrades to today's one-
-        dispatch-per-height behavior, never to a wrong answer.  Block
+        real verification never queries: the frontier then verifies its own.
+        A window that fails is dropped, with the same effect.  Block
         *application* stays strictly sequential in ``_process_blocks``;
         a bad block still takes the same redo/ban path there."""
         k = _window_k()
@@ -332,80 +389,86 @@ class BlocksyncReactor(Reactor):
         if not validation.fused_verify_eligible([self.state.validators]):
             # no trusted accelerator, every device breaker open (catchup
             # then degrades to the authoritative per-commit host verify in
-            # _process_blocks; prefetch resumes once a half-open probe
-            # passes), or non-ed25519 validators — nothing to fuse
+            # _process_blocks; the window resumes once a half-open probe
+            # passes), or non-ed25519 validators: nothing to queue
             return
-        peek = getattr(self.pool, "peek_window", None)
-        if peek is None:
-            return
-        window = peek(k)
-        if len(window) < 3:
-            return  # the two-block pipeline covers short runs
-        to_fuse = []  # (fingerprint, height, prepared, sigcache.Partition)
-        for i in range(len(window) - 1):
-            h = window[i][0]
-            commit = window[i + 1][1].last_commit
-            fp = self._commit_fingerprint(h, commit)
-            if fp in self._fused:
-                continue
-            # best-effort validator-set prediction past the frontier; a miss
-            # is safe (see docstring)
-            vals = self.state.validators if i == 0 else self.state.next_validators
-            try:
-                # count_all: cover the full-verification superset, so both
-                # the frontier verify_commit_light AND validate_block's
-                # apply-time verify_commit resolve from cache
-                prepared = validation.prepare_commit_light(
-                    self.state.chain_id,
-                    vals,
-                    commit.block_id,
-                    h,
-                    commit,
-                    count_all=True,
-                )
-            except validation.CommitVerificationError:
-                continue  # malformed: let the sequential path raise/redo/ban
-            part = sigcache.partition_misses(
-                prepared.pubs, prepared.msgs, prepared.sigs
-            )
-            if not part.miss:
-                self._fused[fp] = h  # fully cached already
-                continue
-            to_fuse.append((fp, h, prepared, part))
-        if not to_fuse:
-            return
-        from cometbft_tpu.libs import tracing
-        from cometbft_tpu.ops import verify as ov
-
-        try:
-            with tracing.span(
-                "blocksync.prefetch",
-                commits=len(to_fuse),
-                h0=to_fuse[0][1],
-                sigs=sum(len(part.miss) for *_, part in to_fuse),
-            ):
-                results = ov.verify_segments(
-                    [
-                        (
-                            [p.pubs[j] for j in part.miss],
-                            [p.msgs[j] for j in part.miss],
-                            [p.sigs[j] for j in part.miss],
-                        )
-                        for _, _, p, part in to_fuse
-                    ]
-                )
-        except Exception as e:  # noqa: BLE001 — prefetch must never stall sync
-            self.logger.error("fused verify prefetch failed", err=repr(e))
-            return
-        for (fp, h, _, part), got in zip(to_fuse, results):
-            sigcache.writeback(part, got)
-            self._fused[fp] = h
-        # trim memo entries behind the frontier
         frontier = self.pool.height
-        if len(self._fused) > 4 * max(k, 1):
-            self._fused = {
-                fp: h for fp, h in self._fused.items() if h >= frontier
-            }
+        self._fused = {
+            fp: v for fp, v in self._fused.items() if v[0] >= frontier - 1
+        }
+        # the commits the next ticks read, as the blocks held carry them:
+        # block H carries H - 1's (validate_block's full check), H + 1 H's
+        todo = []  # (height, commit, fingerprint) not queued yet
+        for h, block, _, _ in self.pool.peek_window(2 * (k - 1)):
+            fp = self._commit_fingerprint(h - 1, block.last_commit)
+            if h > 1 and fp not in self._fused:
+                todo.append((h - 1, block.last_commit, fp))
+        todo = todo[: k - 1]
+        if not todo or (len(todo) < k - 1 and todo[0][0] > frontier):
+            # a full window, or whatever the frontier's own ticks need now
+            # (a start, a redo): no commit leaves alone while more can come
+            return
+        from cometbft_tpu import verifysched
+
+        with tracing.span("blocksync.window") as sp:
+            parts, pubs, msgs, sigs, keys = [], [], [], [], []
+            for h, commit, fp in todo:
+                # best-effort validator-set prediction past the frontier; a
+                # miss is safe (see docstring)
+                vals = (
+                    self.state.last_validators if h < frontier
+                    else self.state.validators if h == frontier
+                    else self.state.next_validators
+                )
+                try:
+                    p = validation.prepare_commit_light(
+                        self.state.chain_id, vals, commit.block_id, h, commit,
+                        count_all=True,
+                    )
+                except validation.CommitVerificationError:
+                    continue  # malformed: the frontier's checks reject it
+                part = sigcache.partition_misses(p.pubs, p.msgs, p.sigs)
+                self._fused[fp] = (h, None)
+                if not part.miss:
+                    continue  # every verdict in the cache already
+                parts.append((fp, h, part, len(pubs)))
+                pubs += [p.pubs[j] for j in part.miss]
+                msgs += [p.msgs[j] for j in part.miss]
+                sigs += [p.sigs[j] for j in part.miss]
+                keys += part.keys
+            sp.set(commits=len(parts), sigs=len(pubs))
+            if not parts:
+                return
+            if not verifysched.scheduler_active():
+                from cometbft_tpu.crypto import batch as cbatch
+
+                bv = cbatch.TpuBatchVerifier()
+                for pub, msg, sig in zip(pubs, msgs, sigs):
+                    bv.add(pub, msg, sig)
+                bv.verify()  # the seam writes the verdicts back
+                return
+            pending = verifysched.submit_segment_async(
+                pubs, msgs, sigs, verifysched.PRIO_BLOCKSYNC, keys
+            )
+        ends = [a for *_, a in parts[1:]] + [len(pubs)]
+        win = _Window(
+            pending, [(part, a, b) for (_, _, part, a), b in zip(parts, ends)]
+        )
+        for fp, h, _, _ in parts:
+            self._fused[fp] = (h, win)
+
+    def _settle(self, height: int, commit) -> None:
+        """Before the frontier verifies ``commit``: if it rides a window
+        whose verdicts are not in the cache yet, wait for that window.  A
+        window that fails to settle leaves the frontier to verify its own."""
+        got = self._fused.get(self._commit_fingerprint(height, commit))
+        if got is None or got[1] is None or got[1].settled:
+            return
+        try:
+            with tracing.span("blocksync.wait", height=height):
+                got[1].settle()
+        except Exception as e:  # noqa: BLE001 — the frontier verifies itself
+            self.logger.error("blocksync window failed", err=repr(e))
 
     def _process_blocks(self) -> bool:
         """Verify + apply the frontier block using the NEXT block's
@@ -413,7 +476,7 @@ class BlocksyncReactor(Reactor):
         try:
             self._prefetch_window()
         except Exception as e:  # noqa: BLE001 — speculative only
-            self.logger.error("blocksync prefetch error", err=repr(e))
+            self.logger.error("blocksync window error", err=repr(e))
         first, second, first_peer, second_peer, first_ext = (
             self.pool.peek_two_blocks()
         )
@@ -423,6 +486,9 @@ class BlocksyncReactor(Reactor):
         first_id = BlockID(hash=first.hash(), part_set_header=first_parts.header)
         from cometbft_tpu import verifysched
 
+        # the two commits the checks below read, if a window holds them
+        self._settle(first.header.height, second.last_commit)
+        self._settle(first.header.height - 1, first.last_commit)
         try:
             # THE verification: batch Ed25519 through the pluggable seam,
             # tagged bulk-priority for the shared verify scheduler —
@@ -444,8 +510,10 @@ class BlocksyncReactor(Reactor):
                 # (internal/blocksync/reactor.go:546 ValidateBlock) —
                 # otherwise a peer could pair the legitimately signed
                 # header with tampered txs/last_commit/evidence.
-                self.block_exec.validate_block(self.state, first)
+                with tracing.span("blocksync.validate"):
+                    self.block_exec.validate_block(self.state, first)
         except (validation.CommitVerificationError, InvalidBlockError) as e:
+            tracing.mark(outcome="rejected")
             self.logger.error(
                 "invalid block in blocksync",
                 height=first.header.height,
@@ -467,6 +535,7 @@ class BlocksyncReactor(Reactor):
                 first, first_id, first_ext, second.last_commit
             )
             if err is not None:
+                tracing.mark(outcome="rejected")
                 self.logger.error(
                     "bad extended commit in blocksync",
                     height=first.header.height,
@@ -478,15 +547,17 @@ class BlocksyncReactor(Reactor):
                     if p is not None:
                         self.switch.stop_peer_for_error(p, ValueError(err))
                 return True
-        self.block_store.save_block(
-            first,
-            first_parts,
-            second.last_commit,
-            extended_commit=first_ext if need_ext else None,
-        )
-        self.state = self.block_exec.apply_verified_block(
-            self.state, first_id, first
-        )
+        with tracing.span("blocksync.apply"):
+            self.block_store.save_block(
+                first,
+                first_parts,
+                second.last_commit,
+                extended_commit=first_ext if need_ext else None,
+            )
+            self.state = self.block_exec.apply_verified_block(
+                self.state, first_id, first
+            )
+        tracing.mark(outcome="applied")
         self.pool.pop_request()
         if self.block_store.height() % 100 == 0:
             self.logger.info(

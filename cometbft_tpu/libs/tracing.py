@@ -55,7 +55,10 @@ per dispatch, never per signature):
   * ``supervisor.host_fallback`` / ``supervisor.bisect``
   * ``consensus.vote`` / ``consensus.proposal`` / ``consensus.vote_ext``
     (per height-round)
-  * ``blocksync.prefetch``                       — speculative window
+  * ``blocksync.tick`` > ``blocksync.window``, ``blocksync.wait``,
+    ``blocksync.validate``, ``blocksync.apply``; ``blocksync.receive`` —
+    a joiner's frontier tick, its window queued, its wait, its checks and
+    apply, and the decode of a block on the thread that receives
   * ``light.sync`` > ``light.store`` (``op`` load / save), ``light.chain``
     > ``light.chain.prep`` (> ``light.checks``, ``commit.sign_bytes``,
     ``sched.segment``), ``light.chain.wait`` — the light client's request,

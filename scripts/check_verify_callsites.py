@@ -61,13 +61,9 @@ ALLOWED_DIRS = (
 ALLOWED_FILES = ("cometbft_tpu/crypto/batch.py",)
 
 # Legacy direct call sites that predate the scheduler, pinned at their
-# current counts.  blocksync prefetch and the light chain path keep their
-# hand-built overlapped/fused pipelines (they already coalesce across
-# commits and run at most once per window); the sim scenario file only
-# warms the kernel.  Anything above these counts is NEW direct usage.
+# current counts: the sim scenario file only warms the kernel.  Anything
+# above these counts is NEW direct usage.
 LEGACY_MAX = {
-    "cometbft_tpu/blocksync/reactor.py": 1,
-    "cometbft_tpu/light/verifier.py": 1,
     "cometbft_tpu/sim/scenarios.py": 1,
 }
 

@@ -1,7 +1,7 @@
-"""The fused verification stream (ISSUE 3): ``verify_segments`` bitwise
-equivalence + dispatch accounting, blocksync window prefetch semantics
-(including bad-block redo/ban), and the light client's sequential chain on
-the served path.
+"""The fused verification stream: ``verify_segments`` bitwise equivalence +
+dispatch accounting, blocksync's window on the served path (including
+bad-block redo/ban), and the light client's sequential chain on the served
+path.
 
 Device-dispatch budget matters on the CPU-XLA CI host (~10 s per launch):
 the equivalence test doubles as the fewer-dispatches smoke check, and the
@@ -235,28 +235,40 @@ def _make_reactor(state, blocks, frontier=1):
 
 
 @pytest.fixture
-def tpu_backend(monkeypatch):
-    monkeypatch.setenv("COMETBFT_TPU_CRYPTO_BACKEND", "tpu")
+def window(served, monkeypatch):
+    """The served path (``served``, below) with the reactor's default
+    window of 8 blocks: 7 commits a segment."""
     monkeypatch.setenv("COMETBFT_TPU_BLOCKSYNC_WINDOW", "8")
-    yield
+    yield served
+
+
+def _flushes() -> int:
+    from cometbft_tpu.verifysched import stats as sstats
+
+    return sum(sstats.snapshot()["flushes"].values())
 
 
 class TestBlocksyncFusedPrefetch:
-    def test_window_prefetch_then_zero_dispatch_verification(
-        self, tpu_backend
-    ):
-        """One fused dispatch covers the whole window; the authoritative
-        light AND full commit verifications then resolve from cache, and a
-        repeat prefetch (apply/redo tick) never re-dispatches."""
+    """Blocksync's window on the served path: a window of commits is ONE
+    segment at bulk priority, queued without waiting; the frontier waits on
+    it only where it has not landed."""
+
+    def test_window_prefetch_then_zero_dispatch_verification(self, window):
+        """One window is one flush; the authoritative light AND full commit
+        verifications then resolve from cache, and a repeat tick (apply or
+        redo) queues nothing again."""
+        from cometbft_tpu.verifysched import stats as sstats
+
         state, privs, blocks, commits = _make_chain(5)
         r = _make_reactor(state, blocks)
 
-        d0 = dispatch_stats.dispatch_count()
         r._prefetch_window()
-        assert dispatch_stats.dispatch_count() - d0 == 1  # 4 commits fused
+        r._settle(1, blocks[1].last_commit)
+        assert window == [16]  # 4 commits of 4 signatures, one dispatch
+        assert _flushes() == 1
+        assert sstats.snapshot()["segments"]["bulk"] == 1
 
         # authoritative verification: zero further device work
-        d0 = dispatch_stats.dispatch_count()
         for h in range(1, 5):
             c = commits[h]
             validation.verify_commit_light(
@@ -266,18 +278,16 @@ class TestBlocksyncFusedPrefetch:
         validation.verify_commit(
             CHAIN_ID, state.validators, commits[2].block_id, 2, commits[2]
         )
-        assert dispatch_stats.dispatch_count() == d0
-
-        # memoized: another tick re-fuses nothing
+        # memoized: another tick queues nothing
         r._prefetch_window()
-        assert dispatch_stats.dispatch_count() == d0
+        r._settle(1, blocks[1].last_commit)
+        assert window == [16]
+        assert sstats.snapshot()["segments"]["bulk"] == 1
 
-    def test_bad_block_same_redo_ban_path_under_fused_prefetch(
-        self, tpu_backend
-    ):
-        """A forged commit signature discovered through the fused window
-        takes the identical redo/ban path: both provider requests dropped,
-        both peers banned, loop reports handled."""
+    def test_bad_block_same_redo_ban_path_under_fused_prefetch(self, window):
+        """A forged commit signature found through the window takes the
+        identical redo/ban path: both provider requests dropped, both peers
+        banned, loop reports handled."""
         state, privs, blocks, commits = _make_chain(5)
         # forge the commit for height 2 (carried inside block 3)
         c2 = blocks[2].last_commit
@@ -287,26 +297,26 @@ class TestBlocksyncFusedPrefetch:
         ) + cs.signature[33:]
         r = _make_reactor(state, blocks, frontier=2)
 
-        d0 = dispatch_stats.dispatch_count()
         handled = r._process_blocks()
         assert handled is True
-        # exactly the prefetch dispatch; the authoritative rejection came
+        # exactly the window's dispatch (commits 2-4; block 2's own
+        # LastCommit is for the genesis state's empty last set, which the
+        # window leaves to the frontier); the authoritative rejection came
         # from the cached False verdict
-        assert dispatch_stats.dispatch_count() - d0 == 1
+        assert window == [12]
         assert 2 not in r.pool.requests and 3 not in r.pool.requests
         now = time.monotonic()
         assert r.pool.peers["peer-2"].banned_until > now
         assert r.pool.peers["peer-3"].banned_until > now
 
-    def test_prefetch_disabled_paths(self, tpu_backend, monkeypatch):
+    def test_prefetch_disabled_paths(self, window, monkeypatch):
         state, privs, blocks, commits = _make_chain(5)
-        d0 = dispatch_stats.dispatch_count()
 
         # kill-switch: no cache -> no speculative work at all
         monkeypatch.setenv("COMETBFT_TPU_SIGCACHE", "0")
         r = _make_reactor(state, blocks)
         r._prefetch_window()
-        assert dispatch_stats.dispatch_count() == d0
+        assert window == [] and _flushes() == 0
         assert len(sigcache.get_cache()) == 0
         monkeypatch.delenv("COMETBFT_TPU_SIGCACHE")
 
@@ -314,14 +324,47 @@ class TestBlocksyncFusedPrefetch:
         monkeypatch.setenv("COMETBFT_TPU_BLOCKSYNC_WINDOW", "1")
         r = _make_reactor(state, blocks)
         r._prefetch_window()
-        assert dispatch_stats.dispatch_count() == d0
+        assert window == [] and _flushes() == 0
         monkeypatch.setenv("COMETBFT_TPU_BLOCKSYNC_WINDOW", "8")
 
         # cpu backend: host library path has no dispatch floor to amortize
         monkeypatch.setenv("COMETBFT_TPU_CRYPTO_BACKEND", "cpu")
         r = _make_reactor(state, blocks)
         r._prefetch_window()
-        assert dispatch_stats.dispatch_count() == d0
+        assert window == [] and _flushes() == 0
+
+    def test_steady_state_window_leaves_in_one_flush(self, window):
+        """As the frontier applies height after height, the next window is
+        queued whole while the one before is still ahead of it: 19 heights
+        take three flushes (7, 7 and the last 5 commits), not 19."""
+        state, privs, blocks, commits = _make_chain(20)
+        r = _make_reactor(state, blocks)
+        for h in range(1, 20):
+            r._prefetch_window()
+            r._settle(h, blocks[h].last_commit)
+            c = blocks[h].last_commit
+            validation.verify_commit_light(
+                CHAIN_ID, state.validators, c.block_id, h, c
+            )
+            r.pool.pop_request()
+        assert window == [28, 28, 20]
+        assert _flushes() == 3
+
+    def test_scheduler_off_window_is_one_seam_call(self, window, monkeypatch):
+        """With the scheduler switched off the window is one synchronous
+        batch through the seam: one dispatch, nothing left to wait for."""
+        monkeypatch.setenv("COMETBFT_TPU_VERIFY_SCHED", "0")
+        state, privs, blocks, commits = _make_chain(5)
+        r = _make_reactor(state, blocks)
+        r._prefetch_window()
+        assert window == [16] and _flushes() == 0
+        r._settle(1, blocks[1].last_commit)
+        for h in range(1, 5):
+            c = commits[h]
+            validation.verify_commit_light(
+                CHAIN_ID, state.validators, c.block_id, h, c
+            )
+        assert window == [16]
 
     def test_pool_peek_window(self):
         state, privs, blocks, commits = _make_chain(4)
