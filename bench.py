@@ -791,15 +791,13 @@ def _warmboot_child() -> None:
     )
 
 
-def run_warmboot(emit, buckets: "str | None" = None, reps: int = 5) -> dict:
+def run_warmboot(emit, buckets: "str | None" = None) -> dict:
     """Warm-boot pipeline bench (docs/warm-boot.md): two cold processes
     against one empty exec+compile cache.  Boot 1 pays the full trace+XLA
     compile matrix; boot 2 must deserialize EVERY padding-bucket shape
     (``exec_cache: hit``, zero compiles) and reach its first verified
     commit >=5x faster.  Verdicts are asserted bitwise-equal across boots
-    (the cached executable is the same computation).  Then a donation
-    micro-bench: dispatch latency of the donated vs non-donated executable
-    at the smallest bucket, fresh input buffers per rep."""
+    (the cached executable is the same computation)."""
     import tempfile
 
     import numpy as np
@@ -839,41 +837,6 @@ def run_warmboot(emit, buckets: "str | None" = None, reps: int = 5) -> dict:
         f"warm boot only {speedup:.1f}x faster to first verified commit"
     )
 
-    # donation micro-bench, in-process: steady-state dispatch latency of
-    # the donated vs non-donated executable (fresh jnp input buffers per
-    # rep — donated buffers are consumed by the call)
-    import jax.numpy as jnp
-
-    from cometbft_tpu.ops import verify as ov
-
-    donation = {}
-    try:
-        impl = "pallas" if ov._use_pallas() else "xla"
-        b = ov._BUCKETS[0]
-        pubs, msgs, sigs = _make_batch(b)
-        arrays, _, _ = ov.prepare_batch(pubs, msgs, sigs, b)
-
-        def time_variant(donated: bool) -> float:
-            call, _ = ov.bucket_executable(impl, b, donated=donated)
-            times = []
-            for _ in range(reps + 1):
-                kw = {k: jnp.asarray(v) for k, v in arrays.items()}
-                t0 = time.perf_counter()
-                np.asarray(call(**kw))
-                times.append(time.perf_counter() - t0)
-            return min(times[1:])  # drop the load/compile-bearing first rep
-
-        t_plain = time_variant(False)
-        t_donated = time_variant(True)
-        donation = {
-            "donation_bucket": b,
-            "dispatch_ms_plain": round(t_plain * 1e3, 2),
-            "dispatch_ms_donated": round(t_donated * 1e3, 2),
-            "donation_speedup": round(t_plain / max(t_donated, 1e-9), 3),
-        }
-    except Exception as e:  # noqa: BLE001 — advisory, never costs the stage
-        donation = {"donation_error": repr(e)}
-
     rec = {
         "metric": "warmboot_second_boot",
         "stage": "warmboot",
@@ -887,7 +850,6 @@ def run_warmboot(emit, buckets: "str | None" = None, reps: int = 5) -> dict:
         "second_boot_compiles": boot2["stats"]["compiles"],
         "verdicts_equal": verdicts_equal,
         "shapes_pruned": boot2["pruned"],
-        **donation,
     }
     emit(rec)
     return rec
@@ -1899,12 +1861,10 @@ def worker() -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from cometbft_tpu.ops import aot_cache
     from cometbft_tpu.ops import verify as ov
 
     platform = jax.devices()[0].platform
     impl = "pallas" if ov._use_pallas() else "xla"
-    jitted = ov._verify_kernel_pallas if impl == "pallas" else ov._verify_kernel
     batches = TPU_BATCHES
     cap = os.environ.get("BENCH_BATCH")  # bound the sweep (legacy knob)
     if cap:
@@ -1914,13 +1874,13 @@ def worker() -> None:
             batches = tuple(sorted(set(batches) | {cap_n}))
     reps = int(os.environ.get("BENCH_REPS", "5"))
 
-    def measure(call, kw, b: int) -> float:
-        accept = np.asarray(call(**kw))
+    def measure(call, packed, b: int) -> float:
+        accept = np.asarray(call(packed))
         assert accept[:b].all(), f"batch {b} failed to verify"
         times = []
         for _ in range(reps):
             t0 = time.perf_counter()
-            np.asarray(call(**kw))
+            np.asarray(call(packed))
             times.append(time.perf_counter() - t0)
         return min(times)
 
@@ -1928,8 +1888,9 @@ def worker() -> None:
     prep = {}
     for i, b in enumerate(batches):
         pubs, msgs, sigs = _make_batch(b)
-        arrays, _, _ = ov.prepare_batch(pubs, msgs, sigs)
-        kw = {k: jnp.asarray(v) for k, v in arrays.items()}
+        packed, _, structural, _ = ov.pack_batch(pubs, msgs, sigs)
+        lanes = structural.shape[0]
+        packed = jnp.asarray(packed)
         # heartbeat BEFORE the (possibly minutes-long) compile: the
         # orchestrator grants compile-sized stall budgets only while the
         # latest line is a compile-start marker
@@ -1939,9 +1900,7 @@ def worker() -> None:
                 dict(impl=impl, platform=platform, partial=True, batch=b),
             )
         )
-        call, info = aot_cache.load_or_compile(
-                jitted, kw, f"verify-{impl}-{arrays['s_ok'].shape[0]}"
-            )
+        call, info = ov.bucket_executable(impl, lanes)
         prep[b] = (pubs, msgs, sigs)
         if i == 0:
             # correctness of the COMPILED artifact before any timed run:
@@ -1949,8 +1908,7 @@ def worker() -> None:
             from scripts import chip_validate
 
             verdict = chip_validate.validate_with(
-                lambda **kws: np.asarray(call(**kws)),
-                bucket=arrays["s_ok"].shape[0],
+                lambda p: np.asarray(call(jnp.asarray(p))), bucket=lanes
             )
             chip_validate.write_artifact(verdict, impl=impl, platform=platform)
             _emit(
@@ -1964,7 +1922,7 @@ def worker() -> None:
             if not verdict["ok"]:
                 # broken bits: a throughput number would be meaningless
                 sys.exit(3)
-        t = measure(call, kw, b)
+        t = measure(call, packed, b)
         stage_s[b] = t
         _emit(
             _result_line(
@@ -1999,33 +1957,25 @@ def worker() -> None:
     e2e_s = e2e_times[len(e2e_times) // 2]  # p50
 
     # breakdown: sign-bytes (native commit_sign_bytes on a synthetic
-    # eb-sig commit), host pack (prepare_batch), transfer (device_put),
-    # kernel+fetch (AOT call on resident arrays), dispatch amortization.
+    # eb-sig commit), host pack (pack_batch), transfer (the one packed
+    # buffer), kernel+fetch (AOT call on the resident buffer), dispatch
+    # amortization.
     # The WHOLE breakdown is advisory — a failure here must never cost the
     # final headline line.
     breakdown = {}
     try:
         breakdown["signbytes_ms"] = round(_time_sign_bytes(eb) * 1e3, 2)
         t0 = time.perf_counter()
-        arrays_e, _, _ = ov.prepare_batch(pubs, msgs, sigs)
+        packed_e, _, structural_e, _ = ov.pack_batch(pubs, msgs, sigs)
         breakdown["host_pack_ms"] = round((time.perf_counter() - t0) * 1e3, 2)
         t0 = time.perf_counter()
-
-        def _transfer():
-            kw = {k: jnp.asarray(v) for k, v in arrays_e.items()}
-            for v in kw.values():
-                v.block_until_ready()
-            return kw
-
-        kw_e = _transfer()
+        packed_e = jnp.asarray(packed_e).block_until_ready()
         breakdown["transfer_ms"] = round((time.perf_counter() - t0) * 1e3, 2)
-        call_e, _ = aot_cache.load_or_compile(
-                jitted, kw_e, f"verify-{impl}-{arrays_e['s_ok'].shape[0]}"
-            )
+        call_e, _ = ov.bucket_executable(impl, structural_e.shape[0])
         kt = []
         for _ in range(max(reps, 3)):
             t0 = time.perf_counter()
-            np.asarray(call_e(**kw_e))
+            np.asarray(call_e(packed_e))
             kt.append(time.perf_counter() - t0)
         kt.sort()
         breakdown["kernel_fetch_p50_ms"] = round(kt[len(kt) // 2] * 1e3, 2)
@@ -2546,9 +2496,8 @@ def main() -> None:
         help="run only the warm-boot pipeline stage: two cold processes "
         "against one empty exec cache — first vs second boot "
         "time-to-first-verified-commit, per-shape exec_cache statuses "
-        "(second boot must be all hits, zero compiles), verdict "
-        "differential, and donated vs non-donated dispatch latency; "
-        "BENCH_WARMBOOT_BUCKETS bounds the matrix",
+        "(second boot must be all hits, zero compiles) and verdict "
+        "differential; BENCH_WARMBOOT_BUCKETS bounds the matrix",
     )
     ap.add_argument(
         "--warmboot-child", action="store_true", help=argparse.SUPPRESS

@@ -407,7 +407,7 @@ def supervised_device_call(
 
 
 def _pack(pubs, msgs, sigs, min_b: int):
-    """``prepare_batch`` (host pack, SHA-512) under its span, which says
+    """``pack_batch`` (host pack, SHA-512) under its span, which says
     which ``path`` packed (``native`` / ``python``) and holds the stage's
     two halves as children: ``verify.pack.glue`` (buffers, joins, lengths;
     the whole loop on the Python path) and ``verify.pack.native`` (the
@@ -415,15 +415,13 @@ def _pack(pubs, msgs, sigs, min_b: int):
     from cometbft_tpu.ops import verify as ov
 
     with tracing.span("verify.pack", n=len(pubs)) as sp:
-        arrays, n, structural, how = ov.pack_batch(pubs, msgs, sigs, min_b)
+        packed, n, structural, how = ov.pack_batch(pubs, msgs, sigs, min_b)
         sp.set(
-            lanes=arrays["s_ok"].shape[0],
-            bytes=_nbytes(arrays),
-            path=how["path"],
+            lanes=structural.shape[0], bytes=packed.nbytes, path=how["path"]
         )
         for lp in how["laps"]:
             lp.record(parent=sp)
-    return arrays, n, structural
+    return packed, n, structural
 
 
 def _min_bucket(backend: str) -> int:
@@ -436,10 +434,11 @@ def _nbytes(arrays: dict) -> int:
     return sum(v.nbytes for v in arrays.values())
 
 
-def _launch(backend: str, lanes: int, arrays: dict, laps):
-    """Resolve the bucket's executable, transfer, call: returns the
-    UNFETCHED device array.  Runs on the watchdog worker; ``laps`` time the
-    three (``verify.launch.lookup`` / ``.put`` / ``.call``)."""
+def _launch(backend: str, lanes: int, packed: np.ndarray, laps):
+    """Resolve the bucket's executable, transfer its ONE packed buffer,
+    call: returns the UNFETCHED device array and the number of arrays
+    placed.  Runs on the watchdog worker; ``laps`` time the three
+    (``verify.launch.lookup`` / ``.put`` / ``.call``)."""
     import jax.numpy as jnp
 
     from cometbft_tpu.ops import verify as ov
@@ -448,16 +447,17 @@ def _launch(backend: str, lanes: int, arrays: dict, laps):
     with lookup:
         call, _ = ov.bucket_executable(backend, lanes)
     with put:
-        placed = {k: jnp.asarray(v) for k, v in arrays.items()}
+        placed = [jnp.asarray(packed)]
     with called:
-        return call(**placed)
+        return call(*placed), len(placed)
 
 
 def _launch_mesh(backend: str, lanes: int, arrays: dict, mesh, laps):
     """The same over a mesh: resolve the sharded executable for this
     width, place one shard a chip (its lap is ``mesh.put``), call ONCE.
-    Returns the UNFETCHED sharded accept bits; the ``psum`` of the
-    per-shard counts stays on the devices.  Runs on the watchdog worker."""
+    Returns the UNFETCHED sharded accept bits (the ``psum`` of the
+    per-shard counts stays on the devices) and the number of arrays
+    placed.  Runs on the watchdog worker."""
     from cometbft_tpu.parallel import mesh as pmesh
 
     lookup, put, called = laps
@@ -466,7 +466,7 @@ def _launch_mesh(backend: str, lanes: int, arrays: dict, mesh, laps):
     with put:
         placed = pmesh.device_put_args(arrays, mesh)
     with called:
-        return call(*placed)[0]
+        return call(*placed)[0], len(placed)
 
 
 def _validate_accept(accept, lanes: int) -> np.ndarray:
@@ -551,15 +551,18 @@ def _launch_verify(h: _InflightVerify, **attrs) -> None:
     record.  It carries the (tier, lanes) pair, and with ``attrs`` the
     dispatch ordinal, that an anomaly dump attributes a watchdog fire to."""
     backend, pubs, msgs, sigs = h.backend, h.pubs, h.msgs, h.sigs
-    arrays, n, h.structural = _pack(pubs, msgs, sigs, _min_bucket(backend))
-    mesh = None
+    packed, n, h.structural = _pack(pubs, msgs, sigs, _min_bucket(backend))
+    mesh = arrays = None
+    lanes, size = h.structural.shape[0], packed.nbytes
     if h.mesh:
+        from cometbft_tpu.ops import verify as ov
         from cometbft_tpu.parallel import mesh as pmesh
 
         mesh = pmesh.mesh_of(h.mesh)
-        arrays = pmesh.pad_to_mesh(arrays, mesh)
+        arrays = pmesh.pad_to_mesh(ov.packed_views(packed), mesh)
+        lanes, size = arrays["s_ok"].shape[0], _nbytes(arrays)
         attrs["mesh"] = len(h.mesh)
-    h.lanes = lanes = arrays["s_ok"].shape[0]
+    h.lanes = lanes
     # a mesh's faults are per ordinal and surface at the shards' fetch
     # (``elastic.set_fault_injector``); this one is the single chip's
     inj = _FAULT_INJECTOR if mesh is None else None
@@ -579,22 +582,25 @@ def _launch_verify(h: _InflightVerify, **attrs) -> None:
         with launched:
             if mesh is not None:
                 dispatch_stats.record_mesh_dispatch(len(h.mesh))
-                return _launch_mesh(backend, lanes, arrays, mesh, laps), None
+                dev, placed = _launch_mesh(backend, lanes, arrays, mesh, laps)
+                return dev, None, placed
             if runner is not None:
                 # device-runner seam (sim/tests): a synchronous stand-in,
                 # whose fetch is then a no-op
-                return runner(backend, pubs, msgs, sigs, lanes), transform
+                dev = runner(backend, pubs, msgs, sigs, lanes)
+                return dev, transform, 0
             # executable resolution (exec-cache load or AOT compile)
             # runs INSIDE the watchdog worker: a wedged compile is
             # abandoned like a wedged dispatch, and the device-runner
             # seam above never pays a compile at all
-            return _launch(backend, lanes, arrays, laps), transform
+            dev, placed = _launch(backend, lanes, packed, laps)
+            return dev, transform, placed
 
     try:
         with tracing.span(
             "verify.dispatch", tier=backend, lanes=lanes, n=n, **attrs
         ) as dsp:
-            h.dev, h.transform = watchdog_call(
+            h.dev, h.transform, placed = watchdog_call(
                 run,
                 backend=backend if mesh is None else "mesh",
                 note_anomaly=False,
@@ -602,17 +608,20 @@ def _launch_verify(h: _InflightVerify, **attrs) -> None:
             # timed inside the watchdog closure, recorded by the calling
             # thread once ``watchdog_call`` has returned: an abandoned
             # worker writes no span
-            size = _nbytes(arrays)
             on_mesh = {} if mesh is None else {"mesh": len(h.mesh)}
             lsp = launched.record(
                 parent=dsp, tier=backend, lanes=lanes, bytes=size, **on_mesh
             )
             lookup, put, called = laps
             lookup.record(parent=lsp)
+            # ``transfers``: the arrays the placement handed the device
             if mesh is None:
-                put.record(parent=lsp)
+                put.record(parent=lsp, transfers=placed)
             else:
-                put.record(parent=lsp, shards=len(h.mesh), bytes=size)
+                put.record(
+                    parent=lsp, shards=len(h.mesh), bytes=size,
+                    transfers=placed,
+                )
             called.record(parent=lsp)
     except DispatchTimeoutError:
         # the failed span is already in the ring (the with-block closed),

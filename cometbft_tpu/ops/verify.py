@@ -15,7 +15,9 @@ accept bits come out for free — no recheck pass to attribute failures
 
 The device inputs are RAW BYTES (32 B per element: pub, R, s, m) — limb
 packing and digit extraction happen on device, keeping the host->device
-transfer minimal and the host prep trivial.
+transfer minimal and the host prep trivial.  On one chip they travel as ONE
+packed u8 buffer, split on the device (``split_packed``, ``verify_packed``):
+one transfer a launch.
 """
 
 from __future__ import annotations
@@ -34,9 +36,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-# Donated input buffers that XLA cannot alias to the (much smaller) accept
-# bitmap produce a cosmetic compile-time warning; donation still lets the
-# compiler reuse them as scratch.  Message-scoped so real warnings survive.
+# The mesh's donated input shards, which XLA cannot alias to the (much
+# smaller) accept bitmap, produce a cosmetic compile-time warning; donation
+# still lets the compiler reuse them as scratch.  Message-scoped so real
+# warnings survive.
 warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable"
 )
@@ -127,17 +130,55 @@ def _decompress_pair(ya, sa, yr, sr):
     return ok_all[:t], a, ok_all[t:], r
 
 
-_DONATE_ARGS = ("a_bytes", "r_bytes", "s_bytes", "m_bytes", "s_ok")
+ARG_NAMES = ("a_bytes", "r_bytes", "s_bytes", "m_bytes", "s_ok")
 
-_verify_kernel = jax.jit(verify_core)
-# Donated variant for the steady-state hot loop: the padded input buffers
-# are freshly packed per dispatch (prepare_batch -> jnp.asarray) and never
-# reused by the caller, so XLA may alias them for its outputs/scratch
-# instead of allocating — steady-state verify stops paying alloc+copy per
-# dispatch.  Callers that DO reuse device-resident inputs across calls
-# (bench.py's timed reps, chip_validate's vector suite) use the
-# non-donated executables.
-_verify_kernel_donated = jax.jit(verify_core, donate_argnames=_DONATE_ARGS)
+
+# -- the one-chip input: ONE packed buffer -----------------------------------
+#
+# A bucket of ``lanes`` lanes is ONE (4·lanes + lanes/32, 32) u8 array: rows
+# 0 … 4·lanes−1 the four tables a, r, s, m (a lane's 32 bytes a row), the
+# last lanes/32 rows ``s_ok``, one byte a lane.  129 bytes a lane, as the five
+# arrays were, with no padding (every bucket is a multiple of 32).  The host
+# packs into it in place (``pack_batch``) and the launch transfers it once.
+
+
+def packed_rows(lanes: int) -> int:
+    """Rows of the packed buffer of a ``lanes``-lane bucket."""
+    return 4 * lanes + lanes // 32
+
+
+def split_packed(packed) -> tuple:
+    """The five inputs of ``verify_core`` as row slices of one packed
+    buffer, numpy or jax alike: four (lanes, 32) tables and ``s_ok`` as
+    (lanes,) u8.  On the host these are views of the buffer's memory; on a
+    TPU each slice fuses into the byte unpacking that reads it (a reshape
+    of the four tables into one (4, lanes, 32) array is a copy there)."""
+    lanes = packed.shape[0] * 32 // 129
+    tables = (packed[i * lanes : (i + 1) * lanes] for i in range(4))
+    return (*tables, packed[4 * lanes :].reshape(lanes))
+
+
+def packed_views(packed: np.ndarray) -> dict:
+    """The five named arrays the rest of the code knows, as VIEWS into one
+    host packed buffer (``s_ok`` read as bool)."""
+    *tables, ok = split_packed(packed)
+    return dict(zip(ARG_NAMES, (*tables, ok.view(bool))))
+
+
+def _packed_front(core, name: str):
+    """The one-chip executable of a tier: ONE packed input, split on the
+    device, then the tier's unchanged five-input ``core``.
+    (packed_rows(B), 32) u8 in, (B,) bool accept bits out."""
+
+    def front(packed):
+        *tables, ok = split_packed(packed)
+        return core(*tables, ok != 0)
+
+    front.__name__ = front.__qualname__ = name
+    return jax.jit(front)
+
+
+verify_packed = _packed_front(verify_core, "verify_packed")
 
 
 def select_impl(devices=None) -> str:
@@ -172,26 +213,23 @@ def _pallas_core(a_bytes, r_bytes, s_bytes, m_bytes, s_ok):
     )
 
 
-_verify_kernel_pallas = jax.jit(_pallas_core)
-_verify_kernel_pallas_donated = jax.jit(
-    _pallas_core, donate_argnames=_DONATE_ARGS
-)
+verify_packed_pallas = _packed_front(_pallas_core, "verify_packed_pallas")
 
 
 # -- AOT executable cache seam ----------------------------------------------
 #
 # Every bucketed verify dispatch obtains its executable here instead of
-# calling the jitted kernels directly: on first use of a (impl, lanes,
-# donated) shape the executable is AOT-compiled (or deserialized from the
-# on-disk cache, skipping tracing AND compilation) and memoized for the
-# process.  The memo plays the role jit's internal cache played — including
-# its limitation that anything read at trace time only takes effect before
-# a shape's first use.
+# calling the jitted kernels directly: on first use of an (impl, lanes)
+# shape the executable is AOT-compiled (or deserialized from the on-disk
+# cache, skipping tracing AND compilation) and memoized for the process.
+# The memo plays the role jit's internal cache played — including its
+# limitation that anything read at trace time only takes effect before a
+# shape's first use.
 
 _EXEC_LOCK = threading.Lock()
-_EXEC_CACHE: dict = {}  # (impl, lanes, donated) -> callable
+_EXEC_CACHE: dict = {}  # (impl, lanes) -> callable
 # shape key -> the compiler's message, for every shape whose lowering or
-# compile failed in this process: (impl, lanes, donated) here, ("mesh", ...)
+# compile failed in this process: (impl, lanes) here, ("mesh", ...)
 # for parallel/mesh's sharded executables.  A latched shape raises
 # ``TierCompileError`` again at once: the supervisor demotes the tier
 # through its breaker (the node keeps verifying one tier down), nothing
@@ -224,55 +262,39 @@ def compile_failed(key, what: str, e: BaseException) -> TierCompileError:
 
 
 def donation_enabled() -> bool:
-    """Whether the hot loop uses input-donating executables by default:
-    ON exactly for the Pallas/TPU production path.  The XLA-CPU CI path is
-    OFF on purpose: donation changes the compiled artifact, so turning it
-    on would force a fresh ~100s compile of every bucket shape the first
-    time a host runs this code (measured on the CI host) for an aliasing
-    win that only matters at device-HBM bandwidth.  Callers that reuse
-    device-resident inputs across calls (bench timed reps, chip_validate)
-    always pass ``donated=False`` explicitly."""
+    """Whether the MESH-WIDE executables donate their input shards: ON
+    exactly for the Pallas/TPU production path.  The XLA-CPU CI path is OFF
+    on purpose: donation changes the compiled artifact, so turning it on
+    would force a fresh ~100s compile of every mesh shape the first time a
+    host runs this code (measured on the CI host) for an aliasing win that
+    only matters at device-HBM bandwidth.  The one-chip executable donates
+    nothing: its one (packed_rows(B), 32) u8 input cannot alias its (B,)
+    bool output."""
     return _use_pallas()
 
 
-def bucket_tag(impl: str, lanes: int, donated: bool = False) -> str:
-    """On-disk cache tag for one bucket executable.  The non-donated form
-    is shared with bench.py/chip_validate's direct load_or_compile use;
-    donation changes the compiled artifact (input aliasing), so donated
-    executables get their own entry."""
-    base = f"verify-{impl}-{lanes}"
-    return base + "-donated" if donated else base
+def bucket_tag(impl: str, lanes: int) -> str:
+    """On-disk cache tag for one bucket's one-chip executable: the packed
+    input's, so that no entry of the five-input form can be taken for it."""
+    return f"verify-{impl}-packed-{lanes}"
 
 
-def _bucket_jitted(impl: str, donated: bool):
-    if impl == "pallas":
-        return (
-            _verify_kernel_pallas_donated if donated else _verify_kernel_pallas
-        )
-    return _verify_kernel_donated if donated else _verify_kernel
+def _bucket_jitted(impl: str):
+    return verify_packed_pallas if impl == "pallas" else verify_packed
 
 
-def _bucket_shapes(lanes: int) -> dict:
-    byte = jax.ShapeDtypeStruct((lanes, 32), jnp.uint8)
-    return dict(
-        a_bytes=byte,
-        r_bytes=byte,
-        s_bytes=byte,
-        m_bytes=byte,
-        s_ok=jax.ShapeDtypeStruct((lanes,), jnp.bool_),
-    )
+def _bucket_shapes(lanes: int) -> tuple:
+    return (jax.ShapeDtypeStruct((packed_rows(lanes), 32), jnp.uint8),)
 
 
-def bucket_executable(
-    impl: str, lanes: int, donated: "Optional[bool]" = None
-):
-    """The executable for one padded bucket shape: (call, info).
+def bucket_executable(impl: str, lanes: int):
+    """The one-chip executable for one padded bucket shape: (call, info).
 
-    ``call(**arrays)`` runs it (async dispatch, same calling convention as
-    the jitted kernels).  info["exec_cache"] records where it came from:
-    ``memo`` (process cache), ``hit`` (deserialized from disk — no tracing,
-    no compilation), ``miss``/``stale`` + ``compile_s`` (freshly built and
-    persisted).
+    ``call(packed)`` runs it (async dispatch) on the bucket's ONE packed
+    buffer (``pack_batch``'s first value, placed on the device).
+    info["exec_cache"] records where it came from: ``memo`` (process
+    cache), ``hit`` (deserialized from disk — no tracing, no compilation),
+    ``miss``/``stale`` + ``compile_s`` (freshly built and persisted).
 
     A lowering or compile failure is LOUD: logged at error with the
     compiler's message, counted (``warm_stats`` ``compile_failures``),
@@ -286,9 +308,7 @@ def bucket_executable(
     bucket before serving (a node's start, ``chip_smoke.py``, the
     benchmark's set-up) leaves nothing to compile inside a request, under
     the watchdog's deadline."""
-    if donated is None:
-        donated = donation_enabled()
-    key = (impl, lanes, bool(donated))
+    key = (impl, lanes)
     raise_if_broken(key)
     with _EXEC_LOCK:
         memo = _EXEC_CACHE.get(key)
@@ -296,20 +316,14 @@ def bucket_executable(
         return memo, {"exec_cache": "memo"}
     from cometbft_tpu.ops import aot_cache
 
-    # the served path launches the default form (``donation_enabled``)
-    wide = None
-    if bool(donated) == donation_enabled():
-        wide = _resolve_mesh_wide(impl, lanes, bool(donated))
-
+    wide = _resolve_mesh_wide(impl, lanes)
     try:
         call, info = aot_cache.load_or_compile(
-            _bucket_jitted(impl, donated),
-            _bucket_shapes(lanes),
-            bucket_tag(impl, lanes, donated),
+            _bucket_jitted(impl), _bucket_shapes(lanes), bucket_tag(impl, lanes)
         )
     except Exception as e:  # noqa: BLE001 — whatever the compiler raised
         raise compile_failed(
-            key, f"verify tier {bucket_tag(impl, lanes, donated)}", e
+            key, f"verify tier {bucket_tag(impl, lanes)}", e
         ) from e
     with _EXEC_LOCK:
         # two racing compilers: first writer wins, both results correct
@@ -319,9 +333,7 @@ def bucket_executable(
     return call, info
 
 
-def _resolve_mesh_wide(
-    impl: str, lanes: int, donated: bool
-) -> "Optional[dict]":
+def _resolve_mesh_wide(impl: str, lanes: int) -> "Optional[dict]":
     """The mesh-wide executable the served path launches for a batch of
     this bucket (``ops/supervisor._launch_mesh``), resolved through the
     executable cache: {tag: info}, or None where no mesh-wide launch can
@@ -343,9 +355,9 @@ def _resolve_mesh_wide(
         return None
     m = pmesh.mesh_of(ordinals)
     padded = lanes + (-lanes) % len(ordinals)
-    tag = pmesh.mesh_tag(impl, len(ordinals), padded, donated)
+    tag = pmesh.mesh_tag(impl, len(ordinals), padded, donation_enabled())
     try:
-        _, info = pmesh.sharded_verify_call(m, padded, impl, donated)
+        _, info = pmesh.sharded_verify_call(m, padded, impl)
     except TierCompileError as e:
         return {tag: {"error": str(e)}}
     return {tag: dict(info)}
@@ -381,12 +393,14 @@ def prepare_batch(
     loop (``_pack_python``) is the fallback and the differential oracle for
     it.
     """
-    arrays, n, structural, _ = pack_batch(pubs, msgs, sigs, min_bucket)
-    return arrays, n, structural
+    packed, n, structural, _ = pack_batch(pubs, msgs, sigs, min_bucket)
+    return packed_views(packed), n, structural
 
 
 def pack_batch(pubs, msgs, sigs, min_bucket: int = _PALLAS_MIN_BUCKET):
-    """``prepare_batch`` and how it went: a fourth value
+    """``prepare_batch`` as the one-chip executable takes it, and how it
+    went: (packed, n, structural, how).  ``packed`` is the ONE buffer
+    (``packed_rows``; ``packed_views`` names its five arrays), ``how``
     ``{"path": "native" | "python", "laps": (glue, native)}``, the two
     halves of the stage timed where they ran (``tracing.lap``; the second
     never opened on the Python path) for whoever holds the stage's span to
@@ -397,8 +411,9 @@ def pack_batch(pubs, msgs, sigs, min_bucket: int = _PALLAS_MIN_BUCKET):
     call = tracing.lap("verify.pack.native")
     pack_into = _native_pack_into() if n else None
     with glue:
-        # one allocation, four tables: a, r, s, m
-        bufs = (*np.zeros((4, b, 32), np.uint8), np.zeros((b,), bool))
+        # one allocation: the four tables and s_ok, written in place
+        packed = np.zeros((packed_rows(b), 32), np.uint8)
+        bufs = tuple(packed_views(packed).values())
         structural = np.zeros((b,), bool)
         if pack_into is None:
             _pack_python(pubs, msgs, sigs, bufs, structural)
@@ -413,10 +428,7 @@ def pack_batch(pubs, msgs, sigs, min_bucket: int = _PALLAS_MIN_BUCKET):
         else:  # refused before anything was written: no row can be outside
             structural[:] = False
             _pack_python(pubs, msgs, sigs, bufs, structural)
-    arrays = dict(
-        zip(("a_bytes", "r_bytes", "s_bytes", "m_bytes", "s_ok"), bufs)
-    )
-    return arrays, n, structural, {"path": path, "laps": (glue, call)}
+    return packed, n, structural, {"path": path, "laps": (glue, call)}
 
 
 def _native_pack_into():

@@ -36,7 +36,7 @@ from cometbft_tpu.ops import verify as ov
 SIG_AXIS = "sig"
 # Packed batch arrays from ops.verify.prepare_batch: raw bytes, batch-major
 # (B, 32) — limb unpacking happens per-shard on device.
-ARG_ORDER = ("a_bytes", "r_bytes", "s_bytes", "m_bytes", "s_ok")
+ARG_ORDER = ov.ARG_NAMES
 
 
 def make_mesh(devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
@@ -141,10 +141,9 @@ def sharded_verify_fn(
 
     ``donated=True`` donates all five input buffers (ROADMAP item 4's mesh
     leftover): the packed arrays are repacked per dispatch and placed fresh
-    by ``device_put_args``, so the aliasing is safe by the same argument as
-    the single-chip hot loop (docs/warm-boot.md "Donated buffers") — XLA
-    reuses the shards' HBM for the kernel's scratch instead of allocating
-    alongside them."""
+    by ``device_put_args``, so no caller observes a donated shard's
+    invalidation (docs/warm-boot.md) — XLA reuses the shards' HBM for the
+    kernel's scratch instead of allocating alongside them."""
     impl = impl or ov.select_impl(mesh.devices.flat)
     key = (impl, bool(donated)) + tuple(
         (d.platform, d.id) for d in mesh.devices.flat
@@ -187,7 +186,7 @@ def mesh_tag(impl: str, n_dev: int, lanes: int, donated: bool = False) -> str:
     executable — what lets a restarted dry-run/bench process load the
     sharded executable instead of re-lowering per shard count.  Donation
     changes the compiled artifact (input aliasing), so donated executables
-    get their own entry, mirroring ``ops.verify.bucket_tag``."""
+    get their own entry."""
     base = f"mesh-{impl}-{n_dev}dev-{lanes}"
     return base + "-donated" if donated else base
 
@@ -208,7 +207,7 @@ def sharded_verify_call(
     (``ops.verify.compile_failed``: logged at error, counted, latched) and
     raised: the elastic supervisor drops the batch to the single-chip
     chain, and nothing retries the compile quietly through plain jit.
-    ``donated`` defaults to the single-chip donation policy
+    ``donated`` defaults to the mesh's donation policy
     (``ops.verify.donation_enabled`` — Pallas/TPU on, CPU CI off)."""
     impl = impl or ov.select_impl(mesh.devices.flat)
     if donated is None:
@@ -466,15 +465,12 @@ def run_single_shard(
         _ensure_base_registry()
         device = _DEVICE_BY_ORDINAL[int(ordinal)]
     impl = ov.select_impl([device])
-    arrays, n, structural = ov.prepare_batch(pubs, msgs, sigs)
-    # plain jit (not the AOT cache): jit re-specializes per committed
-    # device, so the probe really exercises the probed chip instead of
-    # whatever device the cached executable was compiled for
-    jitted = ov._bucket_jitted(impl, False)
-    placed = {
-        k: jax.device_put(np.asarray(v), device) for k, v in arrays.items()
-    }
-    accept = np.asarray(jitted(**placed))
+    packed, n, structural, _ = ov.pack_batch(pubs, msgs, sigs)
+    # the one-chip executable's jit (not the AOT cache): jit re-specializes
+    # per committed device, so the probe really exercises the probed chip
+    # instead of whatever device the cached executable was compiled for
+    jitted = ov._bucket_jitted(impl)
+    accept = np.asarray(jitted(jax.device_put(packed, device)))
     real = (accept[: len(structural)] & structural)[:n]
     out = np.zeros(int(lanes) if lanes else n, dtype=bool)
     out[: min(n, out.shape[0])] = real[: out.shape[0]]

@@ -12,10 +12,12 @@ A/R encodings accepted, s strictly < L.
 
 Usable two ways:
   * `validate_with(call, bucket)` — bench.py hands in its already-compiled
-    executable; vectors are padded into that batch shape (no extra compile).
+    one-chip executable; vectors are packed into that bucket's one buffer
+    (no extra compile).
   * `python scripts/chip_validate.py` — standalone: selects the platform's
-    kernel like production does, compiles (or AOT-loads) at a small bucket,
-    validates, writes the artifact.
+    kernel like production does, resolves the bucket executable production
+    launches (compile or AOT load) at a small bucket, validates, writes the
+    artifact.
 """
 
 from __future__ import annotations
@@ -96,25 +98,17 @@ def _vectors():
 
 
 def validate_with(call, bucket: int) -> dict:
-    """Run the vector suite through ``call`` (a compiled kernel taking the
-    packed batch kwargs at ``bucket`` lanes).  Returns the verdict dict."""
+    """Run the vector suite through ``call`` (a compiled one-chip executable
+    taking the ONE packed buffer of ``bucket`` lanes).  Returns the verdict
+    dict."""
     import numpy as np
 
     from cometbft_tpu.ops import verify as ov
 
     pubs, msgs, sigs, expect, labels = _vectors()
-    arrays, n, structural = ov.prepare_batch(pubs, msgs, sigs)
-    b = arrays["s_ok"].shape[0]
-    assert b <= bucket, (b, bucket)
-    if b < bucket:
-        pad = bucket - b
-        arrays = {
-            k: np.concatenate(
-                [v, np.zeros((pad,) + v.shape[1:], v.dtype)]
-            )
-            for k, v in arrays.items()
-        }
-    accept = np.asarray(call(**arrays))[: len(structural)]
+    packed, n, structural, _ = ov.pack_batch(pubs, msgs, sigs, bucket)
+    assert structural.shape == (bucket,), (structural.shape, bucket)
+    accept = np.asarray(call(packed))
     got = list((accept & structural)[:n])
     failures = [
         {"label": lbl, "want": bool(w), "got": bool(g)}
@@ -163,24 +157,15 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
 
-    from cometbft_tpu.ops import aot_cache
     from cometbft_tpu.ops import verify as ov
 
     platform = jax.devices()[0].platform
     impl = "pallas" if ov._use_pallas() else "xla"
-    jitted = (
-        ov._verify_kernel_pallas if impl == "pallas" else ov._verify_kernel
-    )
-    # compile at the smallest bucket that holds the vector suite
-    pubs, msgs, sigs, _, _ = _vectors()
-    arrays, _, _ = ov.prepare_batch(pubs, msgs, sigs)
-    kw = {k: jnp.asarray(v) for k, v in arrays.items()}
-    call, info = aot_cache.load_or_compile(
-        jitted, kw, f"verify-{impl}-{arrays['s_ok'].shape[0]}"
-    )
+    # the smallest bucket that holds the vector suite
+    bucket = ov.bucket_size(len(_vectors()[0]))
+    call, info = ov.bucket_executable(impl, bucket)
     verdict = validate_with(
-        lambda **kws: np.asarray(call(**{k: jnp.asarray(v) for k, v in kws.items()})),
-        bucket=arrays["s_ok"].shape[0],
+        lambda packed: np.asarray(call(jnp.asarray(packed))), bucket=bucket
     )
     write_artifact(verdict, impl=impl, platform=platform)
     print(json.dumps({**verdict, "impl": impl, "platform": platform, **info}))

@@ -327,7 +327,7 @@ def _mixed_batch(n=6):
     return pubs, msgs, sigs
 
 
-@pytest.mark.warmcache("verify-xla-32")
+@pytest.mark.warmcache("verify-xla-packed-32")
 def test_cached_executable_verdicts_bitwise_equal():
     """ISSUE 8 acceptance differential: the DESERIALIZED bucket executable
     produces bitwise the verdicts of the freshly-compiled one (the process

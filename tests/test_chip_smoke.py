@@ -62,10 +62,11 @@ def test_pallas_compile_failure_fails_the_tier_check(
     def load_or_compile(jitted, shapes, tag):
         if "pallas" in tag:
             raise RuntimeError("Mosaic failed to compile TPU kernel: forced")
-        lanes = shapes["s_ok"].shape[0]
+        (packed,) = shapes  # the one-chip executable's one input
+        lanes = packed.shape[0] * 32 // 129
         want = np.zeros(lanes, dtype=bool)
         want[0] = True  # stands in for the XLA tier's (right) verdicts
-        return (lambda **kw: want), {"exec_cache": "miss", "compile_s": 0.0}
+        return (lambda packed: want), {"exec_cache": "miss", "compile_s": 0.0}
 
     monkeypatch.setattr(aot_cache, "load_or_compile", load_or_compile)
     monkeypatch.setenv("COMETBFT_TPU_VERIFY_IMPL", "pallas")  # as on a TPU

@@ -840,6 +840,29 @@ class TestServedMesh:
                       "sched.handoff.wake"):
             assert len(_spans(stage)) == 1, stage
 
+    def test_the_mesh_launch_still_places_five_arrays(
+        self, served, monkeypatch
+    ):
+        """The packed buffer is the one-chip launch's: mesh-wide, each of
+        the five arrays is placed across the width, one placement call an
+        array, and ``mesh.put`` counts them (``transfers`` 5)."""
+        from cometbft_tpu.verifysched import service
+
+        width = 4
+        served(width)
+        placed = []
+        real = jax.device_put
+        monkeypatch.setattr(
+            jax, "device_put",
+            lambda x, *a, **k: placed.append(np.shape(x)) or real(x, *a, **k),
+        )
+        pubs, msgs, sigs = _signed(b"served/five", self.N)
+        assert all(service.verify_segment_sync(pubs, msgs, sigs))
+        assert placed == [(BUCKET, 32)] * 4 + [(BUCKET,)]
+        (put,) = _spans("mesh.put")
+        assert put["attrs"]["transfers"] == len(placed) == 5
+        assert put["attrs"]["shards"] == width
+
     def test_every_shard_pull_says_which_worker_served_it(self, served):
         """ISSUE 37: the launch and each shard's pull are watchdog calls in
         turn, so once the launch's worker has parked every pull finds it:
@@ -866,7 +889,8 @@ class TestServedMesh:
         pubs, msgs, sigs = _signed(b"parts/%d" % width, self.N)
         for i in (0, BUCKET // width, self.N - 1):
             _forge(sigs, i)
-        arrays, n, structural = ov.prepare_batch(pubs, msgs, sigs, BUCKET)
+        packed, n, structural, _ = ov.pack_batch(pubs, msgs, sigs, BUCKET)
+        arrays = ov.packed_views(packed)
         assert arrays["s_ok"].shape[0] == BUCKET and n == self.N
         call, _ = pmesh.sharded_verify_call(m, BUCKET, "xla")
         accept, n_ok = call(*pmesh.device_put_args(arrays, m))
@@ -879,9 +903,7 @@ class TestServedMesh:
             k * (BUCKET // width) for k in range(width)
         ]
         joined = np.concatenate([np.asarray(s.data) for s in parts])
-        one = np.asarray(ov.bucket_executable("xla", BUCKET, False)[0](**{
-            k: np.asarray(v) for k, v in arrays.items()
-        }))
+        one = np.asarray(ov.bucket_executable("xla", BUCKET)[0](packed))
         assert (joined == one).all()
         assert int(n_ok) == int(joined.sum()) == sum(
             int(np.asarray(s.data).sum()) for s in parts

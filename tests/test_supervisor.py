@@ -98,9 +98,10 @@ def _install_fake_chip(monkeypatch, batches, sleep_s=0.0):
     """The ``chip`` kind without a compile: the bucket's EXECUTABLE is
     replaced by a host stand-in that answers each lane it was handed with
     the oracle's bit for the triple packed there, as an unfetched "device
-    array".  ``_launch`` itself (lookup, the five transfers, the call, each
-    under its lap), pack, both watchdog calls, the spans and the breaker
-    handling above it run as on a chip.  Returns the launches."""
+    array".  ``_launch`` itself (lookup, the one transfer of the packed
+    buffer, the call, each under its lap), pack, both watchdog calls, the
+    spans and the breaker handling above it run as on a chip.  Returns the
+    launches."""
     known = {}
     for pubs, msgs, sigs in batches:
         for p, m, g in zip(pubs, msgs, sigs):
@@ -111,8 +112,9 @@ def _install_fake_chip(monkeypatch, batches, sleep_s=0.0):
     from cometbft_tpu.ops import verify as ov
 
     def fake_executable(backend, lanes):
-        def call(**arrays):
-            a, r = np.asarray(arrays["a_bytes"]), np.asarray(arrays["r_bytes"])
+        def call(packed):
+            arrays = ov.packed_views(np.asarray(packed))
+            a, r = arrays["a_bytes"], arrays["r_bytes"]
             out = np.zeros(lanes, dtype=bool)
             for i in range(lanes):
                 out[i] = known.get((a[i].tobytes(), r[i].tobytes()), False)
@@ -599,6 +601,49 @@ class TestFaultDifferential:
         hist = snap["dispatch_hist"]["xla-32"]
         assert hist["count"] == 1 and hist["sum"] >= 0.02  # the fetch wait
 
+    def test_a_chip_launch_is_one_transfer(self, monkeypatch):
+        """The kind ``chip`` places ONE buffer: the packed one, whose views
+        the five arrays are, handed to the executable as its one input;
+        ``verify.launch.put`` says so (``transfers`` 1)."""
+        import jax
+        import jax.numpy as jnp
+
+        from cometbft_tpu.libs import tracing
+        from cometbft_tpu.ops import verify as ov
+
+        pubs, msgs, sigs = _mixed_batch(np.random.default_rng(9), 9)
+        _install_fake_chip(monkeypatch, [(pubs, msgs, sigs)])
+        handed = []
+        fake = ov.bucket_executable
+
+        def recording(backend, lanes):
+            call, info = fake(backend, lanes)
+            return (lambda *a, **k: handed.append((a, k)) or call(*a, **k)), info
+
+        monkeypatch.setattr(ov, "bucket_executable", recording)
+        placed = []
+        for mod, name in ((jnp, "asarray"), (jax, "device_put")):
+            real = getattr(mod, name)
+            monkeypatch.setattr(
+                mod, name,
+                lambda x, *a, _real=real, **k: placed.append(np.shape(x))
+                or _real(x, *a, **k),
+            )
+        tracing.get_tracer().reset()
+        h = supervisor.dispatch_verify(pubs, msgs, sigs)
+        assert h.kind == "chip"
+        assert placed == [(ov.packed_rows(32), 32)]
+        ((args, kwargs),) = handed
+        assert kwargs == {} and len(args) == 1
+        assert args[0].shape == (ov.packed_rows(32), 32)
+        assert args[0].dtype == np.uint8
+        assert list(supervisor.fetch_verify(h)) == _oracle(pubs, msgs, sigs)
+        (put,) = [
+            sp for sp in tracing.get_tracer().tail(20)
+            if sp["stage"] == "verify.launch.put"
+        ]
+        assert put["attrs"]["transfers"] == len(placed) == 1
+
     def test_abandoned_launch_worker_records_no_lap(self, monkeypatch):
         """ISSUE 36: the launch's laps are closed on the watchdog worker and
         recorded by the caller only after ``watchdog_call`` returned: a
@@ -611,7 +656,7 @@ class TestFaultDifferential:
         released = threading.Event()
 
         def wedged_executable(backend, lanes):
-            def call(**arrays):
+            def call(packed):
                 time.sleep(0.3)
                 released.set()
                 return np.zeros(lanes, dtype=bool)

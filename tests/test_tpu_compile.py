@@ -6,8 +6,8 @@ chip that is described and not attached.  Interpret-mode Pallas
 concatenate of the merged decompress passed every interpret-mode test and
 failed to lower for a v5e — so the executables production selects on a TPU
 are compiled here for a described ``v5e:2x2``: one chip at the 128- and
-10,240-lane buckets, and the four-device ``shard_map`` form at 10,240,
-8,192 and 256.
+10,240-lane buckets (the packed input), and the four-device ``shard_map``
+form at 10,240, 8,192 and 256.
 Nothing runs, so this says nothing about verdicts or times; chip_smoke.py
 does that on the chip.
 
@@ -65,14 +65,17 @@ def _shapes(lanes: int, two_d, one_d) -> dict:
 @pytest.mark.parametrize("lanes", [128, 10240])
 def test_pallas_bucket_compiles_for_one_chip(topo, lanes):
     """What ``bucket_executable("pallas", lanes)`` builds on a TPU: the
-    donated Pallas executable (``donation_enabled`` is on there)."""
+    Pallas kernel behind the ONE packed input, split on the device by row
+    slices that fuse into the unpacking (no (4, lanes, 32) copy of the
+    four tables)."""
     one_chip = SingleDeviceSharding(topo.devices[0])
-    compiled = (
-        ov._bucket_jitted("pallas", donated=True)
-        .lower(**_shapes(lanes, one_chip, one_chip))
-        .compile()
+    packed = jax.ShapeDtypeStruct(
+        (ov.packed_rows(lanes), 32), jnp.uint8, sharding=one_chip
     )
-    assert "tpu_custom_call" in compiled.as_text()
+    compiled = ov._bucket_jitted("pallas").lower(packed).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    assert f"u8[4,{lanes},32]" not in text
 
 
 @pytest.mark.parametrize("lanes", [10240, 8192, 256])

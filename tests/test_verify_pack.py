@@ -276,11 +276,13 @@ def _both_paths(monkeypatch, pubs, msgs, sigs, min_bucket=32):
 def _assert_byte_for_byte(got, want):
     assert got[1] == want[1]
     assert np.array_equal(got[2], want[2])
-    assert got[0].keys() == want[0].keys() == set(ARRAYS)
+    assert got[0].shape == want[0].shape and got[0].dtype == want[0].dtype
+    g, w = ov.packed_views(got[0]), ov.packed_views(want[0])
+    assert g.keys() == w.keys() == set(ARRAYS)
     for k in ARRAYS:
-        assert got[0][k].dtype == want[0][k].dtype, k
-        assert got[0][k].shape == want[0][k].shape, k
-        assert np.array_equal(got[0][k], want[0][k]), k
+        assert g[k].dtype == w[k].dtype, k
+        assert g[k].shape == w[k].shape, k
+        assert np.array_equal(g[k], w[k]), k
 
 
 @pytest.mark.parametrize("n", [1, 3, 40, 128, 129])
@@ -292,7 +294,7 @@ def test_prepare_batch_straight_path(nlib, monkeypatch, n):
     got, want = _both_paths(monkeypatch, pubs, msgs, sigs)
     assert got[3]["path"] == "native"
     _assert_byte_for_byte(got, want)
-    arrays, m, structural = got[:3]
+    arrays, m, structural = ov.packed_views(got[0]), *got[1:3]
     assert m == n and structural[:n].all() and not structural[n:].any()
     assert arrays["s_ok"].shape[0] == ov.bucket_size(n, 32)
     assert not arrays["s_ok"][n // 2] and not arrays["s_bytes"][n // 2].any()
@@ -322,7 +324,7 @@ def test_prepare_batch_index_path(nlib, monkeypatch, where, what):
     got, want = _both_paths(monkeypatch, pubs, msgs, sigs)
     assert got[3]["path"] == "native"
     _assert_byte_for_byte(got, want)
-    arrays, n, structural = got[:3]
+    arrays, n, structural = ov.packed_views(got[0]), *got[1:3]
     assert n == 40
     assert [i for i in range(40) if not structural[i]] == where
     for k in ARRAYS:
@@ -359,12 +361,12 @@ def test_a_library_without_the_symbol_falls_back_without_raising(monkeypatch):
 
     monkeypatch.setattr(native, "lib", lambda: Stale())
     pubs, msgs, sigs = _signed(6)
-    arrays, n, structural, how = ov.pack_batch(pubs, msgs, sigs, 32)
+    packed, n, structural, how = ov.pack_batch(pubs, msgs, sigs, 32)
     assert how["path"] == "python" and n == 6 and structural[:6].all()
     monkeypatch.setattr(native, "lib", lambda: None)  # no toolchain at all
     again = ov.pack_batch(pubs, msgs, sigs, 32)
     assert again[3]["path"] == "python"
-    _assert_byte_for_byte((arrays, n, structural), again)
+    _assert_byte_for_byte((packed, n, structural), again)
 
 
 def test_a_refused_call_is_packed_in_python(nlib, monkeypatch):
@@ -374,6 +376,68 @@ def test_a_refused_call_is_packed_in_python(nlib, monkeypatch):
     monkeypatch.setattr(ov, "_native_pack_into", lambda: None)
     assert got[3]["path"] == "python"
     _assert_byte_for_byte(got, ov.pack_batch(pubs, msgs, sigs, 32))
+
+
+# -- the packed layout: ONE buffer, the five arrays its views ------------------
+
+
+@pytest.mark.parametrize("b", [128, 512, 8192])
+def test_the_five_views_share_the_one_buffer_at_their_rows(nlib, b):
+    """Rows 0 … 4b−1 are the tables a, r, s, m; the last b/32 rows are
+    ``s_ok``, one byte a lane; every array is a view of the one buffer."""
+    pubs, msgs, sigs = _signed(5)
+    packed, n, structural, _ = ov.pack_batch(pubs, msgs, sigs, b)
+    arrays = ov.packed_views(packed)
+    assert packed.shape == (4 * b + b // 32, 32) == (ov.packed_rows(b), 32)
+    assert packed.dtype == np.uint8 and packed.flags.c_contiguous
+    assert packed.nbytes == 129 * b == sum(v.nbytes for v in arrays.values())
+    base = packed.ctypes.data
+    for row, k in zip((0, b, 2 * b, 3 * b, 4 * b), ARRAYS):
+        assert np.shares_memory(arrays[k], packed), k
+        assert arrays[k].ctypes.data - base == 32 * row, k
+    assert arrays["s_ok"].shape == (b,) and arrays["s_ok"].dtype == bool
+    tables = packed[: 4 * b].reshape(4, b, 32)
+    for i, k in enumerate(ARRAYS[:4]):
+        assert np.array_equal(tables[i], arrays[k]), k
+    ok_bytes = packed[4 * b :].reshape(b)
+    assert np.array_equal(ok_bytes, arrays["s_ok"].view(np.uint8))
+    assert ok_bytes[:n].all() and not ok_bytes[n:].any()
+    assert structural.shape == (b,)
+    assert not np.shares_memory(structural, packed)  # never sent
+
+
+@pytest.mark.parametrize(
+    "broken",
+    [(), ((0, 39), "pub"), ((7, 8, 20), "sig"), ((3,), "both")],
+    ids=["all_right", "pub_first_and_last", "sig_inside", "both_one"],
+)
+def test_native_and_python_write_the_same_packed_buffer(
+    nlib, monkeypatch, broken
+):
+    pubs, msgs, sigs = _signed(40, b"packed")
+    sigs[11] = sigs[11][:32] + (L + 9).to_bytes(32, "little")
+    if broken:
+        pubs, msgs, sigs = _broken(pubs, msgs, sigs, list(broken[0]), broken[1])
+    got, want = _both_paths(monkeypatch, pubs, msgs, sigs, 128)
+    assert got[3]["path"] == "native"
+    assert got[0].shape == want[0].shape == (ov.packed_rows(128), 32)
+    assert got[0].tobytes() == want[0].tobytes()
+    _assert_byte_for_byte(got, want)
+
+
+@pytest.mark.parametrize("n", [1, 5, 117])
+def test_an_odd_count_leaves_the_padding_lanes_zero(nlib, n):
+    pubs, msgs, sigs = _signed(n, b"odd")
+    packed, _, structural, _ = ov.pack_batch(pubs, msgs, sigs, 128)
+    arrays = ov.packed_views(packed)
+    b = arrays["s_ok"].shape[0]
+    assert b == 128
+    for k in ARRAYS[:4]:
+        assert arrays[k][:n].any(axis=1).all(), k
+        assert not arrays[k][n:].any(), k
+    assert arrays["s_ok"][:n].all() and not arrays["s_ok"][n:].any()
+    assert not packed[4 * b :].reshape(b)[n:].any()
+    assert not structural[n:].any()
 
 
 # -- the span: which path packed, and how the stage splits ----------------------

@@ -1561,7 +1561,7 @@ class TestMetricsAndTooling:
 # ----------------------------------------------------------------------
 
 
-@pytest.mark.warmcache("verify-xla-32")
+@pytest.mark.warmcache("verify-xla-packed-32")
 def test_real_dispatch_smoke(monkeypatch):
     """One real kernel dispatch end-to-end: submit -> flush ->
     verify_segments -> supervisor -> XLA -> futures.  Runs in tier-1 when

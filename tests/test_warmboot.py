@@ -81,9 +81,9 @@ class TestRun:
     def test_warms_matrix_and_records(self, monkeypatch):
         calls = []
 
-        def fake_exec(backend, bucket, donated=None):
+        def fake_exec(backend, bucket):
             calls.append((backend, bucket))
-            return (lambda **kw: None), {"exec_cache": "hit"}
+            return (lambda packed: None), {"exec_cache": "hit"}
 
         monkeypatch.setattr(ov, "bucket_executable", fake_exec)
         monkeypatch.setenv("COMETBFT_TPU_WARMBOOT_BUCKETS", "32,64")
@@ -104,7 +104,7 @@ class TestRun:
         the pass — remaining shapes of that tier are skipped, the pass
         returns normally."""
 
-        def fake_exec(backend, bucket, donated=None):
+        def fake_exec(backend, bucket):
             raise RuntimeError("compile exploded")
 
         monkeypatch.setattr(ov, "bucket_executable", fake_exec)
@@ -136,13 +136,13 @@ class TestRun:
         c0 = warm_stats.snapshot()["compile_failures"]
         with caplog.at_level("ERROR", logger="cometbft_tpu.crypto"):
             with pytest.raises(ov.TierCompileError, match="Mosaic failed"):
-                ov.bucket_executable("pallas", 128, donated=True)
+                ov.bucket_executable("pallas", 128)
         assert any("Mosaic failed" in r.getMessage() for r in caplog.records)
         assert warm_stats.snapshot()["compile_failures"] == c0 + 1
-        assert ("pallas", 128, True) in ov._AOT_BROKEN
+        assert ("pallas", 128) in ov._AOT_BROKEN
         with pytest.raises(ov.TierCompileError):
-            ov.bucket_executable("pallas", 128, donated=True)
-        assert calls == ["verify-pallas-128-donated"]
+            ov.bucket_executable("pallas", 128)
+        assert calls == ["verify-pallas-packed-128"]
         ov.reset_executable_memo()
         assert not ov._AOT_BROKEN
 
@@ -269,9 +269,9 @@ class TestExtraMatrix:
         assert not [s for _, f, s in shapes if f.startswith("transport-")]
 
     def _fake_exec(self, calls):
-        def fake(backend, bucket, donated=None):
+        def fake(backend, bucket):
             calls.append((backend, bucket))
-            return (lambda **kw: None), {"exec_cache": "hit"}
+            return (lambda packed: None), {"exec_cache": "hit"}
 
         return fake
 
