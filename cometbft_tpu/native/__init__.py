@@ -181,6 +181,39 @@ def lib() -> Optional[ctypes.CDLL]:
             ]
         except AttributeError:
             pass
+        try:
+            # the signature cache (``crypto/sigcache``, which keeps its
+            # Python key and OrderedDict store where a prebuilt .so lacks
+            # these): keys are n x 32 bytes, verdicts n bytes.  Bound on a
+            # PyDLL handle of the same library, so that the GIL stays held:
+            # a call of microseconds that let it go would hand it to any
+            # busy thread (a receive loop decoding blocks), and the caller
+            # would then wait out that thread's turn.
+            vp, i64, buf = ctypes.c_void_p, ctypes.c_int64, ctypes.c_char_p
+            held = ctypes.PyDLL(_SO)
+            for name, restype, argtypes in (
+                ("sigcache_keys", ctypes.c_int, [
+                    buf, vp, i64,  # pubs, their lengths (None: all pub_len)
+                    buf, vp,  # msgs, their lengths
+                    buf, vp, i64,  # sigs, their lengths (None: all sig_len)
+                    i64,  # n
+                    buf,  # the keys out
+                    ctypes.c_int,  # the block function: -1 the best
+                ]),
+                ("sigcache_new", vp, [i64]),
+                ("sigcache_free", None, [vp]),
+                ("sigcache_get_many", ctypes.c_int, [vp, buf, i64, buf]),
+                ("sigcache_put_many", ctypes.c_int, [vp, buf, buf, i64]),
+                ("sigcache_len", i64, [vp]),
+                ("sigcache_clear", None, [vp]),
+                ("sigcache_counts", None, [vp, vp]),
+                ("sigcache_items", i64, [vp, buf, buf, i64]),
+            ):
+                fn = getattr(held, name)
+                fn.restype, fn.argtypes = restype, argtypes
+                setattr(cdll, name, fn)
+        except (AttributeError, OSError):
+            pass
         _lib = cdll
         return _lib
 

@@ -17,14 +17,20 @@
 //    ed25519 set encoded and the RFC 6962 tree reduced in one call
 //    (valset_root_ed25519), SHA-256 with the SHA extensions where the CPU
 //    has them.
+//  * The signature cache: a segment's keys in one pass (sigcache_keys, the
+//    same SHA-256) and the bounded LRU of verdicts they index (sigcache_new
+//    and the calls on its handle), behind one mutex.
 //
 // Build: g++ -O3 -shared -fPIC -std=c++17, no -march (driven by
 // cometbft_tpu/native/__init__.py); what needs AVX2 says so itself.
 
+#include <chrono>
 #include <cstdint>
 #include <cstdio>
 #include <cstring>
 #include <mutex>
+#include <new>
+#include <random>
 #include <vector>
 #include <cstdlib>
 #include <fcntl.h>
@@ -1093,6 +1099,311 @@ int sha256_ni(const uint8_t* data, int64_t len, uint8_t* out32, int ni) {
     if (len < 0) return -1;
     sha256_msg(blk, data, (size_t)len, out32);
     return 0;
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
+// The signature cache (cometbft_tpu/crypto/sigcache.py): a segment's keys in
+// one pass, and the bounded LRU of verdicts they index.  Differential-tested
+// against ``sigcache._key`` and the OrderedDict store in
+// tests/test_sigcache_native.py.
+// ---------------------------------------------------------------------------
+
+static inline void store_le32(uint8_t* p, uint32_t v) {
+    p[0] = (uint8_t)v;
+    p[1] = (uint8_t)(v >> 8);
+    p[2] = (uint8_t)(v >> 16);
+    p[3] = (uint8_t)(v >> 24);
+}
+
+static int sigcache_keys_with(Sha256Block blk, const uint8_t* pubs,
+                              const int64_t* pub_lens, int64_t pub_len,
+                              const uint8_t* msgs, const int64_t* msg_lens,
+                              const uint8_t* sigs, const int64_t* sig_lens,
+                              int64_t sig_len, int64_t n, uint8_t* out32) {
+    if (n < 0) return -1;
+    size_t most = 0;  // the longest framed message, for the one scratch buffer
+    for (int64_t i = 0; i < n; i++) {
+        int64_t p = pub_lens ? pub_lens[i] : pub_len;
+        int64_t s = sig_lens ? sig_lens[i] : sig_len;
+        int64_t m = msg_lens[i];
+        if (p < 0 || m < 0 || s < 0 || p > UINT32_MAX || m > UINT32_MAX)
+            return -1;
+        size_t len = 8 + (size_t)p + (size_t)m + (size_t)s;
+        if (len > most) most = len;
+    }
+    // each framed message assembled in ``buf`` and padded there: its blocks
+    // in one call of the block function
+    std::vector<uint8_t> buf(most + 72);
+    uint8_t* b = buf.data();
+    for (int64_t i = 0; i < n; i++) {
+        size_t p = (size_t)(pub_lens ? pub_lens[i] : pub_len);
+        size_t m = (size_t)msg_lens[i];
+        size_t s = (size_t)(sig_lens ? sig_lens[i] : sig_len);
+        size_t len = 8 + p + m + s;
+        store_le32(b, (uint32_t)p);
+        memcpy(b + 4, pubs, p);
+        store_le32(b + 4 + p, (uint32_t)m);
+        memcpy(b + 8 + p, msgs, m);
+        memcpy(b + 8 + p + m, sigs, s);
+        size_t blocks = (len + 9 + 63) / 64;
+        memset(b + len, 0, 64 * blocks - len);
+        b[len] = 0x80;
+        uint64_t bits = (uint64_t)len * 8;
+        for (int k = 0; k < 8; k++) b[64 * blocks - 1 - k] = (uint8_t)(bits >> (8 * k));
+        uint32_t st[8];
+        memcpy(st, SHA256_IV, sizeof(st));
+        blk(st, b, blocks);
+        sha256_out(st, out32 + 32 * i);
+        pubs += p;
+        msgs += m;
+        sigs += s;
+    }
+    return 0;
+}
+
+// The bounded LRU: ``cap`` entries (a 32-byte digest, its verdict, its place
+// in the list, oldest first, and its slot in the index) and an open-addressed
+// index of twice as many slots or more, linear probing, backward-shift
+// deletion.  The probe starts from the digest mixed with a seed of the
+// store's own, so that keys ground to collide in the index cannot be made
+// without it.  One mutex: a store may be called from several threads at once.
+struct SigEntry {
+    uint8_t key[32];
+    int32_t prev, next, slot;
+    uint8_t ok;
+};
+
+struct SigSlot {
+    int32_t e;   // the entry, -1 empty
+    uint32_t h;  // its hash, whose low bits are its home slot
+};
+
+struct SigStore {
+    std::mutex mtx;
+    int64_t cap = 0;
+    uint32_t mask = 0;
+    uint64_t seed = 0;
+    std::vector<SigSlot> index;
+    std::vector<SigEntry> ent;
+    int32_t head = -1, tail = -1;  // the oldest and the newest
+    int64_t size = 0;
+    int64_t hits = 0, misses = 0, puts = 0;
+};
+
+static inline uint32_t store_hash(const SigStore* s, const uint8_t* k) {
+    uint64_t x;
+    memcpy(&x, k, 8);
+    x ^= s->seed;  // splitmix64's finaliser
+    x ^= x >> 30;
+    x *= 0xbf58476d1ce4e5b9ULL;
+    x ^= x >> 27;
+    x *= 0x94d049bb133111ebULL;
+    x ^= x >> 31;
+    return (uint32_t)x;
+}
+
+// the slot that holds ``k``, or -1
+static int64_t store_find(const SigStore* s, const uint8_t* k, uint32_t h) {
+    for (uint32_t i = h & s->mask;; i = (i + 1) & s->mask) {
+        const SigSlot& sl = s->index[i];
+        if (sl.e < 0) return -1;
+        if (sl.h == h && memcmp(s->ent[sl.e].key, k, 32) == 0) return i;
+    }
+}
+
+static void list_unlink(SigStore* s, int32_t e) {
+    SigEntry& x = s->ent[e];
+    if (x.prev >= 0) s->ent[x.prev].next = x.next; else s->head = x.next;
+    if (x.next >= 0) s->ent[x.next].prev = x.prev; else s->tail = x.prev;
+}
+
+static void list_append(SigStore* s, int32_t e) {
+    SigEntry& x = s->ent[e];
+    x.prev = s->tail;
+    x.next = -1;
+    if (s->tail >= 0) s->ent[s->tail].next = e; else s->head = e;
+    s->tail = e;
+}
+
+static void list_touch(SigStore* s, int32_t e) {
+    if (s->tail == e) return;
+    list_unlink(s, e);
+    list_append(s, e);
+}
+
+// empties slot ``i``, moving each later slot of its run back where its home
+// allows, so that no probe meets a hole before its key
+static void index_remove(SigStore* s, uint32_t i) {
+    const uint32_t mask = s->mask;
+    for (uint32_t j = (i + 1) & mask;; j = (j + 1) & mask) {
+        SigSlot sj = s->index[j];
+        if (sj.e < 0) break;
+        uint32_t home = sj.h & mask;
+        if (((j - home) & mask) >= ((j - i) & mask)) {  // home not in (i, j]
+            s->index[i] = sj;
+            s->ent[sj.e].slot = (int32_t)i;
+            i = j;
+        }
+    }
+    s->index[i].e = -1;
+}
+
+static void store_put(SigStore* s, const uint8_t* k, uint8_t ok) {
+    uint32_t h = store_hash(s, k);
+    int64_t at = store_find(s, k, h);
+    if (at >= 0) {
+        int32_t e = s->index[at].e;
+        s->ent[e].ok = ok;
+        list_touch(s, e);
+        return;
+    }
+    int32_t e;
+    if (s->size < s->cap) {
+        e = (int32_t)s->size++;
+    } else {  // evict the oldest and take its entry
+        e = s->head;
+        list_unlink(s, e);
+        index_remove(s, (uint32_t)s->ent[e].slot);
+    }
+    uint32_t i = h & s->mask;
+    while (s->index[i].e >= 0) i = (i + 1) & s->mask;
+    s->index[i] = SigSlot{e, h};
+    SigEntry& x = s->ent[e];
+    memcpy(x.key, k, 32);
+    x.ok = ok;
+    x.slot = (int32_t)i;
+    list_append(s, e);
+}
+
+static void store_reset(SigStore* s) {
+    for (SigSlot& sl : s->index) sl.e = -1;
+    s->head = s->tail = -1;
+    s->size = s->hits = s->misses = s->puts = 0;
+}
+
+extern "C" {
+
+// The signature cache's keys of n triples, each SHA-256(u32le(len pub) ||
+// pub || u32le(len msg) || msg || sig), n x 32 bytes to out32.  Each field
+// list joined; its lengths int64 [n], or, for pubs and sigs, null and every
+// one ``pub_len`` / ``sig_len`` bytes.  ni as ``sha256_ni``: -1 the best
+// block function this CPU has, 0 the scalar one, 1 SHA-NI (-2 where the CPU
+// lacks it).  -1 for n < 0 or a length out of range.
+int sigcache_keys(const uint8_t* pubs, const int64_t* pub_lens,
+                  int64_t pub_len, const uint8_t* msgs,
+                  const int64_t* msg_lens, const uint8_t* sigs,
+                  const int64_t* sig_lens, int64_t sig_len, int64_t n,
+                  uint8_t* out32, int ni) {
+    Sha256Block blk = (ni >= -1 && ni <= 1) ? sha256_block_fn(ni) : nullptr;
+    if (!blk) return -2;
+    return sigcache_keys_with(blk, pubs, pub_lens, pub_len, msgs, msg_lens,
+                              sigs, sig_lens, sig_len, n, out32);
+}
+
+// A store of ``cap`` entries, 1 <= cap < 2^29; null where cap is out of that
+// range or the memory is not there.
+void* sigcache_new(int64_t cap) {
+    if (cap < 1 || cap >= ((int64_t)1 << 29)) return nullptr;
+    SigStore* s = new (std::nothrow) SigStore();
+    if (!s) return nullptr;
+    size_t slots = 2;
+    while (slots < 2 * (size_t)cap) slots *= 2;
+    try {
+        s->index.assign(slots, SigSlot{-1, 0});
+        s->ent.resize((size_t)cap);
+    } catch (...) {
+        delete s;
+        return nullptr;
+    }
+    s->cap = cap;
+    s->mask = (uint32_t)(slots - 1);
+    uint64_t seed = (uint64_t)(uintptr_t)s;
+    try {
+        std::random_device rd;
+        seed ^= ((uint64_t)rd() << 32) ^ rd();
+    } catch (...) {
+    }
+    seed ^= (uint64_t)std::chrono::steady_clock::now().time_since_epoch().count();
+    s->seed = seed;
+    return s;
+}
+
+void sigcache_free(void* h) { delete static_cast<SigStore*>(h); }
+
+// The verdicts of n keys (n x 32 bytes) to out: 0 false, 1 true, 2 absent;
+// each key found becomes the newest, in order.  Counts the hits and misses.
+int sigcache_get_many(void* h, const uint8_t* keys, int64_t n, uint8_t* out) {
+    SigStore* s = static_cast<SigStore*>(h);
+    if (!s || n < 0) return -1;
+    std::lock_guard<std::mutex> g(s->mtx);
+    int64_t hits = 0;
+    for (int64_t i = 0; i < n; i++) {
+        const uint8_t* k = keys + 32 * i;
+        int64_t at = store_find(s, k, store_hash(s, k));
+        if (at < 0) {
+            out[i] = 2;
+            continue;
+        }
+        int32_t e = s->index[at].e;
+        out[i] = s->ent[e].ok;
+        list_touch(s, e);
+        hits++;
+    }
+    s->hits += hits;
+    s->misses += n - hits;
+    return 0;
+}
+
+// Stores n verdicts (oks: n bytes, nonzero true) under their keys, in order,
+// each the newest, the oldest evicted at capacity: the survivors and their
+// order are a loop of single puts'.  Counts n puts.
+int sigcache_put_many(void* h, const uint8_t* keys, const uint8_t* oks,
+                      int64_t n) {
+    SigStore* s = static_cast<SigStore*>(h);
+    if (!s || n < 0) return -1;
+    std::lock_guard<std::mutex> g(s->mtx);
+    for (int64_t i = 0; i < n; i++) store_put(s, keys + 32 * i, oks[i] != 0);
+    s->puts += n;
+    return 0;
+}
+
+int64_t sigcache_len(void* h) {
+    SigStore* s = static_cast<SigStore*>(h);
+    std::lock_guard<std::mutex> g(s->mtx);
+    return s->size;
+}
+
+// Drops every entry and zeroes the counts.
+void sigcache_clear(void* h) {
+    SigStore* s = static_cast<SigStore*>(h);
+    std::lock_guard<std::mutex> g(s->mtx);
+    store_reset(s);
+}
+
+// hits, misses, puts and size to out[4], read together
+void sigcache_counts(void* h, int64_t* out) {
+    SigStore* s = static_cast<SigStore*>(h);
+    std::lock_guard<std::mutex> g(s->mtx);
+    out[0] = s->hits;
+    out[1] = s->misses;
+    out[2] = s->puts;
+    out[3] = s->size;
+}
+
+// The entries oldest first, at most ``most``: keys to keys_out (x 32 bytes),
+// verdicts to oks_out; returns how many were written.
+int64_t sigcache_items(void* h, uint8_t* keys_out, uint8_t* oks_out,
+                       int64_t most) {
+    SigStore* s = static_cast<SigStore*>(h);
+    std::lock_guard<std::mutex> g(s->mtx);
+    int64_t k = 0;
+    for (int32_t e = s->head; e >= 0 && k < most; e = s->ent[e].next, k++) {
+        memcpy(keys_out + 32 * k, s->ent[e].key, 32);
+        oks_out[k] = s->ent[e].ok;
+    }
+    return k;
 }
 
 }  // extern "C"

@@ -5,8 +5,9 @@
 // suite with -race (tests.mk:56); the C++ surface here gets the TSAN
 // equivalent — hammer the WAL handle from multiple threads (append,
 // sync, size), the batch packer, the old entry point and the in-place
-// one, and the validator set's root, concurrently, then verify the WAL
-// contents are a clean sequence of CRC-framed records.
+// one, the validator set's root, and the signature cache's key pass and its
+// store (one store shared by every thread), concurrently, then verify the
+// WAL contents are a clean sequence of CRC-framed records.
 //
 // Exit code 0 = no sanitizer report and all invariants held.
 
@@ -14,6 +15,8 @@
 #include <cstdint>
 #include <cstdio>
 #include <cstring>
+#include <list>
+#include <map>
 #include <string>
 #include <thread>
 #include <vector>
@@ -37,6 +40,21 @@ int valset_root_ed25519(const uint8_t* keys32, const int64_t* powers,
 int valset_root_ed25519_ni(const uint8_t* keys32, const int64_t* powers,
                            int64_t n, uint8_t* out32, int ni);
 int sha256_ni(const uint8_t* data, int64_t len, uint8_t* out32, int ni);
+int sigcache_keys(const uint8_t* pubs, const int64_t* pub_lens,
+                  int64_t pub_len, const uint8_t* msgs,
+                  const int64_t* msg_lens, const uint8_t* sigs,
+                  const int64_t* sig_lens, int64_t sig_len, int64_t n,
+                  uint8_t* out32, int ni);
+void* sigcache_new(int64_t cap);
+void sigcache_free(void* h);
+int sigcache_get_many(void* h, const uint8_t* keys, int64_t n, uint8_t* out);
+int sigcache_put_many(void* h, const uint8_t* keys, const uint8_t* oks,
+                      int64_t n);
+int64_t sigcache_len(void* h);
+void sigcache_clear(void* h);
+void sigcache_counts(void* h, int64_t* out);
+int64_t sigcache_items(void* h, uint8_t* keys_out, uint8_t* oks_out,
+                       int64_t most);
 }
 
 static std::atomic<int> failures{0};
@@ -228,6 +246,165 @@ static void valset_roots(int tid, int iters) {
   if (valset_root_ed25519(nullptr, nullptr, -1, out) != -1) failures++;
 }
 
+static uint64_t xorshift(uint64_t& x) {
+  x ^= x << 13; x ^= x >> 7; x ^= x << 17;
+  return x;
+}
+
+// The signature cache's keys of random triples (pubs of 32 or 33 bytes,
+// messages of 0 to 300, signatures of 64 or an odd size), the three fields
+// in buffers of EXACTLY their bytes, each length given or, where a field is
+// of one size, not: against SHA-256 of the framed bytes built here, with
+// the library's scalar SHA-256 alone; each block function by name.
+static void sigcache_keys_check(int tid, int iters) {
+  uint64_t x = 0x2545f4914f6cdd1dULL * (uint64_t)(tid + 3);
+  for (int it = 0; it < iters; it++) {
+    const int64_t n = (int64_t)(xorshift(x) % 40);
+    const bool fixed = it & 1;  // every pub 32 and every sig 64, not listed
+    std::vector<int64_t> pl(n), ml(n), sl(n);
+    size_t pt = 0, mt = 0, st = 0;
+    for (int64_t i = 0; i < n; i++) {
+      pl[i] = fixed ? 32 : 32 + (int64_t)(xorshift(x) % 2);
+      ml[i] = (int64_t)(xorshift(x) % 301);
+      sl[i] = fixed ? 64 : (xorshift(x) % 2 ? 64 : 1 + 2 * (int64_t)(xorshift(x) % 50));
+      pt += pl[i]; mt += ml[i]; st += sl[i];
+    }
+    std::vector<uint8_t> pubs(pt), msgs(mt), sigs(st), want((size_t)n * 32);
+    for (auto& b : pubs) b = (uint8_t)xorshift(x);
+    for (auto& b : msgs) b = (uint8_t)xorshift(x);
+    for (auto& b : sigs) b = (uint8_t)xorshift(x);
+    size_t po = 0, mo = 0, so = 0;
+    for (int64_t i = 0; i < n; i++) {
+      std::vector<uint8_t> f;
+      for (int k = 0; k < 4; k++) f.push_back((uint8_t)(pl[i] >> (8 * k)));
+      f.insert(f.end(), pubs.begin() + po, pubs.begin() + po + pl[i]);
+      for (int k = 0; k < 4; k++) f.push_back((uint8_t)(ml[i] >> (8 * k)));
+      f.insert(f.end(), msgs.begin() + mo, msgs.begin() + mo + ml[i]);
+      f.insert(f.end(), sigs.begin() + so, sigs.begin() + so + sl[i]);
+      sha256_ni(f.data(), (int64_t)f.size(), &want[(size_t)i * 32], 0);
+      po += pl[i]; mo += ml[i]; so += sl[i];
+    }
+    for (int ni = -1; ni < 2; ni++) {
+      std::vector<uint8_t> got((size_t)n * 32);
+      int rc = sigcache_keys(pubs.data(), fixed ? nullptr : pl.data(), 32,
+                             msgs.data(), ml.data(), sigs.data(),
+                             fixed ? nullptr : sl.data(), 64, n, got.data(),
+                             ni);
+      if (rc == -2 && ni == 1) continue;  // no SHA extensions on this CPU
+      if (rc != 0 || got != want) failures++;
+    }
+  }
+  if (sigcache_keys(nullptr, nullptr, 0, nullptr, nullptr, nullptr, nullptr,
+                    0, -1, nullptr, -1) != -1)
+    failures++;
+}
+
+// A key whose verdict is its own low bit, so a verdict torn between
+// threads shows as a wrong bit.
+static void cache_key(int k, uint8_t out[32]) {
+  uint64_t x = 0x9e3779b97f4a7c15ULL * (uint64_t)(k + 1);
+  for (int b = 0; b < 32; b++) out[b] = (uint8_t)xorshift(x);
+  out[0] = (uint8_t)((out[0] & 0xFE) | (k & 1));
+}
+
+// One store, capacity 64, shared by every thread: gets and puts of batches
+// over 200 keys, every verdict read checked against its key; the counts
+// are checked whole after the join (main).
+static std::atomic<int64_t> cache_gets{0}, cache_puts{0};
+
+static void sigcache_hammer(void* store, int tid, int iters) {
+  uint64_t x = 0xda942042e4dd58b5ULL * (uint64_t)(tid + 1);
+  for (int it = 0; it < iters; it++) {
+    const int64_t n = 1 + (int64_t)(xorshift(x) % 24);
+    std::vector<uint8_t> keys((size_t)n * 32), oks((size_t)n), got((size_t)n);
+    for (int64_t i = 0; i < n; i++) {
+      int k = (int)(xorshift(x) % 200);
+      cache_key(k, &keys[(size_t)i * 32]);
+      oks[(size_t)i] = (uint8_t)(k & 1);
+    }
+    if (xorshift(x) % 2) {
+      if (sigcache_put_many(store, keys.data(), oks.data(), n) != 0) failures++;
+      cache_puts += n;
+    } else {
+      if (sigcache_get_many(store, keys.data(), n, got.data()) != 0) failures++;
+      cache_gets += n;
+      for (int64_t i = 0; i < n; i++)
+        if (got[(size_t)i] != 2 && got[(size_t)i] != oks[(size_t)i]) failures++;
+    }
+    if (it % 50 == 0 && sigcache_len(store) > 64) failures++;
+  }
+}
+
+// The store against an LRU built here (a list, newest last, and a map)
+// over random gets and puts at capacities 1, 7 and 64: every answer, the
+// counts, and the entries in their order.
+static void sigcache_oracle_check() {
+  static const int64_t caps[] = {1, 7, 64};
+  for (int64_t cap : caps) {
+    void* s = sigcache_new(cap);
+    if (!s) { failures++; continue; }
+    std::list<std::pair<std::vector<uint8_t>, uint8_t>> lru;
+    std::map<std::vector<uint8_t>, decltype(lru)::iterator> at;
+    int64_t hits = 0, misses = 0, puts = 0;
+    uint64_t x = 0x853c49e6748fea9bULL + (uint64_t)cap;
+    for (int it = 0; it < 2000; it++) {
+      const int64_t n = (int64_t)(xorshift(x) % 9);
+      std::vector<uint8_t> keys((size_t)n * 32), oks((size_t)n), got((size_t)n);
+      for (int64_t i = 0; i < n; i++) {
+        cache_key((int)(xorshift(x) % (3 * cap + 2)), &keys[(size_t)i * 32]);
+        oks[(size_t)i] = (uint8_t)(xorshift(x) % 2);
+      }
+      const bool put = xorshift(x) % 2;
+      if (put) sigcache_put_many(s, keys.data(), oks.data(), n);
+      else sigcache_get_many(s, keys.data(), n, got.data());
+      for (int64_t i = 0; i < n; i++) {
+        std::vector<uint8_t> k(&keys[(size_t)i * 32], &keys[(size_t)i * 32] + 32);
+        auto f = at.find(k);
+        if (put) {
+          if (f != at.end()) lru.erase(f->second);
+          lru.emplace_back(k, oks[(size_t)i]);
+          at[k] = std::prev(lru.end());
+          if ((int64_t)lru.size() > cap) {
+            at.erase(lru.front().first);
+            lru.pop_front();
+          }
+          puts++;
+        } else if (f == at.end()) {
+          misses++;
+          if (got[(size_t)i] != 2) failures++;
+        } else {
+          hits++;
+          if (got[(size_t)i] != f->second->second) failures++;
+          lru.splice(lru.end(), lru, f->second);
+        }
+      }
+      if (it % 97 == 0) {
+        int64_t c[4];
+        sigcache_counts(s, c);
+        if (c[0] != hits || c[1] != misses || c[2] != puts ||
+            c[3] != (int64_t)lru.size() || sigcache_len(s) != c[3])
+          failures++;
+        std::vector<uint8_t> ks((size_t)cap * 32), vs((size_t)cap);
+        int64_t k = sigcache_items(s, ks.data(), vs.data(), cap);
+        if (k != (int64_t)lru.size()) failures++;
+        int64_t j = 0;
+        for (auto& e : lru) {
+          if (j >= k || std::memcmp(&ks[(size_t)j * 32], e.first.data(), 32) ||
+              vs[(size_t)j] != e.second)
+            failures++;
+          j++;
+        }
+      }
+    }
+    sigcache_clear(s);
+    int64_t c[4];
+    sigcache_counts(s, c);
+    if (c[0] || c[1] || c[2] || c[3]) failures++;
+    sigcache_free(s);
+  }
+  if (sigcache_new(0) != nullptr) failures++;
+}
+
 int main(int argc, char** argv) {
   const char* path = argc > 1 ? argv[1] : "/tmp/native_stress.wal";
   std::remove(path);
@@ -242,7 +419,18 @@ int main(int argc, char** argv) {
   for (int t = 0; t < 4; t++) ts.emplace_back(packer, t, 200);
   for (int t = 0; t < 4; t++) ts.emplace_back(packer_into, t, 100);
   for (int t = 0; t < 4; t++) ts.emplace_back(valset_roots, t, 16);
+  for (int t = 0; t < 4; t++) ts.emplace_back(sigcache_keys_check, t, 40);
+  void* store = sigcache_new(64);
+  if (!store) return 2;
+  for (int t = 0; t < 6; t++) ts.emplace_back(sigcache_hammer, store, t, 2000);
+  ts.emplace_back(sigcache_oracle_check);
   for (auto& t : ts) t.join();
+  int64_t counts[4];
+  sigcache_counts(store, counts);
+  if (counts[0] + counts[1] != cache_gets.load() ||
+      counts[2] != cache_puts.load() || counts[3] > 64)
+    failures++;
+  sigcache_free(store);
   wal_sync(h);
   int64_t size = wal_size(h);
   wal_close(h);

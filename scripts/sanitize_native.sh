@@ -9,8 +9,10 @@
 # binary per sanitizer and runs the concurrent stress driver (WAL
 # appends; the batch packer's old entry point and, into tables of exactly
 # the padded size, the in-place one; the validator set's root over random
-# sets of 0 to 10,240 validators); any data race / out-of-bounds / UB
-# report fails the script via the sanitizer's nonzero exit.
+# sets of 0 to 10,240 validators; the signature cache's key pass over
+# random triples, and its store under concurrent gets and puts and against
+# an LRU of its own); any data race / out-of-bounds / UB report fails
+# the script via the sanitizer's nonzero exit.
 set -euo pipefail
 cd "$(dirname "$0")/../cometbft_tpu/native/csrc"
 
