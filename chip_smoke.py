@@ -214,7 +214,7 @@ def cache_counts() -> dict:
     }
 
 
-# -- chain building (the way scripts/bench_light.build_chain does) -------------
+# -- chain building -----------------------------------------------------------
 
 
 class Chain(NamedTuple):

@@ -10,7 +10,7 @@ still redirects the executable cache alone (tests).
 
 Every entry point that may compile calls ``enable()`` once, before its
 first compile: the node's start (``cmd/main.py``), ``chip_smoke.py``,
-``bench.py``'s workers, ``scripts/*`` and ``tests/conftest.py``.  Free of
+the benchmark's cells, ``scripts/*`` and ``tests/conftest.py``.  Free of
 jax imports unless jax is already loaded — a CPU node that never verifies
 on a device must not pay a backend import to learn where its cache lives.
 """

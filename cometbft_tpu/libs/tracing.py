@@ -110,8 +110,8 @@ and recorded by the calling thread.
 
 Kill switch: ``COMETBFT_TPU_TRACE=0`` compiles spans down to no-ops (a
 shared null context manager; one look-up in the environment's own mapping
-per span site, one clock read per hand-off) — bench.py ``--obs`` pins the
-disabled overhead at ≤1% of the sched bench.
+per span site, one clock read per hand-off) — the benchmark's
+``recorder_cost.py`` reads what the recorder costs on against off.
 
 Cross-node correlation (docs/observability.md "Cross-node tracing"): a
 ``TraceContext`` is the compact (trace_id, span_id, origin-node) triple a

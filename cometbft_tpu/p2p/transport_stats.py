@@ -15,8 +15,8 @@ Counters (all guarded by one lock):
     or a caller without batch support)
   * ``batches[op]``        — coalesced plane calls by op (``seal``/``open``)
   * ``dispatches[tier]``   — AEAD kernel passes by execution tier
-    (``device`` / ``numpy`` / ``pure``); the bench's
-    dispatches-per-1k-frames numerator counts every tier
+    (``device`` / ``numpy`` / ``pure``); a dispatch count over
+    frames counts every tier
   * ``aead_frames_device`` / ``aead_lanes`` — frames processed on the
     device tier and the bucket-padded lanes they occupied
     (aead_lanes_occupancy = frames / lanes)

@@ -247,7 +247,7 @@ _FAULT_INJECTOR: Optional[Callable] = None
 def host_oracle_runner(ordinal, pubs, msgs, sigs, lanes) -> np.ndarray:
     """THE reference per-shard runner for ``set_mesh_runner`` — the host
     ZIP-215 oracle over one shard, padding lanes False.  Sim scenarios,
-    the bench stage and the test suite all share this single definition
+    the dry run and the test suite all share this single definition
     (the "verdict-identical by construction" argument needs ONE oracle,
     not five copies); the first argument is ignored so it also serves as
     a single-chip device-runner stand-in."""

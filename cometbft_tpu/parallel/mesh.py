@@ -261,7 +261,8 @@ def device_put_args(arrays: dict, mesh: Mesh) -> list:
 
     Hands numpy straight to ``jax.device_put`` with the mesh sharding: the
     arrays must never materialize on the default device first (which may not
-    even be part of the mesh — MULTICHIP_r01 failed exactly this way).
+    even be part of the mesh — an early multi-chip dry run failed exactly
+    this way).
     """
     batch_first, vec = mesh_shardings(mesh)
     return [

@@ -25,7 +25,7 @@ Counters (all guarded by one lock):
     flushes and the (kind, height) groups they coalesced into
     (queries_per_flush = flush_queries / flushes)
   * ``tree_builds[kind]``  — root/proof-set builds (one per coalesced
-    group miss — the number the bench gates as dispatches-per-1k-proofs)
+    group miss — tests/test_proofserve.py holds it to one a height)
   * ``trees_device`` / ``trees_host`` — tree passes by path (device
     kernel / runner seam vs host fallback)
   * ``tree_leaves`` / ``tree_lanes`` — leaves hashed and bucket-padded

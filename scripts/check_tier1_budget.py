@@ -52,8 +52,8 @@ _TRACE_RE = re.compile(
 )
 
 # the recorder's measured in-process overhead must stay a rounding error
-# of tier-1 wall time (the sched-bench gate in bench.py --obs is the
-# precise one; this is the coarse suite-wide backstop)
+# of tier-1 wall time (benchmarks/recorder_cost.py reads the precise cost
+# on the chip; this is the coarse suite-wide backstop)
 TRACE_OVERHEAD_MAX_SHARE = 0.05
 
 # tests whose dominant cost is a device-kernel compile (the population the

@@ -1,23 +1,22 @@
-"""Correctness artifact for the COMPILED verify kernel on real hardware.
+"""Correctness check of the COMPILED verify kernel on the live platform.
 
 The CI parity test for the Pallas kernel runs in interpret mode on CPU
 (tests/test_pallas.py); this script runs the same known-answer + tampered
-vector suite through the actually-compiled kernel on the live platform and
-writes a JSON verdict to CHIP_VALIDATE.json — a hardware-correctness record
-independent of the throughput bench (VERDICT r3 #4).
+vector suite through the actually-compiled kernel and prints its verdict
+as one JSON line (exit 0 when every vector agrees, 3 otherwise).  It
+writes no file: a record a CPU run could overwrite under a chip's name
+would say nothing about the chip.
 
 Vector semantics: ZIP-215 as the reference's ed25519 verify applies it
 (crypto/ed25519/ed25519.go:170-222) — cofactored equation, non-canonical
 A/R encodings accepted, s strictly < L.
 
-Usable two ways:
-  * `validate_with(call, bucket)` — bench.py hands in its already-compiled
-    one-chip executable; vectors are packed into that bucket's one buffer
-    (no extra compile).
-  * `python scripts/chip_validate.py` — standalone: selects the platform's
-    kernel like production does, resolves the bucket executable production
-    launches (compile or AOT load) at a small bucket, validates, writes the
-    artifact.
+    python scripts/chip_validate.py
+
+selects the platform's kernel like production does, resolves the bucket
+executable production launches (compile or AOT load) at the smallest
+bucket that holds the vectors, and validates it.  ``_vectors`` is also
+the known-answer set of ``chip_smoke.py`` and of the tests.
 """
 
 from __future__ import annotations
@@ -25,14 +24,8 @@ from __future__ import annotations
 import json
 import os
 import sys
-import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-ARTIFACT = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "CHIP_VALIDATE.json",
-)
 
 
 def _vectors():
@@ -97,58 +90,6 @@ def _vectors():
     return pubs, msgs, sigs, expect, labels
 
 
-def validate_with(call, bucket: int) -> dict:
-    """Run the vector suite through ``call`` (a compiled one-chip executable
-    taking the ONE packed buffer of ``bucket`` lanes).  Returns the verdict
-    dict."""
-    import numpy as np
-
-    from cometbft_tpu.ops import verify as ov
-
-    pubs, msgs, sigs, expect, labels = _vectors()
-    packed, n, structural, _ = ov.pack_batch(pubs, msgs, sigs, bucket)
-    assert structural.shape == (bucket,), (structural.shape, bucket)
-    accept = np.asarray(call(packed))
-    got = list((accept & structural)[:n])
-    failures = [
-        {"label": lbl, "want": bool(w), "got": bool(g)}
-        for lbl, w, g in zip(labels, expect, got)
-        if bool(w) != bool(g)
-    ]
-    return {
-        "ok": not failures,
-        "n_vectors": n,
-        "failures": failures,
-    }
-
-
-def write_artifact(verdict: dict, impl: str, platform: str) -> None:
-    """Append this run's verdict to CHIP_VALIDATE.json (keeping prior runs:
-    the whole point of the artifact is the hardware-failure evidence).
-    Top-level ``ok`` reflects the LATEST run per (impl, platform)."""
-    rec = dict(verdict)
-    rec.update(
-        impl=impl,
-        platform=platform,
-        timestamp=time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
-    )
-    runs = []
-    try:
-        with open(ARTIFACT) as f:
-            runs = json.load(f).get("runs", [])
-    except (OSError, ValueError):
-        pass
-    runs.append(rec)
-    runs = runs[-20:]  # bound growth across rounds
-    latest = {}
-    for r in runs:
-        latest[(r.get("impl"), r.get("platform"))] = bool(r.get("ok"))
-    doc = {"ok": all(latest.values()), "latest": rec, "runs": runs}
-    with open(ARTIFACT, "w") as f:
-        json.dump(doc, f, indent=1)
-        f.write("\n")
-
-
 def main() -> int:
     from cometbft_tpu.libs import cachedir
 
@@ -161,15 +102,23 @@ def main() -> int:
 
     platform = jax.devices()[0].platform
     impl = "pallas" if ov._use_pallas() else "xla"
+    pubs, msgs, sigs, expect, labels = _vectors()
     # the smallest bucket that holds the vector suite
-    bucket = ov.bucket_size(len(_vectors()[0]))
+    bucket = ov.bucket_size(len(pubs))
     call, info = ov.bucket_executable(impl, bucket)
-    verdict = validate_with(
-        lambda packed: np.asarray(call(jnp.asarray(packed))), bucket=bucket
-    )
-    write_artifact(verdict, impl=impl, platform=platform)
-    print(json.dumps({**verdict, "impl": impl, "platform": platform, **info}))
-    return 0 if verdict["ok"] else 3
+    packed, n, structural, _ = ov.pack_batch(pubs, msgs, sigs, bucket)
+    accept = np.asarray(call(jnp.asarray(packed)))
+    got = list((accept & structural)[:n])
+    failures = [
+        {"label": lbl, "want": bool(w), "got": bool(g)}
+        for lbl, w, g in zip(labels, expect, got)
+        if bool(w) != bool(g)
+    ]
+    print(json.dumps({
+        "ok": not failures, "n_vectors": n, "failures": failures,
+        "impl": impl, "platform": platform, **info,
+    }))
+    return 3 if failures else 0
 
 
 if __name__ == "__main__":

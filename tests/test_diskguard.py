@@ -240,6 +240,68 @@ class TestAtomicWrite:
         ) == ["doc.json"]
 
 
+class TestDegradableSurfacesKeepAnswers:
+    def test_faults_on_every_degradable_surface_change_no_answer(
+        self, tmp_path, monkeypatch
+    ):
+        """ENOSPC on the executable cache and the status files, EIO on the
+        black box, all at once: a verify gives the clean run's verdicts
+        (the oracle's, two bad lanes included), an executable whose store
+        failed still answers as the clean one, the status write and the
+        journal degrade to counted drops, and nothing fail-stops."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from cometbft_tpu.crypto import ed25519_ref as ref
+        from cometbft_tpu.libs import blackbox
+        from cometbft_tpu.ops import aot_cache, supervisor
+        from cometbft_tpu.ops import verify as ov
+        from cometbft_tpu.parallel import elastic
+
+        seeds = [bytes([i + 1]) * 32 for i in range(12)]
+        pubs = [ref.pubkey_from_seed(s) for s in seeds]
+        msgs = [b"disk-fault-%d" % i for i in range(12)]
+        sigs = [ref.sign(s, m) for s, m in zip(seeds, msgs)]
+        sigs[1] = sigs[1][:-1] + bytes([sigs[1][-1] ^ 1])
+        sigs[10] = bytes(64)
+        oracle = [ref.verify_zip215(*t) for t in zip(pubs, msgs, sigs)]
+        monkeypatch.setenv("COMETBFT_TPU_EXEC_CACHE", str(tmp_path / "exec"))
+        kernel = jax.jit(lambda x: x * 3 + 1)
+        x = jnp.arange(8, dtype=jnp.int32)
+        dg.set_sleeper(lambda _s: None)
+        supervisor.set_device_runner(elastic.host_oracle_runner)
+        try:
+            clean = ov.verify_batch(pubs, msgs, sigs).tolist()
+            plan = dg.FaultPlan()
+            plan.add(surface="exec_cache", err=errno.ENOSPC)
+            plan.add(surface="status", err=errno.ENOSPC)
+            plan.add(surface="blackbox", err=errno.EIO)
+            dg.set_fault_plan(plan)
+            faulted = ov.verify_batch(pubs, msgs, sigs).tolist()
+            aot_cache.reset_memo()
+            call, info = aot_cache.load_or_compile(kernel, (x,), "t-disk")
+            with pytest.raises(OSError) as ei:
+                dg.atomic_write("status", str(tmp_path / "status.json"), b"{}")
+            journal = blackbox.BlackboxJournal(
+                str(tmp_path / "bbox"), threaded=True, queue_max=64
+            )
+            for i in range(16):
+                journal.on_anomaly("disk_fault_test", {"i": i}, float(i))
+            journal.close(clean=True)
+        finally:
+            supervisor.clear_device_runner()
+            aot_cache.reset_memo()
+        assert faulted == clean == oracle
+        assert info["exec_cache_write"].startswith("unwritable:")
+        assert np.asarray(call(x)).tolist() == np.asarray(kernel(x)).tolist()
+        assert not isinstance(ei.value, dg.StorageFatal)
+        jstats = journal.stats()
+        assert jstats["dropped"] > 0 and jstats["closed"]
+        totals = storage_stats.snapshot()["totals"]
+        assert totals["drops"] >= 3 and totals["fatals"] == 0
+
+
 class TestSqliteSurfaces:
     def test_state_surface_fail_stops(self, tmp_path):
         from cometbft_tpu.store.kv import SqliteKV

@@ -79,6 +79,25 @@ def test_mul_adversarial_bounds():
         assert _f_to_ints(fe.mul(a, b)) == [want]
 
 
+def test_kernel_mode_mul_agrees_with_the_default():
+    """The Pallas kernel's shifted-row multiplier (``kernel_mode``) gives
+    the default skew-reshape one's products, python's ints, with limbs at
+    the interval bounds."""
+    nrng = np.random.default_rng(7)
+    limbs = nrng.integers(fe.RED_LO, fe.RED_HI + 1, size=(2, fe.NLIMBS, 32))
+    limbs[0, fe.NLIMBS - 1, :8] = fe.RED_HI
+    limbs[1, fe.NLIMBS - 1, :8] = fe.RED_LO
+    a, b = (fe.F(jnp.asarray(x.astype(np.int32)), fe.RED_LO, fe.RED_HI)
+            for x in limbs)
+    want = [
+        fe.int_of_limbs(limbs[0, :, i]) * fe.int_of_limbs(limbs[1, :, i]) % P
+        for i in range(32)
+    ]
+    with fe.kernel_mode(32):
+        rows = fe.mul(a, b)
+    assert _f_to_ints(rows) == _f_to_ints(fe.mul(a, b)) == want
+
+
 def test_mul_unreduced_operands():
     """mul must be correct when fed unreduced sums/differences (wide static
     bounds) — the ladder feeds it these constantly."""

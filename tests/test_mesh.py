@@ -1,6 +1,6 @@
 """Multi-chip sharding regression tests on the virtual 8-device CPU mesh.
 
-These guard the driver's ``dryrun_multichip`` path (MULTICHIP_r01 failed
+These guard ``__graft_entry__.dryrun_multichip`` (an early dry run failed
 because arrays were materialized on the default device before resharding) —
 the full sharded verify must compile AND execute hermetically on whatever
 mesh it is given.  They also pin the kernel-selection seam: the sharded
@@ -490,6 +490,61 @@ class TestElasticMesh:
         anomalies = tracing.get_tracer().snapshot()["anomalies"]
         assert anomalies.get("mesh_shrink", 0) >= 1
         assert anomalies.get("mesh_restore", 0) == 1
+
+    def test_a_chip_dead_for_good_costs_one_shrink_and_one_dispatch(self):
+        """A chip that stays dead: the one dispatch that meets it is
+        verified again at the next width and every later dispatch leaves
+        it out, so three verifies cost the healthy dispatch count plus
+        one, and one shrink — the oracle's verdicts throughout."""
+        from cometbft_tpu.crypto import backend_health
+        from cometbft_tpu.ops import dispatch_stats
+        from cometbft_tpu.parallel import elastic
+
+        pubs, msgs, sigs, expected = self._mixed_batch(47, 24)
+
+        def three_verifies() -> dict:
+            dispatch_stats.reset()
+            for _ in range(3):
+                bits = elastic.verify_elastic(pubs, msgs, sigs)
+                assert (bits == expected).all()
+            return dispatch_stats.snapshot()
+
+        healthy = three_verifies()
+        backend_health.reset()
+        elastic.clear()
+        elastic.configure(range(self.WIDTH))
+        elastic.set_mesh_runner(self._oracle_runner)
+        elastic.set_fault_injector(elastic.FaultyDevice("raise", ordinals=(1,)))
+        dead = three_verifies()
+        assert healthy["mesh_shrinks"] == 0
+        assert dead["mesh_shrinks"] == 1
+        assert dead["mesh_width"] == self.WIDTH - 1
+        assert dead["dispatches"] == healthy["dispatches"] + 1
+
+    def test_a_pipelined_commit_keeps_its_depth_in_flight_on_every_lane(self):
+        """``verify_pipelined`` cuts a commit into bucket-sized chunks,
+        round-robin over the lanes with one in flight a lane: the bad
+        lanes keep their indices, the in-flight high-water mark reaches
+        the depth, and every lane takes a chunk."""
+        from cometbft_tpu.ops import dispatch_stats
+
+        distinct = self._mixed_batch(53, 16)
+        n = 320  # five 64-lane chunks over four lanes
+        pubs, msgs = (distinct[0] * 20)[:n], (distinct[1] * 20)[:n]
+        sigs = list((distinct[2] * 20)[:n])
+        expected = np.array((list(distinct[3]) * 20)[:n])
+        for i in (0, n // 2, n - 1):
+            sigs[i] = bytes(64)
+            expected[i] = False
+        dispatch_stats.reset()
+        bits = ov.verify_pipelined(pubs, msgs, sigs)
+        assert (bits == expected).all()
+        snap = dispatch_stats.snapshot()
+        lanes = snap["lane_dispatches"]
+        chunks = sum(lanes.values())
+        assert chunks == 5 and len(lanes) == self.WIDTH
+        assert snap["inflight_hwm"] == self.WIDTH
+        assert snap["inflight_depth"] == 0
 
     def test_probe_down_proactive_exclusion(self):
         """An ops/device_health down-probe for an ordinal removes it from

@@ -2,8 +2,8 @@
 
 The compiled Mosaic kernel only runs on real TPU hardware; interpret mode
 executes the same kernel logic op-for-op on CPU, so this is the CI-side
-differential gate for ``ops.pallas_verify`` (the chip run happens in
-bench.py / the driver's BENCH step).
+differential gate for ``ops.pallas_verify`` (on the chip, ``chip_smoke.py``
+and the benchmark's known-answer check run the compiled kernel).
 
 Interpret mode dispatches every ladder iteration eagerly (~10 min for one
 batch), so the full-parity test is opt-in via COMETBFT_TPU_SLOW_TESTS=1;
@@ -26,7 +26,8 @@ from cometbft_tpu.ops import verify as ov
 pytestmark = pytest.mark.skipif(
     not os.environ.get("COMETBFT_TPU_SLOW_TESTS"),
     reason="interpret-mode Pallas is minutes-slow; set "
-    "COMETBFT_TPU_SLOW_TESTS=1 (bench.py covers the compiled kernel)",
+    "COMETBFT_TPU_SLOW_TESTS=1 (on the chip, chip_smoke.py and the "
+    "benchmark's known-answer check cover the compiled kernel)",
 )
 
 

@@ -257,6 +257,39 @@ def test_server_coalesces_same_height_queries(monkeypatch):
         server.close()
 
 
+def test_bursts_over_many_heights_equal_the_serial_proofs():
+    """Queries over many heights in paused bursts, on the device tree
+    seam: one tree build a height however many queries ask for it, none
+    shed, and every height's root and proofs (total, index, leaf hash,
+    aunts) equal to one serial ``proofs_from_byte_slices`` build."""
+    sha256_tree.set_tree_runner(sha256_tree.host_tree_runner)
+    chain = _chain(n_heights=8, txs=16)
+    heights = [1 + i % 8 for i in range(96)]
+    server = ProofServer(
+        chain.get, lambda h: None, lambda h: None, queue_cap=32
+    )
+    served = {}
+    try:
+        for start in range(0, len(heights), 32):
+            burst = heights[start : start + 32]
+            server.pause()
+            futs = [server.submit("tx", h) for h in burst]
+            server.resume()
+            for h, f in zip(burst, futs):
+                served[h] = f.result(timeout=10)
+    finally:
+        server.close()
+    snap = pstats.snapshot()
+    assert snap["shed_total"] == 0
+    assert snap["tree_builds_total"] == len(served) == 8
+    for h, (root, proofs) in served.items():
+        ref_root, ref_proofs = merkle.proofs_from_byte_slices(chain[h])
+        assert root == ref_root, h
+        assert [(p.total, p.index, p.leaf_hash, p.aunts) for p in proofs] == [
+            (p.total, p.index, p.leaf_hash, p.aunts) for p in ref_proofs
+        ], h
+
+
 def test_server_cache_hit_and_miss_accounting():
     chain = _chain()
     server = ProofServer(chain.get, lambda h: None, lambda h: None)

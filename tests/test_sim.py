@@ -1252,6 +1252,19 @@ class TestDiskFaultScenarios:
         assert res.postmortems[0]["node"] == DISK_VICTIM
         assert res.postmortems[0]["report"]["unclean_shutdown"] is True
 
+    def test_disk_full_same_seed_same_trace(self, tmp_path):
+        """The disk-full run twice with one seed: byte-identical traces
+        (the injector consumes the same rule windows on the same IO
+        sequence), the victim halted at -1 in both, at least one fatal
+        counted, the survivors at the target with no violation."""
+        a = run_scenario("disk-full", 11, root=tmp_path / "a")
+        b = run_scenario("disk-full", 11, root=tmp_path / "b")
+        assert a.trace == b.trace
+        assert a.reached and not a.violations
+        assert a.fail_stopped
+        assert all(a.heights[v] == -1 for v in a.fail_stopped)
+        assert a.storage["totals"]["fatals"] >= 1
+
     @pytest.mark.slow
     def test_disk_scenarios_deterministic(self, tmp_path):
         import json as _json
@@ -1344,6 +1357,30 @@ class TestBlocksyncScenarios:
         assert res.bsync.get("heights_synced", 0) >= 40, res.bsync
         assert res.bsync["probes"] == 0, res.bsync     # no half-open plane
         assert res.bsync["probe_passes"] == 0, res.bsync
+
+    def test_blocksync_storm_same_seed_same_trace(self, tmp_path):
+        """The blocksync storm twice with one seed: byte-identical traces
+        and pool counters, the joiner done past 40 heights through a ban
+        and a passed probe, and the fused prefetch under one dispatch a
+        synced height (the count its completion line reports)."""
+        import re
+
+        a = run_scenario("blocksync-storm", 11, root=tmp_path / "a")
+        b = run_scenario("blocksync-storm", 11, root=tmp_path / "b")
+        assert a.trace == b.trace
+        assert a.bsync == b.bsync
+        assert a.reached and b.reached and not a.violations
+        heights = a.bsync["heights_synced"]
+        assert heights >= 40, a.bsync
+        assert a.bsync["bans"] >= 1 and a.bsync["probe_passes"] >= 1
+        done = [
+            m for m in (
+                re.search(r"bsync node\d+ complete h=\d+ dispatches=(\d+)", l)
+                for l in a.trace
+            ) if m
+        ]
+        assert done, a.trace[-20:]
+        assert 1 <= int(done[-1].group(1)) < heights
 
     @pytest.mark.slow
     def test_blocksync_scenarios_deterministic(self, tmp_path):

@@ -295,6 +295,37 @@ class TestTransportPlane:
         pts, bad = transportplane.open_frames(key, 100, sealed)
         assert bad is None and pts == payloads
 
+    def test_a_stream_sealed_in_bursts_is_one_dispatch_a_burst(self):
+        """A stream of frames sealed in bursts on the device runner seam:
+        one dispatch a burst, not one a frame, every frame byte-identical
+        to the serial reference at its nonce, and the whole stream opens
+        back to its payloads burst by burst."""
+        dispatches = []
+
+        def counting(op, frames):
+            dispatches.append((op, len(frames)))
+            return chacha_aead.host_aead_runner(op, frames)
+
+        key = _key("stream-key")
+        payloads = [_payload("stream-pt-%d" % i, 300) for i in range(64)]
+        chacha_aead.set_aead_runner(counting)
+        sealed = []
+        for start in range(0, 64, 16):
+            sealed += transportplane.seal_frames(
+                key, start, payloads[start : start + 16]
+            )
+        assert dispatches == [("seal", 16)] * 4
+        cipher = aead_ref.ChaCha20Poly1305Ref(key)
+        assert sealed == [
+            cipher.encrypt(transportplane.nonce_bytes(i), p, b"")
+            for i, p in enumerate(payloads)
+        ]
+        for start in range(0, 64, 16):
+            pts, bad = transportplane.open_frames(
+                key, start, sealed[start : start + 16]
+            )
+            assert bad is None and pts == payloads[start : start + 16]
+
 
 # -- handshake admission pool -------------------------------------------------
 

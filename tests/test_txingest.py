@@ -689,6 +689,45 @@ class TestIngestCoalescer:
         assert ing.submit(_valid_ed(9)) is not None
         assert mp.size() == 4
 
+    def test_a_flood_sheds_no_consensus_verify(self, ingest_env):
+        """A flood of signed txs queued whole and flushed in bulk-class
+        batches while a consensus caller verifies votes on another thread:
+        every vote verifies, no consensus-class item is shed, and every tx
+        reaches the mempool."""
+        conn, mp = _stack(count=True)
+        n_txs = 64
+        ing = IngestCoalescer(
+            mp, batch_max=16, queue_cap=n_txs, start_thread=False
+        )
+        flood = [_valid_ed(i, tag=b"flood") for i in range(n_txs)]
+        votes = [b"consensus-vote-%d" % i for i in range(24)]
+        priv = ED_PRIVS[0]
+        pub = priv.pub_key()
+        verdicts: list = []
+        shed0 = verifysched.stats.snapshot()["shed"]["consensus"]
+
+        def voter():
+            for msg in votes:
+                verdicts.append(
+                    verifysched.verify_cached(
+                        pub, msg, priv.sign(msg),
+                        priority=verifysched.PRIO_CONSENSUS,
+                    )
+                )
+
+        th = threading.Thread(target=voter)
+        th.start()
+        try:
+            for tx in flood:
+                assert ing.submit(tx) is None  # a queue sized to the flood
+            assert ing.flush_now() == n_txs
+        finally:
+            th.join(timeout=60)
+        assert verdicts == [True] * len(votes)
+        assert verifysched.stats.snapshot()["shed"]["consensus"] == shed0
+        assert mp.size() == n_txs
+        assert conn.check_txs_calls == n_txs // 16 and conn.check_tx_calls == 0
+
 
 # ---------------------------------------------------------------------------
 # LRUTxCache (previously untested seam the coalescer leans on)

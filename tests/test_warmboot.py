@@ -4,7 +4,7 @@ matrix (docs/warm-boot.md).
 The executable seam (``ops.verify.bucket_executable``) is monkeypatched
 throughout — these tests pin the MATRIX WALK, breaker integration and
 threading, not the compiles themselves (test_aot_cache covers the cache;
-bench.py --warmboot drives the real cold/warm boots)."""
+``TestRun`` boots twice against one cache with a stand-in kernel)."""
 
 import threading
 
@@ -97,6 +97,49 @@ class TestRun:
         assert s1["warm_runs"] == s0["warm_runs"] + 1
         assert s1["shapes_warmed"] == s0["shapes_warmed"] + 2
         assert s1["shapes_pruned"] > s0["shapes_pruned"]
+
+    def test_a_second_boot_loads_every_shape_and_compiles_nothing(
+        self, monkeypatch, tmp_path
+    ):
+        """Two boots against one empty executable cache, the memos dropped
+        between them as a new process has them: the first compiles and
+        stores every shape of the matrix, the second loads every one from
+        disk and compiles none, and each shape answers as it did at the
+        first boot.  The kernel is a stand-in that compiles in well under
+        a second; the cache, the matrix walk and the statuses are real."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        monkeypatch.setenv("COMETBFT_TPU_EXEC_CACHE", str(tmp_path / "exec"))
+        monkeypatch.setenv("COMETBFT_TPU_WARMBOOT_BUCKETS", "32,64")
+        kernel = jax.jit(lambda packed: packed.sum(axis=1, dtype=jnp.uint32))
+        monkeypatch.setattr(ov, "_bucket_jitted", lambda impl: kernel)
+
+        def boot():
+            ov.reset_executable_memo()
+            compiles = warm_stats.snapshot()["compiles"]
+            report = warmboot.run()
+            compiles = warm_stats.snapshot()["compiles"] - compiles
+            answers = {}
+            for shape in report["statuses"]:
+                impl, lanes = shape.rsplit("-", 1)
+                call, _ = ov.bucket_executable(impl, int(lanes))
+                rows = ov.packed_rows(int(lanes))
+                packed = np.arange(rows * 32, dtype=np.uint8).reshape(rows, 32)
+                answers[shape] = np.asarray(call(jnp.asarray(packed))).tolist()
+            return report["statuses"], compiles, answers
+
+        try:
+            first, first_compiles, first_answers = boot()
+            second, second_compiles, second_answers = boot()
+        finally:
+            ov.reset_executable_memo()
+        assert first == {"xla-32": "compiled", "xla-64": "compiled"}
+        assert first_compiles == 2
+        assert second == {"xla-32": "hit", "xla-64": "hit"}
+        assert second_compiles == 0
+        assert second_answers == first_answers
 
     def test_compile_failure_demotes_via_breaker(self, monkeypatch):
         """A compile failure must surface through the EXISTING breaker
